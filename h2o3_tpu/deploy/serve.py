@@ -11,6 +11,7 @@ Pod-native:    ... --discover <headless-service> --cluster-size N
 from __future__ import annotations
 
 import argparse
+import os
 import signal
 import sys
 import threading
@@ -67,12 +68,7 @@ def main(argv=None):
         # multi-host: the TPU environment auto-detects slice topology.
         args.coordinator = None
 
-    import os
     import jax
-    if os.environ.get("JAX_PLATFORMS"):
-        # some images pre-import jax with a baked-in platform (e.g. a TPU
-        # plugin from sitecustomize); the env var must win for the launcher
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import h2o3_tpu
     cl = h2o3_tpu.init(coordinator=args.coordinator,
                        num_processes=args.num_processes,

@@ -37,7 +37,7 @@ from ..runtime import dkv
 _NA = {"", "na", "n/a", "nan", "null", "none", "?", "-", "NA", "NaN", "NULL", "None"}
 
 # Per-stage wall times of the most recent native-path parse on this process
-# (PROFILE.md measurement hook + test assertion surface): mmap, scan,
+# (measurement hook + test assertion surface): mmap, scan,
 # tokenize, device-dispatch, decode/typing, total.
 last_parse_stats: Dict[str, float] = {}
 

@@ -217,9 +217,8 @@ class GBM(SharedTree):
             init_host = float(f0)
         # Commit F to the replicated sharding the scan chunk outputs use:
         # an uncommitted F0 and a committed chunk-output F key DIFFERENT
-        # jit executables for the same scan program — the warmup paid a
-        # silent ~16 s recompile between chunk 1 and chunk 2 (the round-2
-        # "first-execution anomaly" decoded).
+        # jit executables for the same scan program, i.e. a silent
+        # recompile between chunk 1 and chunk 2.
         from jax.sharding import NamedSharding, PartitionSpec
         from ...runtime.cluster import cluster
         F = jax.device_put(F, NamedSharding(cluster().mesh, PartitionSpec()))

@@ -26,24 +26,19 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...runtime.cluster import cluster, ROW_AXES, ROW_AXIS
-from ...runtime.compat import shard_map
 from ...runtime.mapreduce import checked_pair, psum_shards, \
     resolve_reduce_mode
 
 
 def _row_sds(shape, dtype):
-    """ShapeDtypeStruct carrying the rows-varying VMA mark; jax<0.5 has
-    no VMA typing, where the plain struct is equivalent."""
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype,
-                                    vma=frozenset(ROW_AXES))
-    except TypeError:
-        return jax.ShapeDtypeStruct(shape, dtype)
+    """ShapeDtypeStruct carrying the rows-varying VMA mark."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(ROW_AXES))
 
 
 def _ledger(name, jitted, orig=None, **kw):
@@ -279,7 +274,7 @@ def _make_pallas_varbin_hist(L: int, F: int, bin_counts, B: int,
     nblk = (n_local + R - 1) // R
     pad_to = nblk * R
     dt = jnp.bfloat16 if precision == "bf16" else jnp.float32
-    # PROFILE.md roadmap: stream codes+leaf as int16 and stats as bf16 —
+    # stream codes+leaf as int16 and stats as bf16 —
     # halves the kernel's HBM input bytes.  The VPU cannot compare
     # sub-32-bit ints (Mosaic), so values upcast in-VMEM after the DMA;
     # int16 only when every id fits (packed bin ids < Q8, leaf < L).
@@ -435,8 +430,7 @@ def _make_einsum_hist(L: int, F: int, B: int, n_local: int, planes: int = 3):
             acc = acc + jnp.einsum("rsl,frb->slfb", PS, OH)
             return acc, None
         H0 = jnp.zeros((planes, L, F, B), jnp.float32)
-        if hasattr(jax.lax, "pcast"):     # jax<0.5 has no VMA typing
-            H0 = jax.lax.pcast(H0, ROW_AXES, to='varying')
+        H0 = jax.lax.pcast(H0, ROW_AXES, to='varying')
         H, _ = jax.lax.scan(body, H0, (codes, leaf, S))
         return H
 
@@ -553,7 +547,7 @@ def _make_subtract_level_fn(d: int, F: int, B: int, n_padded: int,
     ``H_parent_local - H_small_local`` in f32 before the cross-shard psum.
     The compaction itself is a cumsum-positioned monotonic scatter over the
     packed code/leaf/stat planes — one bandwidth-bound pass, NOT a per-row
-    gather (PROFILE.md fix #1).
+    gather.
 
     The per-shard parent histogram needed for the subtraction rides along
     as a carry: each call returns ``(H_global, H_carry)`` where ``H_carry``
@@ -596,7 +590,7 @@ def _make_subtract_level_fn(d: int, F: int, B: int, n_padded: int,
         chosen_child = jnp.stack(
             [small_is_left, ~small_is_left], axis=1).reshape(-1)   # [Lc]
         # per-row smaller-sibling flag via the MXU one-hot product —
-        # per-row gathers are poison (PROFILE.md fix #1)
+        # not a per-row gather, which serializes on the TPU
         chosen = table_lookup(
             chosen_child.astype(jnp.float32)[None], leaf, Lc)[0] > 0.5
         # dense-prefix positions; unchosen rows target the out-of-bounds
@@ -1264,8 +1258,7 @@ def _make_einsum_fine_hist(L: int, F: int, W: int, K: int, nbins: int,
             acc = acc + jnp.einsum("rsl,rfkt->slfkt", PS, OH)
             return acc, None
         H0 = jnp.zeros((3, L, F, K, W), jnp.float32)
-        if hasattr(jax.lax, "pcast"):     # jax<0.5 has no VMA typing
-            H0 = jax.lax.pcast(H0, ROW_AXES, to='varying')
+        H0 = jax.lax.pcast(H0, ROW_AXES, to='varying')
         H, _ = jax.lax.scan(body, H0, (codes, leaf, S))
         return H
 
@@ -1431,9 +1424,9 @@ def best_splits(Hist, nbins: int, reg_lambda: float, min_rows: float,
 #
 # best_splits above materializes ~15 [L, F, B] intermediates (cumsums, both
 # NA-direction gain planes, child stats) through HBM every level — at bench
-# shape that read-back is ~5 ms/level (PROFILE.md round 6), comparable to
-# the histogram kernel itself below the root.  The fused path replaces it
-# with a single-pass Pallas kernel that reads the [3, L, F, B] block ONCE
+# shape that read-back was projected comparable to the histogram kernel
+# itself below the root (a projection; see PERF.md for what the chip
+# says).  The fused path replaces it with a single-pass Pallas kernel that reads the [3, L, F, B] block ONCE
 # into VMEM, computes cumulative G/H/C via an upper-triangular one-hot
 # matmul on the MXU, evaluates both NA-direction boundary gains, takes the
 # per-(leaf, feature) argmax on-chip, and writes only a compact

@@ -184,32 +184,18 @@ def _invalidate_compiled_caches() -> None:
     # or a rebuilt mesh could be served a choice tuned for the dead one
     from . import autotune
     autotune.invalidate("cluster_reinit")
-    for mod_name, names in (
-        ("..models.tree.hist", ("make_hist_fn", "make_fine_hist_fn",
-                                "make_varbin_hist_fn",
-                                "make_subtract_level_fn",
-                                "make_batched_level_fn",
-                                "make_sparse_level_fn",
-                                "make_batched_sparse_level_fn")),
-        ("..models.tree.shared", ("make_build_tree_fn", "make_tree_scan_fn",
-                                  "make_multinomial_scan_fn")),
-    ):
-        try:
-            import importlib
-            mod = importlib.import_module(mod_name, package=__package__)
-        except Exception:   # noqa: BLE001 — model layer optional at boot
-            continue
-        for name in names:
-            clear = getattr(getattr(mod, name, None), "cache_clear", None)
-            if clear is not None:
-                try:
-                    clear()
-                except Exception:         # noqa: BLE001
-                    pass
-    try:
-        jax.clear_caches()
-    except Exception:                     # noqa: BLE001
-        pass
+    # every cached builder of the tree engine, found by what it is and not
+    # by name: a list of names drifts (the scan-level and grid builders
+    # were missing from it, and a mesh rebuilt at the same padded row count
+    # was then handed a level program bound to the dead mesh)
+    import importlib
+    for mod_name in ("..models.tree.hist", "..models.tree.shared"):
+        mod = importlib.import_module(mod_name, package=__package__)
+        for obj in list(vars(mod).values()):
+            clear = getattr(obj, "cache_clear", None)
+            if callable(clear):
+                clear()
+    jax.clear_caches()
 
 
 def publish_mesh_gauges(cl: "Cluster | None" = None) -> None:
@@ -225,6 +211,22 @@ def publish_mesh_gauges(cl: "Cluster | None" = None) -> None:
     for axis, size in cl.mesh.shape.items():
         obs.set_gauge("mesh_shape", size, axis=axis)
     obs.set_gauge("mesh_shape", cl.n_devices, axis="total")
+
+
+def _place_compile_cache() -> None:
+    """Point JAX's persistent compilation cache at a fixed directory.
+
+    The cache key includes the directory, so it must not move between
+    runs: with ``JAX_COMPILATION_CACHE_DIR`` set the choice is entirely
+    JAX's (nothing is configured here); otherwise the cache lives in
+    ``.jax_cache`` beside the package, i.e. at the root of the checkout.
+    """
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    jax.config.update("jax_compilation_cache_dir",
+                      os.path.join(root, ".jax_cache"))
 
 
 def init(devices=None, model_axis: int | None = None,
@@ -245,6 +247,8 @@ def init(devices=None, model_axis: int | None = None,
     """
     global _cluster
     with _lock:
+        if _cluster is None:
+            _place_compile_cache()      # before the first compile
         if _cluster is not None:
             if coordinator is not None:
                 raise RuntimeError(
@@ -288,13 +292,8 @@ def init(devices=None, model_axis: int | None = None,
             # like the multiprocess tests may have initialized already).
             # num_processes=None stays valid: the TPU environment
             # auto-detects the slice topology.
-            try:
-                already = jax.distributed.is_initialized()
-            except AttributeError:      # older jax: private-state probe
-                from jax._src import distributed as _dist
-                already = getattr(_dist.global_state, "client",
-                                  None) is not None
-            if num_processes != 1 and not already:
+            if num_processes != 1 \
+                    and not jax.distributed.is_initialized():
                 jax.distributed.initialize(coordinator_address=coordinator,
                                            num_processes=num_processes,
                                            process_id=process_id)

@@ -45,7 +45,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from .cluster import CHIP_AXIS, HOST_AXIS, ROW_AXES, ROW_AXIS, cluster
-from .compat import shard_map
+from jax import shard_map
 
 REDUCE_MODES = ("flat", "hier", "check")
 

@@ -657,7 +657,7 @@ def network_test(sizes=(1_024, 1_048_576, 16_777_216)) -> List[Dict]:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from .cluster import CHIP_AXIS, HOST_AXIS, ROW_AXES, ROW_AXIS, cluster
-    from .compat import shard_map
+    from jax import shard_map
 
     cl = cluster()
     rows = cl.n_row_shards

@@ -11,11 +11,14 @@ at first request, and their flops/bytes are already Prometheus series.
 
 Implementations mirror the hist.py convention:
 
-* ``impl="xla"`` — gather-based twin, the off-TPU oracle (CPU/GPU).
-* ``impl="pallas"`` — batch-tiled Mosaic kernel, node planes in VMEM;
-  real-chip validation is a carry-over acceptance gate like the other
-  TPU kernels (``pallas_interpret`` pins interpret mode for CI).
-* ``impl="auto"`` — pallas on TPU, xla elsewhere.
+* ``impl="xla"`` — gather-based traversal; what ``"auto"`` resolves to
+  on every backend (``autotune.resolve_serve_impl``).
+* ``impl="pallas"`` — batch-tiled Mosaic kernel, node planes in VMEM.
+  It runs on a ``tpu`` backend only and raises anywhere else; today
+  Mosaic refuses its 1-D gathers, so on the chip it fails at compile
+  time with the compiler's own error.
+* ``impl="pallas_interpret"`` — the same kernel in interpret mode, the
+  parity twin the tests run.
 
 ``PackedScorer.score(..., score_mode=...)`` mirrors the
 ``hist_mode``/``split_mode`` knob convention: ``"packed"`` runs the
@@ -108,8 +111,12 @@ def _traverse_impl(impl: str, depth: int, R: int, F: int, B: int):
     if impl == "xla":
         return functools.partial(_traverse_xla, depth=depth)
     if impl in ("pallas", "pallas_interpret"):
-        interpret = impl == "pallas_interpret" or \
-            jax.default_backend() != "tpu"
+        interpret = impl == "pallas_interpret"
+        if not interpret and jax.default_backend() != "tpu":
+            raise ValueError(
+                "serve impl='pallas' needs a tpu backend, found "
+                f"{jax.default_backend()!r}; use 'xla', or "
+                "'pallas_interpret' for the interpret-mode twin")
         tile_b = B if B <= 128 else 128
         while B % tile_b:
             tile_b //= 2

@@ -342,7 +342,31 @@ def test_reduce_mode_auto_off_is_hier():
 
 def test_serve_impl_auto(tuner_on):
     impl = tuner_on.resolve_serve_impl(depth=10, R=300, F=32, B=256)
-    assert impl == "xla"                 # cpu backend under the suite
+    assert impl == "xla"                 # on every backend, tpu included
     sigs = [d["signature"] for d in
             tuner_on.decision_table()["decisions"]]
     assert any(s.startswith("serve:") for s in sigs)
+
+
+# ------------------------------------------------------- peaks by device
+
+def test_peaks_table_has_the_v5e_row():
+    """Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s,
+    16 GB of HBM; keyed by the device_kind JAX reports for that chip."""
+    from h2o3_tpu.runtime import autotune
+    assert autotune._DEVICE_PEAKS["TPU v5 lite"] == (1.97e14, 8.19e11,
+                                                     1.6e10)
+    assert autotune._peaks() == autotune._DEVICE_PEAKS["cpu"]
+
+
+def test_unknown_device_kind_is_an_error(monkeypatch):
+    """A device that is not in the table is an error, not the CPU's row:
+    both the roofline seed and the grid-batch memory gate refuse it."""
+    from h2o3_tpu.runtime import autotune
+    unknown = types.SimpleNamespace(platform="tpu", device_kind="TPU v9")
+    monkeypatch.setattr(autotune, "_device", lambda: unknown)
+    with pytest.raises(ValueError, match="TPU v9"):
+        autotune._peaks()
+    with pytest.raises(ValueError, match="TPU v9"):
+        autotune.resolve_grid_batch(kind="gbm", F=8, N=4096, G=4,
+                                    max_depth=5, nbins=64)

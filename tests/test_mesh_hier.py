@@ -246,6 +246,54 @@ def test_reinit_same_geometry_is_cached(cl):
     assert h2o3_tpu.init(hosts=cl.n_hosts) is h2o3_tpu.init()
 
 
+def test_reinit_drops_every_cached_tree_builder(cl):
+    """Regression (found on four real chips, PR 24): the flush went by a
+    list of builder names that had drifted, so after a re-init at the same
+    padded row count the scan-level builder handed out a program bound to
+    the dead mesh.  Every lru-cached builder of the tree engine is
+    dropped, whatever its name."""
+    from h2o3_tpu.models.tree import hist, shared
+    builders = {name: obj for mod in (hist, shared)
+                for name, obj in vars(mod).items()
+                if hasattr(obj, "cache_info")}
+    assert {"_make_scan_level_fn", "_make_batched_scan_level_fn",
+            "make_grid_scan_fn", "make_build_tree_fn"} <= set(builders)
+    hist.make_scan_level_fn(2, 3, 9, 512)
+    hist.make_subtract_level_fn(1, 3, 9, 512)
+    assert builders["_make_scan_level_fn"].cache_info().currsize == 1
+    new_hosts = 4 if cl.n_hosts != 4 else 2
+    try:
+        h2o3_tpu.init(hosts=new_hosts)
+        stale = {n: b.cache_info().currsize for n, b in builders.items()
+                 if b.cache_info().currsize}
+        assert not stale, f"builders kept programs of the dead mesh: {stale}"
+    finally:
+        h2o3_tpu.init(hosts=cl.n_hosts)
+
+
+def test_compile_cache_is_placed_from_outside_or_in_the_checkout(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: init() configures no directory and
+    leaves the cache entirely to JAX; unset: <checkout>/.jax_cache, derived
+    from the package's own location (the path is part of the cache key, so
+    it must not move).  The suite itself keeps the cache disabled."""
+    import os
+    import jax
+    from h2o3_tpu.runtime import cluster
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        cluster._place_compile_cache()
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        cluster._place_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert jax.config.jax_compilation_cache_dir == \
+            os.path.join(root, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
 def test_reinit_drops_autotune_decisions(cl):
     """Regression: _invalidate_compiled_caches must also flush the
     autotuner's per-signature mode decisions — they bind the mesh

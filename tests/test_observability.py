@@ -309,8 +309,9 @@ def test_program_passthrough_under_trace(xprof):
 
 
 def test_program_fallback_never_breaks_seam(xprof):
-    """AOT failures flip the wrapper to permanent passthrough (with an
-    xprof_fallback event) but the call still returns the answer."""
+    """Misuse of the wrapper itself flips it to permanent passthrough
+    (with an xprof_fallback event) and the call still returns the answer;
+    an error from the compiler is raised once and never retried."""
     import jax
     import jax.numpy as jnp
 
@@ -334,6 +335,21 @@ def test_program_fallback_never_breaks_seam(xprof):
              if e.get("kind") == "xprof_fallback"]
     assert {e.get("program") for e in falls} >= {"unit_nolower",
                                                  "unit_mismatch"}
+
+    # compiler refusal (here raised while lowering, as Mosaic does): it
+    # surfaces where it happens, the plain jit is not tried after it
+    attempts = []
+
+    def refused(x):
+        attempts.append(1)
+        raise NotImplementedError("Only 2D gather is supported")
+    prog3 = xprof.register_program("unit_refused", jax.jit(refused))
+    with pytest.raises(NotImplementedError, match="2D gather"):
+        prog3(jnp.ones((2,), jnp.float32))
+    assert len(attempts) == 1 and not prog3.fallback
+    assert "unit_refused" not in {
+        e.get("program") for e in obs.timeline_events(500)
+        if e.get("kind") == "xprof_fallback"}
 
 
 def test_maybe_device_sync_modes(monkeypatch):

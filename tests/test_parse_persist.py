@@ -402,8 +402,8 @@ def test_header_and_no_header_paths(cl, tmp_path, monkeypatch):
 
 
 def test_parse_stage_timings_recorded(cl, tmp_path):
-    """The native pipeline records per-stage wall times (PROFILE.md's
-    measurement surface) and observability keeps the parse record."""
+    """The native pipeline records per-stage wall times (the ingest
+    layer's measurement surface) and observability keeps the parse record."""
     from h2o3_tpu import native
     if native.load() is None:
         pytest.skip("native tokenizer unavailable")

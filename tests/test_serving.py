@@ -230,6 +230,45 @@ def test_pallas_interpret_impl_matches(cl, rng):
                                rtol=1e-5, atol=1e-6)
 
 
+def test_explicit_pallas_never_degrades_to_interpret(cl, rng):
+    """impl="pallas" means the Mosaic kernel: on a backend where it cannot
+    run it raises (no silent interpret mode); the interpret-mode twin has
+    its own name."""
+    _, fr_bin, data = _frames(rng)
+    m = GBM(response_column="y", ntrees=3, seed=1).train(fr_bin)
+    ps = PackedScorer(ScoringModel(*mojo._extract(m)), impl="pallas")
+    X = ps.featurize(_na_rows(data, rng, k=8))
+    with pytest.raises(ValueError, match="needs a tpu backend"):
+        ps.score(X)
+
+
+def test_auto_impl_records_its_choice(cl, rng, monkeypatch):
+    """impl="auto" resolves to the XLA traversal on every backend and the
+    decision shows in the autotuner's table and decision counter."""
+    from h2o3_tpu.runtime import autotune, config, observability as obs
+    monkeypatch.setenv("H2O3_TPU_AUTOTUNE", "on")
+    config.reload()
+    autotune.reset()
+    try:
+        _, fr_bin, data = _frames(rng)
+        m = GBM(response_column="y", ntrees=3, seed=1).train(fr_bin)
+        ps = PackedScorer(ScoringModel(*mojo._extract(m)))
+        assert ps.impl == "auto"
+        X = ps.featurize(_na_rows(data, rng, k=8))
+        np.testing.assert_allclose(ps.score(X),
+                                   ps.score(X, score_mode="ref"),
+                                   rtol=1e-4, atol=1e-5)
+        rows = [d for d in autotune.decision_table()["decisions"]
+                if d["signature"].startswith("serve:")]
+        assert rows and all(d["choice"] == "xla" for d in rows)
+        assert obs.counter("autotune_decisions_total", knob="serve_impl",
+                           choice="xla", source="model").value >= 1
+    finally:
+        monkeypatch.undo()
+        config.reload()
+        autotune.reset()
+
+
 def test_scoring_model_iterative_traverse(cl, rng):
     """export/scoring.py now routes _traverse through the packed walk;
     the portable predict must keep matching in-cluster predict."""

@@ -1,0 +1,212 @@
+"""Compile the main path's kernels for a DESCRIBED TPU v5e, without a chip.
+
+The TPU compiler beside JAX compiles for a topology that is described and
+not attached, so what Mosaic or XLA:TPU would refuse on the chip (a slice
+off the tiling, a kernel over its scoped VMEM, an op Mosaic does not lower)
+is refused here, at no chip time.  Interpret mode and the export-level gate
+(tests/test_mosaic_lowering.py) cannot see those.
+
+Every kernel seam chooses its branch from the platform of the live mesh's
+first device, so the module boots the cluster over one described device
+(``h2o3_tpu.init(devices=...)``), which steers the real ``tpu`` branches
+with no option in the program, and puts the CPU test mesh back afterwards.
+Shapes are the airlines-10M geometry bench.py and chip_smoke.py train on.
+
+The whole-program compiles (tens of seconds each) are
+``tools/tpu_compile_rehearsal.py``.  Nothing here runs on a device: a
+compile that passes is not a chip run.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+import h2o3_tpu
+
+BIN_COUNTS = (21, 12, 7, 256, 256, 22, 256, 256)
+F, NBINS = 8, 256
+B = NBINS + 1
+N = 10_000_000
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A described-chip executable is written to the persistent cache but
+    cannot be read back without a chip; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _boot(devices):
+    """Cluster over described ``devices``; yields (cluster, sds)."""
+    cl = h2o3_tpu.init(devices=list(devices), hosts=1)
+    assert cl.describe()["platform"] == "tpu"
+
+    def sds(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(cl.mesh, P(*spec)))
+    return cl, sds
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, no_persistent_cache):
+    from h2o3_tpu.runtime.cluster import ROW_AXIS
+    cl, sds = _boot(topo.devices[:1])
+    yield cl, sds, ROW_AXIS
+    h2o3_tpu.init(devices=jax.devices())       # the CPU test mesh again
+
+
+def _hist_operands(sds, rows, n, code_dtype):
+    return (sds((F, n), code_dtype, None, rows), sds((n,), jnp.int32, rows),
+            sds((n,), jnp.float32, rows), sds((n,), jnp.float32, rows),
+            sds((n,), jnp.float32, rows))
+
+
+def _compile(fn, *operands):
+    compiled = getattr(fn, "jitted", fn).lower(*operands).compile()
+    return compiled, compiled.as_text()
+
+
+@pytest.mark.parametrize("L", [1, 32])
+def test_uniform_hist_kernel_compiles(one_chip, L):
+    from h2o3_tpu.models.tree.hist import make_hist_fn
+    _, sds, rows = one_chip
+    _, text = _compile(make_hist_fn(L, F, B, N),
+                       *_hist_operands(sds, rows, N, jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("L,precision", [(1, "bf16"), (32, "bf16"),
+                                         (8, "f32")])
+def test_varbin_hist_kernel_compiles(one_chip, L, precision):
+    """The packed per-feature kernel: int16 codes, bf16 or f32 stats."""
+    from h2o3_tpu.models.tree.hist import make_varbin_hist_fn
+    _, sds, rows = one_chip
+    fn = make_varbin_hist_fn(L, F, BIN_COUNTS, B, N, precision=precision)
+    _, text = _compile(fn, *_hist_operands(sds, rows, N, jnp.int16))
+    assert "tpu_custom_call" in text
+
+
+def test_kernels_compile_at_their_vmem_bounds(one_chip):
+    """The widest levels each kernel is still given (hist.py's 12 MiB /
+    3L <= 2048 and shared.py's 3L <= 1024 gates): the uniform kernel's
+    stationary-tile variant at L=512 with a shrunk row block, and the
+    varbin kernel at L=256."""
+    from h2o3_tpu.models.tree.hist import make_hist_fn, make_varbin_hist_fn
+    _, sds, rows = one_chip
+    f = 6
+    _, text = _compile(make_hist_fn(512, f, B, N),
+                       sds((f, N), jnp.int32, None, rows),
+                       *_hist_operands(sds, rows, N, jnp.int32)[1:])
+    assert "tpu_custom_call" in text
+    _, text = _compile(make_varbin_hist_fn(256, F, BIN_COUNTS, B, N),
+                       *_hist_operands(sds, rows, N, jnp.int16))
+    assert "tpu_custom_call" in text
+
+
+def test_deep_level_falls_to_einsum_by_its_size_bound(one_chip):
+    """3L > 2048: the A-build intermediates cannot fit scoped VMEM, which
+    is the bound the threshold expresses; the portable path compiles."""
+    from h2o3_tpu.models.tree.hist import make_hist_fn
+    _, sds, rows = one_chip
+    n = 1 << 20
+    _, text = _compile(make_hist_fn(1024, 3, 33, n),
+                       *(sds((3, n), jnp.int32, None, rows),
+                         *_hist_operands(sds, rows, n, jnp.int32)[1:]))
+    assert "tpu_custom_call" not in text
+
+
+def test_hier_fine_kernel_compiles(one_chip):
+    from h2o3_tpu.models.tree.hist import make_fine_hist_fn
+    _, sds, rows = one_chip
+    W, K, L = 16, 2, 4
+    _, text = _compile(make_fine_hist_fn(L, F, W, K, NBINS, N),
+                       *_hist_operands(sds, rows, N, jnp.int32),
+                       sds((L, F, K), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("L", [32, 7 * 32])
+def test_split_records_kernel_compiles(one_chip, L):
+    """The fused split search's winner-records kernel at the deepest level
+    of a depth-6 build, alone and with 7 class trees in the leaf axis."""
+    from h2o3_tpu.models.tree.hist import split_records
+    _, sds, _ = one_chip
+    fn = jax.jit(functools.partial(
+        split_records, nbins=NBINS, reg_lambda=1.0, min_rows=1.0,
+        reg_alpha=0.0, gamma=0.0, min_child_weight=1.0))
+    _, text = _compile(fn, sds((3, L, F, B), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+def test_serve_traversal_auto_resolves_to_compiles(one_chip):
+    """What ``impl="auto"`` resolves to compiles for the chip; the Pallas
+    traversal is refused by Mosaic, which is why "auto" is not it."""
+    from h2o3_tpu.runtime import autotune
+    from h2o3_tpu.serving import kernel
+    _, sds, _ = one_chip
+    depth, trees, batch = 6, 100, 256
+    nodes = trees * (2 ** (depth + 1) - 1)
+    operands = (sds((nodes,), jnp.int32), sds((nodes,), jnp.float32),
+                sds((trees,), jnp.int32), sds((batch, F), jnp.float32))
+    impl = autotune.resolve_serve_impl(depth=depth, R=trees, F=F, B=batch)
+    _compile(jax.jit(kernel._traverse_impl(impl, depth, trees, F, batch)),
+             *operands)
+    pallas = kernel._make_pallas_traverse(depth, trees, F, 128)
+    with pytest.raises(NotImplementedError, match="gather"):
+        _compile(jax.jit(pallas), *operands)
+
+
+def test_glm_irls_path_compiles_at_higgs_shape(one_chip):
+    """The whole IRLS path program at 10M x 28 (+ intercept) fits one
+    chip's 16 GB with room for the frame beside it."""
+    from h2o3_tpu.models import glm
+    _, sds, rows = one_chip
+    p = 29
+    fam = glm._make_family("binomial", glm.GLMParameters())
+    vec, coef = sds((N,), jnp.float32, rows), sds((p,), jnp.float32)
+    scalar = sds((), jnp.float32)
+    compiled, _ = _compile(
+        glm._make_path_runner(fam, False, 50),
+        sds((N, p), jnp.float32, rows, None), vec, vec, vec,
+        sds((1,), jnp.float32), scalar, coef, coef, scalar, scalar)
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 8e9
+
+
+def test_four_chip_histogram_has_kernel_and_all_reduce(topo,
+                                                       no_persistent_cache):
+    """The row-sharded mesh: each chip runs the kernel on its rows and the
+    histograms meet in an all-reduce."""
+    from h2o3_tpu.models.tree.hist import make_varbin_hist_fn
+    from h2o3_tpu.runtime.cluster import ROW_AXIS
+    cl, sds = _boot(topo.devices)
+    try:
+        assert cl.n_row_shards == 4
+        n = cl.pad_rows(N)
+        compiled, text = _compile(
+            make_varbin_hist_fn(32, F, BIN_COUNTS, B, n),
+            *_hist_operands(sds, ROW_AXIS, n, jnp.int16))
+        assert "tpu_custom_call" in text and "all-reduce" in text
+        ma = compiled.memory_analysis()
+        assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 16e9
+    finally:
+        h2o3_tpu.init(devices=jax.devices())

@@ -11,8 +11,8 @@ VMEM copy of the [Q8, R] one-hot per row block; these variants remove it:
              then ONE dot
 
 All share the stat/A build; parity is asserted against the shipped kernel
-before timing.  Timing uses PROFILE.md methodology (fori_loop of REPS
-dependent calls in one jit, small-fetch sync).
+before timing.  Timing uses bench_util.py methodology (fori_loop of REPS
+dependent calls in one jit, block_until_ready sync).
 
 Usage (chip): python tools/kernel_lab.py
 CPU check:    JAX_PLATFORMS=cpu H2O3_LAB_ROWS=100000 python tools/kernel_lab.py
@@ -33,9 +33,6 @@ B = NBINS + 1
 
 
 def main():
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl

@@ -1,0 +1,217 @@
+"""Compile the whole device programs for a DESCRIBED TPU v5e, without a chip.
+
+The TPU compiler is installed beside JAX and compiles for a topology that is
+described and not attached (``jax.experimental.topologies``).  Every kernel
+seam picks its branch from ``cluster().mesh.devices.flat[0].platform`` and
+``init(devices=...)`` accepts any device list, so booting the cluster over
+the described devices steers the real ``tpu`` branches from a CPU-only host;
+each program is then lowered on ``ShapeDtypeStruct``s that carry
+``NamedSharding``s on that mesh and compiled.  What Mosaic or XLA:TPU would
+refuse on the chip, it refuses here.  Nothing runs: this says nothing about
+results or times on the device.
+
+One JSON line per program: seconds to lower + compile ON THIS HOST, the
+``tpu_custom_call`` and ``all-reduce`` counts in the compiled text, and the
+compiler's memory analysis (bytes per device).
+
+    JAX_PLATFORMS=cpu python tools/tpu_compile_rehearsal.py            # all
+    JAX_PLATFORMS=cpu python tools/tpu_compile_rehearsal.py tree_build glm_path
+    JAX_PLATFORMS=cpu python tools/tpu_compile_rehearsal.py --chips 4 tree_build
+
+The cheap single-kernel compiles live in tests/test_tpu_compile.py (tier-1);
+these take tens of seconds each and are run by hand before a chip call.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# bench.py's airlines-10M geometry (fit_bins on make_airlines_like)
+BIN_COUNTS = (21, 12, 7, 256, 256, 22, 256, 256)
+F, NBINS, DEPTH = 8, 256, 6
+N_AIRLINES = 10_000_000
+N_HIGGS, P_HIGGS = 10_000_000, 29          # 28 numerics + intercept
+
+# scalar operands of the tree programs, in call order: reg_lambda, min_rows,
+# min_split_improvement, learn_rate, col_sample_rate, reg_alpha, gamma,
+# min_child_weight (python floats trace as weak f32 scalars)
+SCALARS = (1.0, 1.0, 1e-5, 0.3, 1.0, 0.0, 0.0, 1.0)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("programs", nargs="*",
+                    help="program names (default: all)")
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
+    args = ap.parse_args(argv)
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import h2o3_tpu
+    from h2o3_tpu.models import glm as glm_mod
+    from h2o3_tpu.models.tree import hist, shared
+    from h2o3_tpu.runtime import autotune
+    from h2o3_tpu.runtime.cluster import ROW_AXIS
+    from h2o3_tpu.serving import kernel as serve_kernel
+
+    # a described-chip executable cannot be read back from the persistent
+    # cache without a chip; keep the rehearsal out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    cl = h2o3_tpu.init(devices=list(topo.devices[:args.chips]), hosts=1)
+    dev = cl.mesh.devices.flat[0]
+    mesh = cl.mesh
+    rows, rep = NamedSharding(mesh, P(ROW_AXIS)), NamedSharding(mesh, P())
+    cols = NamedSharding(mesh, P(None, ROW_AXIS))
+    mat = NamedSharding(mesh, P(ROW_AXIS, None))
+
+    def sds(shape, dtype, sharding=rep):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def tree_args(n, f, nk=1):
+        """Operands of a ``make_build_tree_fn`` program."""
+        lead = (nk,) if nk > 1 else ()
+        row_k = NamedSharding(mesh, P(None, ROW_AXIS)) if nk > 1 else rows
+        return (sds((f, n), jnp.int32, cols),
+                sds(lead + (n,), jnp.float32, row_k),
+                sds(lead + (n,), jnp.float32, row_k),
+                sds((n,), jnp.float32, rows),
+                sds((f, NBINS), jnp.float32),
+                sds(lead + (2,), jnp.uint32),
+                *SCALARS[:5], sds(lead + (f,), jnp.bool_), *SCALARS[5:])
+
+    n = cl.pad_rows(N_AIRLINES)
+    knobs = dict(hist_mode="subtract", split_mode="fused")
+
+    def tree_build():
+        fn = shared.make_build_tree_fn(DEPTH, NBINS, F, n, "bf16",
+                                       bin_counts=BIN_COUNTS, **knobs)
+        return fn, tree_args(n, F)
+
+    def tree_build_scan():
+        fn = shared.make_build_tree_fn(DEPTH, NBINS, F, n, "bf16",
+                                       bin_counts=BIN_COUNTS,
+                                       tree_program="scan", **knobs)
+        return fn, tree_args(n, F)
+
+    def tree_build_k7():
+        n1 = cl.pad_rows(1_000_000)
+        fn = shared.make_build_tree_fn(DEPTH, NBINS, F, n1, "bf16",
+                                       bin_counts=BIN_COUNTS, nk=7, **knobs)
+        return fn, tree_args(n1, F, nk=7)
+
+    def tree_scan():
+        """The chunk program the 10M-row XGBoost fit dispatches, under the
+        knobs the autotuner resolves for that signature."""
+        import types
+        p = types.SimpleNamespace(
+            hist_mode="auto", split_mode="auto", hist_layout="auto",
+            tree_program="auto", sparse_depth_threshold=8,
+            max_depth=DEPTH, nbins=NBINS)
+        k = autotune.resolve_tree_knobs(p, kind="xgboost", F=F, N=n)
+        print(json.dumps({"autotune": {
+            "hist_mode": k.hist_mode, "split_mode": k.split_mode,
+            "hist_layout": k.hist_layout, "tree_program": k.tree_program,
+            "sources": k.sources}}), flush=True)
+        fn = shared.make_tree_scan_fn(
+            "bernoulli", 1.5, 0.5, 0.9, DEPTH, NBINS, F, n, "bf16", 1.0, 1.0,
+            bin_counts=BIN_COUNTS, hist_mode=k.hist_mode,
+            split_mode=k.split_mode, hist_layout=k.hist_layout,
+            sparse_depth_threshold=k.sparse_depth_threshold,
+            tree_program=k.tree_program)
+        return fn, (sds((F, n), jnp.int32, cols),
+                    sds((n,), jnp.float32, rows), sds((n,), jnp.float32, rows),
+                    sds((n,), jnp.float32), sds((F, NBINS), jnp.float32),
+                    sds((2,), jnp.uint32), 0, 5, *SCALARS, 0)
+
+    def sparse_level():
+        Ap, A = 128, 256
+        fn = hist.make_sparse_level_fn(Ap, A, F, NBINS + 1, n,
+                                       bin_counts=BIN_COUNTS)
+        return fn, (sds((F, n), jnp.int16, cols),
+                    sds((n,), jnp.int32, rows),
+                    *(sds((n,), jnp.float32, rows),) * 3,
+                    sds((cl.n_row_shards, 3, Ap, F, NBINS + 1), jnp.float32,
+                        NamedSharding(mesh, P(ROW_AXIS))),
+                    sds((A,), jnp.int32))
+
+    def grid_scan():
+        G, n1 = 4, cl.pad_rows(1_000_000)
+        fn = shared.make_grid_scan_fn(G, "bernoulli", 1.5, 0.5, 0.9, DEPTH,
+                                      NBINS, F, n1, "bf16")
+        g = sds((G,), jnp.float32)
+        return fn, (sds((F, n1), jnp.int32, cols),
+                    sds((n1,), jnp.float32, rows),
+                    sds((n1,), jnp.float32, rows),
+                    sds((G, n1), jnp.float32), sds((F, NBINS), jnp.float32),
+                    sds((G, 2), jnp.uint32), 0, 5,
+                    g, g, g, g, g, g, g, sds((G,), jnp.bool_), g, g, g)
+
+    def serve_xla():
+        depth, trees, batch = DEPTH, 100, 256
+        impl = autotune.resolve_serve_impl(depth=depth, R=trees, F=F, B=batch)
+        nn = trees * (2 ** (depth + 1) - 1)
+        fn = jax.jit(serve_kernel._traverse_impl(impl, depth, trees, F,
+                                                 batch))
+        return fn, (sds((nn,), jnp.int32), sds((nn,), jnp.float32),
+                    sds((trees,), jnp.int32), sds((batch, F), jnp.float32))
+
+    def glm_path():
+        fam = glm_mod._make_family("binomial", glm_mod.GLMParameters())
+        fn = glm_mod._make_path_runner(fam, False, 50)
+        nh = cl.pad_rows(N_HIGGS)
+        vec = sds((nh,), jnp.float32, rows)
+        return fn, (sds((nh, P_HIGGS), jnp.float32, mat), vec, vec, vec,
+                    sds((1,), jnp.float32), sds((), jnp.float32),
+                    sds((P_HIGGS,), jnp.float32), sds((P_HIGGS,), jnp.float32),
+                    sds((), jnp.float32), sds((), jnp.float32))
+
+    programs = {f.__name__: f for f in (
+        tree_build, tree_build_scan, tree_build_k7, tree_scan, sparse_level,
+        grid_scan, serve_xla, glm_path)}
+    unknown = [p for p in args.programs if p not in programs]
+    if unknown:
+        ap.error(f"unknown program(s) {unknown}; known: {sorted(programs)}")
+
+    failed = 0
+    for name in args.programs or list(programs):
+        rec = {"program": name, "platform": dev.platform,
+               "device_kind": dev.device_kind, "devices": cl.n_devices,
+               "attached": False}
+        t0 = time.perf_counter()
+        try:
+            fn, operands = programs[name]()
+            compiled = getattr(fn, "jitted", fn).lower(*operands).compile()
+        except Exception as e:              # noqa: BLE001 — report, go on
+            rec.update(ok=False, error=f"{type(e).__name__}: {e}"[:600])
+            failed += 1
+        else:
+            text = compiled.as_text()
+            ma = compiled.memory_analysis()
+            rec.update(ok=True,
+                       host_compile_s=round(time.perf_counter() - t0, 1),
+                       tpu_custom_calls=text.count("tpu_custom_call"),
+                       all_reduces=text.count("all-reduce("),
+                       temp_gb=round(ma.temp_size_in_bytes / 1e9, 3),
+                       argument_gb=round(ma.argument_size_in_bytes / 1e9, 3),
+                       output_gb=round(ma.output_size_in_bytes / 1e9, 3))
+        print(json.dumps(rec), flush=True)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

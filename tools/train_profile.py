@@ -1,8 +1,8 @@
 """Stage-level timing of the bench.py XGBoost train path on the chip.
 
-bench.py r04 measured 1.69 trees/s end-to-end while bench_pieces.py's
-kernel sum projects ~5/s — this script finds the missing ~380 ms/tree by
-timing each stage of the exact train() pipeline separately:
+Where end-to-end trees/s falls short of what bench_pieces.py's kernel sum
+would give, this script finds the rest by timing each stage of the exact
+train() pipeline separately:
 
   ingest     Frame.from_numpy (host->device push of the 10M x 9 table)
   fit_bins   quantile edge fit + 10M x 8 quantization to codes
@@ -24,9 +24,6 @@ N_ROWS = int(os.environ.get("H2O3_TP_ROWS", 10_000_000))
 
 
 def main():
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import jax
     import jax.numpy as jnp
 
