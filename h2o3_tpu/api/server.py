@@ -1196,10 +1196,12 @@ class Api:
 
     def profiler_stop(self, **kw) -> dict:
         """POST /3/Profiler/stop — stop the live device trace (no-op when
-        none is running)."""
+        none is running).  ``idle_by_span`` is the trace's first reading:
+        the device's idle seconds by the program span that was open."""
         from ..runtime import observability as obs
         stopped = obs.stop_device_trace()
-        return {"stopped": stopped, "active": obs.profiler_active()}
+        return {"stopped": stopped, "active": obs.profiler_active(),
+                "idle_by_span": obs.profiler_summary() if stopped else None}
 
     def profiler_memory(self) -> bytes:
         """GET /3/Profiler/memory — pprof-format device memory profile

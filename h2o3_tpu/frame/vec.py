@@ -227,8 +227,10 @@ class Vec:
                 vals = (vals - time_base) / 1000.0
             buf = np.full(padded, np.nan, dtype=np.float32)
             buf[:n] = vals.astype(np.float32)
+        from ..runtime import observability as obs
         from ..runtime.cluster import put_sharded
         data = put_sharded(buf, cl.row_sharding)
+        obs.inc("transfer_bytes_total", buf.nbytes, dir="h2d")
         return Vec(data, vtype, n, domain=domain, host_data=host_data,
                    time_base=time_base or 0.0)
 

@@ -26,6 +26,7 @@ import numpy as np
 
 from ..frame.frame import Frame
 from ..runtime import dkv
+from ..runtime import observability as obs
 from ..runtime.job import Job
 from .base import Model, ModelBuilder, Parameters
 from .datainfo import DataInfo
@@ -486,13 +487,14 @@ class GLM(ModelBuilder):
              valid: Optional[Frame]) -> GLMModel:
         p: GLMParameters = self.params
         fam_name = self._resolve_family(di)
-        X = di.make_matrix(frame)
-        y = di.response(frame)
-        w = di.weights(frame)
-        y = jnp.nan_to_num(y)
-        offset = di.offsets(frame)
-        offset = offset if offset is not None else jnp.zeros_like(y)
-        n = float(jnp.sum(w))
+        with obs.span("glm.matrix"):
+            X = di.make_matrix(frame)
+            y = di.response(frame)
+            w = di.weights(frame)
+            y = jnp.nan_to_num(y)
+            offset = di.offsets(frame)
+            offset = offset if offset is not None else jnp.zeros_like(y)
+            n = float(jnp.sum(w))
         P = di.nfeatures
         penalize = np.ones(P)
         if di.add_intercept:
@@ -735,8 +737,9 @@ class GLM(ModelBuilder):
         P = di.nfeatures
         beta = np.zeros(P, dtype=np.float64)
         if di.add_intercept:
-            eta0 = fam.init_eta(y, w)
-            beta[-1] = float(eta0[0])
+            with obs.span("glm.path", part="init"):
+                eta0 = fam.init_eta(y, w)
+                beta[-1] = float(eta0[0])      # a fetch: waits for the device
         if getattr(self, "_nonneg", None) is None:
             # every fit (single lambda included) runs as one fused device
             # program — the host loop below pays a device->host round trip
@@ -746,14 +749,23 @@ class GLM(ModelBuilder):
             # a while_loop per IRLS step that a plain solve doesn't.
             from ..runtime import failure
             failure.maybe_inject("glm_lambda")
-            runner = _make_path_runner(
-                fam, l1_mode=p.alpha > 0 and float(np.max(lambdas)) > 0,
-                max_iter=p.max_iterations)
-            betas, devs, iters, gram_fin, dev_fin = jax.device_get(runner(
-                X, y, w, offset, jnp.asarray(lambdas, jnp.float32),
-                jnp.float32(p.alpha), jnp.asarray(penalize, jnp.float32),
-                jnp.asarray(beta, jnp.float32), jnp.float32(n),
-                jnp.float32(p.beta_epsilon)))
+            with obs.span("glm.path", lambdas=len(lambdas)):
+                runner = _make_path_runner(
+                    fam, l1_mode=p.alpha > 0 and float(np.max(lambdas)) > 0,
+                    max_iter=p.max_iterations)
+                out = runner(
+                    X, y, w, offset, jnp.asarray(lambdas, jnp.float32),
+                    jnp.float32(p.alpha), jnp.asarray(penalize, jnp.float32),
+                    jnp.asarray(beta, jnp.float32), jnp.float32(n),
+                    jnp.float32(p.beta_epsilon))
+            with obs.span("glm.wait"):
+                # the wait for the device and the fetch of its few KB in
+                # one call, as device_get queues the copies behind the
+                # program
+                fetched = jax.device_get(out)
+                obs.inc("transfer_bytes_total",
+                        sum(a.nbytes for a in fetched), dir="d2h")
+            betas, devs, iters, gram_fin, dev_fin = fetched
             hist = [{"lambda": float(lam), "iteration": int(iters[li]),
                      "deviance": float(devs[li]), "delta": float("nan")}
                     for li, lam in enumerate(lambdas)]
@@ -851,6 +863,7 @@ class GLM(ModelBuilder):
         return model
 
     # ------------------------------------------------------------ finalize
+    @obs.span("glm.finalize")       # a span is a decorator too: one per call
     def _finalize(self, model, di, beta_std, fam_name, X, y, w, offset, n,
                   deviance, hist, lam, frame, valid, gram_last=None):
         p: GLMParameters = self.params

@@ -30,6 +30,7 @@ import numpy as np
 
 from ...frame.frame import Frame
 from ...frame.vec import T_CAT
+from ...runtime import observability as obs
 
 
 @dataclasses.dataclass
@@ -208,11 +209,33 @@ def fit_bins(frame: Frame, features: List[str], nbins: int = 64,
     domains = [v.domain if c else None for v, c in zip(vecs, is_cat)]
     num_idx = [f for f, c in enumerate(is_cat) if not c]
 
-    # --- sketch: one device program over the stacked numeric block.
-    # Exact quantiles when the stack fits a device budget; above it, a
-    # strided row subsample (the old host sketch's ``sample`` bound, kept
-    # on device) caps sort memory — rows are unordered, so a stride is as
-    # good a sample as a uniform draw.
+    with obs.span("binning.sketch", rows=n, columns=len(num_idx)):
+        num_edges = _sketch_edges(vecs, num_idx, n, nbins, sample, weights,
+                                  htype, rng)
+
+    edges_list = []
+    for f, cat in enumerate(is_cat):
+        if cat:
+            card = vecs[f].cardinality
+            edges_list.append(np.arange(
+                0.5, min(card, nbins) - 0.5 + 1e-9, 1.0, dtype=np.float32))
+        else:
+            edges_list.append(num_edges[f])
+
+    with obs.span("binning.encode", rows=n, columns=len(features)):
+        codes = encode_bins(frame, features, edges_list, is_cat, nbins)
+    return BinnedFrame(codes=codes, edges=edges_list, names=list(features),
+                       is_cat=is_cat, cat_domains=domains, nbins=nbins)
+
+
+def _sketch_edges(vecs, num_idx, n: int, nbins: int, sample: int, weights,
+                  htype: str, rng) -> dict:
+    """``fit_bins``' sketch: numeric feature index -> ascending edges, from
+    one device program over the stacked numeric block and the host fetch of
+    its small result.  Exact quantiles when the stack fits a device
+    budget; above it, a strided row subsample (the old host sketch's
+    ``sample`` bound, kept on device) caps sort memory — rows are
+    unordered, so a stride is as good a sample as a uniform draw."""
     num_edges: dict = {}
     if num_idx:
         full_padded = int(vecs[num_idx[0]].data.shape[0])
@@ -275,19 +298,7 @@ def fit_bins(frame: Frame, features: List[str], nbins: int = 64,
                 e = np.unique(edges_q[i].astype(np.float32))
                 e = e[np.isfinite(e)]
             num_edges[f] = e
-
-    edges_list = []
-    for f, cat in enumerate(is_cat):
-        if cat:
-            card = vecs[f].cardinality
-            edges_list.append(np.arange(
-                0.5, min(card, nbins) - 0.5 + 1e-9, 1.0, dtype=np.float32))
-        else:
-            edges_list.append(num_edges[f])
-
-    codes = encode_bins(frame, features, edges_list, is_cat, nbins)
-    return BinnedFrame(codes=codes, edges=edges_list, names=list(features),
-                       is_cat=is_cat, cat_domains=domains, nbins=nbins)
+    return num_edges
 
 
 def edges_matrix(edges_list, nbins: int) -> np.ndarray:

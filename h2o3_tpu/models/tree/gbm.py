@@ -26,6 +26,7 @@ import numpy as np
 
 from ...frame.frame import Frame
 from ...runtime import dkv
+from ...runtime import observability as obs
 from ...runtime.job import Job
 from ..datainfo import DataInfo
 from ..distributions import make_distribution, Multinomial
@@ -405,7 +406,6 @@ class GBM(SharedTree):
                 if sparse_deep:
                     # kill/resume while node-sparse deep levels are live
                     failure.maybe_inject("deep_level")
-                from ...runtime import observability as obs
                 from ...runtime import xprof
                 t0 = time.perf_counter()
                 with obs.span("tree_chunk", job=job.key, chunk=chunk_no,
@@ -440,11 +440,12 @@ class GBM(SharedTree):
                                         maximize):
                     break
             from .shared import TreeListMulti
-            stacks = [StackedTrees.concat(ch) for ch in chunks_k]
-            return self._finalize_fused(
-                model, di, dist, F, y, w, valid, history, binned, init_host,
-                stacks[0].ntrees, stacked=stacks,
-                trees=TreeListMulti(stacks))
+            with obs.span("tree.finalize"):
+                stacks = [StackedTrees.concat(ch) for ch in chunks_k]
+                return self._finalize_fused(
+                    model, di, dist, F, y, w, valid, history, binned,
+                    init_host, stacks[0].ntrees, stacked=stacks,
+                    trees=TreeListMulti(stacks))
 
         if fused:
             # fast path: scan a whole scoring interval of trees per dispatch
@@ -471,7 +472,6 @@ class GBM(SharedTree):
                 if sparse_deep:
                     # kill/resume while node-sparse deep levels are live
                     failure.maybe_inject("deep_level")
-                from ...runtime import observability as obs
                 from ...runtime import xprof
                 t0 = time.perf_counter()
                 with obs.span("tree_chunk", job=job.key, chunk=chunk_no,
@@ -500,10 +500,12 @@ class GBM(SharedTree):
                                         history, vstate, metric_name,
                                         maximize):
                     break
-            stacked = StackedTrees.concat(chunks)
-            return self._finalize_fused(
-                model, di, dist, F, y, w, valid, history, binned, init_host,
-                stacked.ntrees, stacked=stacked, trees=TreeList(stacked))
+            with obs.span("tree.finalize"):
+                stacked = StackedTrees.concat(chunks)
+                return self._finalize_fused(
+                    model, di, dist, F, y, w, valid, history, binned,
+                    init_host, stacked.ntrees, stacked=stacked,
+                    trees=TreeList(stacked))
 
         if prior is not None:
             # materialized per-tree list continuation (DART / multinomial).
@@ -670,14 +672,16 @@ class GBM(SharedTree):
                                              p.stopping_tolerance, maximize):
                         break
 
-        model.output["trees"] = trees
-        model.output["init_score"] = init_host
-        model.output["ntrees_trained"] = len(trees)
-        model.output["edges"] = binned.edges
-        model.scoring_history = history
-        # F already holds the final training scores — no tree re-traversal
-        model.training_metrics = make_metrics(
-            di, self._scores_to_preds(F, dist, di), y, w)
-        if valid is not None:
-            model.validation_metrics = model.model_performance(valid)
+        with obs.span("tree.finalize"):
+            model.output["trees"] = trees
+            model.output["init_score"] = init_host
+            model.output["ntrees_trained"] = len(trees)
+            model.output["edges"] = binned.edges
+            model.scoring_history = history
+            # F already holds the final training scores — no tree
+            # re-traversal
+            model.training_metrics = make_metrics(
+                di, self._scores_to_preds(F, dist, di), y, w)
+            if valid is not None:
+                model.validation_metrics = model.model_performance(valid)
         return model

@@ -51,6 +51,25 @@ def _ledger(name, jitted, orig=None, **kw):
     return xprof.register_program(name, jitted, orig=orig, **kw)
 
 
+def _named_kernel(name: str, **pallas_kwargs):
+    """``pl.pallas_call(..., name=name)``, launched under
+    ``jax.named_scope(name)``.
+
+    XLA:TPU names a custom call's instruction after the innermost scope of
+    its ``op_name`` (``.../branch_0_fun/pallas_call`` gave
+    ``%branch_0_fun.3``), and the profiler's ``XLA Ops`` line shows that
+    name.  ``name=`` alone gives ``%<name>.N``, but under ``vmap`` (the
+    K-tree builds) its scope reads ``vmap(<name>)``; with the explicit
+    scope around it the innermost one stays ``<name>`` there too
+    (tests/test_tpu_compile.py pins both)."""
+    call = pl.pallas_call(name=name, **pallas_kwargs)
+
+    def launch(*operands):
+        with jax.named_scope(name):
+            return call(*operands)
+    return launch
+
+
 def _reduce_mode_dispatch(builder):
     """Resolve ``reduce_mode`` in front of a cached builder.
 
@@ -170,8 +189,8 @@ def _make_pallas_hist(L: int, F: int, B: int, n_local: int,
     out_bytes = n_fb * FBT * L3 * 4
     a_bytes = R * L3 * (2 if precision == "bf16" else 4)
     if out_bytes + a_bytes <= 8 * 1024 * 1024:
-        call = pl.pallas_call(
-            kernel,
+        call = _named_kernel(
+            "hist_uniform", kernel=kernel,
             grid=(nblk, n_fb),
             in_specs=[
                 pl.BlockSpec((F, R), lambda i, j: (0, i),
@@ -186,8 +205,8 @@ def _make_pallas_hist(L: int, F: int, B: int, n_local: int,
             interpret=interpret,
         )
     else:
-        call = pl.pallas_call(
-            kernel_deep,
+        call = _named_kernel(
+            "hist_uniform_deep", kernel=kernel_deep,
             grid=(n_fb, nblk),
             in_specs=[
                 pl.BlockSpec((F, R), lambda j, i: (0, i),
@@ -314,8 +333,8 @@ def _make_pallas_varbin_hist(L: int, F: int, bin_counts, B: int,
         OHT = jnp.concatenate(pieces, axis=0)          # [Q8, R]
         out_ref[:] += jnp.dot(OHT, A, preferred_element_type=jnp.float32)
 
-    call = pl.pallas_call(
-        kernel,
+    call = _named_kernel(
+        "hist_varbin", kernel=kernel,
         grid=(nblk,),
         in_specs=[
             pl.BlockSpec((F, R), lambda i: (0, i), memory_space=pltpu.VMEM),
@@ -1183,8 +1202,8 @@ def _make_pallas_fine_hist(L: int, F: int, W: int, K: int, nbins: int,
         A = jnp.where(match, sv, 0.0).astype(dt)
         out_ref[:] += jnp.dot(OHT, A, preferred_element_type=jnp.float32)
 
-    call = pl.pallas_call(
-        kernel,
+    call = _named_kernel(
+        "hist_fine", kernel=kernel,
         grid=(n_ft, nblk),
         in_specs=[
             pl.BlockSpec((TF, R), lambda j, i: (j, i),
@@ -1615,8 +1634,8 @@ def _make_pallas_split_records(LF: int, B: int, interpret: bool = False,
     sc_spec = pl.BlockSpec((RS, 8), lambda i: (i, 0),
                            memory_space=pltpu.VMEM) if per_row else \
         pl.BlockSpec((1, 8), lambda i: (0, 0), memory_space=pltpu.SMEM)
-    return pl.pallas_call(
-        kernel,
+    return _named_kernel(
+        "hist_split_records", kernel=kernel,
         grid=(nblk,),
         in_specs=[
             pl.BlockSpec((RS, B), lambda i: (i, 0), memory_space=pltpu.VMEM),

@@ -18,7 +18,6 @@ import contextlib
 import dataclasses
 import functools
 import os
-import time
 from typing import List, Optional, Sequence
 
 import jax
@@ -51,17 +50,16 @@ def level_phase(phase: str, level: int):
     """Host-side span around one per-level phase (hist/split/partition).
 
     The level loop runs at TRACE time inside ``jax.jit``, so inside a
-    jitted build this measures per-phase tracing/dispatch cost on the
-    host (events fire once per compilation; the device-side timeline
-    stays ``jax.profiler``'s job).  Around EAGER phase calls (crosscheck
-    drivers, bench pieces) it times real execution.  Durations land in
-    ``tree_phase_seconds{phase,level}`` and on the event ring."""
+    jitted build ``span_seconds{span="tree_phase"}`` is trace-time cost,
+    once per compile (the device-side timeline stays ``jax.profiler``'s
+    job), and ``jax.named_scope(phase)`` puts the phase into the op
+    metadata of everything traced here, for whoever opens the trace in
+    xprof.  Around EAGER phase calls (crosscheck drivers, bench pieces)
+    the span times real execution."""
     from ...runtime import observability as obs
-    t0 = time.perf_counter()
-    with obs.span("tree_phase", phase=phase, level=level):
+    with obs.span("tree_phase", phase=phase, level=level), \
+            jax.named_scope(phase):
         yield
-    obs.observe("tree_phase_seconds", time.perf_counter() - t0,
-                phase=phase, level=str(level))
 
 
 @dataclasses.dataclass

@@ -32,9 +32,12 @@ import contextlib
 import contextvars
 import logging
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, Iterable, List, Optional, Tuple
+
+from .config import config
 
 _LOG_RING = collections.deque(maxlen=2000)
 _EVENTS = collections.deque(maxlen=2000)
@@ -42,8 +45,10 @@ _lock = threading.Lock()
 
 # master switch (H2O3_TPU_METRICS / config().metrics_enabled): the
 # instrumentation fast-path — span()/observe()/inc()/set_gauge() return
-# immediately when off, which is what bench_pieces.py obs measures
-_enabled = True
+# immediately when off, which is what bench_pieces.py obs measures.  Read
+# here, so that a process started with H2O3_TPU_METRICS=0 is off from its
+# first span and not only after a config.reload()
+_enabled = bool(config().metrics_enabled)
 
 
 def set_enabled(on: bool) -> bool:
@@ -82,7 +87,6 @@ if not log.handlers:
     log.addHandler(_h)
     if os.environ.get("H2O3_TPU_LOG_STDERR"):
         log.addHandler(logging.StreamHandler())
-    from .config import config
     log.setLevel(config().log_level)
 
 
@@ -95,7 +99,6 @@ def open_log_file(path: Optional[str] = None) -> Optional[str]:
     handler; returns the resolved path (None when unconfigured)."""
     global _file_handler
     if path is None:
-        from .config import config
         path = config().log_file
     if not path:
         return None
@@ -459,7 +462,9 @@ def current_trace() -> Optional[Dict[str, str]]:
     """The active trace context, as injected into RPC envelopes:
     ``{"trace_id": ..., "span_id": ...}`` or None outside any trace."""
     ctx = _trace_ctx.get()
-    return dict(ctx) if ctx else None
+    if not ctx:
+        return None
+    return {"trace_id": ctx["trace_id"], "span_id": ctx["span_id"]}
 
 
 @contextlib.contextmanager
@@ -477,37 +482,79 @@ def trace_context(wire: Optional[Dict[str, str]]):
         _trace_ctx.reset(token)
 
 
+_annotation_cls = None
+
+
+def _trace_annotation(name: str):
+    """An unentered ``jax.profiler.TraceAnnotation(name)``, or None in a
+    process that has not imported jax (no profiler session can be live
+    there, and a span must not be what imports it).  The annotation is a
+    TraceMe: while no session is live, entering it costs an atomic load."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(name)
+
+
 @contextlib.contextmanager
 def _timed_event(kind: str, root: bool, fields: dict):
+    """The one span primitive.  While it is open, and only if telemetry is
+    enabled, the span feeds three sinks: the event ring (ids, ``ok`` /
+    ``error``, fields), a profiler annotation ``h2o3.<kind>`` (the host
+    span on the device trace's clock) and the registry's
+    ``span_seconds{span}`` / ``span_self_seconds{span}`` histograms.
+
+    Self time is the duration minus that of the spans opened directly
+    beneath it on the same context: each traced span carries a
+    ``child_ns`` accumulator in its context dict and adds its own
+    duration to its parent's when it closes.  Under one root the self
+    seconds of all spans therefore sum to the root's duration, so any set
+    of span names adds up without counting an interval twice."""
     if not _enabled:
         yield
         return
-    t0 = time.time()
     parent = _trace_ctx.get()
     ids: Dict[str, str] = {}
-    token = None
+    ctx = token = None
     if root or parent is not None:
         trace_id = parent["trace_id"] if parent else _new_id()
         span_id = _new_id()
         ids = {"trace_id": trace_id, "span_id": span_id}
         if parent and parent.get("span_id"):
             ids["parent_span"] = parent["span_id"]
-        token = _trace_ctx.set({"trace_id": trace_id, "span_id": span_id})
+        ctx = {"trace_id": trace_id, "span_id": span_id, "child_ns": 0}
+        token = _trace_ctx.set(ctx)
+    annotation = _trace_annotation("h2o3." + kind)
+    if annotation is not None:
+        annotation.__enter__()
     error = None
+    t0 = time.perf_counter_ns()
     try:
         yield
     except BaseException as e:
         error = type(e).__name__
         raise
     finally:
+        ns = time.perf_counter_ns() - t0
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
         if token is not None:
             _trace_ctx.reset(token)
+        # a context adopted from the wire (trace_context) has no accumulator
+        if parent is not None and "child_ns" in parent:
+            parent["child_ns"] += ns
+        self_ns = max(ns - ctx["child_ns"], 0) if ctx is not None else ns
+        observe("span_seconds", ns / 1e9, span=kind)
+        observe("span_self_seconds", self_ns / 1e9, span=kind)
         ev = dict(fields)
         ev.update(ids)
         ev["ok"] = error is None
         if error is not None:
             ev["error"] = error
-        record(kind, duration_s=round(time.time() - t0, 4), **ev)
+        record(kind, duration_s=ns / 1e9, **ev)
 
 
 def span(kind: str, **fields):
@@ -559,6 +606,8 @@ def trace_forest(events: Iterable[dict]) -> List[dict]:
 # ----------------------------------------------------------- device traces
 
 _profiler_active = False
+_profiler_logdir: Optional[str] = None
+_profiler_summary: Optional[dict] = None
 
 
 def profiler_active() -> bool:
@@ -567,8 +616,19 @@ def profiler_active() -> bool:
         return _profiler_active
 
 
+def profiler_summary() -> Optional[dict]:
+    """``xprof.idle_by_span`` of the last trace ``stop_device_trace``
+    wrote (None before the first stop, or where it could not be read)."""
+    with _lock:
+        return _profiler_summary
+
+
 def start_device_trace(logdir: str) -> bool:
     """Begin a jax.profiler trace (TensorBoard-viewable device timeline).
+
+    The Python tracer is off: the ``h2o3.*`` annotations of ``span()`` say
+    what the host is doing, and Python function events slow the very host
+    path they would explain.
 
     Idempotent: a second start while a capture is live (including one
     jax.profiler reports out-of-band) records a ``profiler_noop`` event
@@ -576,30 +636,44 @@ def start_device_trace(logdir: str) -> bool:
     profiler route must never 500 a double-click.  Returns whether a new
     capture actually started; ``profiler_active`` gauges 1 while one is
     live (shipped in node snapshots like every other gauge)."""
-    global _profiler_active
+    global _profiler_active, _profiler_logdir
     import jax
     with _lock:
         active = _profiler_active
     if active:
         record("profiler_noop", op="start", reason="already_active")
         return False
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
     try:
-        jax.profiler.start_trace(logdir)
+        jax.profiler.start_trace(logdir, profiler_options=options)
     except RuntimeError as e:
         record("profiler_noop", op="start", reason="jax_runtime",
                error=str(e)[:200])
         return False
     with _lock:
         _profiler_active = True
+        _profiler_logdir = logdir
     set_gauge("profiler_active", 1.0)
     record("profiler_start", logdir=logdir)
     return True
 
 
+def _newest_xplane(logdir: Optional[str]) -> Optional[str]:
+    import glob
+    found = glob.glob(os.path.join(logdir or "", "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    return max(found, key=os.path.getmtime) if found else None
+
+
 def stop_device_trace() -> bool:
     """Stop the live device trace; a stop with no capture running records
-    ``profiler_noop`` and returns False (idempotent, like start)."""
-    global _profiler_active
+    ``profiler_noop`` and returns False (idempotent, like start).
+
+    The ``profiler_stop`` event (and ``profiler_summary()``, which the
+    REST route returns) carries the device's idle seconds by program span
+    (``xprof.idle_by_span``): the operator's first reading of the trace."""
+    global _profiler_active, _profiler_summary
     import jax
     with _lock:
         active = _profiler_active
@@ -616,7 +690,17 @@ def stop_device_trace() -> bool:
         with _lock:
             _profiler_active = False
         set_gauge("profiler_active", 0.0)
-    record("profiler_stop")
+    summary = None
+    try:
+        from . import xprof
+        path = _newest_xplane(_profiler_logdir)
+        if path is not None:
+            summary = dict(xprof.idle_by_span(path), xplane=path)
+    except Exception as e:               # noqa: BLE001 — the stop succeeded
+        log.warning("profiler: trace not summarised: %r", e)
+    with _lock:
+        _profiler_summary = summary
+    record("profiler_stop", idle_by_span=summary)
     return True
 
 

@@ -31,13 +31,19 @@ registry:
   signature, or a seam that rebuilds its program per call, like
   ``map_reduce`` over a fresh lambda).
 
-* **jax.monitoring backstop** — a duration listener on
-  ``/jax/core/compile/*`` records every backend compile jax performs,
-  including seams the ledger does not wrap, into
-  ``jax_compile_seconds{event}``.
+* **jax.monitoring listener** — a duration listener on
+  ``/jax/core/compile/*`` records every jaxpr trace, lowering and backend
+  compile (or cache retrieval) jax performs, including seams the ledger
+  does not wrap, into ``jax_compile_seconds{event, fun}``: ``fun`` is the
+  traced function's name, so the series says what a fit re-traces.
 
-* **Device-phase timing** — ``tree_phase_seconds`` measures host
-  dispatch only (the level loop runs at trace time).  With
+* **Idle time by span** — ``idle_by_span(xplane_path)`` reads a profiler
+  trace: the gaps between the programs on the first TPU's ``XLA Modules``
+  line, attributed to the innermost ``h2o3.*`` host span
+  (``observability.span``) open over them.
+
+* **Device-phase timing** — ``span_seconds{span="tree_phase"}`` is
+  trace-time cost only (the level loop runs at trace time).  With
   ``H2O3_TPU_DEVICE_TIMING=sampled|full``, ``maybe_device_sync``
   block-until-ready-syncs eagerly-dispatched work (every Nth call under
   ``sampled``; every call under ``full``) and records the true
@@ -48,6 +54,7 @@ registry:
 from __future__ import annotations
 
 import collections
+import re
 import threading
 import time
 from typing import Any, Dict, Optional, Tuple
@@ -338,11 +345,31 @@ def register_program(name: str, jitted, static_argnums: Tuple[int, ...] = (),
 
 _listener_installed = False
 
+# distinct values of jax_compile_seconds' ``fun`` label: every function
+# jax traces is one, so the label is cut and capped
+_FUN_CHARS = 64
+_MAX_FUNS = 256
+_funs: set = set()
+
+
+def _fun_label(fun_name) -> str:
+    fun = str(fun_name or "unknown")[:_FUN_CHARS]
+    with _lock:
+        if fun in _funs:
+            return fun
+        if len(_funs) < _MAX_FUNS:
+            _funs.add(fun)
+            return fun
+    return "other"
+
 
 def install_monitoring_listener() -> None:
-    """Record every jax backend compile into ``jax_compile_seconds{event}``
-    via ``jax.monitoring`` — the backstop for seams the ledger does not
-    wrap.  Idempotent."""
+    """Record every jaxpr trace, lowering and backend compile jax performs
+    into ``jax_compile_seconds{event, fun}`` via ``jax.monitoring`` — the
+    complete per-program source (the ledger sees wrapped seams only).
+    ``fun`` is the ``fun_name`` jax passes to its duration listeners, cut
+    to 64 characters; past 256 distinct values it reads ``other``.
+    Idempotent."""
     global _listener_installed
     from jax import monitoring
     with _lock:
@@ -353,9 +380,95 @@ def install_monitoring_listener() -> None:
     def _on_duration(event: str, duration: float, **kw) -> None:
         if event.startswith("/jax/core/compile"):
             obs.observe("jax_compile_seconds", duration,
-                        event=event.rsplit("/", 1)[-1])
+                        event=event.rsplit("/", 1)[-1],
+                        fun=_fun_label(kw.get("fun_name")))
 
     monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+# ------------------------------------------------------- idle time by span
+
+SPAN_PREFIX = "h2o3."           # observability's profiler annotations
+_TPU_PLANE = re.compile(r"^/device:TPU:\d+$")
+
+
+def attribute_idle(modules, spans, top: int = 10) -> dict:
+    """Device idle seconds by the host span that was open.
+
+    ``modules`` are the executed programs of one device and ``spans`` the
+    host spans, both as ``(name, start_ns, end_ns)`` on one clock.  The
+    window runs from the first start to the last end of either; a gap is
+    an interval of it in which no program ran.  A gap is cut wherever a
+    span opens or closes, and each piece goes to the innermost span (the
+    latest to open) open over it, or to ``unattributed_s``: the one long
+    gap at the end of a ``predict`` is the fetch, then the labels, then
+    the upload, and its middle alone would name one of them.  ``top``
+    lists the longest gaps whole, as ``(the span open at its middle,
+    offset into the window in s, idle s)``."""
+    events = list(modules) + list(spans)
+    if not events:
+        return {"by_span": {}, "unattributed_s": 0.0, "top": []}
+    lo = min(s for _, s, _ in events)
+    hi = max(e for _, _, e in events)
+    gaps, cursor = [], lo
+    for _, s, e in sorted(modules, key=lambda ev: ev[1]):
+        if s > cursor:
+            gaps.append((cursor, s))
+        cursor = max(cursor, e)
+    if hi > cursor:
+        gaps.append((cursor, hi))
+
+    def innermost(t, candidates):
+        open_at = [sp for sp in candidates if sp[1] <= t < sp[2]]
+        return max(open_at, key=lambda sp: (sp[1], -sp[2]))[0] \
+            if open_at else ""
+
+    # one sweep: gaps come in time order, so the spans that can overlap a
+    # gap are those already opened and not yet closed before it (as many
+    # as spans nest, however long the trace)
+    pending = sorted(spans, key=lambda sp: sp[1], reverse=True)
+    over: list = []
+    by_span: Dict[str, float] = {}
+    longest = []
+    for s, e in gaps:
+        while pending and pending[-1][1] < e:
+            over.append(pending.pop())
+        over = [sp for sp in over if sp[2] > s]
+        cuts = sorted({s, e, *(t for sp in over for t in sp[1:] if s < t < e)})
+        for a, b in zip(cuts, cuts[1:]):
+            name = innermost((a + b) / 2, over)
+            by_span[name] = by_span.get(name, 0.0) + (b - a) / 1e9
+        longest.append((innermost((s + e) / 2, over), (s - lo) / 1e9,
+                        (e - s) / 1e9))
+    unattributed = by_span.pop("", 0.0)
+    longest.sort(key=lambda g: -g[2])
+    return {"by_span": dict(sorted(by_span.items(), key=lambda kv: -kv[1])),
+            "unattributed_s": unattributed, "top": longest[:top]}
+
+
+def idle_by_span(xplane_path: str) -> dict:
+    """``attribute_idle`` over a profiler trace (``.xplane.pb``): the
+    ``XLA Modules`` line of the first ``/device:TPU:n`` plane against the
+    ``h2o3.*`` events of the host planes, names without the prefix.  A
+    trace with no TPU plane (a CPU run) has nothing to attribute."""
+    import jax
+    data = jax.profiler.ProfileData.from_file(xplane_path)
+    modules, spans = [], []
+    tpu = min((p.name for p in data.planes if _TPU_PLANE.match(p.name)),
+              key=lambda name: int(name.rsplit(":", 1)[1]), default=None)
+    for plane in data.planes:
+        if plane.name == tpu:
+            modules = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                       for line in plane.lines if line.name == "XLA Modules"
+                       for e in line.events]
+        elif plane.name.startswith("/host:"):
+            spans += [(e.name[len(SPAN_PREFIX):], e.start_ns,
+                       e.start_ns + e.duration_ns)
+                      for line in plane.lines for e in line.events
+                      if e.name.startswith(SPAN_PREFIX)]
+    if not modules:
+        return attribute_idle([], [])
+    return attribute_idle(modules, spans)
 
 
 # ----------------------------------------------------- device-phase time

@@ -86,19 +86,23 @@ def _make_pallas_traverse(depth: int, R: int, F: int, tile_b: int,
     def call(nodes_i32, nodes_f32, roots, X):
         B = X.shape[0]
         grid = (B // tile_b,)
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec(nodes_i32.shape, lambda i: (0,)),
-                pl.BlockSpec(nodes_f32.shape, lambda i: (0,)),
-                pl.BlockSpec(roots.shape, lambda i: (0,)),
-                pl.BlockSpec((tile_b, F), lambda i: (i, 0)),
-            ],
-            out_specs=pl.BlockSpec((tile_b, R), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((B, R), jnp.float32),
-            interpret=interpret,
-        )(nodes_i32, nodes_f32, roots, X)
+        # XLA:TPU names the custom call after the enclosing scope, so the
+        # scope is what the profiler's XLA Ops line shows (%serve_traverse.N)
+        with jax.named_scope("serve_traverse"):
+            return pl.pallas_call(
+                kernel,
+                name="serve_traverse",
+                grid=grid,
+                in_specs=[
+                    pl.BlockSpec(nodes_i32.shape, lambda i: (0,)),
+                    pl.BlockSpec(nodes_f32.shape, lambda i: (0,)),
+                    pl.BlockSpec(roots.shape, lambda i: (0,)),
+                    pl.BlockSpec((tile_b, F), lambda i: (i, 0)),
+                ],
+                out_specs=pl.BlockSpec((tile_b, R), lambda i: (i, 0)),
+                out_shape=jax.ShapeDtypeStruct((B, R), jnp.float32),
+                interpret=interpret,
+            )(nodes_i32, nodes_f32, roots, X)
 
     return call
 

@@ -81,7 +81,7 @@ def test_worker_metrics_and_trace_stitch_across_processes(tmp_path):
         assert stamp.get("metrics"), "worker shipped no metric snapshot"
         shipped_names = {s["n"] for s in stamp["metrics"]}
         assert "dkv_rpc_seconds" in shipped_names
-        assert "tree_phase_seconds" in shipped_names
+        assert "span_seconds" in shipped_names
         # the compile ledger rides the same snapshot: the worker's train
         # compiled at least the tree-scan program, so its compile series
         # and cost gauges land on the coordinator without extra plumbing
@@ -95,8 +95,8 @@ def test_worker_metrics_and_trace_stitch_across_processes(tmp_path):
                         if f'node="{worker_node}"' in ln]
         assert any(ln.startswith("dkv_rpc_seconds_bucket")
                    for ln in worker_lines)
-        assert any(ln.startswith("tree_phase_seconds_bucket")
-                   for ln in worker_lines)
+        assert any(ln.startswith("span_seconds_bucket")
+                   and 'span="tree_phase"' in ln for ln in worker_lines)
         assert any(ln.startswith("compile_seconds_bucket")
                    for ln in worker_lines)
         assert any(ln.startswith("recompiles_total{")

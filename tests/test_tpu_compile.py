@@ -192,6 +192,178 @@ def test_glm_irls_path_compiles_at_higgs_shape(one_chip):
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 8e9
 
 
+# ----------------------------------------------------- names in the trace
+#
+# What the benchmark's reductions find by name (PERF.md §3): the XLA module
+# of each program on the profiler's ``XLA Modules`` line, and the
+# instruction of each Pallas kernel on its ``XLA Ops`` line.
+
+SMALL_N = 1 << 16
+SCALARS = (1.0, 1.0, 1e-5, 0.3, 1.0, 0.0, 0.0, 1.0)
+
+
+def _tree_build_operands(sds, rows, nk=1):
+    lead = (nk,) if nk > 1 else ()
+    row_k = (None, rows) if nk > 1 else (rows,)
+    return (sds((F, SMALL_N), jnp.int32, None, rows),
+            sds(lead + (SMALL_N,), jnp.float32, *row_k),
+            sds(lead + (SMALL_N,), jnp.float32, *row_k),
+            sds((SMALL_N,), jnp.float32, rows), sds((F, NBINS), jnp.float32),
+            sds(lead + (2,), jnp.uint32),
+            *SCALARS[:5], sds(lead + (F,), jnp.bool_), *SCALARS[5:])
+
+
+def _glm_path(sds, rows):
+    from h2o3_tpu.models import glm
+    p = 29
+    fam = glm._make_family("binomial", glm.GLMParameters())
+    vec, coef = sds((SMALL_N,), jnp.float32, rows), sds((p,), jnp.float32)
+    scalar = sds((), jnp.float32)
+    return glm._make_path_runner(fam, False, 50), (
+        sds((SMALL_N, p), jnp.float32, rows, None), vec, vec, vec,
+        sds((1,), jnp.float32), scalar, coef, coef, scalar, scalar)
+
+
+def _tree_scan(sds, rows):
+    from h2o3_tpu.models.tree import shared
+    fn = shared.make_tree_scan_fn(
+        "bernoulli", 1.5, 0.5, 0.9, 3, NBINS, F, SMALL_N, "bf16", 1.0, 1.0,
+        bin_counts=BIN_COUNTS, hist_mode="subtract", split_mode="fused",
+        tree_program="scan")
+    vec = sds((SMALL_N,), jnp.float32, rows)
+    return fn, (sds((F, SMALL_N), jnp.int32, None, rows), vec, vec,
+                sds((SMALL_N,), jnp.float32), sds((F, NBINS), jnp.float32),
+                sds((2,), jnp.uint32), 0, 2, *SCALARS, 0)
+
+
+def _tree_build(sds, rows, nk=1):
+    from h2o3_tpu.models.tree import shared
+    fn = shared.make_build_tree_fn(3, NBINS, F, SMALL_N, "bf16",
+                                   bin_counts=BIN_COUNTS, nk=nk,
+                                   hist_mode="subtract", split_mode="fused")
+    return fn, _tree_build_operands(sds, rows, nk)
+
+
+def _traverse(sds, rows):
+    from h2o3_tpu.models.tree import shared
+    trees = 4
+    levels = [(sds((trees, 2 ** d), jnp.int32), sds((trees, 2 ** d), jnp.float32),
+               sds((trees, 2 ** d), jnp.bool_), sds((trees, 2 ** d), jnp.bool_))
+              for d in range(3)]
+    return shared.traverse_jit, (levels, sds((trees, 8), jnp.float32),
+                                 sds((SMALL_N, F), jnp.float32, rows, None))
+
+
+def _sketch(sds, rows):
+    from h2o3_tpu.models.tree import binning
+    return binning._make_sketch_fn(SMALL_N, SMALL_N, 5, NBINS - 1), (
+        sds((5, SMALL_N), jnp.float32, None, rows),
+        sds((SMALL_N,), jnp.float32, rows))
+
+
+def _encode(sds, rows):
+    from h2o3_tpu.models.tree import binning
+    is_cat = (False,) * 5 + (True,) * 3
+    ecounts = (255,) * 5 + (21, 255, 255)
+    return binning._make_encode_fn(SMALL_N, ecounts, is_cat, NBINS), (
+        sds((F, SMALL_N), jnp.float32, None, rows), sds((F, 256), jnp.float32))
+
+
+def _hist_kernel(builder):
+    def program(sds, rows):
+        from h2o3_tpu.models.tree import hist
+        return builder(hist, sds, rows)
+    return program
+
+
+def _scan_level(hist, sds, rows, K=0):
+    W, n = 4, 1 << 12       # the compaction around the kernel compiles slowly
+    lead = (K,) if K else ()
+    row_k = (None, rows) if K else (rows,)
+    make = hist.make_batched_scan_level_fn if K else hist.make_scan_level_fn
+    fn = make(W, *lead, F, B, n)
+    return fn, (sds((F, n), jnp.int32, None, rows),
+                sds(lead + (n,), jnp.int32, *row_k),
+                *(sds(lead + (n,), jnp.float32, *row_k),) * 3,
+                sds((1,) + lead + (3, W // 2, F, B), jnp.float32, rows),
+                sds((), jnp.bool_))
+
+
+def _split_records(hist, sds, rows):
+    fn = jax.jit(functools.partial(
+        hist.split_records, nbins=NBINS, reg_lambda=1.0, min_rows=1.0,
+        reg_alpha=0.0, gamma=0.0, min_child_weight=1.0))
+    return fn, (sds((3, 32, F, B), jnp.float32),)
+
+
+MODULES = {
+    "jit_run": _glm_path, "jit_scan_fn": _tree_scan,
+    "jit_build": _tree_build,
+    "jit_buildK": functools.partial(_tree_build, nk=3),
+    "jit_traverse": _traverse, "jit_sketch": _sketch, "jit_encode": _encode,
+}
+KERNELS = {
+    "hist_uniform": _hist_kernel(lambda hist, sds, rows: (
+        hist.make_hist_fn(1, F, B, SMALL_N),
+        _hist_operands(sds, rows, SMALL_N, jnp.int32))),
+    # the stationary-tile variant: the whole histogram is over 8 MiB
+    "hist_uniform_deep": _hist_kernel(lambda hist, sds, rows: (
+        hist.make_hist_fn(512, 6, B, SMALL_N),
+        (sds((6, SMALL_N), jnp.int32, None, rows),
+         *_hist_operands(sds, rows, SMALL_N, jnp.int32)[1:]))),
+    "hist_varbin": _hist_kernel(lambda hist, sds, rows: (
+        hist.make_varbin_hist_fn(8, F, BIN_COUNTS, B, SMALL_N),
+        _hist_operands(sds, rows, SMALL_N, jnp.int16))),
+    "hist_fine": _hist_kernel(lambda hist, sds, rows: (
+        hist.make_fine_hist_fn(4, F, 16, 2, NBINS, SMALL_N),
+        (*_hist_operands(sds, rows, SMALL_N, jnp.int32),
+         sds((4, F, 2), jnp.int32)))),
+    "hist_split_records": _hist_kernel(_split_records),
+    # where PR 26's trace read %branch_0_fun: the live branch of lax.cond
+    "hist_uniform@cond": _hist_kernel(_scan_level),
+    # under vmap the scope of name= alone reads vmap(hist_uniform)
+    "hist_uniform@vmap": _hist_kernel(
+        functools.partial(_scan_level, K=3)),
+}
+
+
+@pytest.mark.parametrize("kind,name", [("module", m) for m in MODULES]
+                         + [("kernel", k) for k in KERNELS]
+                         + [("kernel", "serve_traverse")])
+def test_names_the_trace_reductions_match(one_chip, kind, name):
+    """The seven XLA module names the ``*_share`` metrics match stay as
+    they are, and every Pallas kernel's instruction is ``%<its name>.N``
+    in what the chip's compiler emits (``hist_kernel_share`` matches
+    ``^%hist_``)."""
+    import re
+    _, sds, rows = one_chip
+    if kind == "module":
+        fn, operands = MODULES[name](sds, rows)
+        hlo = getattr(fn, "jitted", fn).lower(*operands) \
+            .compiler_ir("hlo").as_hlo_text()
+        assert re.match(r"HloModule (\w+)", hlo).group(1) == name
+    elif name == "serve_traverse":
+        # Mosaic refuses this kernel (see above), so nothing is emitted
+        # to read a name from: its pallas_call carries name=
+        from h2o3_tpu.serving import kernel
+        depth, trees, batch = 6, 100, 256
+        nodes = trees * (2 ** (depth + 1) - 1)
+        jaxpr = jax.make_jaxpr(
+            kernel._make_pallas_traverse(depth, trees, F, 128))(
+            sds((nodes,), jnp.int32), sds((nodes,), jnp.float32),
+            sds((trees,), jnp.int32), sds((batch, F), jnp.float32))
+        calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        assert [str(e.params["name"]) for e in calls] == [name]
+    else:
+        fn, operands = KERNELS[name](sds, rows)
+        _, text = _compile(fn, *operands)
+        found = re.findall(r"%([\w.]+?)\.\d+ = [^\n]*tpu_custom_call", text)
+        assert found and set(found) == {name.split("@")[0]}
+
+
+# the row-sharded mesh last: it boots its own cluster and hands the CPU test
+# mesh back, which ends what the ``one_chip`` fixture set up
+
 def test_four_chip_histogram_has_kernel_and_all_reduce(topo,
                                                        no_persistent_cache):
     """The row-sharded mesh: each chip runs the kernel on its rows and the
