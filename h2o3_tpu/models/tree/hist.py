@@ -564,9 +564,13 @@ def _make_subtract_level_fn(d: int, F: int, B: int, n_padded: int,
     because orientation is per-shard), (c) histograms only the prefix at
     the parent-slot geometry, and (d) reconstructs the larger siblings as
     ``H_parent_local - H_small_local`` in f32 before the cross-shard psum.
-    The compaction itself is a cumsum-positioned monotonic scatter over the
-    packed code/leaf/stat planes — one bandwidth-bound pass, NOT a per-row
-    gather.
+    The compaction itself is three cumsum-positioned unique-index scatters
+    (code planes, leaf plane, g/h/w planes).  On a TPU v5e they are NOT
+    bandwidth-bound: the two [planes, n] scatters run at ~12 M rows/s each
+    (ledger, PR 28, ``xgb_airlines40m.fit``: 3.30 s and 3.23 s per level of
+    40M rows, 0.39 GB/s of 819), ~165 ns a row against the 2.4 ns a row the
+    halved kernel saves.  ``hist_level_cost`` prices them, so the tuner
+    takes this builder only where the dense grid cannot hold the level.
 
     The per-shard parent histogram needed for the subtraction rides along
     as a carry: each call returns ``(H_global, H_carry)`` where ``H_carry``
@@ -930,21 +934,41 @@ def sparse_slot_budget(F: int, B: int,
     return int(max(16, min(4096, (a // 8) * 8)))
 
 
-def hist_level_bytes(n_rows: int, F: int, B: int, width: int, K: int = 1,
-                     *, layout: str = "dense",
-                     hist_mode: str = "subtract",
-                     cap_bytes: int = 64 * 1024 * 1024):
-    """Roofline byte traffic for ONE level's histogram build — the cost
+# Unique-index scatters of a [planes, n] stack that one compacting level
+# runs per tree: the code planes and the g/h/w planes.  Each costs the same
+# per source row whatever its plane count (8 planes 3.30 s, 3 planes 3.23 s
+# per 40M rows on the v5e).  The leaf plane's 1-D scatter lowers to another
+# op, 17x faster there (0.19 s), and is left out of the price.
+_COMPACTION_STACK_SCATTERS = 2
+
+
+def hist_level_cost(n_rows: int, F: int, B: int, width: int, K: int = 1,
+                    *, layout: str = "dense",
+                    hist_mode: str = "subtract",
+                    cap_bytes: int = 64 * 1024 * 1024):
+    """``(bytes, scatter_rows)`` of ONE level's histogram build — the cost
     atom ``runtime/autotune.py`` seeds its model from, kept next to the
     kernels it prices so a kernel change updates the model in one place.
+    The model divides each term by its own device figure (HBM bandwidth;
+    rows per second of a unique-index row scatter).
 
-    Reads: int32 codes + f32 g/h/w per contributing row per feature
-    (subtract levels stream only the compacted smaller siblings,
-    <= n/2 rows; the full oracle streams every row).  Writes: the
-    [width|A, F, B] triple-plane grid, f32.  Returns ``None`` when the
-    dense grid for ``width`` leaves exceeds the histogram budget — that
-    config cannot run and the model must price it out."""
-    rows = n_rows if (hist_mode == "full" or width <= 1) else n_rows // 2
+    ``bytes`` is the kernel's roofline traffic.  Reads: int32 codes + f32
+    g/h/w per contributing row per feature (past the root a subtract level
+    reads the compacted smaller siblings, <= n/2 rows; the full oracle
+    reads every row).  Writes: the [width|A, F, B] triple-plane grid, f32.
+
+    ``scatter_rows`` is what the compaction moves to build that prefix:
+    every one of the level's ``n_rows`` source rows through each stack
+    scatter, per tree.  Zero at the root and under ``full``.  The scatters
+    run three to four orders of magnitude under the HBM roofline (ledger,
+    PR 28: 6.72 s a level at 40M rows x 8 on a v5e, for 0.097 s of kernel
+    saved), which is why they are counted in rows, not bytes.
+
+    Returns ``None`` when the dense grid for ``width`` leaves exceeds the
+    histogram budget — that config cannot run and the model must price it
+    out."""
+    compacts = hist_mode != "full" and width > 1
+    rows = n_rows // 2 if compacts else n_rows
     read = rows * F * (4 + 3 * 4) * max(K, 1)
     slots = width if layout == "dense" else min(width, sparse_slot_budget(
         F, B, cap_bytes))
@@ -952,10 +976,12 @@ def hist_level_bytes(n_rows: int, F: int, B: int, width: int, K: int = 1,
     if layout == "dense" and grid > cap_bytes * max(K, 1):
         return None
     if layout == "sparse":
-        # slot-map gathers + compaction traffic: a small constant factor
-        # over the dense write path, paid for unbounded depth
+        # slot-map gathers: a small constant factor over the dense write
+        # path, paid for unbounded depth
         grid = int(grid * 1.15) + rows * 4
-    return float(read + grid)
+    scatter_rows = (float(_COMPACTION_STACK_SCATTERS * n_rows * max(K, 1))
+                    if compacts else 0.0)
+    return float(read + grid), scatter_rows
 
 
 def split_search_passes(split_mode: str) -> float:

@@ -17,17 +17,19 @@ needs (``program_flops`` / ``program_bytes_accessed`` per seam, sampled
 
 2. **Cost model seed** — every candidate configuration is scored by a
    roofline-style estimate built from the per-level histogram bytes/flops
-   the kernels in ``models/tree/hist.py`` report (``hist_level_bytes`` /
-   ``split_search_passes``), normalized by per-platform peak bandwidth
-   and calibrated against the ledger's measured ``cost_analysis()``
-   figures when available.  The model's argmin is served immediately
+   and compaction scatter rows the kernels in ``models/tree/hist.py``
+   report (``hist_level_cost`` / ``split_search_passes``), each divided
+   by the device's own figure for it (``_DEVICE_PEAKS``) and calibrated
+   against the ledger's measured ``cost_analysis()`` figures when
+   available.  The model's argmin is served immediately
    (``source="model"``) — no warm-up builds.
 
 3. **Measured refinement** — with ``H2O3_TPU_DEVICE_TIMING`` sampling on,
    ``xprof.maybe_device_sync`` feeds true dispatch→ready seconds back via
    ``on_device_sample``; every ``autotune_explore_every``-th resolve of a
-   model-seeded signature runs the runner-up candidate instead
-   (epsilon-greedy, deterministic counter — no RNG), so an early
+   model-seeded signature then runs the runner-up candidate instead
+   (epsilon-greedy, deterministic counter — no RNG; with the sampling
+   off no measurement can come back, so nothing is explored), so an early
    mis-prediction self-corrects: once two candidates carry measurements
    the faster one wins permanently (``source="measured"``).
 
@@ -83,17 +85,24 @@ _THRESHOLD_CANDIDATES = (4, 6, 8, 10)
 # user-set value is treated as pinned (see docs/operations.md).
 DEFAULT_SPARSE_THRESHOLD = 8
 
-# device_kind -> (peak flop/s, peak HBM bytes/s, device memory bytes): the
-# roofline seed and the batched-grid resident-state budget (a cohort holds
-# G members' F vectors, gradients and level histograms at once, so
-# batching loses outright when that estimate blows the memory).  A device
-# kind that is not in the table is an error, not a default.
+# device_kind -> (peak flop/s, peak HBM bytes/s, device memory bytes,
+# rows/s of a unique-index row scatter): the roofline seed, the
+# batched-grid resident-state budget (a cohort holds G members' F vectors,
+# gradients and level histograms at once, so batching loses outright when
+# that estimate blows the memory) and the price of the smaller-sibling
+# compaction (hist.hist_level_cost counts its rows).  A device kind that is
+# not in the table is an error, not a default.
 _DEVICE_PEAKS = {
     # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s,
-    # 16 GB HBM per chip
-    "TPU v5 lite": (1.97e14, 8.19e11, 1.6e10),
-    # the CPU test mesh: coarse, only candidate *ranking* matters
-    "cpu": (5.0e10, 5.0e10, 8.0e9),
+    # 16 GB HBM per chip.  Scatter: PERF_LEDGER.jsonl, PR 28,
+    # xgb_airlines40m.fit: `.at[:, target].set(unique_indices=True)` of the
+    # [8, 40M] code planes 16.50 s and of the [3, 40M] g/h/w planes 16.15 s
+    # over 5 levels, 12.1 and 12.4 M rows/s (re-read with hist_mode pinned
+    # in PR 29, PERF.md section 6: 16.52 s and 16.16 s)
+    "TPU v5 lite": (1.97e14, 8.19e11, 1.6e10, 1.2e7),
+    # the CPU test mesh: coarse, only candidate *ranking* matters (the same
+    # two scatters at 4M rows under XLA:CPU read 40 and 82 M rows/s)
+    "cpu": (5.0e10, 5.0e10, 8.0e9, 5.0e7),
 }
 
 # per-dispatch overhead for the tree_program dimension: each kernel
@@ -175,9 +184,9 @@ def _signature(kind: str, F: int, N: int, K: int, max_depth: int,
 
 # ------------------------------------------------------------ cost model
 
-def _peaks() -> Tuple[float, float, float]:
-    """(peak flop/s, peak HBM bytes/s, device memory bytes) of the device
-    the kernels are built for."""
+def _peaks() -> Tuple[float, float, float, float]:
+    """(peak flop/s, peak HBM bytes/s, device memory bytes, scatter rows/s)
+    of the device the kernels are built for."""
     kind = _device().device_kind
     try:
         return _DEVICE_PEAKS[kind]
@@ -212,37 +221,43 @@ def _predict_tree_cost(F: int, N: int, K: int, max_depth: int, nbins: int,
                        tree_program: str = "level") -> float:
     """Roofline seconds for one K-tree build under one candidate config.
 
-    Per-level byte/flop counts come from ``hist.hist_level_bytes`` /
+    Per-level byte/flop counts and the rows a subtract level's compaction
+    scatters come from ``hist.hist_level_cost`` /
     ``hist.split_search_passes`` so the estimate lives next to the
     kernels it models; infeasible configs (dense grid over the histogram
-    budget) price at +inf and can never win.
+    budget) price at +inf and can never win.  The scatters feed the
+    kernel, so their seconds add to the roofline term.
 
     ``tree_program="scan"`` runs every level past the root at the padded
     width 2^(max_depth-1) (one fixed-width program) but dispatches O(1)
     kernel programs instead of 2*depth — the ``_DISPATCH_OVERHEAD_S``
     term carries that tradeoff, so deep trees at modest N pick the scan
     and wide shallow frames keep per-level programs."""
-    from ..models.tree.hist import hist_level_bytes, split_search_passes
-    peak_f, peak_b, _ = _peaks()
+    from ..models.tree.hist import hist_level_cost, split_search_passes
+    peak_f, peak_b, _, scatter_rows_s = _peaks()
     B = nbins + 1
     total_bytes = 0.0
     total_flops = 0.0
+    total_scatter_rows = 0.0
     for d in range(max_depth):
         layout_d = ("sparse" if hist_layout == "sparse" and d >= threshold
                     else "dense")
         width = 2 ** (max_depth - 1) if (tree_program == "scan" and d > 0) \
             else 2 ** d
-        b = hist_level_bytes(N, F, B, width, K,
-                             layout=layout_d, hist_mode=hist_mode)
-        if b is None:
+        cost = hist_level_cost(N, F, B, width, K,
+                               layout=layout_d, hist_mode=hist_mode)
+        if cost is None:
             return float("inf")
-        total_bytes += b * split_search_passes(split_mode)
+        level_bytes, scatter_rows = cost
+        total_bytes += level_bytes * split_search_passes(split_mode)
+        total_scatter_rows += scatter_rows
         # one multiply-add per (row, feature, class) scatter contribution
         rows = N if (hist_mode == "full" or d == 0) else N // 2
         total_flops += 2.0 * rows * F * K
     launches = 2 if tree_program == "scan" else 2 * max_depth
     return (max(total_flops / peak_f, total_bytes / peak_b)
             * _ledger_calibration()
+            + total_scatter_rows / scatter_rows_s
             + launches * _DISPATCH_OVERHEAD_S)
 
 
@@ -365,7 +380,12 @@ def _decide(sig: str, candidates: List[dict], predicted: Dict[str, float],
             mode: str) -> dict:
     """Look up / create the decision entry for ``sig`` and pick the config
     to RUN this resolve (usually the decision; sometimes the epsilon
-    exploration of the runner-up)."""
+    exploration of the runner-up).  The runner-up is run only where its
+    measurement can come back: ``xprof.maybe_device_sync`` is the one
+    source of samples and returns at once while the device timing is off,
+    so an exploration then would trace and compile a second tree program
+    and learn nothing."""
+    from . import xprof
     ent = _DECISIONS.get(sig)
     if ent is None:
         cached = _load_cached_entry(sig)
@@ -386,6 +406,7 @@ def _decide(sig: str, candidates: List[dict], predicted: Dict[str, float],
     run_key = ent["choice"]
     ent["explore"] = None
     if (mode == "on" and ent["source"] in ("model", "measured")
+            and obs.enabled() and xprof.device_timing_mode() != "off"
             and len(ent["candidates"]) > 1
             and ent["resolves"] % _explore_every() == 0):
         # deterministic epsilon-greedy: re-measure the best *other*
@@ -682,7 +703,9 @@ def _cache_path() -> Optional[str]:
 
 
 def _cache_header() -> dict:
-    return {"version": 1, "backend": _backend(), "jax": _jax_version()}
+    # version 2: the model prices the compaction scatters (PR 29); a file
+    # written under version 1 holds choices made without that price
+    return {"version": 2, "backend": _backend(), "jax": _jax_version()}
 
 
 _file_entries: Dict[str, dict] = {}

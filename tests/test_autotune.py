@@ -43,8 +43,9 @@ def tuner_on(tmp_path):
     pinned-off environment (and the cached Config) afterwards."""
     from h2o3_tpu.runtime import autotune, config
     keys = ("H2O3_TPU_AUTOTUNE", "H2O3_TPU_AUTOTUNE_CACHE_DIR",
-            "H2O3_TPU_AUTOTUNE_EXPLORE")
+            "H2O3_TPU_AUTOTUNE_EXPLORE", "H2O3_TPU_DEVICE_TIMING")
     saved = {k: os.environ.get(k) for k in keys}
+    os.environ.pop("H2O3_TPU_DEVICE_TIMING", None)    # shipped value: off
     os.environ["H2O3_TPU_AUTOTUNE"] = "on"
     os.environ["H2O3_TPU_AUTOTUNE_CACHE_DIR"] = str(tmp_path / "atcache")
     config.reload()
@@ -199,6 +200,34 @@ def test_stale_cache_header_is_ignored(tuner_on, tmp_path):
     assert k.hist_mode != "full" or row["choice"] != "full|separate|dense|t10"
 
 
+def test_cache_of_the_old_model_version_is_ignored(tuner_on, tmp_path):
+    """A file written before the model priced the compaction (header
+    version 1, this backend, this jax) holds choices made under the old
+    price: it degrades to the model like any stale header."""
+    cache_dir = tmp_path / "atcache"
+    cache_dir.mkdir(parents=True, exist_ok=True)
+    sig = tuner_on._signature("gbm", 8, 65536, 1, 10, 64)
+    old = "subtract|fused|sparse|t8|plevel"
+    header = dict(tuner_on._cache_header(), version=1)
+    assert header != tuner_on._cache_header()
+    payload = {"header": header,
+               "entries": {sig: {"choice": old, "source": "model",
+                                 "predicted": {old: 1e-9}, "measured": {}}}}
+    (cache_dir / "autotune_cache.json").write_text(json.dumps(payload))
+    tuner_on.reset()
+    k = tuner_on.resolve_tree_knobs(_params(), kind="gbm", F=8, N=65536)
+    row = tuner_on.decision_table()["decisions"][0]
+    assert row["source"] == "model" and k.sources["hist_mode"] == "model"
+    # the same file under the current header IS served: the version alone
+    # is what killed it
+    payload["header"] = tuner_on._cache_header()
+    (cache_dir / "autotune_cache.json").write_text(json.dumps(payload))
+    tuner_on.reset()
+    tuner_on.resolve_tree_knobs(_params(), kind="gbm", F=8, N=65536)
+    row = tuner_on.decision_table()["decisions"][0]
+    assert (row["source"], row["choice"]) == ("cache", old)
+
+
 # --------------------------------------------------------- invalidation
 
 def test_cluster_reinit_invalidates_decisions(tuner_on):
@@ -219,20 +248,23 @@ def test_cluster_reinit_invalidates_decisions(tuner_on):
 
 # ------------------------------------------------- measured refinement
 
-def test_forced_wrong_model_self_corrects(tuner_on, monkeypatch):
-    """Invert the cost model so it seeds the WORST candidate, then feed
-    real-shaped device samples: once two candidates carry measurements
-    the faster one wins permanently (source="measured")."""
-    real = tuner_on._predict_costs
-
+def _inverted(real):
+    """``real`` with the finite costs mirrored: the worst candidate seeds."""
     def inverted(F, N, K, max_depth, nbins, candidates):
         costs = real(F, N, K, max_depth, nbins, candidates)
         finite = [v for v in costs.values() if v != float("inf")]
         top = max(finite) if finite else 1.0
         return {k: (v if v == float("inf") else top - v + 1e-9)
                 for k, v in costs.items()}
+    return inverted
 
-    monkeypatch.setattr(tuner_on, "_predict_costs", inverted)
+
+def test_forced_wrong_model_self_corrects(tuner_on, monkeypatch):
+    """Invert the cost model so it seeds the WORST candidate, then feed
+    real-shaped device samples: once two candidates carry measurements
+    the faster one wins permanently (source="measured")."""
+    real = tuner_on._predict_costs
+    monkeypatch.setattr(tuner_on, "_predict_costs", _inverted(real))
     os.environ["H2O3_TPU_AUTOTUNE_EXPLORE"] = "2"
     from h2o3_tpu.runtime import config
     config.reload()
@@ -265,6 +297,50 @@ def test_forced_wrong_model_self_corrects(tuner_on, monkeypatch):
     if "explore" not in k2.sources.values():
         assert k2.run_key == right
     assert tuner_on.decision_table()["decisions"][0]["choice"] == right
+    tuner_on.deactivate()
+
+
+def test_no_exploration_while_device_timing_is_off(tuner_on):
+    """The shipped environment (H2O3_TPU_DEVICE_TIMING off) has no source
+    of measurements, so the runner-up is never run: it would compile a
+    second tree program and learn nothing."""
+    from h2o3_tpu.runtime import xprof
+    assert xprof.device_timing_mode() == "off"
+    assert tuner_on._explore_every() == 16
+    sources = [tuner_on.resolve_tree_knobs(_params(), kind="gbm", F=8,
+                                           N=65536).sources
+               for _ in range(40)]
+    assert all("explore" not in s.values() for s in sources)
+    row = tuner_on.decision_table()["decisions"][0]
+    assert row["resolves"] == 40 and row["exploring"] is None
+    assert row["source"] == "model"
+
+
+def test_sampled_timing_explores_and_self_corrects(tuner_on, monkeypatch):
+    """With H2O3_TPU_DEVICE_TIMING=sampled the forced-wrong lifecycle
+    still runs end to end: the N-th resolve runs the runner-up
+    (source "explore"), its sample and the choice's come back through the
+    measurement sink, and the faster one wins (source="measured")."""
+    from h2o3_tpu.runtime import config
+    monkeypatch.setattr(tuner_on, "_predict_costs",
+                        _inverted(tuner_on._predict_costs))
+    os.environ["H2O3_TPU_AUTOTUNE_EXPLORE"] = "2"
+    os.environ["H2O3_TPU_DEVICE_TIMING"] = "sampled"
+    config.reload()
+
+    k1 = tuner_on.resolve_tree_knobs(_params(), kind="gbm", F=8, N=65536)
+    assert "explore" not in k1.sources.values()
+    tuner_on.activate(k1)
+    tuner_on.on_device_sample("tree_scan", 2.0)      # the wrong seed: slow
+    k2 = tuner_on.resolve_tree_knobs(_params(), kind="gbm", F=8, N=65536)
+    assert k2.sources["hist_mode"] == "explore"
+    assert k2.run_key != k1.run_key
+    assert tuner_on.decision_table()["decisions"][0]["exploring"] == \
+        k2.run_key
+    tuner_on.activate(k2)
+    tuner_on.on_device_sample("tree_scan", 0.1)      # the runner-up: fast
+    row = tuner_on.decision_table()["decisions"][0]
+    assert (row["choice"], row["source"]) == (k2.run_key, "measured")
     tuner_on.deactivate()
 
 
@@ -352,11 +428,14 @@ def test_serve_impl_auto(tuner_on):
 
 def test_peaks_table_has_the_v5e_row():
     """Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s,
-    16 GB of HBM; keyed by the device_kind JAX reports for that chip."""
+    16 GB of HBM; PERF_LEDGER.jsonl, PR 28: 12 M rows/s of a unique-index
+    row scatter; keyed by the device_kind JAX reports for that chip."""
     from h2o3_tpu.runtime import autotune
     assert autotune._DEVICE_PEAKS["TPU v5 lite"] == (1.97e14, 8.19e11,
-                                                     1.6e10)
+                                                     1.6e10, 1.2e7)
     assert autotune._peaks() == autotune._DEVICE_PEAKS["cpu"]
+    assert all(len(row) == 4 and min(row) > 0
+               for row in autotune._DEVICE_PEAKS.values())
 
 
 def test_unknown_device_kind_is_an_error(monkeypatch):
@@ -370,3 +449,80 @@ def test_unknown_device_kind_is_an_error(monkeypatch):
     with pytest.raises(ValueError, match="TPU v9"):
         autotune.resolve_grid_batch(kind="gbm", F=8, N=4096, G=4,
                                     max_depth=5, nbins=64)
+
+
+# ------------------------------------------- the price of the compaction
+
+@pytest.fixture()
+def v5e_row(monkeypatch):
+    """The cost model as the v5e sees it (no chip needed: the model is
+    arithmetic over the device's row)."""
+    from h2o3_tpu.runtime import autotune
+    monkeypatch.setattr(autotune, "_peaks",
+                        lambda: autotune._DEVICE_PEAKS["TPU v5 lite"])
+    return autotune
+
+
+def _model_choice(at, F, N, max_depth, nbins, K=1):
+    tuned = dict.fromkeys(("hist_mode", "split_mode", "hist_layout",
+                           "sparse_depth_threshold", "tree_program"), True)
+    cands = at._tree_candidates(F, N, K, max_depth, nbins, mono=None,
+                                plan=None, hier=False, tuned=tuned)
+    costs = at._predict_costs(F, N, K, max_depth, nbins, cands)
+    return min(costs, key=costs.get), costs
+
+
+def test_v5e_model_builds_over_all_rows_where_the_dense_grid_fits(v5e_row):
+    """The benchmark's headline shape (xgb_airlines40m.fit): with the
+    scatters priced, the argmin is a full build on the dense grid, and the
+    best subtract candidate is priced near what the chip took (33.6 s of
+    scatters: ledger, PR 28)."""
+    choice, costs = _model_choice(v5e_row, F=8, N=40_000_000, max_depth=6,
+                                  nbins=256)
+    assert choice.startswith("full|") and "|dense|" in choice
+    sub = min(v for k, v in costs.items() if k.startswith("subtract|"))
+    assert 25.0 < sub < 45.0
+    assert costs[choice] < sub / 100
+
+
+def test_v5e_model_keeps_subtract_sparse_past_the_dense_budget(v5e_row):
+    """DRF's default depth: the dense grid passes the 64 MB histogram
+    budget, every full candidate prices at +inf, and the only builder of
+    those levels stays the choice."""
+    choice, costs = _model_choice(v5e_row, F=8, N=40_000_000, max_depth=20,
+                                  nbins=256)
+    assert choice.startswith("subtract|") and "|sparse|" in choice
+    assert all(v == float("inf") for k, v in costs.items()
+               if k.startswith("full|"))
+
+
+@pytest.mark.parametrize("log2_n", range(12, 28))
+def test_v5e_subtract_level_costs_more_than_a_full_level(v5e_row, log2_n):
+    """Per row the compaction costs ~165 ns and saves 2.4: no N turns that
+    round.  Two-level trees differ by exactly the one level past the root
+    (same root, same launches)."""
+    kw = dict(split_mode="fused", hist_layout="dense", threshold=8,
+              tree_program="level")
+    N = 2 ** log2_n
+    sub = v5e_row._predict_tree_cost(8, N, 1, 2, 256, hist_mode="subtract",
+                                     **kw)
+    full = v5e_row._predict_tree_cost(8, N, 1, 2, 256, hist_mode="full",
+                                      **kw)
+    assert sub > full
+    # and the whole difference is the scatters less the half read saved
+    from h2o3_tpu.models.tree.hist import hist_level_cost
+    _, moved = hist_level_cost(N, 8, 257, 2, hist_mode="subtract")
+    assert moved == 2.0 * N
+    assert hist_level_cost(N, 8, 257, 1, hist_mode="subtract")[1] == 0.0
+    assert hist_level_cost(N, 8, 257, 2, hist_mode="full")[1] == 0.0
+    assert sub - full == pytest.approx(moved / 1.2e7, rel=0.02)
+
+
+def test_grid_batch_reads_the_memory_budget_of_the_longer_row(v5e_row):
+    """``resolve_grid_batch`` indexes the row, ``_predict_tree_cost``
+    unpacks it: both follow the fourth figure.  A cohort whose resident
+    state passes the v5e's 16 GB runs parallel, a small one batched."""
+    assert v5e_row._peaks()[2] == 1.6e10
+    kw = dict(kind="gbm", F=8, max_depth=5, nbins=64)
+    assert v5e_row.resolve_grid_batch(N=4096, G=4, **kw) == "batched"
+    assert v5e_row.resolve_grid_batch(N=2 ** 28, G=8, **kw) == "parallel"
