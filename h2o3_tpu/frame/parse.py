@@ -70,7 +70,11 @@ def _parse_time_column(values: np.ndarray):
     try:
         import pandas as pd
         with np.errstate(all="ignore"):
-            dt = pd.to_datetime(pd.Series(values), errors="coerce", format="mixed")
+            # dtype=object: pandas 3 would infer its arrow-backed string
+            # dtype, and pyarrow's conversion segfaults now and then when it
+            # first runs on a REST handler thread (PERF.md section 7)
+            dt = pd.to_datetime(pd.Series(values, dtype=object),
+                                errors="coerce", format="mixed")
         ok = dt.notna().to_numpy()
         real = np.array([v not in _NA for v in values.astype(str)])
         if real.sum() == 0 or ok[real].mean() < 0.9:

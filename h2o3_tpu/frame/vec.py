@@ -89,6 +89,27 @@ def _ledger(name, jitted, orig=None, **kw):
     return xprof.register_program(name, jitted, orig=orig, **kw)
 
 
+def _moments(x, present, nf, axis):
+    """(mean, variance over n - 1) of the present values along ``axis`` in
+    float32, by the corrected two-pass sum: a first mean ``m0``, then the
+    sums of ``d = x - m0`` and of ``d * d``.  The one-pass form this
+    replaces, ``sum(x * x) / n - mean ** 2``, subtracts two numbers of the
+    size of ``mean ** 2``: for a column like a year (mean 1997, deviation 6)
+    float32 left the deviation 11-13 % off at 40M rows (PERF.md section 6,
+    PR 30).  ``x`` holds 0 where a value is absent; a column with a
+    non-finite value keeps the first mean and a NaN variance."""
+    keep = axis is not None
+    m0 = jnp.sum(x, axis=axis, dtype=jnp.float32, keepdims=keep) / (
+        nf[:, None] if keep else nf)
+    d = jnp.where(present, x - m0, 0.0)
+    sd = jnp.sum(d, axis=axis, dtype=jnp.float32)
+    sdd = jnp.sum(d * d, axis=axis, dtype=jnp.float32)
+    m0 = m0[:, 0] if keep else m0
+    mean = jnp.where(jnp.isfinite(m0), m0 + sd / nf, m0)
+    var = jnp.maximum(sdd - sd * sd / nf, 0.0) / jnp.maximum(nf - 1.0, 1.0)
+    return mean, var
+
+
 def _batch_rollup_kernel_impl(X, n: int):
     """Rollups for a whole [C, padded] column block in ONE fused pass —
     per-column eager rollups cost a dispatch and a fetch each, which a
@@ -98,16 +119,12 @@ def _batch_rollup_kernel_impl(X, n: int):
     x = jnp.where(present, X, 0.0)
     cnt = jnp.sum(present, axis=1)
     nf = jnp.maximum(cnt, 1).astype(jnp.float32)
-    s = jnp.sum(x, axis=1, dtype=jnp.float32)
-    ss = jnp.sum(x * x, axis=1, dtype=jnp.float32)
-    mean = s / nf
-    var = jnp.maximum(ss / nf - mean * mean, 0.0)
+    mean, var = _moments(x, present, nf, axis=1)
     big = jnp.float32(np.finfo(np.float32).max)
     vmin = jnp.min(jnp.where(present, X, big), axis=1)
     vmax = jnp.max(jnp.where(present, X, -big), axis=1)
     nzero = jnp.sum(present & (X == 0.0), axis=1)
-    return (cnt, mean, var * nf / jnp.maximum(nf - 1.0, 1.0), vmin, vmax,
-            nzero)
+    return cnt, mean, var, vmin, vmax, nzero
 
 
 _batch_rollup_kernel = _ledger(
@@ -123,15 +140,12 @@ def _rollup_kernel_impl(data, valid):
     x = jnp.where(present, data, 0.0)
     n = jnp.sum(present)
     nf = jnp.maximum(n, 1).astype(jnp.float32)
-    s = jnp.sum(x, dtype=jnp.float32)
-    ss = jnp.sum(x * x, dtype=jnp.float32)
-    mean = s / nf
-    var = jnp.maximum(ss / nf - mean * mean, 0.0)
+    mean, var = _moments(x, present, nf, axis=None)
     big = jnp.float32(np.finfo(np.float32).max)
     vmin = jnp.min(jnp.where(present, data, big))
     vmax = jnp.max(jnp.where(present, data, -big))
     nzero = jnp.sum(present & (data == 0.0))
-    return n, mean, var * nf / jnp.maximum(nf - 1.0, 1.0), vmin, vmax, nzero
+    return n, mean, var, vmin, vmax, nzero
 
 
 _rollup_kernel = _ledger("frame_rollup", jax.jit(_rollup_kernel_impl),

@@ -17,7 +17,7 @@ performs test adaptation, guaranteeing train/test layout agreement.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +32,41 @@ MEAN_IMPUTATION = "mean_imputation"
 SKIP = "skip"
 
 
+class CodedDesign(NamedTuple):
+    """The design in code form: what ``make_matrix`` expands, unexpanded.
+
+    ``num``: [padded, P_num] float32, the numeric columns imputed and
+    standardised as ``make_matrix`` does it.  ``codes``: [padded, P_cat]
+    int32, per categorical the column WITHIN its one-hot block that the row
+    lights (its NA column is the block's last), or -1 where the row lights
+    none (the dropped first level, a code past the domain).
+    ``expand_coded(layout, num, codes)`` gives ``make_matrix``'s rows."""
+    num: jax.Array
+    codes: jax.Array
+
+
+def expand_coded(layout: Tuple[Tuple[str, int], ...], num: jax.Array,
+                 codes: jax.Array) -> jax.Array:
+    """[rows, nfeatures] dense rows of a code-form design, in
+    ``make_matrix``'s column order.  ``layout`` is ``DataInfo.coded_layout``
+    (static, hashable), so this traces inside any jitted program: a
+    minibatch or a block of rows is expanded where it is used, the frame
+    never."""
+    cols, i_num, i_cat = [], 0, 0
+    for kind, width in layout:
+        if kind == "num":
+            cols.append(num[:, i_num:i_num + width])
+            i_num += width
+        elif kind == "cat":
+            block = jnp.arange(width, dtype=jnp.int32)
+            cols.append((codes[:, i_cat, None] == block[None, :])
+                        .astype(jnp.float32))
+            i_cat += 1
+        else:                           # the intercept's column of ones
+            cols.append(jnp.ones((num.shape[0], width), jnp.float32))
+    return cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
+
+
 @dataclasses.dataclass
 class ColumnSpec:
     name: str
@@ -42,6 +77,28 @@ class ColumnSpec:
     time_base: float = 0.0
     offset: int = 0                 # first output column index
     width: int = 1                  # number of output columns
+
+
+def _numeric_values(vec: Vec, s: ColumnSpec) -> jax.Array:
+    """A numeric column's device values, a time column moved onto the
+    training frame's time base."""
+    x = vec.data
+    if s.type == T_TIME and abs(vec.time_base - s.time_base) > 0:
+        x = x + (vec.time_base - s.time_base) / 1000.0
+    return x
+
+
+def _standardized(specs, arrs, standardize: bool) -> jax.Array:
+    """[C, padded] float32: a run of numeric columns imputed with their
+    training means and, if asked, standardised — ONE batched block, since
+    per-column eager ops cost a dispatch each."""
+    X = jnp.stack(arrs, axis=0).astype(jnp.float32)
+    means = jnp.asarray([s.mean for s in specs], jnp.float32)[:, None]
+    X = jnp.where(jnp.isnan(X), means, X)
+    if standardize:
+        sigmas = jnp.asarray([s.sigma for s in specs], jnp.float32)[:, None]
+        X = (X - means) / sigmas
+    return X
 
 
 @dataclasses.dataclass
@@ -174,15 +231,7 @@ class DataInfo:
                 return
             specs_r, arrs = zip(*num_run)
             num_run.clear()
-            X = jnp.stack(arrs, axis=0).astype(jnp.float32)  # [C, padded]
-            means = jnp.asarray([s.mean for s in specs_r],
-                                jnp.float32)[:, None]
-            X = jnp.where(jnp.isnan(X), means, X)
-            if standardize:
-                sigmas = jnp.asarray([s.sigma for s in specs_r],
-                                     jnp.float32)[:, None]
-                X = (X - means) / sigmas
-            cols.append(X.T)
+            cols.append(_standardized(specs_r, arrs, standardize).T)
 
         for s in self.specs:
             vec = frame.vec(s.name)
@@ -196,10 +245,7 @@ class DataInfo:
                 na = (codes < 0).astype(jnp.float32)[:, None]
                 cols.append(jnp.concatenate([onehot, na], axis=1))
             else:
-                x = vec.data
-                if s.type == T_TIME and abs(vec.time_base - s.time_base) > 0:
-                    x = x + (vec.time_base - s.time_base) / 1000.0
-                num_run.append((s, x))
+                num_run.append((s, _numeric_values(vec, s)))
         flush_numeric()
         if self.add_intercept:
             cols.append(jnp.ones((frame.padded_rows, 1), jnp.float32))
@@ -208,6 +254,65 @@ class DataInfo:
         mat = put_sharded(mat, cl.matrix_sharding)
         frame._matrix_cache[key] = mat
         return mat
+
+    def coded_layout(self) -> Tuple[Tuple[str, int], ...]:
+        """The expanded layout as runs in column order: ``("num", k)`` for
+        k numeric columns side by side, ``("cat", width)`` for one
+        categorical's block (NA column included), ``("one", 1)`` for the
+        intercept.  The widths sum to ``nfeatures``."""
+        runs: List[Tuple[str, int]] = []
+        for s in self.specs:
+            if s.type == T_CAT:
+                runs.append(("cat", s.width))
+            elif runs and runs[-1][0] == "num":
+                runs[-1] = ("num", runs[-1][1] + 1)
+            else:
+                runs.append(("num", 1))
+        if self.add_intercept:
+            runs.append(("one", 1))
+        return tuple(runs)
+
+    def make_coded(self, frame: Frame,
+                   standardize: Optional[bool] = None) -> CodedDesign:
+        """The design in code form (``CodedDesign``), row-sharded.
+
+        For clients whose first operation on the design is a product with a
+        weight matrix: a categorical of L levels costs them 4 bytes a row
+        here, not 4 L.  Layout, standardisation, NA and unseen-level
+        handling are ``make_matrix``'s: ``expand_coded(self.coded_layout(),
+        *self.make_coded(frame))`` equals ``self.make_matrix(frame)``.
+        Memoized in the Frame's ``_matrix_cache`` under one key per array,
+        so ``Frame.spill()`` evicts and counts both."""
+        standardize = self.standardize if standardize is None else standardize
+        keys = [("__coded__", part, standardize, self._design_signature())
+                for part in ("num", "codes")]
+        hit = [frame._matrix_cache.get(k) for k in keys]
+        if hit[0] is not None and hit[1] is not None:
+            return CodedDesign(*hit)
+        num_specs = [s for s in self.specs if s.type != T_CAT]
+        cat_specs = [s for s in self.specs if s.type == T_CAT]
+        padded = frame.padded_rows
+        if num_specs:
+            arrs = [_numeric_values(frame.vec(s.name), s) for s in num_specs]
+            num = _standardized(num_specs, arrs, standardize).T
+        else:
+            num = jnp.zeros((padded, 0), jnp.float32)
+        lo = 0 if self.use_all_factor_levels else 1
+        local = []
+        for s in cat_specs:
+            c = self._aligned_codes(frame.vec(s.name), s)
+            lit = (c >= lo) & (c < lo + s.width - 1)
+            local.append(jnp.where(c < 0, s.width - 1,
+                                   jnp.where(lit, c - lo, -1)))
+        codes = (jnp.stack(local, axis=1).astype(jnp.int32) if local
+                 else jnp.zeros((padded, 0), jnp.int32))
+        from ..runtime.cluster import put_sharded
+        sharding = cluster().matrix_sharding
+        design = CodedDesign(put_sharded(num, sharding),
+                             put_sharded(codes, sharding))
+        for k, a in zip(keys, design):
+            frame._matrix_cache[k] = a
+        return design
 
     def _design_signature(self) -> tuple:
         """Memo key for the design layout, computed once per DataInfo.

@@ -9,13 +9,24 @@ averaging :751-758).
 
 TPU-native redesign (SURVEY.md §2.10): Hogwild + periodic averaging is an
 artifact of JVM threads — synchronous data-parallel SGD is strictly better on
-TPU, so each step is ONE jit-compiled program: minibatch gather from the
-row-sharded design matrix, batched fprop/bprop as MXU matmuls (the per-row
+TPU, so each step is ONE jit-compiled program: a minibatch read from the
+row-sharded design, batched fprop/bprop as MXU matmuls (the per-row
 gemv loops become [batch, features] @ [features, hidden]), gradients psum'd
 over the mesh by GSPMD, optimizer update via optax (ADADELTA to match the
 reference's adaptive-rate default, DeepLearningModelInfo rho/epsilon).
 ``train_samples_per_iteration`` keeps its reference semantics: samples
 processed between scoring/early-stopping checks.
+
+The design is held in code form (``datainfo.CodedDesign``: numerics beside
+categorical codes), as the reference's ``Neurons.Input`` holds it; the dense
+one-hot row exists only for the minibatch or the scoring block in hand
+(``expand_coded``), never for the frame.  The first layer is then the plain
+product of that expansion with ``W1``: on the MXU a [batch, expanded] one-hot
+product costs less than a gather of three rows a sample and the scatter-add
+of its backward pass (PERF.md §6, PR 30), and input dropout, every
+activation and the autoencoder's target read the expanded minibatch as they
+read the dense matrix before.  ``reference_dl.py`` is the same mathematics
+in plain ``jax.numpy`` on the frame's dense expansion.
 """
 
 from __future__ import annotations
@@ -23,18 +34,22 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import List, Optional, Sequence
+from typing import List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..frame.frame import Frame
 from ..runtime import dkv
+from ..runtime.cluster import ROW_AXES, ROW_AXIS, cluster
 from ..runtime.job import Job
+from ..runtime import observability as obs
 from .base import Model, ModelBuilder, Parameters
-from .datainfo import DataInfo
+from .datainfo import CodedDesign, DataInfo, expand_coded
 from ..metrics.core import make_metrics
 from .scorekeeper import stop_early
 
@@ -88,8 +103,9 @@ class DeepLearningParameters(Parameters):
 def _forward_pass(activation: str, params, X, deterministic=True, rng=None,
                   dropout_in: float = 0.0, dropout_hidden=(),
                   compute_dtype=None):
-    """THE DL forward pass — shared by predict-time ``Model._forward`` and
-    the compiled training program (one implementation, so activation /
+    """THE DL forward pass on dense rows (a minibatch or a scoring block,
+    expanded by ``expand_coded``) — shared by the scoring program
+    (``_make_score``) and the compiled training program (one implementation, so activation /
     dropout semantics cannot drift between training and scoring).
 
     ``compute_dtype=bf16`` runs the matmuls on the MXU in bf16 with f32
@@ -122,43 +138,84 @@ def _forward_pass(activation: str, params, X, deterministic=True, rng=None,
     return mm(h, W) + b
 
 
-def _build_train_steps(activation: str, dropout_in: float, dropout_h: tuple,
-                       loss_kind: str, is_cls: bool, autoenc: bool,
-                       out_dim: int, l1: float, l2: float, opt_cfg: tuple,
-                       batch: int, steps_per_iter: int, n: int,
-                       custom_loss=None, compute_dtype=None):
+class _StepConfig(NamedTuple):
+    """Everything a training step closes over, hashable: the compiled
+    programs are cached on it, and a trained model rebuilds the same step
+    from its parameters and datainfo (``DeepLearningModel.train_interval``)."""
+    layout: tuple                # DataInfo.coded_layout()
+    activation: str
+    dropout_in: float
+    dropout_h: tuple
+    loss_kind: str
+    is_cls: bool
+    autoenc: bool
+    out_dim: int
+    l1: float
+    l2: float
+    opt_cfg: tuple
+    compute_dtype: object        # jnp.bfloat16 or None (float32)
+
+
+def _step_config(p: "DeepLearningParameters", di: DataInfo) -> _StepConfig:
+    layout = di.coded_layout()
+    is_cls = di.is_classifier and not p.autoencoder
+    if p.autoencoder:
+        out_dim = sum(width for _, width in layout)
+    else:
+        out_dim = di.nclasses if is_cls else 1
+    if p.adaptive_rate:
+        opt_cfg = ("adadelta", p.rho, p.epsilon)
+    elif p.momentum_stable > 0 or p.momentum_start > 0:
+        opt_cfg = ("sgd_momentum", p.rate,
+                   p.momentum_stable or p.momentum_start)
+    else:
+        opt_cfg = ("sgd", p.rate)
+    loss_kind = p.loss
+    if loss_kind == "automatic":
+        loss_kind = "cross_entropy" if is_cls else "quadratic"
+    dropout_h = tuple(p.hidden_dropout_ratios or ())
+    if p.activation.endswith("_with_dropout") and not dropout_h:
+        dropout_h = tuple(0.5 for _ in p.hidden)
+    return _StepConfig(layout, p.activation, p.input_dropout_ratio, dropout_h,
+                       loss_kind, is_cls, p.autoencoder, out_dim, p.l1, p.l2,
+                       opt_cfg,
+                       jnp.bfloat16 if p.precision == "bf16" else None)
+
+
+def _build_train_steps(cfg: _StepConfig, batch: int, steps_per_iter: int,
+                       n: int, custom_loss=None):
     """Build the compiled training-interval program (see _make_train_steps
     for the caching story; ``custom_loss`` bypasses the cache)."""
-
-    def forward(params, X, rng):
-        return _forward_pass(activation, params, X, deterministic=False,
-                             rng=rng, dropout_in=dropout_in,
-                             dropout_hidden=dropout_h,
-                             compute_dtype=compute_dtype)
-
-    def loss_fn(params, xb, yb, wb, key):
-        logits = forward(params, xb, key)
+    def loss_fn(params, nb, cb, yb, wb, key):
+        # the dense expansion exists for these rows only
+        xb = expand_coded(cfg.layout, nb, cb)
+        logits = _forward_pass(cfg.activation, params, xb,
+                               deterministic=False, rng=key,
+                               dropout_in=cfg.dropout_in,
+                               dropout_hidden=cfg.dropout_h,
+                               compute_dtype=cfg.compute_dtype)
         if custom_loss is not None:
-            pred = logits if (is_cls or autoenc) else logits[:, 0]
-            per = custom_loss(pred, xb if autoenc else yb)
-        elif autoenc:
+            pred = logits if (cfg.is_cls or cfg.autoenc) else logits[:, 0]
+            per = custom_loss(pred, xb if cfg.autoenc else yb)
+        elif cfg.autoenc:
             per = jnp.mean((logits - xb) ** 2, axis=1)
-        elif is_cls:
-            yi = jnp.clip(yb.astype(jnp.int32), 0, out_dim - 1)
+        elif cfg.is_cls:
+            yi = jnp.clip(yb.astype(jnp.int32), 0, cfg.out_dim - 1)
             per = optax.softmax_cross_entropy_with_integer_labels(logits, yi)
-        elif loss_kind == "absolute":
+        elif cfg.loss_kind == "absolute":
             per = jnp.abs(logits[:, 0] - yb)
-        elif loss_kind == "huber":
+        elif cfg.loss_kind == "huber":
             per = optax.huber_loss(logits[:, 0], yb, delta=1.0)
         else:
             per = (logits[:, 0] - yb) ** 2
         loss = jnp.sum(per * wb) / jnp.maximum(jnp.sum(wb), 1e-12)
-        if l2 > 0 or l1 > 0:
+        if cfg.l2 > 0 or cfg.l1 > 0:
             for W, _ in params:
-                loss = loss + l2 * jnp.sum(W * W) + l1 * jnp.sum(jnp.abs(W))
+                loss = loss + cfg.l2 * jnp.sum(W * W) \
+                    + cfg.l1 * jnp.sum(jnp.abs(W))
         return loss
 
-    kind, *hp = opt_cfg
+    kind, *hp = cfg.opt_cfg
     if kind == "adadelta":
         tx = optax.adadelta(learning_rate=1.0, rho=hp[0], eps=hp[1])
     elif kind == "sgd_momentum":
@@ -166,68 +223,69 @@ def _build_train_steps(activation: str, dropout_in: float, dropout_h: tuple,
     else:
         tx = optax.sgd(hp[0])
 
-    def sgd_step(X, y, w, carry, key):
+    def sgd_step(table, codes, carry, draw):
         params, opt_state = carry
-        k1, k2 = jax.random.split(key)
+        off, key = draw
         # random-offset contiguous block instead of a per-row gather: a
         # [batch]-row gather from a big table is far slower on TPU than a
         # contiguous read; dynamic_slice streams at HBM rate.  The rows
         # were permuted once up front (shuffle_training_data) and the
         # arrays carry a wraparound copy of the first `batch` rows
-        # (_extend_for_blocks), so offsets draw uniformly over [0, n) and
+        # (_sample_copy_fn), so offsets draw uniformly over [0, n) and
         # every row has identical inclusion probability (a [0, n-batch]
         # range would under-sample both array ends by up to batch x).
-        off = jax.random.randint(k1, (), 0, max(n, 1))
-        xb = jax.lax.dynamic_slice_in_dim(X, off, batch, axis=0)
-        yb = jax.lax.dynamic_slice_in_dim(y, off, batch, axis=0)
-        wb = jax.lax.dynamic_slice_in_dim(w, off, batch, axis=0)
-        loss, grads = jax.value_and_grad(loss_fn)(params, xb, yb, wb, k2)
+        tb = jax.lax.dynamic_slice_in_dim(table, off, batch, axis=0)
+        cb = jax.lax.dynamic_slice_in_dim(codes, off, batch, axis=0)
+        loss, grads = jax.value_and_grad(loss_fn)(
+            params, tb[:, :-2], cb, tb[:, -2], tb[:, -1], key)
         updates, opt_state = tx.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
         return (params, opt_state), loss
 
-    @jax.jit
-    def train_steps(params, opt_state, rng0, it, X, y, w):
-        # keys derive in-jit from (rng0, iteration), so the driver loop
-        # dispatches nothing but the step program
-        keys = jax.random.split(jax.random.fold_in(rng0, it), steps_per_iter)
+    # the name the device trace knows the program by: jit_dl_train_steps
+    def dl_train_steps(params, opt_state, rng0, it, table, codes):
+        # offsets and dropout keys derive in-jit from (rng0, iteration), so
+        # the driver loop dispatches nothing but the step program
         (params, opt_state), losses = jax.lax.scan(
-            functools.partial(sgd_step, X, y, w), (params, opt_state), keys)
+            functools.partial(sgd_step, table, codes),
+            (params, opt_state), _interval_draws(rng0, it, steps_per_iter, n))
         return params, opt_state, jnp.mean(losses)
 
-    return train_steps, tx
+    return jax.jit(dl_train_steps), tx
+
+
+def _interval_draws(rng0, it, steps: int, n: int):
+    """(block offsets [steps] in [0, n), dropout keys [steps]) of iteration
+    ``it``.  All of an interval's offsets are one draw up front: a draw
+    inside the step was 8 of its 21 us on the v5e (PERF.md section 6, PR 30)."""
+    k_off, k_drop = jax.random.split(jax.random.fold_in(rng0, it))
+    return (jax.random.randint(k_off, (steps,), 0, max(n, 1)),
+            jax.random.split(k_drop, steps))
 
 
 @functools.lru_cache(maxsize=None)
-def _shuffle_fn(n: int, padded: int):
-    """One compiled row-permutation program per (n, padded) geometry."""
+def _sample_copy_fn(n: int, batch: int, shuffle: bool):
+    """The ONE frame-sized copy a fit makes, as the sampler reads it: a
+    float table [n + batch, P_num + 2] (the numerics, then the label, then
+    the weight) and the codes [n + batch, P_cat], the design's rows permuted
+    if asked and followed by a wraparound copy of the first ``batch`` of
+    them, so that the block sampler's dynamic_slice at any offset in [0, n)
+    stays in bounds.  Label and weight ride in the numerics' table because
+    a gather costs per index, not per value: on the v5e 0.50 s for a
+    40M-row vector, 0.28 s for the [40M, 5] table (PERF.md section 6, PR 30).
+    Compiled once per geometry."""
     @jax.jit
-    def sh(X, y, w, key):
-        perm = jax.random.permutation(key, n)
-        idx = jnp.concatenate([perm, jnp.arange(n, padded)])
-        return (jnp.take(X, idx, axis=0), jnp.take(y, idx),
-                jnp.take(w, idx))
-    return sh
+    def dl_sample_copy(num, codes, y, w, key):
+        order = jax.random.permutation(key, n) if shuffle else jnp.arange(n)
+        idx = jnp.concatenate([order, order[:batch]])
+        table = jnp.concatenate([num, y[:, None], w[:, None]], axis=1)
+        return jnp.take(table, idx, axis=0), jnp.take(codes, idx, axis=0)
+    return dl_sample_copy
 
 
 @functools.lru_cache(maxsize=None)
-def _extend_fn(n: int, batch: int):
-    """Append a wraparound copy of the first `batch` rows so the block
-    sampler's dynamic_slice at any offset in [0, n) stays in bounds."""
-    @jax.jit
-    def ext(X, y, w):
-        return (jnp.concatenate([X[:n], X[:batch]], axis=0),
-                jnp.concatenate([y[:n], y[:batch]]),
-                jnp.concatenate([w[:n], w[:batch]]))
-    return ext
-
-
-@functools.lru_cache(maxsize=None)
-def _make_train_steps(activation: str, dropout_in: float, dropout_h: tuple,
-                      loss_kind: str, is_cls: bool, autoenc: bool,
-                      out_dim: int, l1: float, l2: float, opt_cfg: tuple,
-                      batch: int, steps_per_iter: int, n: int,
-                      compute_dtype=None):
+def _make_train_steps(cfg: _StepConfig, batch: int, steps_per_iter: int,
+                      n: int):
     """Compiled training-interval program, CACHED ACROSS train() calls.
 
     The per-call ``@jax.jit def train_steps`` pattern recompiled (and paid
@@ -236,13 +294,86 @@ def _make_train_steps(activation: str, dropout_in: float, dropout_h: tuple,
     then could not reuse (measured on chip: the timed MNIST run spent most
     of its wall clock there, reporting 2.7k samples/s).  Everything the
     program closes over is reconstructed from hashable config; the data
-    (X, y, w) are traced arguments, so any same-shaped training run reuses
-    the executable.  Returns (train_steps, tx).
+    (the sampler's table and codes) are traced arguments, so any same-shaped training
+    run reuses the executable.  Returns (train_steps, tx).
     """
-    return _build_train_steps(activation, dropout_in, dropout_h, loss_kind,
-                              is_cls, autoenc, out_dim, l1, l2, opt_cfg,
-                              batch, steps_per_iter, n,
-                              compute_dtype=compute_dtype)
+    return _build_train_steps(cfg, batch, steps_per_iter, n)
+
+
+def _train_program(p: "DeepLearningParameters", cfg: _StepConfig, batch: int,
+                   steps_per_iter: int, n: int):
+    """(train_steps, tx) of a fit: cached across train() calls (same
+    architecture, config and shapes reuse one executable: no recompile, no
+    first-execution penalty) unless a custom python loss, which is not
+    hashable, rides along: then the same builder, uncached."""
+    if p.custom_loss_func is None:
+        return _make_train_steps(cfg, batch, steps_per_iter, n)
+    return _build_train_steps(cfg, batch, steps_per_iter, n,
+                              custom_loss=p.custom_loss_func)
+
+
+# ------------------------------------------------------------- scoring
+def _score_block_rows(widths: Sequence[int], rows: int) -> int:
+    """Rows one scoring block holds, from the layer widths and the device's
+    memory: a block keeps its expanded rows and every layer's activations
+    (float32, counted twice over for the casts and the compiler's
+    temporaries) inside a sixteenth of the device, so that a frame which
+    fills the chip still scores.  Where the backend reports no memory (the
+    CPU), a 4 GiB device is assumed."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    budget = int(stats.get("bytes_limit") or 4 << 30) // 16
+    per_row = 2 * 4 * sum(widths)
+    block = max(budget // per_row // 1024, 1) * 1024
+    return min(block, rows)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_score(layout: tuple, activation: str, emit: str, block: int):
+    """Compiled scoring program, cached on what it closes over: every
+    row-shard walks its own rows in blocks of ``block`` (the last block
+    is laid back over the one before it, so every block is whole), expands
+    one block, runs THE forward pass on it and keeps ``emit`` of the result:
+    ``"softmax"`` [rows, K], ``"first"`` (the regression output) [rows],
+    ``"logits"`` [rows, out] or ``"anomaly"`` (mean squared reconstruction
+    error) [rows]."""
+    def rows_of(params, nb, cb):
+        X = expand_coded(layout, nb, cb)
+        logits = _forward_pass(activation, params, X)
+        if emit == "softmax":
+            return jax.nn.softmax(logits, axis=1)
+        if emit == "first":
+            return logits[:, 0]
+        if emit == "anomaly":
+            return jnp.mean((logits - X) ** 2, axis=1)
+        return logits
+
+    def shard(params, num, codes):
+        rows = num.shape[0]
+        if rows <= block:
+            return rows_of(params, num, codes)
+        starts = jnp.minimum(jnp.arange(-(-rows // block)) * block,
+                             rows - block)
+
+        def one(out, start):
+            got = rows_of(params,
+                          jax.lax.dynamic_slice_in_dim(num, start, block),
+                          jax.lax.dynamic_slice_in_dim(codes, start, block))
+            return jax.lax.dynamic_update_slice_in_dim(out, got, start, 0), None
+
+        like = jax.eval_shape(rows_of, params, num[:block], codes[:block])
+        out = jax.lax.pcast(jnp.zeros((rows,) + like.shape[1:], like.dtype),
+                            ROW_AXES, to="varying")
+        return jax.lax.scan(one, out, starts)[0]
+
+    # the name the device trace knows the program by: jit_dl_score
+    def dl_score(params, num, codes):
+        out_spec = P(ROW_AXIS) if emit in ("first", "anomaly") \
+            else P(ROW_AXIS, None)
+        return shard_map(shard, mesh=cluster().mesh,
+                         in_specs=(P(), P(ROW_AXIS, None), P(ROW_AXIS, None)),
+                         out_specs=out_spec)(params, num, codes)
+
+    return jax.jit(dl_score)
 
 
 def _activation_fn(name: str):
@@ -259,22 +390,30 @@ def _activation_fn(name: str):
 class DeepLearningModel(Model):
     algo = "deeplearning"
 
-    def _forward(self, params, X, deterministic=True, rng=None,
-                 dropout_in=0.0, dropout_hidden=()):
-        return _forward_pass(self.params.activation, params, X,
-                             deterministic=deterministic, rng=rng,
-                             dropout_in=dropout_in,
-                             dropout_hidden=tuple(dropout_hidden))
+    def _device_params(self):
+        return [(jnp.asarray(W), jnp.asarray(b))
+                for W, b in self.output["weights"]]
 
-    def _predict_raw(self, X: jax.Array) -> jax.Array:
-        params = [(jnp.asarray(W), jnp.asarray(b))
-                  for W, b in self.output["weights"]]
-        logits = self._forward(params, X)
+    def _score_matrix(self, frame: Frame) -> CodedDesign:
+        """The design ``_predict_raw`` expects: in code form."""
+        return self.datainfo.make_coded(frame)
+
+    def _score(self, X: CodedDesign, emit: str) -> jax.Array:
+        """``emit`` of the forward pass over every row of ``X``, in row
+        blocks sized from the layer widths and the device's memory."""
+        di, weights = self.datainfo, self.output["weights"]
+        widths = [weights[0][0].shape[0]] + [W.shape[1] for W, _ in weights]
+        rows = X.num.shape[0] // cluster().n_row_shards
+        score = _make_score(di.coded_layout(), self.params.activation, emit,
+                            _score_block_rows(widths, rows))
+        return score(self._device_params(), X.num, X.codes)
+
+    def _predict_raw(self, X: CodedDesign) -> jax.Array:
         if self.params.autoencoder:
-            return logits
+            return self._score(X, "logits")
         if self.datainfo.is_classifier:
-            return jax.nn.softmax(logits, axis=1)
-        mu = logits[:, 0]
+            return self._score(X, "softmax")
+        mu = self._score(X, "first")
         if self.datainfo.standardize:
             mu = mu * self.datainfo.response_sigma + self.datainfo.response_mean
         return mu
@@ -288,7 +427,7 @@ class DeepLearningModel(Model):
         from ..frame.vec import Vec, T_NUM, T_CAT
         di = self.datainfo
         R = np.asarray(self._predict_raw(
-            di.make_matrix(frame)))[: frame.nrows].astype(np.float64)
+            self._score_matrix(frame)))[: frame.nrows].astype(np.float64)
         if di.standardize:
             for s in di.specs:
                 if s.type != T_CAT:
@@ -304,11 +443,65 @@ class DeepLearningModel(Model):
     def anomaly(self, frame: Frame) -> Frame:
         """Autoencoder per-row reconstruction MSE (DL anomaly detection)."""
         from ..frame.vec import Vec, T_NUM
-        di = self.datainfo
-        X = di.make_matrix(frame)
-        R = self._predict_raw(X)
-        err = np.asarray(jnp.mean((R - X) ** 2, axis=1))[: frame.nrows]
+        err = np.asarray(self._score(self._score_matrix(frame),
+                                     "anomaly"))[: frame.nrows]
         return Frame(["Reconstruction.MSE"], [Vec.from_numpy(err, T_NUM)])
+
+    def train_interval(self, frame: Frame, steps: int, seed: int = 0) -> dict:
+        """One launch of the fit's own programs on ``frame`` (a small one),
+        from this model's weights and a fresh optimizer state, with all it
+        read, so that a check can replay the launch against a reference.
+        ``jit_dl_sample_copy`` makes the sampler's copy as ``_fit`` has it
+        made, and ``jit_dl_train_steps``, from the builder and at the
+        minibatch size ``_fit`` uses, runs ``steps`` minibatches of it.
+        Returns ``offsets`` [steps] (a minibatch is the ``mini_batch_size``
+        rows of the copy from its offset on), the copy as the step expands it
+        (``rows`` [n + batch, expanded width], in ``coef_names``' order, with
+        ``labels`` and ``row_weights``), the launch's mean ``loss``, the
+        ``weights`` after it and, under ADADELTA, its ``accumulators``
+        (``e_g``, ``e_d``: E[g^2] and E[D^2], shaped like the layers)."""
+        p, di, n = self.params, self.datainfo, frame.nrows
+        cfg = _step_config(p, di)
+        X = di.make_coded(frame)
+        y, w = _targets(p, di, frame, X)
+        batch = min(p.mini_batch_size, n)
+        rng, ks = jax.random.split(jax.random.PRNGKey(seed))
+        table, codes = _sample_copy_fn(n, batch, bool(p.shuffle_training_data))(
+            *X, y, w, ks)
+        train_steps, tx = _train_program(p, cfg, batch, steps, n)
+        params = self._device_params()
+        params, opt_state, loss = train_steps(params, tx.init(params), rng, 0,
+                                              table, codes)
+
+        def to_host(layers):
+            return [(np.asarray(W), np.asarray(b)) for W, b in layers]
+
+        out = {"offsets": np.asarray(_interval_draws(rng, 0, steps, n)[0]),
+               "rows": np.asarray(expand_coded(cfg.layout, table[:, :-2], codes)),
+               "labels": np.asarray(table[:, -2]),
+               "row_weights": np.asarray(table[:, -1]),
+               "loss": float(loss), "weights": to_host(params)}
+        for part in opt_state:
+            if isinstance(part, optax.ScaleByAdaDeltaState):
+                out["accumulators"] = {"e_g": to_host(part.e_g),
+                                       "e_d": to_host(part.e_x)}
+        return out
+
+
+def _targets(p: "DeepLearningParameters", di: DataInfo, frame: Frame,
+             X: CodedDesign):
+    """(y, w) as the training step reads them: class codes, the response
+    (standardised if the design is) or, for an autoencoder, nothing."""
+    if p.autoencoder:
+        y = jnp.zeros(X.num.shape[0], jnp.float32)
+    elif di.is_classifier:
+        y = di.response(frame)
+    else:
+        y = di.response(frame)
+        if di.standardize:
+            y = (y - di.response_mean) / di.response_sigma
+        y = jnp.nan_to_num(y)
+    return y, di.weights(frame)
 
 
 class DeepLearning(ModelBuilder):
@@ -343,25 +536,16 @@ class DeepLearning(ModelBuilder):
     def _fit(self, job: Job, frame: Frame, di: DataInfo,
              valid: Optional[Frame]) -> DeepLearningModel:
         p: DeepLearningParameters = self.params
-        X = di.make_matrix(frame)
+        cfg = _step_config(p, di)
+        with obs.span("dl.matrix"):
+            X0 = di.make_coded(frame)
+            y, w = _targets(p, di, frame, X0)
+            jax.block_until_ready((X0, y, w))
         n = frame.nrows
-        is_cls = di.is_classifier and not p.autoencoder
-        if p.autoencoder:
-            y = jnp.zeros(X.shape[0], jnp.float32)
-            out_dim = X.shape[1]
-        elif is_cls:
-            y = di.response(frame)
-            out_dim = di.nclasses
-        else:
-            y = di.response(frame)
-            if di.standardize:
-                y = (y - di.response_mean) / di.response_sigma
-            y = jnp.nan_to_num(y)
-            out_dim = 1
-        w = di.weights(frame)
 
         maxout = p.activation.startswith("maxout")
-        sizes = [X.shape[1], *p.hidden, out_dim]
+        sizes = [sum(width for _, width in cfg.layout), *p.hidden,
+                 cfg.out_dim]
         seed = p.effective_seed()
         rng = jax.random.PRNGKey(seed)
         rng, k0 = jax.random.split(rng)
@@ -375,28 +559,14 @@ class DeepLearning(ModelBuilder):
             params = [(jnp.asarray(W), jnp.asarray(b))
                       for W, b in prior.output["weights"]]
 
-        if p.adaptive_rate:
-            opt_cfg = ("adadelta", p.rho, p.epsilon)
-        elif p.momentum_stable > 0 or p.momentum_start > 0:
-            opt_cfg = ("sgd_momentum", p.rate,
-                       p.momentum_stable or p.momentum_start)
-        else:
-            opt_cfg = ("sgd", p.rate)
-
-        loss_kind = p.loss
-        if loss_kind == "automatic":
-            loss_kind = "cross_entropy" if is_cls else "quadratic"
-        dropout_h = tuple(p.hidden_dropout_ratios or ())
-        if p.activation.endswith("_with_dropout") and not dropout_h:
-            dropout_h = tuple(0.5 for _ in p.hidden)
-
         batch = min(p.mini_batch_size, n)
-        X0 = X                      # unshuffled view for final scoring
-        if p.shuffle_training_data:
-            rng, ks = jax.random.split(rng)
-            X, y, w = _shuffle_fn(n, X.shape[0])(X, y, w, ks)
-        X, y, w = _extend_fn(n, batch)(X, y, w)
-        cd = jnp.bfloat16 if p.precision == "bf16" else None
+        rng, ks = jax.random.split(rng)
+        with obs.span("dl.shuffle", rows=n):
+            # the design stays as it is (the frame memoizes it, and the
+            # training metrics score it in frame order); the sampler reads
+            # this one copy, dropped when the last iteration has run
+            table, codes = jax.block_until_ready(_sample_copy_fn(
+                n, batch, bool(p.shuffle_training_data))(*X0, y, w, ks))
 
         # iteration sizing: train_samples_per_iteration semantics
         tspi = p.train_samples_per_iteration
@@ -410,20 +580,7 @@ class DeepLearning(ModelBuilder):
         steps_per_iter = max(samples_per_iter // batch, 1)
         n_iters = max(total_samples // (steps_per_iter * batch), 1)
 
-        if p.custom_loss_func is None:
-            # cached across train() calls: same architecture/config/shapes
-            # reuse one executable (no recompile, no first-exec penalty)
-            train_steps, tx = _make_train_steps(
-                p.activation, p.input_dropout_ratio, dropout_h, loss_kind,
-                is_cls, p.autoencoder, out_dim, p.l1, p.l2, opt_cfg,
-                batch, steps_per_iter, n, compute_dtype=cd)
-        else:
-            # custom python loss: not hashable — same builder, uncached
-            train_steps, tx = _build_train_steps(
-                p.activation, p.input_dropout_ratio, dropout_h, loss_kind,
-                is_cls, p.autoencoder, out_dim, p.l1, p.l2, opt_cfg,
-                batch, steps_per_iter, n, custom_loss=p.custom_loss_func,
-                compute_dtype=cd)
+        train_steps, tx = _train_program(p, cfg, batch, steps_per_iter, n)
 
         opt_state = tx.init(params)
         # Commit params/opt_state to the replicated sharding explicitly:
@@ -432,9 +589,7 @@ class DeepLearning(ModelBuilder):
         # previous run's outputs would compile TWO executables for the same
         # program (measured: a 5.7 s recompile inside bench.py's timed DL
         # run, while the warmup had compiled the other variant).
-        from jax.sharding import NamedSharding, PartitionSpec
-        from ..runtime.cluster import cluster
-        rep = NamedSharding(cluster().mesh, PartitionSpec())
+        rep = NamedSharding(cluster().mesh, P())
         params = jax.device_put(params, rep)
         opt_state = jax.device_put(opt_state, rep)
 
@@ -451,58 +606,64 @@ class DeepLearning(ModelBuilder):
         t0 = _time.time()
         from ..runtime import failure, scheduler
         stopped_at = n_iters
-        for it in range(n_iters):
-            failure.maybe_inject("dl_iter")
-            # per-iteration device-lease yield (tree drivers yield at
-            # chunk boundaries): co-resident jobs interleave here
-            scheduler.DEVICE_LEASE.yield_turn()
-            params, opt_state, mean_loss = train_steps(params, opt_state,
-                                                       rng, it, X, y, w)
-            seen += steps_per_iter * batch
-            # progress snapshot: weights-so-far + remaining-epochs cursor;
-            # resume() restores weights via the checkpoint path and trains
-            # only the remaining epochs (throttled/async/best-effort)
-            from ..runtime import snapshot as _snapshot
-            _snapshot.maybe_snapshot(
-                job, model,
-                {"epochs_done": seen / n, "iteration": it,
-                 "resume_params": {
-                     "epochs": max(p.epochs - seen / n, 1e-3)}},
-                lambda ps=params: {
-                    "weights": [(np.asarray(W), np.asarray(b))
-                                for W, b in ps],
-                    "epochs_trained": seen / n,
-                    "samples_trained": seen})
-            if p.stopping_rounds:
-                entry = {"iteration": it, "epochs": seen / n,
-                         "samples": seen, "training_loss": float(mean_loss),
-                         "samples_per_sec": seen / max(_time.time() - t0,
-                                                       1e-9)}
-                history.append(entry)
-                job.update((it + 1) / n_iters,
-                           f"epoch {seen / n:.2f} "
-                           f"loss {float(mean_loss):.5f}")
-                if stop_early(
-                        [h["training_loss"] for h in history],
-                        p.stopping_rounds, p.stopping_tolerance,
-                        maximize=False):
-                    stopped_at = it + 1
-                    break
-            else:
-                device_losses.append(mean_loss)       # device scalar only
-                job.update((it + 1) / n_iters, f"epoch {seen / n:.2f}")
-        if not p.stopping_rounds and device_losses:
-            # batched device_get: one prefetch pass, no per-n_iters
-            # jnp.stack program compile
-            iter_losses = np.asarray(jax.device_get(device_losses))
-            dt = max(_time.time() - t0, 1e-9)
-            seen = 0
-            for it in range(stopped_at):
+        with obs.span("dl.train", iterations=n_iters, steps=steps_per_iter,
+                      batch=batch):
+            for it in range(n_iters):
+                failure.maybe_inject("dl_iter")
+                # per-iteration device-lease yield (tree drivers yield at
+                # chunk boundaries): co-resident jobs interleave here
+                scheduler.DEVICE_LEASE.yield_turn()
+                params, opt_state, mean_loss = train_steps(
+                    params, opt_state, rng, it, table, codes)
                 seen += steps_per_iter * batch
-                history.append({
-                    "iteration": it, "epochs": seen / n, "samples": seen,
-                    "training_loss": float(iter_losses[it]),
-                    "samples_per_sec": seen / (dt * (it + 1) / stopped_at)})
+                obs.inc("dl_train_launches_total")
+                obs.inc("dl_optimizer_steps_total", steps_per_iter)
+                obs.inc("dl_samples_trained_total", steps_per_iter * batch)
+                # progress snapshot: weights-so-far + remaining-epochs cursor;
+                # resume() restores weights via the checkpoint path and trains
+                # only the remaining epochs (throttled/async/best-effort)
+                from ..runtime import snapshot as _snapshot
+                _snapshot.maybe_snapshot(
+                    job, model,
+                    {"epochs_done": seen / n, "iteration": it,
+                     "resume_params": {
+                         "epochs": max(p.epochs - seen / n, 1e-3)}},
+                    lambda ps=params: {
+                        "weights": [(np.asarray(W), np.asarray(b))
+                                    for W, b in ps],
+                        "epochs_trained": seen / n,
+                        "samples_trained": seen})
+                if p.stopping_rounds:
+                    entry = {"iteration": it, "epochs": seen / n,
+                             "samples": seen, "training_loss": float(mean_loss),
+                             "samples_per_sec": seen / max(_time.time() - t0,
+                                                           1e-9)}
+                    history.append(entry)
+                    job.update((it + 1) / n_iters,
+                               f"epoch {seen / n:.2f} "
+                               f"loss {float(mean_loss):.5f}")
+                    if stop_early(
+                            [h["training_loss"] for h in history],
+                            p.stopping_rounds, p.stopping_tolerance,
+                            maximize=False):
+                        stopped_at = it + 1
+                        break
+                else:
+                    device_losses.append(mean_loss)       # device scalar only
+                    job.update((it + 1) / n_iters, f"epoch {seen / n:.2f}")
+            if not p.stopping_rounds and device_losses:
+                # batched device_get: one prefetch pass, no per-n_iters
+                # jnp.stack program compile
+                iter_losses = np.asarray(jax.device_get(device_losses))
+                dt = max(_time.time() - t0, 1e-9)
+                seen = 0
+                for it in range(stopped_at):
+                    seen += steps_per_iter * batch
+                    history.append({
+                        "iteration": it, "epochs": seen / n, "samples": seen,
+                        "training_loss": float(iter_losses[it]),
+                        "samples_per_sec": seen / (dt * (it + 1) / stopped_at)})
+        del table, codes        # the sampler's copy: scoring needs the room
 
         model.output["weights"] = [(np.asarray(W), np.asarray(b))
                                    for W, b in params]
@@ -510,9 +671,13 @@ class DeepLearning(ModelBuilder):
         model.output["samples_trained"] = seen
         model.scoring_history = history
         if not p.autoencoder:
-            raw = model._predict_raw(X0)
-            yy = di.response(frame) if is_cls else jnp.nan_to_num(di.response(frame))
-            model.training_metrics = make_metrics(di, raw, yy, di.weights(frame))
-            if valid is not None:
-                model.validation_metrics = model.model_performance(valid)
+            with obs.span("dl.score", rows=n):
+                raw = model._predict_raw(X0)
+                yy = di.response(frame)
+                if not cfg.is_cls:
+                    yy = jnp.nan_to_num(yy)
+                model.training_metrics = make_metrics(di, raw, yy,
+                                                      di.weights(frame))
+                if valid is not None:
+                    model.validation_metrics = model.model_performance(valid)
         return model

@@ -44,6 +44,31 @@ def test_rollups(cl):
         r.sigma, np.std([34, 28, 45, 51], ddof=1), rtol=1e-5)
 
 
+@pytest.mark.parametrize("batched", [False, True])
+def test_rollups_of_a_column_far_from_zero(cl, batched):
+    """A year-like column (mean 1997, deviation 6) and a wide one with NA:
+    mean and deviation of the float32 rollups agree with float64 to 1e-5 of
+    a deviation, through the per-column kernel and the frame's batched one
+    (the one-pass ``sum(x*x)/n - mean**2`` was 1 % off here at 4,096 rows
+    and 12 % at 40M: PERF.md section 6, PR 30)."""
+    rng = np.random.default_rng(3)
+    n = 200_000
+    cols = {"year": rng.integers(1987, 2008, n).astype(np.float32),
+            "wide": np.abs(rng.normal(700, 500, n)).astype(np.float32)}
+    cols["wide"][::13] = np.nan
+    f = h2o3_tpu.Frame.from_numpy(cols)
+    if batched:
+        f.warm_rollups()
+        assert all(v._rollups is not None for v in f.vecs)
+    for name, x in cols.items():
+        x = x[~np.isnan(x)].astype(np.float64)
+        r = f.vec(name).rollups()
+        assert r.nmissing == n - len(x)
+        dev = x.std(ddof=1)
+        assert abs(r.mean - x.mean()) <= 1e-5 * dev, (name, r.mean, x.mean())
+        assert abs(r.sigma - dev) <= 1e-5 * dev, (name, r.sigma, dev)
+
+
 def test_padding_and_sharding(cl):
     f = make_frame(cl)
     v = f.vec("age")

@@ -296,11 +296,69 @@ def _split_records(hist, sds, rows):
     return fn, (sds((3, 32, F, B), jnp.float32),)
 
 
+# DeepLearning at the benchmark's dl_airlines40m geometry: 5 numerics and
+# categoricals of 22 / 300 / 300 levels in code form, hidden 200 x 200
+DL_LAYOUT = (("num", 5), ("cat", 22), ("cat", 300), ("cat", 300), ("one", 1))
+DL_SIZES = (628, 200, 200, 2)
+DL_BATCH = 128
+
+
+def _dl_params(sds):
+    return [(sds((i, o), jnp.float32), sds((o,), jnp.float32))
+            for i, o in zip(DL_SIZES[:-1], DL_SIZES[1:])]
+
+
+def _dl_design(sds, rows, n, targets=0):
+    """Numerics (with ``targets`` more columns: the sampler's label and
+    weight) and codes."""
+    return (sds((n, 5 + targets), jnp.float32, rows, None),
+            sds((n, 3), jnp.int32, rows, None))
+
+
+def _dl_train(sds, rows, n=SMALL_N, steps=16):
+    from h2o3_tpu.models import deeplearning as dl
+    cfg = dl._StepConfig(DL_LAYOUT, "rectifier", 0.0, (), "cross_entropy",
+                         True, False, 2, 0.0, 0.0, ("adadelta", 0.99, 1e-8),
+                         jnp.bfloat16)
+    fn, tx = dl._build_train_steps(cfg, DL_BATCH, steps, n)
+    params = _dl_params(sds)
+    return fn, (params, jax.eval_shape(tx.init, params),
+                sds((2,), jnp.uint32), 0,
+                *_dl_design(sds, rows, n + DL_BATCH, targets=2))
+
+
+def _dl_score(sds, rows, n=SMALL_N):
+    from h2o3_tpu.models import deeplearning as dl
+    block = dl._score_block_rows(DL_SIZES, n)
+    fn = dl._make_score(DL_LAYOUT, "rectifier", "softmax", block)
+    return fn, (_dl_params(sds), *_dl_design(sds, rows, n))
+
+
+def test_deeplearning_programs_hold_no_frame_sized_expansion(one_chip):
+    """The training-interval and the scoring program at 40M rows of the
+    airlines shape: beside the code-form design (2.24 GB with its targets)
+    neither holds a [rows, 628] expansion (100 GB) or a [rows, 200]
+    activation (32 GB); a scoring block and its activations stay under a
+    sixteenth of the chip."""
+    _, sds, rows = one_chip
+    n = 40_000_000
+    fn, operands = _dl_train(sds, rows, n, n // 10 // DL_BATCH)
+    train, _ = _compile(fn, *operands)
+    ma = train.memory_analysis()
+    assert ma.argument_size_in_bytes < 2.5e9 and ma.temp_size_in_bytes < 1e9
+    fn, operands = _dl_score(sds, rows, n)
+    score, _ = _compile(fn, *operands)
+    ma = score.memory_analysis()
+    assert ma.argument_size_in_bytes < 2.5e9 and ma.temp_size_in_bytes < 1.1e9
+    assert ma.output_size_in_bytes == n * 2 * 4
+
+
 MODULES = {
     "jit_run": _glm_path, "jit_scan_fn": _tree_scan,
     "jit_build": _tree_build,
     "jit_buildK": functools.partial(_tree_build, nk=3),
     "jit_traverse": _traverse, "jit_sketch": _sketch, "jit_encode": _encode,
+    "jit_dl_train_steps": _dl_train, "jit_dl_score": _dl_score,
 }
 KERNELS = {
     "hist_uniform": _hist_kernel(lambda hist, sds, rows: (
@@ -331,7 +389,7 @@ KERNELS = {
                          + [("kernel", k) for k in KERNELS]
                          + [("kernel", "serve_traverse")])
 def test_names_the_trace_reductions_match(one_chip, kind, name):
-    """The seven XLA module names the ``*_share`` metrics match stay as
+    """The XLA module names the ``*_share`` metrics match stay as
     they are, and every Pallas kernel's instruction is ``%<its name>.N``
     in what the chip's compiler emits (``hist_kernel_share`` matches
     ``^%hist_``)."""

@@ -59,6 +59,7 @@ def main(argv=None) -> int:
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     import h2o3_tpu
+    from h2o3_tpu.models import deeplearning as dl_mod
     from h2o3_tpu.models import glm as glm_mod
     from h2o3_tpu.models.tree import hist, shared
     from h2o3_tpu.runtime import autotune
@@ -180,9 +181,45 @@ def main(argv=None) -> int:
                     sds((P_HIGGS,), jnp.float32), sds((P_HIGGS,), jnp.float32),
                     sds((), jnp.float32), sds((), jnp.float32))
 
+    # DeepLearning at the benchmark's dl_airlines40m geometry: 5 numerics,
+    # categoricals of 22 / 300 / 300 levels (first level dropped, NA column
+    # added), intercept; hidden 200 x 200, bf16, ADADELTA, minibatch 128
+    dl_layout = (("num", 5), ("cat", 22), ("cat", 300), ("cat", 300),
+                 ("one", 1))
+    dl_sizes = (sum(w for _, w in dl_layout), 200, 200, 2)
+    n_dl, dl_batch = 40_000_000, 128
+    dl_params = [(sds((i, o), jnp.float32), sds((o,), jnp.float32))
+                 for i, o in zip(dl_sizes[:-1], dl_sizes[1:])]
+
+    def dl_design(n, targets=0):
+        return (sds((n, 5 + targets), jnp.float32, mat),
+                sds((n, 3), jnp.int32, mat))
+
+    def dl_sample_copy():
+        fn = dl_mod._sample_copy_fn(n_dl, dl_batch, True)
+        n = cl.pad_rows(n_dl)
+        vec = sds((n,), jnp.float32, rows)
+        return fn, (*dl_design(n), vec, vec, sds((2,), jnp.uint32))
+
+    def dl_train_steps():
+        cfg = dl_mod._StepConfig(dl_layout, "rectifier", 0.0, (),
+                                 "cross_entropy", True, False, 2, 0.0, 0.0,
+                                 ("adadelta", 0.99, 1e-8), jnp.bfloat16)
+        fn, tx = dl_mod._build_train_steps(cfg, dl_batch,
+                                           n_dl // 10 // dl_batch, n_dl)
+        state = jax.eval_shape(tx.init, dl_params)
+        return fn, (dl_params, state, sds((2,), jnp.uint32), 0,
+                    *dl_design(n_dl + dl_batch, targets=2))
+
+    def dl_score():
+        block = dl_mod._score_block_rows(dl_sizes, cl.pad_rows(n_dl))
+        fn = dl_mod._make_score(dl_layout, "rectifier", "softmax", block)
+        return fn, (dl_params, *dl_design(cl.pad_rows(n_dl)))
+
     programs = {f.__name__: f for f in (
         tree_build, tree_build_scan, tree_build_k7, tree_scan, sparse_level,
-        grid_scan, serve_xla, glm_path)}
+        grid_scan, serve_xla, glm_path, dl_sample_copy, dl_train_steps,
+        dl_score)}
     unknown = [p for p in args.programs if p not in programs]
     if unknown:
         ap.error(f"unknown program(s) {unknown}; known: {sorted(programs)}")
