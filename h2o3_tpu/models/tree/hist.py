@@ -2013,14 +2013,17 @@ def best_splits_hier(Hc, Hf, sel, ub, nbins: int, W: int, reg_lambda,
 
 
 def table_lookup(tables, idx, L: int):
-    """Row-wise lookup t[:, idx] for a small table t [K, L] via one-hot
-    matmul.
+    """Row-wise lookup t[:, idx] for a small table t [K, L], as a
+    [K, L] x [L, N] product with the one-hot of ``idx`` instead of a
+    per-row gather.  The one-hot is built [L, N] (minor dim = rows) so
+    nothing lane-pads; f32 keeps the lookup exact for finite float tables
+    (an infinite entry turns its whole row into NaN: 0 x inf).
 
-    XLA lowers ``t[idx]`` on TPU to a per-row dynamic gather that runs at
-    ~40M rows/sec (measured: 240 ms for 4 lookups over 10M rows) — the MXU
-    does the same lookup as a [K, L] x [L, N] product at memory speed.  The
-    one-hot is built [L, N] (minor dim = rows) so nothing lane-pads; f32
-    keeps the lookup exact for arbitrary float tables.
+    What the tree build uses to fetch a level's split parameters and leaf
+    values by every row's node, and what the per-level ensemble walk
+    (``shared._traverse_levels``: ensembles too deep or frames too wide for
+    ``traverse_block``, and every backend but the TPU) uses the same way.  One look-up is a pass over all
+    N rows, so a walk built on it costs a pass per tree and level.
     """
     oh = (jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
           == idx[None, :]).astype(jnp.float32)
@@ -2119,3 +2122,194 @@ def partition_right(codes, leaf, feat, bin_, na_left, valid,
     return (right & v).astype(jnp.int32)
 
 
+# ------------------------------------------------------------ ensemble walk
+#
+# Scoring rows through a stacked ensemble of shallow trees (shared.traverse).
+# A block of rows is read once and stays on the core while every tree is
+# walked over it: a tree is its 2^D - 1 node predicates, each one compare of
+# a feature's tile against a scalar, folded from the leaves up by selects.
+
+_I32_MIN, _I32_MAX = -2 ** 31, 2 ** 31 - 1
+_WALK_TILE = 128                 # sublane rows a fold works on: 16 registers
+_WALK_BLOCK = 256                # most sublane rows (of 128 rows) a block
+_WALK_VMEM = 24 * 1024 * 1024    # a block's feature tiles and their keys
+_WALK_SMEM_WORDS = 128 * 1024    # a launch's node tables (SMEM holds 256 K)
+_WALK_UNROLL = 6                 # levels a fold is written out for
+
+
+def _order_key(x):
+    """int32 image of a float32 whose integer order is the float order
+    (-0.0 beside +0.0).  Where NaN lands is the caller's to say."""
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    b = jnp.where(b == _I32_MIN, 0, b)
+    return b ^ ((b >> 31) & _I32_MAX)
+
+
+def _nan_keys(x):
+    """(keys with NaN below everything, keys with NaN above everything)."""
+    key, nan = _order_key(x), x != x
+    return jnp.where(nan, _I32_MIN, key), jnp.where(nan, _I32_MAX, key)
+
+
+def walk_block_rows(F: int) -> int:
+    """Sublane rows (of 128 rows each) of the widest row block whose F
+    feature tiles (double buffered) and 2F key tiles fit the kernel's VMEM;
+    0 where not even one register's rows fit: too wide for the blocked
+    walk."""
+    return min(_WALK_BLOCK, _WALK_VMEM // (4 * F * 128 * 4) // 8 * 8)
+
+
+def _walk_tables(levels, values, F: int):
+    """The stacked levels as the blocked walk's scalars, a row a tree with its
+    nodes in level order: which key tile a node compares (feature f
+    with NaN low where NA goes left, F + f with NaN high where it goes
+    right), the threshold's key, and the leaves.  A node that does not split
+    (or whose threshold is NaN) gets a key above every row's, so it sends
+    all rows left and validity costs the walk nothing."""
+    feat, thr, na_left, valid = (
+        jnp.concatenate([lv[i] for lv in levels], axis=1) for i in range(4))
+    na_left, valid = na_left.astype(bool), valid.astype(bool)
+    tile = jnp.clip(feat, 0, F - 1) + F * (valid & ~na_left)
+    thr = thr.astype(jnp.float32)
+    key = jnp.where(valid & (thr == thr), _order_key(thr), _I32_MAX)
+    return tile.astype(jnp.int32), key, values.astype(jnp.float32)
+
+
+def _fold(lo: int, hi: int, root, went_right, leaf):
+    """The value, for every row of a tile, of the subtree under node
+    ``root`` of level ``lo``, cut off at level ``hi``: ``went_right(d, i)``
+    is the predicate of node i of level d, ``leaf(i)`` what stands at node i
+    of level ``hi``.  Depth first, so that hi - lo partial results are
+    live, not 2^(hi - lo)."""
+    def value(d, i):
+        if d == hi:
+            return leaf(i)
+        return jnp.where(went_right(d, i), value(d + 1, 2 * i + 1),
+                         value(d + 1, 2 * i))
+    return value(lo, root)
+
+
+def _tree_value(D: int, t, went_right, leaf):
+    """Tree t's value for every row of a tile.  ``went_right(o)`` and
+    ``leaf(o)`` read the walk's tables at offset o.  A tree deeper than
+    ``_WALK_UNROLL`` is folded in two stages, so that the code stays short:
+    the levels above say which of the subtrees below a row ends in, and a
+    loop over those subtrees keeps the value of that one."""
+    def node(d, i):
+        return went_right(t * (2 ** D - 1) + 2 ** d - 1 + i)
+
+    def value(i):
+        return leaf(t * 2 ** D + i)
+    top = max(0, D - _WALK_UNROLL)
+    if not top:
+        return _fold(0, D, 0, node, value)
+    under = _fold(0, top, 0, node, lambda i: jnp.int32(i))
+
+    def keep(j, v):
+        return jnp.where(under == j, _fold(top, D, j, node, value), v)
+    return jax.lax.fori_loop(0, 2 ** top, keep,
+                             jnp.zeros(under.shape, jnp.float32))
+
+
+def _make_pallas_traverse_block(T: int, D: int, F: int, K: int, RB: int,
+                                nblk: int, carry: bool,
+                                interpret: bool = False):
+    """(tile[T*(2^D-1)], key[...], leaf[T*2^D], x[F, nblk*RB, 128]
+    [, acc[nblk*RB, 128]]) -> margin[nblk*RB, 128]: the sum of T trees'
+    leaves per row, on top of ``acc`` (whose buffer the margin takes) when a
+    launch carries an earlier chunk of trees on.  A fold works on K sublane
+    rows at a time."""
+
+    def kernel(tile_ref, key_ref, leaf_ref, x_ref, *rest):
+        *acc_ref, out_ref, keys_ref = rest
+
+        def keys_of(f, _):
+            keys_ref[f], keys_ref[F + f] = _nan_keys(x_ref[f])
+            return _
+        jax.lax.fori_loop(0, F, keys_of, 0)
+
+        def walk_tile(s, _):
+            rows = pl.ds(pl.multiple_of(s * K, K), K)
+
+            def add_tree(t, acc):
+                return acc + _tree_value(
+                    D, t,
+                    lambda o: keys_ref[tile_ref[o], rows, :] >= key_ref[o],
+                    lambda o: leaf_ref[o])
+
+            acc = acc_ref[0][rows, :] if carry else \
+                jnp.zeros((K, 128), jnp.float32)
+            out_ref[rows, :] = jax.lax.fori_loop(0, T, add_tree, acc)
+            return _
+        jax.lax.fori_loop(0, RB // K, walk_tile, 0)
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    margin = pl.BlockSpec((RB, 128), lambda i: (i, 0),
+                          memory_space=pltpu.VMEM)
+    return _named_kernel(
+        "traverse_block", kernel=kernel, grid=(nblk,),
+        in_specs=[smem, smem, smem,
+                  pl.BlockSpec((F, RB, 128), lambda i: (0, i, 0),
+                               memory_space=pltpu.VMEM)]
+        + ([margin] if carry else []),
+        out_specs=margin,
+        out_shape=_row_sds((nblk * RB, 128), jnp.float32),
+        input_output_aliases={4: 0} if carry else {},
+        scratch_shapes=[pltpu.VMEM((2 * F, RB, 128), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_WALK_VMEM + 16 * 1024 * 1024),
+        interpret=interpret)
+
+
+def traverse_block(levels, values, X, interpret: bool = False):
+    """Sum of leaf values over stacked trees for X [N, F], by the blocked
+    walk, a kernel for the TPU (``shared.traverse`` says when it runs).
+    Float32 leaves summed in tree order, as the per-level walk sums them.
+    ``interpret`` runs the kernel in Pallas' interpreter, for tests off the
+    chip."""
+    cl = cluster()
+    X = X.astype(jnp.float32)
+    N, F = X.shape
+    T, D = values.shape[0], len(levels)
+    shards = cl.n_row_shards
+    n_local = -(-N // shards)
+    cap = walk_block_rows(F)
+    K = min(_WALK_TILE, cap)
+    R = -(-n_local // (128 * K)) * K
+    nblk = -(-R // (cap // K * K))
+    RB = -(-R // (nblk * K)) * K
+    # an ensemble whose tables outgrow SMEM goes in equal chunks of whole
+    # trees, one launch each (a loop, so the kernel is compiled once), every
+    # launch adding to the margin of the one before; the trees that fill the
+    # last chunk up (fewer than there are launches) send every row to a leaf
+    # of 0.0
+    chunks = -(-T // max(1, _WALK_SMEM_WORDS // (3 * 2 ** D)))
+    Tc = -(-T // chunks)
+    tables = tuple(
+        jnp.pad(a, [(0, chunks * Tc - T), (0, 0)], constant_values=fill)
+        for a, fill in zip(_walk_tables(levels, values, F),
+                           (0, _I32_MAX, 0.0)))
+    call = _make_pallas_traverse_block(Tc, D, F, K, RB, nblk,
+                                       carry=chunks > 1, interpret=interpret)
+
+    def local(x, *tables):
+        # column by column, so that XLA writes the padded [F, rows / 128,
+        # 128] tiles in one pass over X (a transpose, a pad and a reshape
+        # of the whole matrix cost two frame-sized copies)
+        x = jnp.stack([jnp.pad(x[:, f], (0, nblk * RB * 128 - n_local))
+                       for f in range(F)]).reshape(F, nblk * RB, 128)
+        if chunks == 1:
+            acc = call(*(a.reshape(-1) for a in tables), x)
+        else:
+            acc, _ = jax.lax.scan(
+                lambda acc, chunk: (call(*chunk, x, acc), None),
+                jnp.zeros((nblk * RB, 128), jnp.float32),
+                tuple(a.reshape(chunks, -1) for a in tables))
+        return acc.reshape(-1)[:n_local]
+
+    if N % shards:
+        X = jnp.pad(X, [(0, n_local * shards - N), (0, 0)])
+    out = shard_map(local, mesh=cl.mesh,
+                    in_specs=(P(ROW_AXIS, None), P(), P(), P()),
+                    out_specs=P(ROW_AXIS), check_vma=False)(X, *tables)
+    return out[:N]

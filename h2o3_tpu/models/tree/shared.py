@@ -42,7 +42,7 @@ from .hist import (_ledger, make_hist_fn, make_fine_hist_fn,
                    offset_codes, best_splits, best_splits_hier,
                    fused_best_splits, fused_best_splits_batched,
                    select_superbins, partition, partition_right,
-                   table_lookup)
+                   table_lookup, traverse_block, walk_block_rows)
 
 
 @contextlib.contextmanager
@@ -355,23 +355,60 @@ class TreeList:
         self._stacked = StackedTrees.from_trees(self._cache)
 
 
-def traverse(levels, values, X):
-    """Sum of leaf values over stacked trees for raw feature matrix X.
+# The deepest ensemble the blocked walk takes: the deepest of which two
+# trees' node tables fit one launch's SMEM.  No crossover in time comes
+# first: on the v5e the blocked walk is 87x ahead of the per-level walk at
+# depth 6, 15x at 10 and 6.7x at 14 (PERF.md section 6, PR 31).
+TRAVERSE_BLOCK_DEPTH = 14
 
-    scan over trees; per level: look up node params, compare, descend.
-    NaN feature -> NA direction (sparsity-aware default, hist.py).  All
-    per-row lookups go through one-hot matmuls (hist.table_lookup) — TPU
-    per-row gathers are ~2 orders of magnitude slower.
-    """
-    from .hist import table_lookup
+
+def _on_tpu() -> bool:
+    from ...runtime.cluster import cluster
+    return cluster().mesh.devices.flat[0].platform == "tpu"
+
+
+def traverse_path(depth: int, F: int) -> str:
+    """Which walk ``traverse`` takes for an ensemble of this depth over F
+    features: "block" (hist.traverse_block, a kernel for the TPU) or
+    "level"."""
+    if _on_tpu() and depth <= TRAVERSE_BLOCK_DEPTH and walk_block_rows(F):
+        return "block"
+    return "level"
+
+
+def traverse(levels, values, X):
+    """Sum of leaf values over stacked trees for raw feature matrix X [N, F].
+
+    A row goes right at a node where its feature is at least the threshold
+    (NaN: where NA does not go left) and the node splits at all; leaves are
+    summed in float32, in tree order.  On the TPU shallow ensembles are
+    walked block by block with the rows resident on the core
+    (hist.traverse_block); deeper ones, frames too wide for a block and
+    every other backend, level by level over whole columns.  Both give the
+    same bits."""
+    if traverse_path(len(levels), X.shape[1]) == "block":
+        return traverse_block(levels, values, X)
+    return _traverse_levels(levels, values, X)
+
+
+def _traverse_levels(levels, values, X):
+    """The walk for deep ensembles, and for every ensemble off the TPU: scan
+    over trees; per level one ``table_lookup`` of the node parameters by
+    every row's node index, the row's feature by selects over X's columns,
+    compare, descend.  Every level is a pass over all rows, whatever its
+    width."""
     N, Fdim = X.shape
+    big = jnp.finfo(jnp.float32).max
 
     def one_tree(carry, tree_slices):
         acc = carry
         node = jnp.zeros(N, jnp.int32)
         for (feat, thr, na_left, valid) in tree_slices[0]:
             L = feat.shape[0]
-            tbl = jnp.stack([feat.astype(jnp.float32), thr,
+            # the look-up is a product with a one-hot, in which an infinite
+            # threshold would turn the whole level's into NaN
+            tbl = jnp.stack([feat.astype(jnp.float32),
+                             jnp.clip(thr, -big, big),
                              na_left.astype(jnp.float32),
                              valid.astype(jnp.float32)], axis=0)
             t = table_lookup(tbl, node, L)
@@ -653,14 +690,13 @@ def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
     A_lv = {d: min(2 ** d, A_cap) for d in range(sparse_from, max_depth)}
     Ap_lv = {d: (2 ** (d - 1) if d == sparse_from else A_lv[d - 1])
              for d in range(sparse_from, max_depth)}
-    from ...runtime.cluster import cluster
     # per-feature packed bins (DHistogram-style): only the TPU Pallas path
     # has the ragged kernel; dense einsum covers CPU tests.  The packed
     # result has the exact same [3, L, F, B] contract, so split search is
     # byte-identical — this is a pure kernel-cost optimization.
     # H2O3_TPU_HIST_IMPL=varbin forces the varbin path off-TPU (interpret
     # Pallas) so the multichip dryrun exercises the bench kernel code path.
-    on_tpu = cluster().mesh.devices.flat[0].platform == "tpu"
+    on_tpu = _on_tpu()
     use_varbin = varbin_kernel_engages(bin_counts, nbins, F)
     # Per-LEVEL kernel choice: the varbin Pallas kernel has no einsum
     # fallback, its minimum row block must keep [R, 3L] A-build
@@ -1585,9 +1621,7 @@ def varbin_kernel_engages(bin_counts, nbins: int, F: int) -> bool:
     programs where varbin wins (the autotuner arbitrates the rest)."""
     if bin_counts is None:
         return False
-    from ...runtime.cluster import cluster
-    on_tpu = cluster().mesh.devices.flat[0].platform == "tpu"
-    if not (on_tpu or os.environ.get("H2O3_TPU_HIST_IMPL", "") == "varbin"):
+    if not (_on_tpu() or os.environ.get("H2O3_TPU_HIST_IMPL", "") == "varbin"):
         return False
     return sum(min(b, nbins) + 9 for b in bin_counts) < F * (nbins + 1)
 
@@ -2544,24 +2578,27 @@ class SharedTreeModel(Model):
         return jnp.stack(cols, axis=1)
 
     def _raw_scores(self, X: jax.Array):
+        from ...runtime import observability as obs
         init = self.output["init_score"]
         K = self.output.get("nclass_trees", 1)
         stacked = self.output.get("stacked")
+
+        def walk(st):
+            obs.inc("traverse_dispatch_total",
+                    path=traverse_path(st.depth, X.shape[1]))
+            return traverse_jit(st.levels, st.values, X)
         if K == 1:
             if stacked is None:
                 stacked = StackedTrees.from_trees(self.output["trees"])
                 self.output["stacked"] = stacked
-            return init + traverse_jit(stacked.levels, stacked.values, X)
+            return init + walk(stacked)
         if stacked is None:
             trees = self.output["trees"]
             stacked = [StackedTrees.from_trees([t[k] for t in trees])
                        for k in range(K)]
             self.output["stacked"] = stacked
-        outs = []
-        for k in range(K):
-            outs.append(init[k]
-                        + traverse_jit(stacked[k].levels, stacked[k].values, X))
-        return jnp.stack(outs, axis=1)
+        return jnp.stack([init[k] + walk(stacked[k]) for k in range(K)],
+                         axis=1)
 
 
 def resolve_checkpoint(params, di, algo: str):

@@ -18,6 +18,7 @@ compile that passes is not a chip run.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -244,14 +245,17 @@ def _tree_build(sds, rows, nk=1):
     return fn, _tree_build_operands(sds, rows, nk)
 
 
-def _traverse(sds, rows):
+def _traverse(sds, rows, trees=4, depth=3, n=SMALL_N):
     from h2o3_tpu.models.tree import shared
-    trees = 4
     levels = [(sds((trees, 2 ** d), jnp.int32), sds((trees, 2 ** d), jnp.float32),
                sds((trees, 2 ** d), jnp.bool_), sds((trees, 2 ** d), jnp.bool_))
-              for d in range(3)]
-    return shared.traverse_jit, (levels, sds((trees, 8), jnp.float32),
-                                 sds((SMALL_N, F), jnp.float32, rows, None))
+              for d in range(depth)]
+    return shared.traverse_jit, (levels, sds((trees, 2 ** depth), jnp.float32),
+                                 sds((n, F), jnp.float32, rows, None))
+
+
+# xgb_airlines40m.score's ensemble, at the 10M rows of this file's geometry
+_traverse_cell = functools.partial(_traverse, trees=100, depth=6, n=N)
 
 
 def _sketch(sds, rows):
@@ -377,6 +381,14 @@ KERNELS = {
         (*_hist_operands(sds, rows, SMALL_N, jnp.int32),
          sds((4, F, 2), jnp.int32)))),
     "hist_split_records": _hist_kernel(_split_records),
+    # the blocked ensemble walk inside jit_traverse, at the score cell's shape
+    "traverse_block": _traverse_cell,
+    # deeper than the levels a fold is written out for: the two-stage fold
+    "traverse_block@deep": functools.partial(_traverse, trees=20, depth=10),
+    # an ordinary ensemble whose tables pass SMEM: a loop of three launches,
+    # each taking the margin of the one before (and its buffer)
+    "traverse_block@chunks": functools.partial(_traverse, trees=100,
+                                               depth=10),
     # where PR 26's trace read %branch_0_fun: the live branch of lax.cond
     "hist_uniform@cond": _hist_kernel(_scan_level),
     # under vmap the scope of name= alone reads vmap(hist_uniform)
@@ -393,7 +405,6 @@ def test_names_the_trace_reductions_match(one_chip, kind, name):
     they are, and every Pallas kernel's instruction is ``%<its name>.N``
     in what the chip's compiler emits (``hist_kernel_share`` matches
     ``^%hist_``)."""
-    import re
     _, sds, rows = one_chip
     if kind == "module":
         fn, operands = MODULES[name](sds, rows)
@@ -438,5 +449,13 @@ def test_four_chip_histogram_has_kernel_and_all_reduce(topo,
         assert "tpu_custom_call" in text and "all-reduce" in text
         ma = compiled.memory_analysis()
         assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 16e9
+        # the ensemble walk at the score cell's shape, X row-sharded: each
+        # chip walks its own rows, and nothing crosses chips
+        fn, operands = _traverse_cell(sds, ROW_AXIS)
+        compiled, text = _compile(fn, *operands)
+        assert "%traverse_block" in text and "tpu_custom_call" in text
+        assert not re.search(r"all-(reduce|gather|to-all)|collective-permute",
+                             text)
+        assert compiled.output_shardings.shard_shape((N,)) == (N // 4,)
     finally:
         h2o3_tpu.init(devices=jax.devices())

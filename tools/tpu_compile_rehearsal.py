@@ -171,6 +171,17 @@ def main(argv=None) -> int:
         return fn, (sds((nn,), jnp.int32), sds((nn,), jnp.float32),
                     sds((trees,), jnp.int32), sds((batch, F), jnp.float32))
 
+    def traverse():
+        # xgb_airlines40m.score's predict: 100 trees of depth 6 over 40M x 8
+        trees, n = 100, cl.pad_rows(40_000_000)
+        levels = [(sds((trees, 2 ** d), jnp.int32),
+                   sds((trees, 2 ** d), jnp.float32),
+                   sds((trees, 2 ** d), jnp.bool_),
+                   sds((trees, 2 ** d), jnp.bool_)) for d in range(DEPTH)]
+        return shared.traverse_jit, (
+            levels, sds((trees, 2 ** DEPTH), jnp.float32),
+            sds((n, F), jnp.float32, mat))
+
     def glm_path():
         fam = glm_mod._make_family("binomial", glm_mod.GLMParameters())
         fn = glm_mod._make_path_runner(fam, False, 50)
@@ -218,7 +229,7 @@ def main(argv=None) -> int:
 
     programs = {f.__name__: f for f in (
         tree_build, tree_build_scan, tree_build_k7, tree_scan, sparse_level,
-        grid_scan, serve_xla, glm_path, dl_sample_copy, dl_train_steps,
+        grid_scan, serve_xla, traverse, glm_path, dl_sample_copy, dl_train_steps,
         dl_score)}
     unknown = [p for p in args.programs if p not in programs]
     if unknown:
