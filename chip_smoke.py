@@ -6,9 +6,10 @@ a user calls (``h2o3_tpu.init``, ``import_file``, ``Frame.from_numpy``, the
 estimators' ``train``, ``model.predict``, ``model_performance``,
 ``serving.publish``, the REST server's realtime route), at the repository's
 headline sizes, on data made from ``--seed``.  Every phase checks its own
-output by the repository's own means (numpy references, the ``check`` modes
-that grow a tree both ways, the numpy ``ScoringModel``); a phase that raises
-or mismatches ends the run with a non-zero exit code.  There is no retry and
+output by the repository's own means (numpy references, two fits through
+both values of a kernel knob compared tree by tree, the numpy
+``ScoringModel``); a phase that raises or mismatches ends the run with a
+non-zero exit code.  There is no retry and
 no CPU form of the result.
 
     python chip_smoke.py                 # one chip; what the driver runs
@@ -42,7 +43,7 @@ import urllib.request
 import jax
 import numpy as np
 
-# make_airlines_like's generating logit is weak: its own AUC against the
+# the airlines generator's logit is weak: its own AUC against the
 # labels it draws is 0.534 (numpy, 2M rows), which is the ceiling a fit can
 # approach; the floor asks the 10-tree fit to have found that signal
 AUC_FLOOR_AIRLINES = 0.53
@@ -55,7 +56,7 @@ def parse_args(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rows", type=int, default=None,
                     help="rows of the headline frames (default 10,000,000; "
-                         "the ingest, check and 7-class frames take a tenth)")
+                         "the ingest, parity and 7-class frames take a tenth)")
     ap.add_argument("--trees", type=int, default=None,
                     help="trees of the headline XGBoost fit (default 10)")
     ap.add_argument("--seed", type=int, default=0)
@@ -167,13 +168,15 @@ def numpy_irls(X, y, iters=25):
 
 
 def airlines_frame(n, seed):
-    from bench import make_airlines_like
+    """The benchmark's airlines-shaped columns (categoricals and the label
+    as integer codes beside their domains) and their frame."""
+    from benchmark.datagen import airlines_like
     from h2o3_tpu import Frame
     from h2o3_tpu.frame.vec import T_CAT
-    cols, types, domains = make_airlines_like(n, seed)
-    fr = Frame.from_numpy(cols, types={k: T_CAT for k in types},
+    cols, domains, response = airlines_like.generate(n, seed)
+    fr = Frame.from_numpy(cols, types={k: T_CAT for k in domains},
                           domains=domains)
-    return cols, fr, types, domains
+    return cols, fr, [k for k in domains if k != response], domains
 
 
 def higgs_arrays(n, seed, d=28):
@@ -197,6 +200,25 @@ def tree_records(model):
     levels, values = jax.device_get((list(st.levels), st.values))
     return [tuple(np.asarray(a) for a in lv) for lv in levels], \
         np.asarray(values)
+
+
+def compare_trees(a, b):
+    """Two ``tree_records`` of the same shape, tree by tree: which nodes
+    split must agree everywhere and how they split wherever they do (a
+    node that does not split holds a candidate nothing reads); the leaves
+    may differ by float32 rounding, which the caller bounds."""
+    (lv_a, v_a), (lv_b, v_b) = a, b
+    differ = []                          # (level, field, nodes that differ)
+    for d, (x, y) in enumerate(zip(lv_a, lv_b)):
+        valid = x[3] & y[3]
+        for name, p, q in (("valid", x[3], y[3]),
+                           ("feat", x[0][valid], y[0][valid]),
+                           ("na_left", x[2][valid], y[2][valid]),
+                           ("thr", x[1][valid], y[1][valid])):
+            if not np.array_equal(p, q):
+                differ.append((d, name, int(np.sum(p != q))))
+    return {"trees": int(v_a.shape[0]), "structure_differs": differ,
+            "leaf_max_abs_diff": float(np.abs(v_a - v_b).max())}
 
 
 # --------------------------------------------------------------- one chip
@@ -297,36 +319,45 @@ def run_one_chip(args, S):
         assert args.rehearse or auc > AUC_FLOOR_AIRLINES, auc
         assert scan["reasons"].get("shape_change", 0) == 0, scan
 
-    # -- the chip's kernels against the repo's oracles, on the chip: each
-    # check mode grows the first tree both ways on the real data and raises
-    # on divergence, then trains on
+    # -- the chip's kernels against the repo's oracles, on the chip: two
+    # fits on the same data through both values of one knob, the other
+    # knobs pinned (so the tuner decides nothing), compared tree by tree
     cols_s = {k: v[:n_small] for k, v in cols.items()}
-    fr_s = Frame.from_numpy(cols_s, types={k: T_CAT for k in cat_cols},
+    fr_s = Frame.from_numpy(cols_s, types={k: T_CAT for k in domains},
                             domains=domains)
     Xh, yh = higgs_arrays(args.rows, args.seed)
     fr_hs = higgs_frame(Xh[:n_small], yh[:n_small])
-    for knob, frame, resp in (("hist_mode", fr_s, "dep_delayed_15min"),
-                              ("split_mode", fr_s, "dep_delayed_15min"),
-                              # the scan program composes with the uniform
-                              # kernels only, so its check needs a frame on
-                              # which the varbin kernel does not engage
-                              ("tree_program", fr_hs, "y")):
-        with S.phase(f"check_{knob}", rows=n_small) as out:
-            m = XGBoost(response_column=resp, ntrees=2, seed=1,
-                        **{knob: "check"}, **args.tree_kw).train(frame)
-            out.update(auc=round(float(m.training_metrics.auc), 4),
-                       tree_program=m.output.get("tree_program"))
+    pins = dict(hist_mode="subtract", split_mode="fused", hist_layout="dense",
+                tree_program="level")
+    for knob, pair, frame, resp in (
+            ("hist_mode", ("subtract", "full"), fr_s, "dep_delayed_15min"),
+            ("split_mode", ("fused", "separate"), fr_s, "dep_delayed_15min"),
+            # the scan program composes with the uniform kernels only, so
+            # its pair needs a frame on which the varbin kernel does not
+            # engage
+            ("tree_program", ("scan", "level"), fr_hs, "y")):
+        with S.phase(f"parity_{knob}", rows=n_small) as out:
+            fits = [XGBoost(response_column=resp, ntrees=2, seed=1,
+                            **{**pins, knob: value},
+                            **args.tree_kw).train(frame) for value in pair]
+            out.update(fits=list(pair),
+                       auc=[round(float(m.training_metrics.auc), 4)
+                            for m in fits],
+                       tree_program=[m.output["tree_program"] for m in fits],
+                       **compare_trees(*map(tree_records, fits)))
+            assert not out["structure_differs"], out
+            assert out["leaf_max_abs_diff"] < 1e-4, out
             if knob == "tree_program":
-                assert m.output["tree_program"] == "scan", \
-                    "tree_program='check' was downgraded: nothing compared"
+                assert out["tree_program"] == list(pair), out
     del fr_hs
 
     # -- K class trees per round as one batched build
     with S.phase("train_gbm_7class", rows=n_small) as out:
         cols7 = {k: v for k, v in cols_s.items() if k != "dep_delayed_15min"}
         cols7["cls"] = make_multiclass(cols_s, 7, rng)
-        fr7 = Frame.from_numpy(cols7, types={k: T_CAT for k in cat_cols},
-                               domains=domains)
+        fr7 = Frame.from_numpy(
+            cols7, types={k: T_CAT for k in cat_cols},
+            domains={k: domains[k] for k in cat_cols})
         m7 = GBM(response_column="cls", ntrees=3, seed=1,
                  **args.tree_kw).train(fr7)
         ll = float(m7.training_metrics.logloss)
@@ -487,28 +518,18 @@ def run_multichip(args, S):
         del fr_air, fr_h, xgb, glm
 
     with S.phase("compare") as out:
-        (lv4, v4), coef4 = results["4chip"]
-        (lv1, v1), coef1 = results["1chip"]
-        differ = []                      # (level, field, nodes that differ)
-        for d, (a, b) in enumerate(zip(lv4, lv1)):
-            valid = a[3] & b[3]
-            for name, x, y in (("valid", a[3], b[3]),
-                               ("feat", a[0][valid], b[0][valid]),
-                               ("na_left", a[2][valid], b[2][valid]),
-                               ("thr", a[1][valid], b[1][valid])):
-                if not np.array_equal(x, y):
-                    differ.append((d, name, int(np.sum(x != y))))
+        trees4, coef4 = results["4chip"]
+        trees1, coef1 = results["1chip"]
         h4, h1 = hists["4chip"], hists["1chip"]
         out.update(
-            trees=int(v4.shape[0]), structure_differs=differ,
-            leaf_max_abs_diff=float(np.abs(v4 - v1).max()),
+            **compare_trees(trees4, trees1),
             glm_coef_max_abs_diff=max(abs(coef4[k] - coef1[k])
                                       for k in coef4),
             root_hist_rel_diff=float(np.abs(h4 - h1).max()
                                      / np.abs(h1).max()),
             root_hist_counts_equal=bool(np.array_equal(h4[2], h1[2])))
         S.say(phase="compare_detail", **out)     # also when it fails below
-        assert not differ, differ
+        assert not out["structure_differs"], out["structure_differs"]
         assert out["root_hist_counts_equal"], "row counts per bin differ"
         # f32 tolerance: the same terms summed in another order
         assert out["leaf_max_abs_diff"] < 1e-4, out

@@ -156,7 +156,6 @@ class H2OGBMEstimator(_RemoteEstimator):
     huber_alpha : float, default 0.9
     score_tree_interval : int, default 5
     hist_precision : str, default 'bf16'
-    split_search : str, default 'auto'
     hist_mode : str, default 'auto'
     split_mode : str, default 'auto'
     hist_layout : str, default 'auto'
@@ -171,10 +170,10 @@ class H2OGBMEstimator(_RemoteEstimator):
     """
 
     algo = 'gbm'
-    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'split_search', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'custom_distribution_func')
-    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 50, 'max_depth': 5, 'min_rows': 10.0, 'nbins': 64, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 0.1, 'sample_rate': 1.0, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 0.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 0.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'split_search': 'auto', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'custom_distribution_func': None}
+    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'custom_distribution_func')
+    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 50, 'max_depth': 5, 'min_rows': 10.0, 'nbins': 64, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 0.1, 'sample_rate': 1.0, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 0.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 0.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'custom_distribution_func': None}
 
-    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, split_search=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, custom_distribution_func=None):
+    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, custom_distribution_func=None):
         self._init_from(locals())
 
 class H2ORandomForestEstimator(_RemoteEstimator):
@@ -230,7 +229,6 @@ class H2ORandomForestEstimator(_RemoteEstimator):
     huber_alpha : float, default 0.9
     score_tree_interval : int, default 5
     hist_precision : str, default 'bf16'
-    split_search : str, default 'auto'
     hist_mode : str, default 'auto'
     split_mode : str, default 'auto'
     hist_layout : str, default 'auto'
@@ -245,10 +243,10 @@ class H2ORandomForestEstimator(_RemoteEstimator):
     """
 
     algo = 'drf'
-    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'split_search', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'mtries')
-    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 50, 'max_depth': 20, 'min_rows': 1.0, 'nbins': 64, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 1.0, 'sample_rate': 0.632, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 0.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 0.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'split_search': 'auto', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'mtries': -1}
+    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'mtries')
+    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 50, 'max_depth': 20, 'min_rows': 1.0, 'nbins': 64, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 1.0, 'sample_rate': 0.632, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 0.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 0.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'mtries': -1}
 
-    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, split_search=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, mtries=None):
+    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, mtries=None):
         self._init_from(locals())
 
 class H2OXGBoostEstimator(_RemoteEstimator):
@@ -304,7 +302,6 @@ class H2OXGBoostEstimator(_RemoteEstimator):
     huber_alpha : float, default 0.9
     score_tree_interval : int, default 5
     hist_precision : str, default 'bf16'
-    split_search : str, default 'auto'
     hist_mode : str, default 'auto'
     split_mode : str, default 'auto'
     hist_layout : str, default 'auto'
@@ -325,10 +322,10 @@ class H2OXGBoostEstimator(_RemoteEstimator):
     """
 
     algo = 'xgboost'
-    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'split_search', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'booster', 'scale_pos_weight', 'rate_drop', 'skip_drop', 'one_drop', 'normalize_type', 'sample_type')
-    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 50, 'max_depth': 6, 'min_rows': 1.0, 'nbins': 256, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 0.3, 'sample_rate': 1.0, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 1.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 1.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'split_search': 'auto', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'booster': 'gbtree', 'scale_pos_weight': 1.0, 'rate_drop': 0.0, 'skip_drop': 0.0, 'one_drop': False, 'normalize_type': 'tree', 'sample_type': 'uniform'}
+    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'booster', 'scale_pos_weight', 'rate_drop', 'skip_drop', 'one_drop', 'normalize_type', 'sample_type')
+    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 50, 'max_depth': 6, 'min_rows': 1.0, 'nbins': 256, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 0.3, 'sample_rate': 1.0, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 1.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 1.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'booster': 'gbtree', 'scale_pos_weight': 1.0, 'rate_drop': 0.0, 'skip_drop': 0.0, 'one_drop': False, 'normalize_type': 'tree', 'sample_type': 'uniform'}
 
-    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, split_search=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, booster=None, scale_pos_weight=None, rate_drop=None, skip_drop=None, one_drop=None, normalize_type=None, sample_type=None):
+    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, booster=None, scale_pos_weight=None, rate_drop=None, skip_drop=None, one_drop=None, normalize_type=None, sample_type=None):
         self._init_from(locals())
 
 class H2ODeepLearningEstimator(_RemoteEstimator):
@@ -636,7 +633,6 @@ class H2OIsolationForestEstimator(_RemoteEstimator):
     huber_alpha : float, default 0.9
     score_tree_interval : int, default 5
     hist_precision : str, default 'bf16'
-    split_search : str, default 'auto'
     hist_mode : str, default 'auto'
     split_mode : str, default 'auto'
     hist_layout : str, default 'auto'
@@ -652,10 +648,10 @@ class H2OIsolationForestEstimator(_RemoteEstimator):
     """
 
     algo = 'isolationforest'
-    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'split_search', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'sample_size', 'contamination')
-    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 50, 'max_depth': 8, 'min_rows': 10.0, 'nbins': 64, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 0.1, 'sample_rate': 1.0, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 0.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 0.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'split_search': 'auto', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'sample_size': 256, 'contamination': -1.0}
+    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'sample_size', 'contamination')
+    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 50, 'max_depth': 8, 'min_rows': 10.0, 'nbins': 64, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 0.1, 'sample_rate': 1.0, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 0.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 0.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'sample_size': 256, 'contamination': -1.0}
 
-    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, split_search=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, sample_size=None, contamination=None):
+    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, sample_size=None, contamination=None):
         self._init_from(locals())
 
 class H2OExtendedIsolationForestEstimator(_RemoteEstimator):
@@ -711,7 +707,6 @@ class H2OExtendedIsolationForestEstimator(_RemoteEstimator):
     huber_alpha : float, default 0.9
     score_tree_interval : int, default 5
     hist_precision : str, default 'bf16'
-    split_search : str, default 'auto'
     hist_mode : str, default 'auto'
     split_mode : str, default 'auto'
     hist_layout : str, default 'auto'
@@ -728,10 +723,10 @@ class H2OExtendedIsolationForestEstimator(_RemoteEstimator):
     """
 
     algo = 'extendedisolationforest'
-    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'split_search', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'sample_size', 'contamination', 'extension_level')
-    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 50, 'max_depth': 8, 'min_rows': 10.0, 'nbins': 64, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 0.1, 'sample_rate': 1.0, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 0.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 0.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'split_search': 'auto', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'sample_size': 256, 'contamination': -1.0, 'extension_level': 0}
+    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'sample_size', 'contamination', 'extension_level')
+    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 50, 'max_depth': 8, 'min_rows': 10.0, 'nbins': 64, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 0.1, 'sample_rate': 1.0, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 0.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 0.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'sample_size': 256, 'contamination': -1.0, 'extension_level': 0}
 
-    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, split_search=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, sample_size=None, contamination=None, extension_level=None):
+    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, sample_size=None, contamination=None, extension_level=None):
         self._init_from(locals())
 
 class H2OIsotonicRegressionEstimator(_RemoteEstimator):
@@ -918,7 +913,6 @@ class H2OAdaBoostEstimator(_RemoteEstimator):
     huber_alpha : float, default 0.9
     score_tree_interval : int, default 5
     hist_precision : str, default 'bf16'
-    split_search : str, default 'auto'
     hist_mode : str, default 'auto'
     split_mode : str, default 'auto'
     hist_layout : str, default 'auto'
@@ -933,10 +927,10 @@ class H2OAdaBoostEstimator(_RemoteEstimator):
     """
 
     algo = 'adaboost'
-    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'split_search', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'nlearners')
-    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 50, 'max_depth': 3, 'min_rows': 5.0, 'nbins': 64, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 0.5, 'sample_rate': 1.0, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 0.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 0.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'split_search': 'auto', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'nlearners': 50}
+    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'nlearners')
+    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 50, 'max_depth': 3, 'min_rows': 5.0, 'nbins': 64, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 0.5, 'sample_rate': 1.0, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 0.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 0.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'nlearners': 50}
 
-    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, split_search=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, nlearners=None):
+    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, nlearners=None):
         self._init_from(locals())
 
 class H2OTargetEncoderEstimator(_RemoteEstimator):
@@ -1339,7 +1333,6 @@ class H2OUpliftRandomForestEstimator(_RemoteEstimator):
     huber_alpha : float, default 0.9
     score_tree_interval : int, default 5
     hist_precision : str, default 'bf16'
-    split_search : str, default 'auto'
     hist_mode : str, default 'auto'
     split_mode : str, default 'auto'
     hist_layout : str, default 'auto'
@@ -1356,10 +1349,10 @@ class H2OUpliftRandomForestEstimator(_RemoteEstimator):
     """
 
     algo = 'upliftdrf'
-    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'split_search', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'treatment_column', 'uplift_metric', 'mtries')
-    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 50, 'max_depth': 10, 'min_rows': 10.0, 'nbins': 64, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 0.1, 'sample_rate': 0.632, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 0.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 0.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'split_search': 'auto', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'treatment_column': '', 'uplift_metric': 'KL', 'mtries': -2}
+    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'treatment_column', 'uplift_metric', 'mtries')
+    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 50, 'max_depth': 10, 'min_rows': 10.0, 'nbins': 64, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 0.1, 'sample_rate': 0.632, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 0.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 0.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'treatment_column': '', 'uplift_metric': 'KL', 'mtries': -2}
 
-    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, split_search=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, treatment_column=None, uplift_metric=None, mtries=None):
+    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, treatment_column=None, uplift_metric=None, mtries=None):
         self._init_from(locals())
 
 class H2ODecisionTreeEstimator(_RemoteEstimator):
@@ -1415,7 +1408,6 @@ class H2ODecisionTreeEstimator(_RemoteEstimator):
     huber_alpha : float, default 0.9
     score_tree_interval : int, default 5
     hist_precision : str, default 'bf16'
-    split_search : str, default 'auto'
     hist_mode : str, default 'auto'
     split_mode : str, default 'auto'
     hist_layout : str, default 'auto'
@@ -1430,10 +1422,10 @@ class H2ODecisionTreeEstimator(_RemoteEstimator):
     """
 
     algo = 'dt'
-    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'split_search', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'mtries')
-    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 1, 'max_depth': 20, 'min_rows': 10.0, 'nbins': 64, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 1.0, 'sample_rate': 1.0, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 0.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 0.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'split_search': 'auto', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'mtries': -2}
+    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'ntrees', 'max_depth', 'min_rows', 'nbins', 'histogram_type', 'monotone_constraints', 'learn_rate', 'sample_rate', 'col_sample_rate', 'col_sample_rate_per_tree', 'min_split_improvement', 'reg_lambda', 'reg_alpha', 'gamma', 'min_child_weight', 'distribution', 'tweedie_power', 'quantile_alpha', 'huber_alpha', 'score_tree_interval', 'hist_precision', 'hist_mode', 'split_mode', 'hist_layout', 'sparse_depth_threshold', 'tree_program', 'calibrate_model', 'calibration_frame', 'calibration_method', 'reproducible', 'efb', 'mtries')
+    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': False, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'ntrees': 1, 'max_depth': 20, 'min_rows': 10.0, 'nbins': 64, 'histogram_type': 'QuantilesGlobal', 'monotone_constraints': None, 'learn_rate': 1.0, 'sample_rate': 1.0, 'col_sample_rate': 1.0, 'col_sample_rate_per_tree': 1.0, 'min_split_improvement': 1e-05, 'reg_lambda': 0.0, 'reg_alpha': 0.0, 'gamma': 0.0, 'min_child_weight': 0.0, 'distribution': 'auto', 'tweedie_power': 1.5, 'quantile_alpha': 0.5, 'huber_alpha': 0.9, 'score_tree_interval': 5, 'hist_precision': 'bf16', 'hist_mode': 'auto', 'split_mode': 'auto', 'hist_layout': 'auto', 'sparse_depth_threshold': 8, 'tree_program': 'auto', 'calibrate_model': False, 'calibration_frame': None, 'calibration_method': 'platt', 'reproducible': False, 'efb': 'auto', 'mtries': -2}
 
-    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, split_search=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, mtries=None):
+    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, ntrees=None, max_depth=None, min_rows=None, nbins=None, histogram_type=None, monotone_constraints=None, learn_rate=None, sample_rate=None, col_sample_rate=None, col_sample_rate_per_tree=None, min_split_improvement=None, reg_lambda=None, reg_alpha=None, gamma=None, min_child_weight=None, distribution=None, tweedie_power=None, quantile_alpha=None, huber_alpha=None, score_tree_interval=None, hist_precision=None, hist_mode=None, split_mode=None, hist_layout=None, sparse_depth_threshold=None, tree_program=None, calibrate_model=None, calibration_frame=None, calibration_method=None, reproducible=None, efb=None, mtries=None):
         self._init_from(locals())
 
 class H2OSupportVectorMachineEstimator(_RemoteEstimator):

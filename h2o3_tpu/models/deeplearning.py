@@ -290,8 +290,8 @@ def _make_train_steps(cfg: _StepConfig, batch: int, steps_per_iter: int,
 
     The per-call ``@jax.jit def train_steps`` pattern recompiled (and paid
     the remote backend's multi-second first-execution penalty) on every
-    train() — bench.py's warmup model compiled a program the timed model
-    then could not reuse (measured on chip: the timed MNIST run spent most
+    train() — a warm-up model compiled a program the timed model then
+    could not reuse (measured on chip: a timed MNIST-shaped run spent most
     of its wall clock there, reporting 2.7k samples/s).  Everything the
     program closes over is reconstructed from hashable config; the data
     (the sampler's table and codes) are traced arguments, so any same-shaped training
@@ -587,8 +587,8 @@ class DeepLearning(ModelBuilder):
         # the jit executable cache keys on input sharding+committedness, and
         # fresh eager arrays ("unspecified") vs committed arrays from a
         # previous run's outputs would compile TWO executables for the same
-        # program (measured: a 5.7 s recompile inside bench.py's timed DL
-        # run, while the warmup had compiled the other variant).
+        # program (measured: a 5.7 s recompile inside a timed DL run, while
+        # the warm-up had compiled the other variant).
         rep = NamedSharding(cluster().mesh, P())
         params = jax.device_put(params, rep)
         opt_state = jax.device_put(opt_state, rep)

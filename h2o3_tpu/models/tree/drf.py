@@ -30,10 +30,7 @@ from .binning import fit_bins, edges_matrix
 from .shared import (SharedTree, SharedTreeModel, SharedTreeParameters,
                      StackedTrees, TreeList, chunk_schedule, dense_mem_cap,
                      make_multinomial_scan_fn, make_tree_scan_fn,
-                     run_hist_crosscheck,
-                     run_layout_crosscheck, run_program_crosscheck,
-                     run_split_crosscheck,
-                     traverse_jit, use_hier_split_search)
+                     traverse_jit)
 from ...metrics.core import make_metrics
 
 
@@ -107,8 +104,7 @@ class DRF(SharedTree):
         from ...runtime import autotune
         knobs = autotune.resolve_tree_knobs(
             p, kind=self.algo, F=Fw, N=N, K=K,
-            plan=plan, hier=use_hier_split_search(p, N),
-            checkpoint=prior is not None)
+            plan=plan, checkpoint=prior is not None)
         autotune.activate(knobs)
         hist_mode, split_mode, hist_layout = (
             knobs.hist_mode, knobs.split_mode, knobs.hist_layout)
@@ -139,7 +135,7 @@ class DRF(SharedTree):
         eff_depth = record_effective_depth(model, p, Fw, N,
                                            hist_layout=hist_layout)
         # deep_level chaos hook fires only when sparse levels actually run
-        sparse_deep = (hist_layout in ("sparse", "check") and eff_depth
+        sparse_deep = (hist_layout == "sparse" and eff_depth
                        > max(1, min(p.sparse_depth_threshold,
                                     dense_mem_cap(p.nbins, Fw))))
 
@@ -187,77 +183,6 @@ class DRF(SharedTree):
         # a whole scoring interval of trees is one device dispatch.  The same
         # per-tree keys are reused across classes so every class sees the
         # same bootstrap sample per iteration (DRF.java samples once/tree).
-        if hist_mode == "check":
-            # driver assert: the forest's mean-fit gradients (g=-y, h=1)
-            # through both histogram paths must grow the same tree
-            run_hist_crosscheck(
-                wcodes, -targets[0] * w, w, w, edges_mat, rng,
-                max_depth=p.max_depth, nbins=p.nbins, F=Fw, n_padded=N,
-                bin_counts=wbin_counts, plan=plan,
-                reg_lambda=p.reg_lambda, min_rows=p.min_rows,
-                min_split_improvement=p.min_split_improvement,
-                learn_rate=1.0, reg_alpha=p.reg_alpha, gamma=p.gamma,
-                min_child_weight=p.min_child_weight)
-            hist_mode = "subtract"
-        # split_mode="check" — fused (batched-K for multiclass) vs the
-        # sequential best_splits oracle on the real mean-fit gradients
-        if split_mode == "check":
-            gK = jnp.stack([-t * w for t in targets])
-            hK = jnp.broadcast_to(w, gK.shape)
-            kchk = jnp.stack([jax.random.fold_in(rng, k)
-                              for k in range(K)]) if K > 1 else rng
-            run_split_crosscheck(
-                wcodes, gK if K > 1 else gK[0],
-                hK if K > 1 else hK[0], w, edges_mat, kchk,
-                max_depth=p.max_depth, nbins=p.nbins, F=Fw, n_padded=N,
-                bin_counts=wbin_counts, hist_mode=hist_mode,
-                reg_lambda=p.reg_lambda, min_rows=p.min_rows,
-                min_split_improvement=p.min_split_improvement,
-                learn_rate=1.0, col_sample_rate=col_rate,
-                reg_alpha=p.reg_alpha, gamma=p.gamma,
-                min_child_weight=p.min_child_weight)
-            split_mode = "fused"
-        # hist_layout="check" — dense vs node-sparse deep levels on the
-        # real mean-fit gradients, then training rides the sparse path
-        if hist_layout == "check":
-            gK = jnp.stack([-t * w for t in targets])
-            hK = jnp.broadcast_to(w, gK.shape)
-            kchk = jnp.stack([jax.random.fold_in(rng, k)
-                              for k in range(K)]) if K > 1 else rng
-            run_layout_crosscheck(
-                wcodes, gK if K > 1 else gK[0],
-                hK if K > 1 else hK[0], w, edges_mat, kchk,
-                max_depth=p.max_depth, nbins=p.nbins, F=Fw, n_padded=N,
-                bin_counts=wbin_counts,
-                sparse_depth_threshold=p.sparse_depth_threshold,
-                reg_lambda=p.reg_lambda, min_rows=p.min_rows,
-                min_split_improvement=p.min_split_improvement,
-                learn_rate=1.0, col_sample_rate=col_rate,
-                reg_alpha=p.reg_alpha, gamma=p.gamma,
-                min_child_weight=p.min_child_weight)
-            hist_layout = "sparse"
-            model.output["hist_layout"] = hist_layout
-        # tree_program="check" — the whole-tree scan program vs the
-        # per-level dispatch loop on the real mean-fit gradients, then
-        # training rides the scan-fused path (resolve_tree_program
-        # already downgraded "check" where the scan cannot grow)
-        if tree_program == "check":
-            gK = jnp.stack([-t * w for t in targets])
-            hK = jnp.broadcast_to(w, gK.shape)
-            kchk = jnp.stack([jax.random.fold_in(rng, k)
-                              for k in range(K)]) if K > 1 else rng
-            run_program_crosscheck(
-                wcodes, gK if K > 1 else gK[0],
-                hK if K > 1 else hK[0], w, edges_mat, kchk,
-                max_depth=p.max_depth, nbins=p.nbins, F=Fw, n_padded=N,
-                hist_precision=p.effective_hist_precision,
-                hist_mode=hist_mode, split_mode=split_mode,
-                reg_lambda=p.reg_lambda, min_rows=p.min_rows,
-                min_split_improvement=p.min_split_improvement,
-                learn_rate=1.0, col_sample_rate=col_rate,
-                reg_alpha=p.reg_alpha, gamma=p.gamma,
-                min_child_weight=p.min_child_weight)
-            tree_program = "scan"
         model.output["tree_program"] = tree_program
         # batched multiclass: one K-tree build per round (one hist + one
         # split launch per level for all K class trees) instead of K
@@ -276,7 +201,6 @@ class DRF(SharedTree):
             scan_fn = make_tree_scan_fn(
                 "drf", 0.0, 0.0, 0.0, p.max_depth, p.nbins, Fw, N,
                 p.effective_hist_precision, p.sample_rate, 1.0,
-                hier=use_hier_split_search(p, N),
                 bin_counts=wbin_counts, plan=plan, hist_mode=hist_mode,
                 split_mode=split_mode, hist_layout=hist_layout,
                 sparse_depth_threshold=p.sparse_depth_threshold,

@@ -35,11 +35,7 @@ from .binning import fit_bins, edges_matrix
 from .shared import (SharedTree, SharedTreeModel, SharedTreeParameters,
                      StackedTrees, Tree, TreeList, build_tree,
                      chunk_schedule, dense_mem_cap, make_build_tree_fn,
-                     make_tree_scan_fn,
-                     run_hist_crosscheck, run_layout_crosscheck,
-                     run_program_crosscheck,
-                     run_split_crosscheck, stack_trees,
-                     traverse_jit, use_hier_split_search)
+                     make_tree_scan_fn, stack_trees, traverse_jit)
 from ...metrics.core import make_metrics
 
 
@@ -157,8 +153,7 @@ class GBM(SharedTree):
         from ...runtime import autotune
         knobs = autotune.resolve_tree_knobs(
             p, kind=self.algo, F=Fw, N=N, K=K if multinomial else 1,
-            mono=mono, plan=plan, hier=use_hier_split_search(p, N),
-            checkpoint=prior is not None)
+            mono=mono, plan=plan, checkpoint=prior is not None)
         autotune.activate(knobs)
         hist_mode, split_mode, hist_layout = (
             knobs.hist_mode, knobs.split_mode, knobs.hist_layout)
@@ -187,7 +182,7 @@ class GBM(SharedTree):
         eff_depth = record_effective_depth(model, p, Fw, N,
                                            hist_layout=hist_layout)
         # deep_level chaos hook fires only when sparse levels actually run
-        sparse_deep = (hist_layout in ("sparse", "check") and eff_depth
+        sparse_deep = (hist_layout == "sparse" and eff_depth
                        > max(1, min(p.sparse_depth_threshold,
                                     dense_mem_cap(p.nbins, Fw))))
         if plan is not None:
@@ -277,105 +272,6 @@ class GBM(SharedTree):
             p.stopping_metric, di.is_classifier)
         fused = not multinomial and not dart
         fused_multi = multinomial and not dart
-
-        # hist_mode="check" — the driver assert: one tree grown with both
-        # the subtraction path and the full oracle on the REAL first-tree
-        # gradients must agree (shared.run_hist_crosscheck), then training
-        # proceeds on the subtraction path.
-        if hist_mode == "check":
-            if multinomial:
-                g0, h0 = grads_multi(Y1, F)
-                g0, h0 = g0[:, 0], h0[:, 0]
-            else:
-                g0, h0 = grads_single(y, F)
-            run_hist_crosscheck(
-                wcodes, g0 * w, h0 * w, w, edges_mat, rng,
-                max_depth=p.max_depth, nbins=p.nbins, F=Fw, n_padded=N,
-                bin_counts=wbin_counts, mono=mono, plan=plan,
-                reg_lambda=p.reg_lambda, min_rows=p.min_rows,
-                min_split_improvement=p.min_split_improvement,
-                learn_rate=p.learn_rate, reg_alpha=p.reg_alpha,
-                gamma=p.gamma, min_child_weight=p.min_child_weight)
-            hist_mode = "subtract"
-
-        # split_mode="check" — fused (batched-K for multinomial) vs the
-        # sequential best_splits oracle on the REAL first-round gradients
-        # (shared.run_split_crosscheck), then training rides the fused path.
-        if split_mode == "check":
-            if multinomial:
-                g0, h0 = grads_multi(Y1, F)
-                gc_, hc_ = (g0 * w[:, None]).T, (h0 * w[:, None]).T
-                kchk = jnp.stack([jax.random.fold_in(rng, k)
-                                  for k in range(K)])
-            else:
-                g0, h0 = grads_single(y, F)
-                gc_, hc_ = g0 * w, h0 * w
-                kchk = rng
-            run_split_crosscheck(
-                wcodes, gc_, hc_, w, edges_mat, kchk,
-                max_depth=p.max_depth, nbins=p.nbins, F=Fw, n_padded=N,
-                bin_counts=wbin_counts, hist_mode=hist_mode,
-                reg_lambda=p.reg_lambda, min_rows=p.min_rows,
-                min_split_improvement=p.min_split_improvement,
-                learn_rate=p.learn_rate, col_sample_rate=p.col_sample_rate,
-                reg_alpha=p.reg_alpha, gamma=p.gamma,
-                min_child_weight=p.min_child_weight)
-            split_mode = "fused"
-
-        # hist_layout="check" — dense vs node-sparse deep levels on the
-        # REAL first-round gradients (shared.run_layout_crosscheck: depth
-        # clamped to the DENSE cap so both layouts can grow it), then
-        # training rides the sparse path at the full layout-aware depth.
-        if hist_layout == "check":
-            if multinomial:
-                g0, h0 = grads_multi(Y1, F)
-                gc_, hc_ = (g0 * w[:, None]).T, (h0 * w[:, None]).T
-                kchk = jnp.stack([jax.random.fold_in(rng, k)
-                                  for k in range(K)])
-            else:
-                g0, h0 = grads_single(y, F)
-                gc_, hc_ = g0 * w, h0 * w
-                kchk = rng
-            run_layout_crosscheck(
-                wcodes, gc_, hc_, w, edges_mat, kchk,
-                max_depth=p.max_depth, nbins=p.nbins, F=Fw, n_padded=N,
-                bin_counts=wbin_counts,
-                sparse_depth_threshold=p.sparse_depth_threshold,
-                reg_lambda=p.reg_lambda, min_rows=p.min_rows,
-                min_split_improvement=p.min_split_improvement,
-                learn_rate=p.learn_rate, col_sample_rate=p.col_sample_rate,
-                reg_alpha=p.reg_alpha, gamma=p.gamma,
-                min_child_weight=p.min_child_weight)
-            hist_layout = "sparse"
-            model.output["hist_layout"] = hist_layout
-
-        # tree_program="check" — the whole-tree scan program vs the
-        # per-level dispatch loop on the REAL first-round gradients
-        # (shared.run_program_crosscheck), then training rides the
-        # scan-fused path.  resolve_tree_program already downgraded
-        # "check" to "level" for shapes the scan cannot grow (mono/plan/
-        # hier, engaged sparse layout, effective depth < 2, varbin).
-        if tree_program == "check":
-            if multinomial:
-                g0, h0 = grads_multi(Y1, F)
-                gc_, hc_ = (g0 * w[:, None]).T, (h0 * w[:, None]).T
-                kchk = jnp.stack([jax.random.fold_in(rng, k)
-                                  for k in range(K)])
-            else:
-                g0, h0 = grads_single(y, F)
-                gc_, hc_ = g0 * w, h0 * w
-                kchk = rng
-            run_program_crosscheck(
-                wcodes, gc_, hc_, w, edges_mat, kchk,
-                max_depth=p.max_depth, nbins=p.nbins, F=Fw, n_padded=N,
-                hist_precision=p.effective_hist_precision,
-                hist_mode=hist_mode, split_mode=split_mode,
-                reg_lambda=p.reg_lambda, min_rows=p.min_rows,
-                min_split_improvement=p.min_split_improvement,
-                learn_rate=p.learn_rate, col_sample_rate=p.col_sample_rate,
-                reg_alpha=p.reg_alpha, gamma=p.gamma,
-                min_child_weight=p.min_child_weight)
-            tree_program = "scan"
         model.output["tree_program"] = tree_program
 
         if fused_multi:
@@ -385,7 +281,6 @@ class GBM(SharedTree):
             scan_fn = make_multinomial_scan_fn(
                 K, p.max_depth, p.nbins, Fw, N,
                 p.effective_hist_precision, p.sample_rate, p.col_sample_rate_per_tree,
-                hier=use_hier_split_search(p, N),
                 bin_counts=wbin_counts, plan=plan, hist_mode=hist_mode,
                 split_mode=split_mode, hist_layout=hist_layout,
                 sparse_depth_threshold=p.sparse_depth_threshold,
@@ -453,7 +348,6 @@ class GBM(SharedTree):
                 dist.name, p.tweedie_power, p.quantile_alpha, p.huber_alpha,
                 p.max_depth, p.nbins, Fw, N, p.effective_hist_precision,
                 p.sample_rate, p.col_sample_rate_per_tree,
-                hier=use_hier_split_search(p, N) and mono is None,
                 bin_counts=wbin_counts, mono=mono, plan=plan,
                 custom_fn=getattr(p, "custom_distribution_func", None),
                 hist_mode=hist_mode, split_mode=split_mode,
@@ -559,7 +453,7 @@ class GBM(SharedTree):
                     rng, kk = jax.random.split(rng)
                     kks.append(kk)
                 from .hist import table_lookup
-                if split_mode == "fused" and not use_hier_split_search(p, N):
+                if split_mode == "fused":
                     # DART candidate round on the batched path: ONE build
                     # grows all K class trees (one launch per level)
                     fnK = make_build_tree_fn(
@@ -602,7 +496,6 @@ class GBM(SharedTree):
                             p.col_sample_rate, tree_mask,
                             p.reg_alpha, p.gamma, p.min_child_weight,
                             hist_precision=p.effective_hist_precision,
-                            hier=use_hier_split_search(p, N),
                             hist_mode=hist_mode, split_mode=split_mode,
                             hist_layout=hist_layout,
                             sparse_depth_threshold=p.sparse_depth_threshold,
@@ -632,7 +525,6 @@ class GBM(SharedTree):
                     p.col_sample_rate, tree_mask,
                     p.reg_alpha, p.gamma, p.min_child_weight, mono=mono,
                     hist_precision=p.effective_hist_precision,
-                    hier=use_hier_split_search(p, N) and mono is None,
                     hist_mode=hist_mode, split_mode=split_mode,
                     hist_layout=hist_layout,
                     sparse_depth_threshold=p.sparse_depth_threshold,

@@ -13,8 +13,8 @@ grow each cohort with ``make_grid_scan_fn`` — the grid analog of the
 multinomial K-tree batch: one histogram launch and one split launch per
 level for ALL G members, per-member PRNG via vmapped key chains, scalar
 hyperparameters as ``[G]`` operands.  A G-loop of sequential builds is
-the bitwise oracle (run_split_crosscheck's nk contract + the vmapped
-threefry contract).
+the bitwise oracle (the batched build's nk contract + the vmapped
+threefry contract; tests/test_grid_batch.py).
 
 Successive halving (``search_criteria={"successive_halving": True}``)
 retires losing members mid-train through the traced ``alive [G]`` mask:
@@ -23,9 +23,9 @@ leaf values are zero and its margin column freezes — zero recompiles,
 since ``alive`` is an operand of the one compiled program.
 
 Anything shape-changing or path-changing (multinomial, EFB bundling,
-hier split search, sparse layout, DART, monotone constraints, CV folds,
-checkpoints) falls back to the scheduler-parallel wave path in
-``grid.py`` — raised here as ``CohortFallback`` with the reason.
+sparse layout, DART, monotone constraints, CV folds, checkpoints) falls
+back to the scheduler-parallel wave path in ``grid.py`` — raised here as
+``CohortFallback`` with the reason.
 """
 
 from __future__ import annotations
@@ -76,17 +76,12 @@ def _eligibility(builder_cls, p) -> Optional[str]:
     if str(getattr(p, "histogram_type", "auto")).lower() == "random":
         return "random histogram_type (per-seed bin edges cannot share " \
                "one binning)"
-    if str(getattr(p, "split_search", "auto")).lower() == "hier":
-        return "hierarchical split search"
     if str(getattr(p, "split_mode", "auto")).lower() not in ("auto",
                                                              "fused"):
         return "split_mode (batched builds are fused-only)"
     if str(getattr(p, "hist_layout", "auto")).lower() not in ("auto",
                                                               "dense"):
         return "hist_layout (batched builds are dense-only)"
-    for knob in ("hist_mode", "tree_program"):
-        if str(getattr(p, knob, "auto")).lower() == "check":
-            return f"{knob}=check (per-member crosscheck diagnostics)"
     if str(getattr(p, "efb", "auto")).lower() == "on":
         return "efb=on (bundled working codes are per-plan)"
     if getattr(p, "calibrate_model", False):
@@ -183,8 +178,7 @@ def train_cohort(builder_cls, base_params: dict, combos: Sequence[dict],
     from .shared import (StackedTrees, TreeList, chunk_schedule,
                          effective_max_depth, make_grid_scan_fn,
                          maybe_bundle, record_effective_depth,
-                         traverse_jit, tree_snapshot_state,
-                         use_hier_split_search)
+                         traverse_jit, tree_snapshot_state)
 
     G = len(combos)
     if G < 2:
@@ -224,10 +218,8 @@ def train_cohort(builder_cls, base_params: dict, combos: Sequence[dict],
     plan, wcodes, Fw, _wbc = maybe_bundle(binned, p0, None, frame.nrows)
     if plan is not None:
         raise CohortFallback("EFB bundling engaged")
-    if use_hier_split_search(p0, N):
-        raise CohortFallback("hierarchical split search engaged")
     knobs = autotune.resolve_tree_knobs(p0, kind=rep.algo, F=Fw, N=N, K=1,
-                                        mono=None, plan=None, hier=False,
+                                        mono=None, plan=None,
                                         checkpoint=False)
     autotune.activate(knobs)
     hist_layout = knobs.hist_layout
@@ -235,7 +227,7 @@ def train_cohort(builder_cls, base_params: dict, combos: Sequence[dict],
         # _eligibility already rerouted an explicit "sparse", so this is
         # auto-resolution picking the node-sparse layout as a perf
         # choice.  Layouts are bitwise-equal at equal effective depth
-        # (run_layout_crosscheck contract), and they only diverge through
+        # (tests/test_sparse_levels.py), and they only diverge through
         # the dense memory cap — so pin the cohort to dense whenever
         # dense can grow the same depth, and fall back only when it
         # genuinely caps the tree shallower.
@@ -252,8 +244,7 @@ def train_cohort(builder_cls, base_params: dict, combos: Sequence[dict],
         hist_layout = "dense"
     if knobs.split_mode != "fused":
         raise CohortFallback(f"split_mode={knobs.split_mode}")
-    tree_program = knobs.tree_program \
-        if knobs.tree_program in ("level", "scan") else "level"
+    tree_program = knobs.tree_program
     if knobs.sparse_depth_threshold != p0.sparse_depth_threshold:
         for i, b in enumerate(builders):
             b.params = dataclasses.replace(

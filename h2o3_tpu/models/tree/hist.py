@@ -92,8 +92,7 @@ def _reduce_mode_dispatch(builder):
     return wrapper
 
 def _make_pallas_hist(L: int, F: int, B: int, n_local: int,
-                      interpret: bool = False, precision: str = "bf16",
-                      planes: int = 3):
+                      interpret: bool = False, precision: str = "bf16"):
     """tpu_hist kernel: histogram as an in-VMEM one-hot matmul.
 
     The XLA einsum path materializes the [rows, F*B] one-hot in HBM every
@@ -105,7 +104,7 @@ def _make_pallas_hist(L: int, F: int, B: int, n_local: int,
     and gpu_hist's shared-memory atomics).
     """
     R = int(min(4096, max(256, ((n_local + 255) // 256) * 256)))
-    L3 = planes * L
+    L3 = 3 * L
     # the A build materializes [R, L3] intermediates (int32 iota + f32
     # selects + bf16 A ~ 12 B/elem) on the 16M scoped-VMEM stack; deep
     # trees (large L) must shrink the row block (found on chip: L=256,
@@ -121,7 +120,7 @@ def _make_pallas_hist(L: int, F: int, B: int, n_local: int,
     # the scoped-VMEM budget.
     F8 = (F + 7) // 8 * 8
     TB = max(1, min(512 // F8, 2_097_152 // (F8 * R)))
-    # never build one-hot tiles wider than the bin range (small-B coarse
+    # never build one-hot tiles wider than the bin range (small-B
     # pass: TB=64 for B=17 wasted 3.7x of the kernel's dominant VPU work)
     TB = min(TB, (B + 7) // 8 * 8)
     FBT = F * TB
@@ -130,19 +129,16 @@ def _make_pallas_hist(L: int, F: int, B: int, n_local: int,
     dt = jnp.bfloat16 if precision == "bf16" else jnp.float32
 
     def _build_A(LS):
-        # A[r, planes*l+s] = S[r, s] where leaf[r] == l, else 0.  Plane 3
-        # (hierarchical bounds) is |g|, derived in-kernel from plane 0.
+        # A[r, 3*l+s] = S[r, s] where leaf[r] == l, else 0.
         # (A 3-D match*stat form would halve the op count but Mosaic cannot
         # shape-cast [R, L, p] minor dims back to [R, L*p].)
         leaf = LS[0].astype(jnp.int32)
         cols = jax.lax.broadcasted_iota(jnp.int32, (R, L3), 1)
-        l_of, s_of = cols // planes, cols % planes
+        l_of, s_of = cols // 3, cols % 3
         match = leaf[:, None] == l_of
         sv = jnp.where(s_of == 0, LS[1][:, None],
                        jnp.where(s_of == 1, LS[2][:, None],
                                  LS[3][:, None]))
-        if planes == 4:
-            sv = jnp.where(s_of == 3, jnp.abs(LS[1])[:, None], sv)
         return jnp.where(match, sv, 0.0).astype(dt)
 
     def kernel(codes_ref, ls_ref, out_ref, a_scratch):
@@ -229,8 +225,8 @@ def _make_pallas_hist(L: int, F: int, B: int, n_local: int,
             return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
         LS = jnp.stack([leaf.astype(jnp.float32), g, h, w], axis=0)
         out = call(padr(codes), padr(LS))[: B * F]
-        # [B*F, pL] rows ordered (b*F + f), cols (l*p + s) -> [p, L, F, B]
-        return out.reshape(B, F, L, planes).transpose(3, 2, 1, 0)
+        # [B*F, 3L] rows ordered (b*F + f), cols (l*3 + s) -> [3, L, F, B]
+        return out.reshape(B, F, L, 3).transpose(3, 2, 1, 0)
 
     return local
 
@@ -269,7 +265,7 @@ def varbin_layout(bin_counts, B: int):
 
 def _make_pallas_varbin_hist(L: int, F: int, bin_counts, B: int,
                              n_local: int, interpret: bool = False,
-                             precision: str = "bf16", planes: int = 3):
+                             precision: str = "bf16"):
     """tpu_hist with a PACKED per-feature bin axis.
 
     The uniform kernel compares every feature row against every global bin
@@ -286,7 +282,7 @@ def _make_pallas_varbin_hist(L: int, F: int, bin_counts, B: int,
     R = int(min(4096, max(512, (4_194_304 // max(Q8 * 2, 1))
                           // 128 * 128)))
     R = min(R, max(512, ((n_local + 511) // 512) * 512))
-    L3 = planes * L
+    L3 = 3 * L
     # deep-tree guard: A-build intermediates are [R, L3] (~12 B/elem) on
     # the scoped-VMEM stack — see _make_pallas_hist
     R = int(min(R, max(512, (6_291_456 // (12 * L3)) // 128 * 128)))
@@ -316,13 +312,11 @@ def _make_pallas_varbin_hist(L: int, F: int, bin_counts, B: int,
         # layout pass rejects it.)
         ST = st_ref[:].astype(jnp.float32)
         cols = jax.lax.broadcasted_iota(jnp.int32, (R, L3), 1)
-        l_of, s_of = cols // planes, cols % planes
+        l_of, s_of = cols // 3, cols % 3
         match = leaf[:, None] == l_of
         sv = jnp.where(s_of == 0, ST[0][:, None],
                        jnp.where(s_of == 1, ST[1][:, None],
                                  ST[2][:, None]))
-        if planes == 4:
-            sv = jnp.where(s_of == 3, jnp.abs(ST[0])[:, None], sv)
         A = jnp.where(match, sv, 0.0).astype(dt)
         codes = codes_ref[:].astype(jnp.int32)         # [F, R]
         pieces = []
@@ -419,11 +413,11 @@ def _make_varbin_hist_fn(L: int, F: int, bin_counts: tuple, B: int,
 make_varbin_hist_fn = _reduce_mode_dispatch(_make_varbin_hist_fn)
 
 
-def _make_einsum_hist(L: int, F: int, B: int, n_local: int, planes: int = 3):
+def _make_einsum_hist(L: int, F: int, B: int, n_local: int):
     """Portable XLA path (CPU mesh tests, non-TPU backends, and the
-    deep-level fallback where [R, planes*L] exceeds scoped VMEM)."""
+    deep-level fallback where [R, 3*L] exceeds scoped VMEM)."""
     blk = max((4 * 1024 * 1024) // max(F * B, 1), 256)
-    # deep levels: the [blk, L] leaf one-hot / [blk, planes, L] stats
+    # deep levels: the [blk, L] leaf one-hot / [blk, 3, L] stats
     # intermediates must stay bounded too
     blk = max(min(blk, 8_388_608 // max(L, 1)), 64)
     blk = min(n_local, blk)
@@ -436,10 +430,9 @@ def _make_einsum_hist(L: int, F: int, B: int, n_local: int, planes: int = 3):
                            + [(0, pad_to - n_local)], constant_values=fill)
         codes = padr(codes).reshape(F, nblk, blk).transpose(1, 0, 2)
         leaf = padr(leaf).reshape(nblk, blk)
-        stats = [g, h, w] + ([jnp.abs(g)] if planes == 4 else [])
-        S = jnp.stack(stats, axis=1)              # [n, planes]
+        S = jnp.stack([g, h, w], axis=1)          # [n, 3]
         S = jnp.pad(S, [(0, pad_to - n_local), (0, 0)]) \
-            .reshape(nblk, blk, planes)
+            .reshape(nblk, blk, 3)
 
         def body(acc, args):
             c, lf, s = args
@@ -448,7 +441,7 @@ def _make_einsum_hist(L: int, F: int, B: int, n_local: int, planes: int = 3):
             PS = jnp.einsum("rl,rs->rsl", Pl, s)                # [blk,p,L]
             acc = acc + jnp.einsum("rsl,frb->slfb", PS, OH)
             return acc, None
-        H0 = jnp.zeros((planes, L, F, B), jnp.float32)
+        H0 = jnp.zeros((3, L, F, B), jnp.float32)
         H0 = jax.lax.pcast(H0, ROW_AXES, to='varying')
         H, _ = jax.lax.scan(body, H0, (codes, leaf, S))
         return H
@@ -459,34 +452,31 @@ def _make_einsum_hist(L: int, F: int, B: int, n_local: int, planes: int = 3):
 @functools.lru_cache(maxsize=None)
 def _make_hist_fn(L: int, F: int, B: int, n_padded: int,
                   force_impl: str = "", precision: str = "bf16",
-                  planes: int = 3, reduce_mode: str = "hier"):
+                  reduce_mode: str = "hier"):
     """Compiled histogram: (codes[N,F], leaf[N], g[N], h[N], w[N]) ->
-    H[planes, L, F, B] with planes (sum g, sum h, sum w[, sum |g|]),
-    psum'd over the mesh.
+    H[3, L, F, B] with planes (sum g, sum h, sum w), psum'd over the mesh.
 
     ``B`` here includes the NA bin (= nbins + 1).  On TPU the local pass is
     the Pallas tpu_hist kernel; elsewhere (CPU test mesh) an equivalent
     einsum program.  ``force_impl`` ("pallas_interpret" | "einsum") pins the
-    implementation for cross-checking.  ``planes=4`` adds the |g| plane the
-    hierarchical split-search bounds need.
+    implementation for cross-checking.
     """
     cl = cluster()
     n_local = n_padded // cl.n_row_shards
     platform = cl.mesh.devices.flat[0].platform
     # very deep levels: the [F*B, 3L] result exceeds what XLA will stage
     # through VMEM for the custom call — take the portable path there
-    hist_bytes = F * B * planes * L * 4
+    hist_bytes = F * B * 3 * L * 4
     if force_impl == "pallas_interpret":
         inner = _make_pallas_hist(L, F, B, n_local, interpret=True,
-                                  precision=precision, planes=planes)
+                                  precision=precision)
     elif force_impl == "einsum" or platform != "tpu" \
-            or hist_bytes > 12 * 1024 * 1024 or planes * L > 2048:
-        # planes*L > 2048: even the minimum row block's [R, planes*L]
+            or hist_bytes > 12 * 1024 * 1024 or 3 * L > 2048:
+        # 3L > 2048: even the minimum row block's [R, 3L]
         # A-build intermediates overflow the 16M scoped-VMEM stack
-        inner = _make_einsum_hist(L, F, B, n_local, planes=planes)
+        inner = _make_einsum_hist(L, F, B, n_local)
     else:
-        inner = _make_pallas_hist(L, F, B, n_local, precision=precision,
-                                  planes=planes)
+        inner = _make_pallas_hist(L, F, B, n_local, precision=precision)
 
     def local_hist(codes, leaf, g, h, w):
         return psum_shards(inner(codes, leaf, g, h, w), reduce_mode)
@@ -1009,7 +999,7 @@ def sparse_slot_maps(valid_prev, A_next: int):
     When a level has more alive children than ``A_next`` slots, later
     pairs are dropped ATOMICALLY in slot order and those children become
     terminal leaves — the deterministic num_leaves-style degradation the
-    operations guide documents; ``hist_layout="check"`` surfaces it."""
+    operations guide documents (tests/test_sparse_levels.py shows it)."""
     Ap = valid_prev.shape[0]
     idx = jnp.cumsum(valid_prev.astype(jnp.int32)) - 1          # [Ap]
     kept = valid_prev & (2 * idx + 1 < A_next)
@@ -1103,7 +1093,7 @@ def _make_sparse_level_fn(A_prev: int, A: int, F: int, B: int,
     A = 2^d the slot map is the identity and the output is bit-identical
     to make_subtract_level_fn; with dead chains the compaction prefix
     differs (dead rows are dropped rather than histogrammed), so parity
-    is structural + f32-tolerance, which hist_layout="check" asserts.
+    is structural + f32-tolerance (tests/test_sparse_levels.py).
 
     Returns ``(H_global [3, A, F, B], carry [n_shards, 3, A, F, B])``.
     """
@@ -1164,188 +1154,6 @@ def _make_batched_sparse_level_fn(A_prev: int, A: int, K: int, F: int,
 
 make_batched_sparse_level_fn = \
     _reduce_mode_dispatch(_make_batched_sparse_level_fn)
-
-
-def _make_pallas_fine_hist(L: int, F: int, W: int, K: int, nbins: int,
-                           n_local: int, interpret: bool = False,
-                           precision: str = "bf16"):
-    """Fine-refinement kernel: histogram only the K selected super-bins.
-
-    For each (leaf, feature) the coarse pass selected K candidate super-bins
-    (``sel``); this kernel builds the [F*K*W, R] one-hot of "row's code falls
-    on fine slot t of its leaf's k-th selected super-bin" and contracts with
-    the A stats matrix on the MXU.  The per-row selected-super-bin table is
-    itself an MXU product (one-hot(leaf) x sel) — no gathers anywhere.  VPU
-    cost per row is F*K*(W+2) + 2L instead of the full pass's F*(nbins+1).
-    """
-    R = int(min(4096, max(256, ((n_local + 255) // 256) * 256)))
-    L3 = 3 * L
-    # deep-tree guard — see _make_pallas_hist
-    R = int(min(R, max(256, (6_291_456 // (12 * L3)) // 256 * 256)))
-    nblk = (n_local + R - 1) // R
-    pad_to = nblk * R
-    FK = F * K
-    # feature tile: the [TF, K, W, R] one-hot intermediate must fit VMEM
-    TF = max(1, min(F, 4_194_304 // (K * W * R * 2)))
-    n_ft = (F + TF - 1) // TF
-    dt = jnp.bfloat16 if precision == "bf16" else jnp.float32
-
-    def kernel(codes_ref, ls_ref, sel_ref, out_ref):
-        # grid (feature tiles j, row blocks i): out tile stationary over i
-        i = pl.program_id(1)
-
-        @pl.when(i == 0)
-        def _():
-            out_ref[:] = jnp.zeros_like(out_ref)
-
-        LS = ls_ref[:]                             # [4, R] (leaf,g,h,w)
-        leaf = LS[0].astype(jnp.int32)
-        # one-hot(leaf) [L, R] -> selected super-bin per (f-in-tile, k, row)
-        # (iota is full [L, R]: Mosaic rejects 1x1-shaped iota vectors)
-        liota = jax.lax.broadcasted_iota(jnp.int32, (L, R), 0)
-        onehL = (liota == leaf[None, :]).astype(dt)            # [L, R]
-        S = jnp.dot(sel_ref[:], onehL,
-                    preferred_element_type=jnp.float32)        # [TF*K, R]
-        codes_f = codes_ref[:].astype(jnp.float32)
-        # mask the NA code (== nbins): when nbins < S*W it would otherwise
-        # alias into a fine slot of the last super-bin
-        codes_f = jnp.where(codes_f >= nbins, jnp.float32(-1e9), codes_f)
-        rel = (codes_f[:, None, :]
-               - jnp.float32(W) * S.reshape(TF, K, R)) \
-            .reshape(TF * K, R)                                # [TF*K, R]
-        rel_i = jnp.clip(rel, -2.0, jnp.float32(W)).astype(jnp.int32)
-        # t-major one-hot rows ((t, f, k) order) via the same rank-3 int32
-        # (T, 1, 1)-iota the coarse kernel uses — Mosaic rejects f32 iotas
-        t_of = jax.lax.broadcasted_iota(jnp.int32, (W, 1, 1), 0)
-        OHT = (rel_i[None] == t_of).astype(dt).reshape(W * TF * K, R)
-        # A[r, 3l+s]
-        cols = jax.lax.broadcasted_iota(jnp.int32, (R, L3), 1)
-        l_of, s_of = cols // 3, cols % 3
-        match = leaf[:, None] == l_of
-        sv = jnp.where(s_of == 0, LS[1][:, None],
-                       jnp.where(s_of == 1, LS[2][:, None],
-                                 LS[3][:, None]))
-        A = jnp.where(match, sv, 0.0).astype(dt)
-        out_ref[:] += jnp.dot(OHT, A, preferred_element_type=jnp.float32)
-
-    call = _named_kernel(
-        "hist_fine", kernel=kernel,
-        grid=(n_ft, nblk),
-        in_specs=[
-            pl.BlockSpec((TF, R), lambda j, i: (j, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((4, R), lambda j, i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((TF * K, L), lambda j, i: (j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((TF * K * W, L3), lambda j, i: (j, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=_row_sds((n_ft * TF * K * W, L3), jnp.float32),
-        interpret=interpret,
-    )
-
-    def local(codes, leaf, g, h, w, sel):
-        # sel: [L, F, K] int32 -> operand [F*K, L] f32 (feature-major rows)
-        sel_t = sel.reshape(L, FK).T.astype(jnp.float32)
-        if n_ft * TF > F:
-            sel_t = jnp.pad(sel_t, [(0, n_ft * TF * K - FK), (0, 0)],
-                            constant_values=-1.0)
-        pad = pad_to - n_local
-
-        def padr(x):
-            if pad == 0:
-                return x
-            return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
-        LS = jnp.stack([leaf.astype(jnp.float32), g, h, w], axis=0)
-        codes_p = padr(codes)
-        if n_ft * TF > F:
-            codes_p = jnp.pad(codes_p, [(0, n_ft * TF - F), (0, 0)],
-                              constant_values=-1)
-        out = call(codes_p, padr(LS), sel_t)
-        # tile-j rows ordered (t, f_local, k), cols l*3+s -> [3, L, F, K, W]
-        out = out.reshape(n_ft, W, TF, K, L, 3) \
-            .transpose(5, 4, 0, 2, 3, 1) \
-            .reshape(3, L, n_ft * TF, K, W)
-        return out[:, :, :F]
-
-    return local
-
-
-def _make_einsum_fine_hist(L: int, F: int, W: int, K: int, nbins: int,
-                           n_local: int):
-    """Portable fine-refinement path (CPU mesh tests)."""
-    blk = max((2 * 1024 * 1024) // max(F * K * W, 1), 256)
-    blk = min(n_local, blk)
-    nblk = (n_local + blk - 1) // blk
-    pad_to = nblk * blk
-
-    def local(codes, leaf, g, h, w, sel):
-        def padr(x, fill=0):
-            return jnp.pad(x, [(0, 0)] * (x.ndim - 1)
-                           + [(0, pad_to - n_local)], constant_values=fill)
-        codes = padr(codes).reshape(F, nblk, blk).transpose(1, 0, 2)
-        leaf = padr(leaf).reshape(nblk, blk)
-        S = jnp.stack([g, h, w], axis=1)
-        S = jnp.pad(S, [(0, pad_to - n_local), (0, 0)]).reshape(nblk, blk, 3)
-        self_f = sel.astype(jnp.float32)                       # [L, F, K]
-
-        def body(acc, args):
-            c, lf, s = args
-            Pl = jax.nn.one_hot(lf, L, dtype=jnp.float32)       # [blk, L]
-            Sr = jnp.einsum("rl,lfk->rfk", Pl, self_f)          # [blk,F,K]
-            cf = jnp.where(c >= nbins, jnp.float32(-1e9),
-                           c.astype(jnp.float32))
-            rel = cf.T[:, :, None] - W * Sr                     # [blk,F,K]
-            OH = (rel[..., None]
-                  == jnp.arange(W, dtype=jnp.float32)).astype(jnp.float32)
-            PS = jnp.einsum("rl,rs->rsl", Pl, s)                # [blk,3,L]
-            acc = acc + jnp.einsum("rsl,rfkt->slfkt", PS, OH)
-            return acc, None
-        H0 = jnp.zeros((3, L, F, K, W), jnp.float32)
-        H0 = jax.lax.pcast(H0, ROW_AXES, to='varying')
-        H, _ = jax.lax.scan(body, H0, (codes, leaf, S))
-        return H
-
-    return local
-
-
-@functools.lru_cache(maxsize=None)
-def _make_fine_hist_fn(L: int, F: int, W: int, K: int, nbins: int,
-                       n_padded: int, force_impl: str = "",
-                       precision: str = "bf16", reduce_mode: str = "hier"):
-    """Compiled fine-refinement histogram:
-    (codes[F,N], leaf, g, h, w, sel[L,F,K]) -> H[3, L, F, K, W] where slot
-    (l,f,k,t) sums rows with leaf l whose code == sel[l,f,k]*W + t
-    (NA rows, code == nbins, never land in a fine slot).
-    """
-    cl = cluster()
-    n_local = n_padded // cl.n_row_shards
-    platform = cl.mesh.devices.flat[0].platform
-    out_bytes = F * K * W * 3 * L * 4
-    if force_impl == "pallas_interpret":
-        inner = _make_pallas_fine_hist(L, F, W, K, nbins, n_local,
-                                       interpret=True, precision=precision)
-    elif force_impl == "einsum" or platform != "tpu" \
-            or out_bytes > 12 * 1024 * 1024 or 3 * L > 1024:
-        # 3L > 1024: the minimum row block's [R, 3L] A-build intermediates
-        # would overflow scoped VMEM (see make_hist_fn)
-        inner = _make_einsum_fine_hist(L, F, W, K, nbins, n_local)
-    else:
-        inner = _make_pallas_fine_hist(L, F, W, K, nbins, n_local,
-                                       precision=precision)
-
-    def local_hist(codes, leaf, g, h, w, sel):
-        return psum_shards(inner(codes, leaf, g, h, w, sel), reduce_mode)
-
-    specs_in = (P(None, ROW_AXIS), P(ROW_AXIS), P(ROW_AXIS), P(ROW_AXIS),
-                P(ROW_AXIS), P())
-    f = shard_map(local_hist, mesh=cl.mesh, in_specs=specs_in, out_specs=P(),
-                  check_vma=False)
-    return _ledger("hist_fine", jax.jit(f), orig=f)
-
-
-make_fine_hist_fn = _reduce_mode_dispatch(_make_fine_hist_fn)
 
 
 def _soft_threshold(G, alpha):
@@ -1496,7 +1304,7 @@ def best_splits(Hist, nbins: int, reg_lambda: float, min_rows: float,
 # default off-TPU so CPU crosschecks compare exactly.  On chip the kernel's
 # matmul cumsum accumulates in a different order than jnp.cumsum (both
 # f32-exact per element, ±1 ulp on the sums), so exactly-tied gains are the
-# one legitimate divergence source — same caveat as hist_mode="check".
+# one legitimate divergence source (as between hist_mode's two values).
 
 _REC_PLANES = 12
 
@@ -1844,172 +1652,6 @@ def fused_best_splits_batched(HistK, nbins: int, reg_lambda, min_rows,
     return (feat.reshape(K, L), bin_.reshape(K, L),
             na_left.reshape(K, L), gain.reshape(K, L),
             valid.reshape(K, L), children.reshape(K, L, 6))
-
-
-def _coarse_totals(Hc, reg_lambda, reg_alpha):
-    """Shared preamble for the hierarchical search: per-(leaf, feature)
-    totals (NA included) and the parent score from a coarse histogram."""
-    cums = tuple(jnp.cumsum(Hc[i][..., :-1], -1) for i in range(3))
-    nas = tuple(Hc[i][..., -1] for i in range(3))
-    totG, totH, totC = (c[..., -1] + na for c, na in zip(cums, nas))
-    parent = _score(totG, totH, reg_lambda, reg_alpha)
-    return cums, nas, (totG, totH, totC), parent
-
-
-def _gain_with_na(glx, hlx, clx, nas, tots, parent, reg_lambda, reg_alpha,
-                  gamma, min_rows, min_child_weight):
-    """Split gain at candidate left sums (EXCLUDING the NA bucket), maxed
-    over the two NA directions — the one split-evaluation formula shared
-    by super-bin selection and the refined search.  Returns (gain, na_left,
-    na-resolved left stats)."""
-    totG, totH, totC = tots
-    gna, hna, cna = (x[..., None] for x in nas)
-
-    def gain_dir(gl, hl, cl):
-        gr = totG[..., None] - gl
-        hr = totH[..., None] - hl
-        cr = totC[..., None] - cl
-        gn = 0.5 * (_score(gl, hl, reg_lambda, reg_alpha)
-                    + _score(gr, hr, reg_lambda, reg_alpha)
-                    - parent[..., None]) - gamma
-        ok = (cl >= min_rows) & (cr >= min_rows) & \
-            (hl >= min_child_weight) & (hr >= min_child_weight)
-        return jnp.where(ok, gn, -jnp.inf)
-
-    gL = gain_dir(glx + gna, hlx + hna, clx + cna)
-    gR = gain_dir(glx, hlx, clx)
-    na_left = gL >= gR
-    gain = jnp.maximum(gL, gR)
-    gl = jnp.where(na_left, glx + gna, glx)
-    hl = jnp.where(na_left, hlx + hna, hlx)
-    cl = jnp.where(na_left, clx + cna, clx)
-    return gain, na_left, gl, hl, cl
-
-
-def select_superbins(Hc, nbins: int, W: int, K: int, reg_lambda, reg_alpha,
-                     gamma, min_rows, min_child_weight, feat_mask=None):
-    """Pick the K super-bins per (leaf, feature) most likely to hold the
-    best split — the first stage of the two-level quantile search.
-
-    ``Hc``: [3, L, F, S+1] coarse histogram (G, H, count; NA last).
-    The coarse boundaries give EXACT split gains at W-bin spacing; the best
-    split is overwhelmingly adjacent to the best sampled boundary, so
-    refinement targets the two super-bins touching each of the top
-    ceil(K/2) boundaries.  (Sup-style upper bounds were tried and are
-    useless for ranking: with the g/h coupling relaxed, edge super-bins
-    with near-empty prefixes dominate every ranking regardless of signal.)
-    """
-    cums, nas, tots, parent = _coarse_totals(Hc, reg_lambda, reg_alpha)
-    S = cums[0].shape[-1]
-    # exact gains at the S-1 coarse boundaries (split after super-bin s)
-    bgain, _, _, _, _ = _gain_with_na(
-        cums[0][..., :-1], cums[1][..., :-1], cums[2][..., :-1],
-        nas, tots, parent, reg_lambda, reg_alpha, gamma, min_rows,
-        min_child_weight)                                   # [L, F, S-1]
-    if feat_mask is not None:
-        m = feat_mask if feat_mask.ndim == 2 else feat_mask[None, :]
-        bgain = jnp.where(m[..., None], bgain, -jnp.inf)
-    nb = max(1, (K + 1) // 2)
-    _, top_b = jax.lax.top_k(bgain, nb)                     # [L, F, nb]
-    # boundary s touches super-bins s and s+1
-    pairs = jnp.stack([top_b, jnp.minimum(top_b + 1, S - 1)], axis=-1)
-    sel = pairs.reshape(*top_b.shape[:-1], 2 * nb)[..., :K]
-    return sel.astype(jnp.int32), bgain
-
-
-def best_splits_hier(Hc, Hf, sel, ub, nbins: int, W: int, reg_lambda,
-                     min_rows, min_split_improvement, feat_mask=None,
-                     reg_alpha: float = 0.0, gamma: float = 0.0,
-                     min_child_weight: float = 0.0):
-    """Best split per leaf from coarse + refined histograms.
-
-    Candidate splits = every coarse (super-bin) boundary + every fine
-    boundary inside the K refined super-bins; gains and child statistics
-    at every candidate are exact.  Returns the same tuple as
-    ``best_splits`` plus a placeholder (kept for signature stability).
-    Differs from the full pass only when the true best split hides in an
-    unrefined super-bin away from every top coarse boundary.
-    """
-    cums, nas, tots, parent = _coarse_totals(Hc, reg_lambda, reg_alpha)
-    cumG, cumH, cumC = cums
-    totG, totH, totC = tots
-    G, Hs, C = (Hc[i][..., :-1] for i in range(3))
-    L, F, S = G.shape
-    K = sel.shape[-1]
-    if feat_mask is not None:
-        fmask = feat_mask if feat_mask.ndim == 2 else feat_mask[None, :]
-    else:
-        fmask = jnp.ones((L, F), bool)
-
-    def eval_cands(glx, hlx, clx, allowed):
-        gain, na_left, gl, hl, cl = _gain_with_na(
-            glx, hlx, clx, nas, tots, parent, reg_lambda, reg_alpha,
-            gamma, min_rows, min_child_weight)
-        gain = jnp.where(allowed & fmask[..., None], gain, -jnp.inf)
-        return gain, na_left, gl, hl, cl
-
-    # (a) coarse boundaries: split after super-bin s, s in 0..S-2
-    bins_a = (jnp.arange(S - 1, dtype=jnp.int32) + 1) * W - 1
-    allowed_a = (bins_a <= nbins - 2)[None, None, :]
-    res_a = eval_cands(cumG[..., :-1], cumH[..., :-1], cumC[..., :-1],
-                       allowed_a)
-    bins_a_full = jnp.broadcast_to(bins_a, (L, F, S - 1))
-    feat_a = jnp.broadcast_to(
-        jnp.arange(F, dtype=jnp.int32)[None, :, None], (L, F, S - 1))
-
-    # (b) fine boundaries inside refined super-bins
-    Gpre_s = jnp.take_along_axis(cumG - G, sel, axis=-1)      # [L, F, K]
-    Hpre_s = jnp.take_along_axis(cumH - Hs, sel, axis=-1)
-    Cpre_s = jnp.take_along_axis(cumC - C, sel, axis=-1)
-    cumGf = jnp.cumsum(Hf[0], -1)                             # [L, F, K, W]
-    cumHf = jnp.cumsum(Hf[1], -1)
-    cumCf = jnp.cumsum(Hf[2], -1)
-    bins_f = sel[..., None] * W + jnp.arange(W, dtype=jnp.int32)
-    allowed_f = bins_f <= nbins - 2
-    res_f = eval_cands(
-        (Gpre_s[..., None] + cumGf).reshape(L, F, K * W),
-        (Hpre_s[..., None] + cumHf).reshape(L, F, K * W),
-        (Cpre_s[..., None] + cumCf).reshape(L, F, K * W),
-        allowed_f.reshape(L, F, K * W))
-    bins_f_full = bins_f.reshape(L, F, K * W)
-    feat_f = jnp.broadcast_to(
-        jnp.arange(F, dtype=jnp.int32)[None, :, None], (L, F, K * W))
-
-    def flat(a_part, f_part):
-        return jnp.concatenate(
-            [a_part.reshape(L, -1), f_part.reshape(L, -1)], axis=1)
-
-    gain_all = flat(res_a[0], res_f[0])
-    best = jnp.argmax(gain_all, axis=1)
-
-    def pick(a_part, f_part):
-        return jnp.take_along_axis(flat(a_part, f_part),
-                                   best[:, None], 1)[:, 0]
-
-    best_gain = jnp.take_along_axis(gain_all, best[:, None], 1)[:, 0]
-    feat = pick(feat_a, feat_f)
-    bin_ = pick(bins_a_full, bins_f_full)
-    na_left = pick(res_a[1], res_f[1])
-    gl = pick(res_a[2], res_f[2])
-    hl = pick(res_a[3], res_f[3])
-    cl = pick(res_a[4], res_f[4])
-
-    ftot = jnp.take_along_axis(totG, feat[:, None], 1)[:, 0]
-    htot = jnp.take_along_axis(totH, feat[:, None], 1)[:, 0]
-    ctot = jnp.take_along_axis(totC, feat[:, None], 1)[:, 0]
-    valid = jnp.isfinite(best_gain) & \
-        (best_gain > min_split_improvement) & (totC >= 2 * min_rows).any(-1)
-    gr, hr, cr = ftot - gl, htot - hl, ctot - cl
-    gl = jnp.where(valid, gl, ftot)
-    hl = jnp.where(valid, hl, htot)
-    cl = jnp.where(valid, cl, ctot)
-    gr = jnp.where(valid, gr, 0.0)
-    hr = jnp.where(valid, hr, 0.0)
-    cr = jnp.where(valid, cr, 0.0)
-    children = jnp.stack([gl, hl, cl, gr, hr, cr], axis=1)
-
-    return (feat.astype(jnp.int32), bin_.astype(jnp.int32), na_left,
-            best_gain, valid, children, jnp.array(False))
 
 
 def table_lookup(tables, idx, L: int):

@@ -33,15 +33,14 @@ from ..datainfo import DataInfo, ColumnSpec
 from ..scorekeeper import stop_early, metric_direction
 from ..distributions import make_distribution
 from .binning import BinnedFrame, fit_bins, encode_bins
-from .hist import (_ledger, make_hist_fn, make_fine_hist_fn,
-                   make_varbin_hist_fn,
+from .hist import (_ledger, make_hist_fn, make_varbin_hist_fn,
                    make_subtract_level_fn, make_batched_level_fn,
                    make_scan_level_fn, make_batched_scan_level_fn,
                    make_sparse_level_fn, make_batched_sparse_level_fn,
                    sparse_slot_budget, sparse_slot_maps,
-                   offset_codes, best_splits, best_splits_hier,
+                   offset_codes, best_splits,
                    fused_best_splits, fused_best_splits_batched,
-                   select_superbins, partition, partition_right,
+                   partition, partition_right,
                    table_lookup, traverse_block, walk_block_rows)
 
 
@@ -54,8 +53,7 @@ def level_phase(phase: str, level: int):
     once per compile (the device-side timeline stays ``jax.profiler``'s
     job), and ``jax.named_scope(phase)`` puts the phase into the op
     metadata of everything traced here, for whoever opens the trace in
-    xprof.  Around EAGER phase calls (crosscheck drivers, bench pieces)
-    the span times real execution."""
+    xprof.  Around an EAGER phase call the span times real execution."""
     from ...runtime import observability as obs
     with obs.span("tree_phase", phase=phase, level=level), \
             jax.named_scope(phase):
@@ -90,14 +88,11 @@ class SharedTreeParameters(Parameters):
     stopping_rounds: int = 0
     standardize: bool = False            # trees never standardize
     hist_precision: str = "bf16"         # f32 for exact reproducibility
-    split_search: str = "auto"           # auto | exact | hier (see shared.py)
     # histogram build strategy per level (DHistogram/gpu_hist sibling trick):
     #   "subtract" (default) — compact each parent's SMALLER child into a
     #     dense row prefix, histogram only those <= N/2 rows, reconstruct
     #     the larger sibling as parent - small (hist.make_subtract_level_fn);
     #   "full"     — histogram every child from all N rows (the oracle);
-    #   "check"    — driver assert mode: grow one tree both ways on the
-    #     real data and raise on divergence, then train with "subtract";
     #   "auto"     (default) — the cost-model autotuner picks per
     #     (shape, depth, K, mesh) signature (runtime/autotune.py); with
     #     H2O3_TPU_AUTOTUNE=off this is exactly "subtract".
@@ -110,12 +105,10 @@ class SharedTreeParameters(Parameters):
     #     ONE batched level program (one kernel launch per level);
     #   "separate" — the multi-pass best_splits oracle + sequential
     #     K-iteration class loops (the pre-batching pipeline, kept whole);
-    #   "check"    — driver assert mode: grow the first round both ways on
-    #     the real data and raise on divergence, then train with "fused".
     #   "auto"     (default) — autotuner-decided, as with hist_mode
     #     ("fused" with the tuner off).
-    # Monotone constraints, EFB bundling and the hierarchical search stay
-    # on the separate path (drivers downgrade automatically).
+    # Monotone constraints and EFB bundling stay on the separate path
+    # (drivers downgrade automatically).
     split_mode: str = "auto"
     # per-level histogram LAYOUT (mirrors hist_mode/split_mode):
     #   "auto"   (default) — dense [2^d, F, B] slot grids above
@@ -126,12 +119,9 @@ class SharedTreeParameters(Parameters):
     #   "dense"  — the dense grid at every level (the oracle);
     #   "sparse" — force the sparse layout below the threshold even when
     #     "auto" would (identically) pick it; fails fast when it cannot
-    #     engage (hist_mode="full" has no carry to subtract from);
-    #   "check"  — driver assert mode: grow one tree both ways on the real
-    #     data, compare structure exactly and values to f32 tolerance
-    #     (run_layout_crosscheck), then train with "auto".
-    # Monotone constraints, EFB bundling and the hierarchical search stay
-    # dense (drivers downgrade automatically, as with split_mode).
+    #     engage (hist_mode="full" has no carry to subtract from).
+    # Monotone constraints and EFB bundling stay dense (drivers downgrade
+    # automatically, as with split_mode).
     hist_layout: str = "auto"
     # first sparse level under hist_layout auto/sparse (expert knob): level
     # d >= threshold histograms in slot space.  Clamped per frame to the
@@ -147,15 +137,11 @@ class SharedTreeParameters(Parameters):
     #     masking, the early-exit fence a scan-carried on-device
     #     predicate, O(1) compiled kernel programs per tree regardless of
     #     depth (and a far smaller program to compile for deep trees);
-    #   "check" — driver assert mode: grow the first tree/round both ways
-    #     on the real data and raise on divergence (run_program_crosscheck),
-    #     then train with "scan";
     #   "auto"  (default) — autotuner-decided, as with hist_mode ("level"
     #     with the tuner off — bit-identical to the pre-scan pipeline).
-    # Monotone constraints, EFB bundling, the hierarchical search,
-    # node-sparse deep levels, the variable-bin kernel and depth-1 trees
-    # stay on the level path ("auto"/"check" downgrade automatically;
-    # uplift always grows level-wise).
+    # Monotone constraints, EFB bundling, node-sparse deep levels, the
+    # variable-bin kernel and depth-1 trees stay on the level path ("auto"
+    # downgrades automatically; uplift always grows level-wise).
     tree_program: str = "auto"
     # probability calibration (hex/tree CalibrationHelper)
     calibrate_model: bool = False
@@ -473,7 +459,7 @@ def effective_max_depth(max_depth: int, nbins: int, F: int,
     earlier via min_rows/purity (valid masking); configs asking for more
     depth get the capped tree — a documented design bound."""
     row_cap = max(1, int(np.ceil(np.log2(max(n_padded, 2)))) + 1)
-    if hist_layout in ("sparse", "auto", "check"):
+    if hist_layout in ("sparse", "auto"):
         return max(1, min(max_depth, row_cap))
     return max(1, min(max_depth, row_cap, dense_mem_cap(nbins, F)))
 
@@ -534,9 +520,8 @@ def _per_k(x, extra_dims: int):
 
 @functools.lru_cache(maxsize=None)
 def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
-                       hist_precision: str = "bf16", hier: bool = False,
-                       fine_k: int = 2, bin_counts=None, mono=None,
-                       plan=None, hist_mode: str = "subtract",
+                       hist_precision: str = "bf16", bin_counts=None,
+                       mono=None, plan=None, hist_mode: str = "subtract",
                        nk: int = 1, split_mode: str = "separate",
                        hist_layout: str = "dense",
                        sparse_depth_threshold: int = 8,
@@ -549,26 +534,14 @@ def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
     (per-level (feat, thr, na_left, valid) tuples, leaf values, final leaf
     assignment), all device-resident.
 
-    ``hist_mode`` picks the per-level histogram strategy (non-hier path):
+    ``hist_mode`` picks the per-level histogram strategy:
     ``"subtract"`` (default) compacts each parent's smaller child into a
     dense row prefix, histograms only those <= N/2 rows and reconstructs
     the larger sibling by f32 subtraction from a per-shard parent carry
     (hist.make_subtract_level_fn — the DHistogram/gpu_hist sibling trick
     with the row stream actually halved, not just masked); ``"full"``
     histograms every child from all N rows and is kept as the exactness
-    oracle (run_hist_crosscheck / the hist_mode="check" driver assert).
-
-    ``hier=True`` takes the hierarchical split-search path: a coarse
-    super-bin histogram (S = 8/16) + fine refinement of the ``fine_k`` most
-    promising super-bins per (leaf, feature) — ~4-5x fewer VPU element-ops
-    than the full (nbins+1)-bin pass.  Refinement targets the
-    super-bins adjacent to the best exact coarse-boundary gains; the
-    refined search is exact WITHIN the refined bins plus all super-bin
-    boundaries, so it can (rarely) choose a different split than the full
-    pass when the best split hides far from every top coarse boundary.
-    Drivers therefore enable it only at benchmark scale
-    (split_search="auto" gate) or on request.  ``hier`` keeps its own
-    coarse-level subtraction; ``hist_mode`` does not apply to it.
+    oracle (tests/test_hist_subtract.py grows trees both ways).
 
     ``split_mode="fused"`` swaps best_splits for the single-pass
     winner-record path (hist.fused_best_splits — on TPU a Pallas kernel
@@ -593,7 +566,7 @@ def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
     chains are not reproduced (sparse never histograms dead rows), so
     parity with "dense" is: valid/leaf routing exact, feat/thr/na_left
     exact WHERE VALID, leaf values to f32 tolerance
-    (run_layout_crosscheck).
+    (tests/test_sparse_levels.py).
 
     ``tree_program="scan"`` replaces the trace-time level unroll with a
     ``lax.scan`` over levels inside the same jit (one fixed-width level
@@ -605,7 +578,7 @@ def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
     skips the histogram kernel and the builder skips partition on dead
     levels — both skips are bitwise the live computation).  Composes
     with hist_mode subtract/full, split_mode separate/fused and the
-    batched K-tree build; NOT with mono/EFB/hier/sparse layout (raises)
+    batched K-tree build; NOT with mono/EFB/sparse layout (raises)
     or the variable-bin kernel (silently uses the uniform kernels —
     "auto" keeps per-level programs where varbin wins).
     """
@@ -613,53 +586,47 @@ def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
     if hist_layout not in ("dense", "sparse"):
         raise ValueError(
             f"hist_layout={hist_layout!r}: use 'dense' or 'sparse' here "
-            "('auto'/'check' are driver modes — see resolve_hist_layout)")
+            "('auto' is a driver mode — see resolve_hist_layout)")
     if hist_layout == "sparse":
         if hist_mode != "subtract":
             raise ValueError(
                 "hist_layout='sparse' requires hist_mode='subtract': the "
                 "slot-space level carry is the subtraction carry "
                 "(hist_mode='full' has no carry to subtract from)")
-        if hier or mono is not None or plan is not None:
+        if mono is not None or plan is not None:
             raise ValueError(
                 "hist_layout='sparse' does not compose with monotone "
-                "constraints, EFB bundling or the hierarchical search; "
-                "the drivers downgrade to 'dense' automatically under "
-                "hist_layout='auto'")
+                "constraints or EFB bundling; the drivers downgrade to "
+                "'dense' automatically under hist_layout='auto'")
     if split_mode not in ("separate", "fused"):
         raise ValueError(
             f"split_mode={split_mode!r}: use 'separate' or 'fused' here "
-            "('check' is a driver mode — see run_split_crosscheck)")
-    if split_mode == "fused" and (mono is not None or plan is not None
-                                  or hier):
+            "('auto' is a driver mode — see resolve_split_mode)")
+    if split_mode == "fused" and (mono is not None or plan is not None):
         raise ValueError(
             "split_mode='fused' does not compose with monotone "
-            "constraints, EFB bundling or the hierarchical search; the "
-            "drivers downgrade to 'separate' automatically")
+            "constraints or EFB bundling; the drivers downgrade to "
+            "'separate' automatically")
     if nk > 1 and split_mode != "fused":
         raise ValueError("the batched K-tree build (nk > 1) requires "
                          "split_mode='fused'")
-    if mono is not None and hier:
-        raise ValueError("monotone constraints are not supported with "
-                         "the hierarchical split search")
-    if plan is not None and (mono is not None or hier):
+    if plan is not None and mono is not None:
         raise ValueError("feature bundling (EFB) does not compose with "
-                         "monotone constraints or the hierarchical search; "
-                         "the drivers disable it automatically")
+                         "monotone constraints; the drivers disable it "
+                         "automatically")
     if hist_mode not in ("subtract", "full"):
         raise ValueError(
             f"hist_mode={hist_mode!r}: use 'subtract' or 'full' here "
-            "('check' is a driver mode — see run_hist_crosscheck)")
+            "('auto' is a driver mode — see resolve_hist_mode)")
     if tree_program not in ("level", "scan"):
         raise ValueError(
             f"tree_program={tree_program!r}: use 'level' or 'scan' here "
-            "('auto'/'check' are driver modes — see resolve_tree_program)")
-    if tree_program == "scan" and (hier or mono is not None
-                                   or plan is not None):
+            "('auto' is a driver mode — see resolve_tree_program)")
+    if tree_program == "scan" and (mono is not None or plan is not None):
         raise ValueError(
             "tree_program='scan' does not compose with monotone "
-            "constraints, EFB bundling or the hierarchical split search; "
-            "tree_program='auto' downgrades to 'level' automatically")
+            "constraints or EFB bundling; tree_program='auto' downgrades "
+            "to 'level' automatically")
     max_depth = effective_max_depth(max_depth, nbins, F, n_padded,
                                     hist_layout, sparse_depth_threshold)
     # first node-sparse level: the threshold clamps to the dense memory
@@ -695,7 +662,7 @@ def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
     # result has the exact same [3, L, F, B] contract, so split search is
     # byte-identical — this is a pure kernel-cost optimization.
     # H2O3_TPU_HIST_IMPL=varbin forces the varbin path off-TPU (interpret
-    # Pallas) so the multichip dryrun exercises the bench kernel code path.
+    # Pallas) so the multichip dryrun exercises the varbin kernel's code.
     on_tpu = _on_tpu()
     use_varbin = varbin_kernel_engages(bin_counts, nbins, F)
     # Per-LEVEL kernel choice: the varbin Pallas kernel has no einsum
@@ -940,7 +907,7 @@ def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
             return levels, vals, cover, leaf
 
         return _ledger("tree_build_batched", jax.jit(buildK), orig=buildK)
-    if not hier and hist_mode == "subtract":
+    if hist_mode == "subtract":
         level_fns = [
             make_subtract_level_fn(
                 d, F, B, n_padded,
@@ -957,15 +924,6 @@ def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
             else make_hist_fn(kern_L[d], F, B, n_padded,
                               precision=hist_precision)
             for d in range(max_depth)]
-    if hier:
-        S = 16 if nbins >= 128 else 8
-        W = -(-nbins // S)
-        coarse_fns = [make_hist_fn(2 ** max(d - 1, 0), F, S + 1, n_padded,
-                                   precision=hist_precision)
-                      for d in range(max_depth)]
-        fine_fns = [make_fine_hist_fn(2 ** d, F, W, fine_k, nbins, n_padded,
-                                      precision=hist_precision)
-                    for d in range(max_depth)]
 
     def build(codes, g, h, w, edges_mat, rng_key, reg_lambda, min_rows,
               min_split_improvement, learn_rate, col_sample_rate, tree_mask,
@@ -987,10 +945,7 @@ def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
             mono_arr = jnp.asarray(mono, jnp.float32)        # [F] in {-1,0,1}
             lo = jnp.full((1,), -jnp.inf)                    # per-node value
             hi = jnp.full((1,), jnp.inf)                     # bounds
-        H_prev = None
         H_carry = None            # subtract path: per-shard local hist stack
-        if hier:
-            ccodes = jnp.where(codes >= nbins, S, codes // W)
         hcodes = offset_codes(codes, bin_counts, nbins) \
             if any(varbin_level) else codes
         for d in range(max_depth):
@@ -1054,76 +1009,47 @@ def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
                 leaf = 2 * leaf + right
                 levels.append((feat, thr, na_left, valid))
                 continue
-            if hier:
-                with level_phase("hist", d):
+            lcodes = hcodes if varbin_level[d] else codes
+            with level_phase("hist", d):
+                if hist_mode == "subtract":
+                    # smaller-sibling compaction + parent subtraction:
+                    # the kernel streams only the <= N/2 rows of each
+                    # parent's smaller child; the larger sibling is
+                    # reconstructed from the per-shard parent carry
+                    # (hist.py)
                     if d == 0:
-                        Hc = coarse_fns[0](ccodes, leaf, g, h, w)
+                        H, H_carry = level_fns[0](lcodes, leaf, g, h, w)
                     else:
-                        em = ((leaf & 1) == 0).astype(jnp.float32)
-                        Hcl = coarse_fns[d](ccodes, leaf >> 1,
-                                            g * em, h * em, w * em)
-                        # clamp the h/w planes at 0: per-level kernel
-                        # routing can pair differently-rounded kernels
-                        # across the subtraction (bf16 vs f32), and
-                        # negative hessian/weight sums would corrupt
-                        # best_splits at the boundary level
-                        Hcr = H_prev - Hcl
-                        Hcr = Hcr.at[1:].max(0.0)
-                        Hc = jnp.stack([Hcl, Hcr], axis=2) \
-                            .reshape(3, L, F, S + 1)
-                    H_prev = Hc
-                    sel, ub = select_superbins(
-                        Hc, nbins, W, fine_k, reg_lambda, reg_alpha, gamma,
-                        min_rows, min_child_weight, mask)
-                    Hf = fine_fns[d](codes, leaf, g, h, w, sel)
-                with level_phase("split", d):
-                    feat, bin_, na_left, gain, valid, children, _ = \
-                        best_splits_hier(
-                            Hc, Hf, sel, ub, nbins, W, reg_lambda, min_rows,
-                            min_split_improvement, mask, reg_alpha, gamma,
-                            min_child_weight)
-            else:
-                lcodes = hcodes if varbin_level[d] else codes
-                with level_phase("hist", d):
-                    if hist_mode == "subtract":
-                        # smaller-sibling compaction + parent subtraction:
-                        # the kernel streams only the <= N/2 rows of each
-                        # parent's smaller child; the larger sibling is
-                        # reconstructed from the per-shard parent carry
-                        # (hist.py)
-                        if d == 0:
-                            H, H_carry = level_fns[0](lcodes, leaf, g, h, w)
-                        else:
-                            H, H_carry = level_fns[d](lcodes, leaf, g, h, w,
-                                                      H_carry)
-                    else:
-                        # "full" oracle: every child histogrammed from
-                        # all rows
-                        H = hist_fns[d](lcodes, leaf, g, h, w)
-                with level_phase("split", d):
-                    if plan is not None:
-                        from .efb import best_splits_mixed
-                        (feat, bin_, na_left, gain, valid, children, wfeat,
-                         lo_w, hi_w, inv_w) = best_splits_mixed(
-                            H, nbins, plan, reg_lambda, min_rows,
-                            min_split_improvement, mask, reg_alpha, gamma,
-                            min_child_weight)
-                    elif split_mode == "fused":
-                        # single-pass winner records between hist and the
-                        # tiny feature argmax — no [3, L, F, B] gain
-                        # intermediates
-                        feat, bin_, na_left, gain, valid, children = \
-                            fused_best_splits(
-                                H, nbins, reg_lambda, min_rows,
-                                min_split_improvement, mask, reg_alpha,
-                                gamma, min_child_weight)
-                    else:
-                        feat, bin_, na_left, gain, valid, children = \
-                            best_splits(
-                                H, nbins, reg_lambda, min_rows,
-                                min_split_improvement, mask, reg_alpha,
-                                gamma, min_child_weight,
-                                mono=mono_arr if mono is not None else None)
+                        H, H_carry = level_fns[d](lcodes, leaf, g, h, w,
+                                                  H_carry)
+                else:
+                    # "full" oracle: every child histogrammed from
+                    # all rows
+                    H = hist_fns[d](lcodes, leaf, g, h, w)
+            with level_phase("split", d):
+                if plan is not None:
+                    from .efb import best_splits_mixed
+                    (feat, bin_, na_left, gain, valid, children, wfeat,
+                     lo_w, hi_w, inv_w) = best_splits_mixed(
+                        H, nbins, plan, reg_lambda, min_rows,
+                        min_split_improvement, mask, reg_alpha, gamma,
+                        min_child_weight)
+                elif split_mode == "fused":
+                    # single-pass winner records between hist and the
+                    # tiny feature argmax — no [3, L, F, B] gain
+                    # intermediates
+                    feat, bin_, na_left, gain, valid, children = \
+                        fused_best_splits(
+                            H, nbins, reg_lambda, min_rows,
+                            min_split_improvement, mask, reg_alpha,
+                            gamma, min_child_weight)
+                else:
+                    feat, bin_, na_left, gain, valid, children = \
+                        best_splits(
+                            H, nbins, reg_lambda, min_rows,
+                            min_split_improvement, mask, reg_alpha,
+                            gamma, min_child_weight,
+                            mono=mono_arr if mono is not None else None)
             if d > 0:
                 valid = valid & alive
                 # collapse the child stats of dead slots back to "all rows
@@ -1227,7 +1153,7 @@ def _make_scan_build(max_depth: int, nbins: int, F: int, n_padded: int,
     row-block size depends on the slot width, so at padded width W vs
     the level path's true 2^d the row accumulation can associate
     differently once N is large enough to split blocks — structure stays
-    exact, leaf values agree to f32 tolerance (run_program_crosscheck's
+    exact, leaf values agree to f32 tolerance (tests/test_tree_scan.py's
     contract).  The variable-bin kernel is never used here (uniform
     kernels only); resolve_tree_program keeps "auto" on the level path
     when varbin would engage.
@@ -1503,8 +1429,7 @@ def maybe_bundle(binned, params, mono, nrows: int):
     from .efb import plan_bundles, apply_bundles
     mode = str(getattr(params, "efb", "auto")).lower()
     plan = None
-    if mode not in ("off", "false", "0") and mono is None \
-            and not use_hier_split_search(params, nrows):
+    if mode not in ("off", "false", "0") and mono is None:
         plan = plan_bundles(binned.codes, binned.bin_counts, binned.nbins,
                             nrows)
     if plan is None:
@@ -1513,104 +1438,80 @@ def maybe_bundle(binned, params, mono, nrows: int):
             plan.bin_counts)
 
 
-def use_hier_split_search(params, n_padded: int) -> bool:
-    """Policy gate for the hierarchical split-search path.
-
-    ``split_search="hier"`` opts in; anything else (incl. the default
-    "auto") takes the exact full-bin search — with the variable-bin kernel
-    the exact path matched or beat the hierarchical one at benchmark
-    scale when both were last measured, so the approximation never
-    engages implicitly.
-    """
-    mode = getattr(params, "split_search", "auto")
-    if mode == "hier":
-        return True
-    return False
-
-
 def resolve_hist_mode(params) -> str:
-    """Validate + normalize the ``hist_mode`` knob (drivers call this once;
-    ``"check"`` is resolved to ``"subtract"`` AFTER run_hist_crosscheck).
-    ``"auto"`` resolves to the fixed default here — drivers that route
+    """Validate + normalize the ``hist_mode`` knob (drivers call this
+    once).  ``"auto"`` resolves to the fixed default here — drivers that route
     through ``autotune.resolve_tree_knobs`` get the tuned choice
     instead; this fallback is what the tuner's "off" mode serves."""
     mode = str(getattr(params, "hist_mode", "auto")).lower()
     if mode == "auto":
         return "subtract"
-    if mode not in ("subtract", "full", "check"):
+    if mode not in ("subtract", "full"):
         raise ValueError(
-            f"hist_mode={mode!r}: use auto | subtract | full | check")
+            f"hist_mode={mode!r}: use auto | subtract | full")
     return mode
 
 
-def resolve_split_mode(params, *, mono=None, plan=None,
-                       hier: bool = False) -> str:
+def resolve_split_mode(params, *, mono=None, plan=None) -> str:
     """Validate + normalize the ``split_mode`` knob (mirrors
-    resolve_hist_mode; drivers call this once and ``"check"`` is resolved
-    to ``"fused"`` AFTER run_split_crosscheck).  Monotone constraints, EFB
-    bundling and the hierarchical search have no fused implementation, so
-    those builds downgrade to ``"separate"`` here — silently, matching
-    the drivers' existing auto-gating of those features.  ``"auto"``
-    resolves to the fixed default here (see resolve_hist_mode)."""
+    resolve_hist_mode; drivers call this once).  Monotone constraints and
+    EFB bundling have no fused implementation, so those builds downgrade
+    to ``"separate"`` here — silently, matching the drivers' existing
+    auto-gating of those features.  ``"auto"`` resolves to the fixed
+    default here (see resolve_hist_mode)."""
     mode = str(getattr(params, "split_mode", "auto")).lower()
     if mode == "auto":
         mode = "fused"
-    if mode not in ("fused", "separate", "check"):
+    if mode not in ("fused", "separate"):
         raise ValueError(
-            f"split_mode={mode!r}: use auto | fused | separate | check")
-    if mode != "separate" and (mono is not None or plan is not None
-                               or hier):
+            f"split_mode={mode!r}: use auto | fused | separate")
+    if mono is not None or plan is not None:
         return "separate"
     return mode
 
 
 def sparse_layout_active(hist_layout: str, hist_mode: str = "subtract", *,
-                         mono=None, plan=None, hier: bool = False) -> bool:
+                         mono=None, plan=None) -> bool:
     """Whether the node-sparse deep-level layout ENGAGES for a build with
     these features — the single predicate every consumer (the build
     factories, the scan factories' own depth computation,
     record_effective_depth / validate_checkpoint_depth, and the drivers'
     deep_level fault hook) shares, so level counts agree everywhere.
-    ``hist_mode="check"`` counts as subtract (that is what it trains with
-    after the crosscheck); depth-threshold gating is the builder's job."""
-    return (hist_layout in ("sparse", "auto", "check")
-            and hist_mode in ("subtract", "check")
-            and mono is None and plan is None and not hier)
+    Depth-threshold gating is the builder's job."""
+    return (hist_layout in ("sparse", "auto")
+            and hist_mode == "subtract"
+            and mono is None and plan is None)
 
 
-def resolve_hist_layout(params, *, hist_mode=None, mono=None, plan=None,
-                        hier: bool = False) -> str:
+def resolve_hist_layout(params, *, hist_mode=None, mono=None,
+                        plan=None) -> str:
     """Validate + normalize the ``hist_layout`` knob (mirrors
-    resolve_split_mode; drivers call this once, and ``"check"`` is
-    resolved to ``"sparse"`` AFTER run_layout_crosscheck).  Returns the
+    resolve_split_mode; drivers call this once).  Returns the
     BUILDER value — "dense" or "sparse" ("sparse" means "below the
     clamped sparse_depth_threshold"; the builder applies the threshold,
-    so "auto" and "sparse" build identically) — or "check" for the driver
-    to act on first.  "auto" downgrades silently to "dense" for monotone
-    constraints, EFB bundling, the hierarchical search, or
+    so "auto" and "sparse" build identically).  "auto" downgrades
+    silently to "dense" for monotone constraints, EFB bundling or
     hist_mode="full" (no carry to subtract from); an EXPLICIT "sparse"
     with any of those raises — failing fast beats silently training a
     different layout than asked."""
     layout = str(getattr(params, "hist_layout", "auto")).lower()
-    if layout not in ("dense", "sparse", "auto", "check"):
+    if layout not in ("dense", "sparse", "auto"):
         raise ValueError(
-            f"hist_layout={layout!r}: use dense | sparse | auto | check")
+            f"hist_layout={layout!r}: use auto | dense | sparse")
     if int(getattr(params, "sparse_depth_threshold", 8)) < 1:
         raise ValueError("sparse_depth_threshold must be >= 1 (the root "
                          "level seeds the carry and is always dense)")
     if layout == "dense":
         return "dense"
     hm = hist_mode if hist_mode is not None else resolve_hist_mode(params)
-    if not sparse_layout_active(layout, hm, mono=mono, plan=plan,
-                                hier=hier):
+    if not sparse_layout_active(layout, hm, mono=mono, plan=plan):
         if layout == "sparse":
             raise ValueError(
                 "hist_layout='sparse' does not compose with "
-                "hist_mode='full', monotone constraints, EFB bundling or "
-                "the hierarchical split search; use hist_layout='auto' "
-                "to downgrade automatically")
+                "hist_mode='full', monotone constraints or EFB bundling; "
+                "use hist_layout='auto' to downgrade automatically")
         return "dense"
-    return "check" if layout == "check" else "sparse"
+    return "sparse"
 
 
 def varbin_kernel_engages(bin_counts, nbins: int, F: int) -> bool:
@@ -1627,431 +1528,58 @@ def varbin_kernel_engages(bin_counts, nbins: int, F: int) -> bool:
 
 
 def resolve_tree_program(params, *, hist_layout: str = "dense", mono=None,
-                         plan=None, hier: bool = False, bin_counts=None,
-                         F: Optional[int] = None,
+                         plan=None, F: Optional[int] = None,
                          n_padded: Optional[int] = None) -> str:
     """Validate + normalize the ``tree_program`` knob (mirrors
-    resolve_hist_layout; drivers call this once, and ``"check"`` is
-    resolved to ``"scan"`` AFTER run_program_crosscheck).  Returns the
-    BUILDER value — "level" or "scan" — or "check" for the driver to act
-    on first.
+    resolve_hist_layout; drivers call this once).  Returns the BUILDER
+    value — "level" or "scan".
 
     ``"auto"`` resolves to the fixed default ("level") here — drivers
     that route through ``autotune.resolve_tree_knobs`` get the tuned
     choice instead, so with ``H2O3_TPU_AUTOTUNE=off`` the pipeline stays
     bit-identical to the pre-scan per-level path.  The scan composes
     with the dense layout, uniform kernels and the plain (non-mono /
-    non-EFB / non-hier) split search at effective depth >= 2;
-    "auto"/"check" downgrade silently to "level" outside that envelope,
-    while an EXPLICIT "scan" raises for missing features (mono / EFB /
-    hier / engaged sparse levels / depth < 2) but is allowed to forfeit
+    non-EFB) split search at effective depth >= 2; an EXPLICIT "scan"
+    raises for missing features (mono / EFB / engaged sparse levels /
+    depth < 2) but is allowed to forfeit
     the variable-bin kernel (the one-launch program vs the packed
     per-feature kernel is a cost tradeoff, not a correctness one)."""
     prog = str(getattr(params, "tree_program", "auto")).lower()
-    if prog not in ("level", "scan", "auto", "check"):
+    if prog not in ("level", "scan", "auto"):
         raise ValueError(
-            f"tree_program={prog!r}: use auto | level | scan | check")
-    if prog == "level":
+            f"tree_program={prog!r}: use auto | level | scan")
+    if prog in ("level", "auto"):
         return "level"
-    blocked = mono is not None or plan is not None or hier
     md = int(getattr(params, "max_depth", 5))
     nb = int(getattr(params, "nbins", 64))
     thr = int(getattr(params, "sparse_depth_threshold", 8))
     if F is not None and n_padded is not None:
         md = effective_max_depth(md, nb, F, n_padded, hist_layout, thr)
     t0 = max(1, min(thr, dense_mem_cap(nb, F)) if F is not None else thr)
-    sparse = hist_layout in ("sparse", "check") and md > t0
-    if prog == "scan":
-        if blocked:
-            raise ValueError(
-                "tree_program='scan' does not compose with monotone "
-                "constraints, EFB bundling or the hierarchical split "
-                "search; use tree_program='auto' to downgrade "
-                "automatically")
-        if sparse:
-            raise ValueError(
-                "tree_program='scan' requires the dense layout at every "
-                "level (the scan body is ONE fixed-width program; node-"
-                "sparse slot maps reshape per level); use "
-                "hist_layout='dense' or tree_program='auto'")
-        if md < 2:
-            raise ValueError(
-                "tree_program='scan' needs effective max_depth >= 2 (a "
-                "depth-1 tree is the root level only — nothing to scan); "
-                "use tree_program='auto' to downgrade automatically")
-        return "scan"
-    if prog == "auto":
-        return "level"
-    # "check": compare only where the scan can actually engage —
-    # otherwise both builds would BE the level build (nothing to check)
-    if blocked or sparse or md < 2 \
-            or varbin_kernel_engages(bin_counts, nb, F or 0):
-        return "level"
-    return "check"
-
-
-def run_hist_crosscheck(codes, g, h, w, edges_mat, rng_key, *, max_depth,
-                        nbins, F, n_padded, hist_precision="f32",
-                        bin_counts=None, mono=None, plan=None,
-                        reg_lambda=0.0, min_rows=1.0,
-                        min_split_improvement=1e-5, learn_rate=0.1,
-                        reg_alpha=0.0, gamma=0.0, min_child_weight=0.0,
-                        nk: int = 1, atol=1e-4):
-    """The hist_mode="check" driver assert: grow ONE tree with the
-    subtraction path and one with the full oracle on identical inputs and
-    raise AssertionError on any divergence in split structure, row routing
-    or leaf values.
-
-    Runs on the caller's real (codes, gradients, weights) at the real
-    padded shape, so it validates the exact kernel geometry + compaction
-    the training run will use; cost is one extra tree build.  Exactly-tied
-    gains are the one legitimate divergence source (f32 subtraction
-    rounding can reorder equal gains) — that trips the assert by design:
-    "byte-exact or provably within tolerance" is the contract checked.
-
-    ``nk > 1`` covers the batched K-tree path: g/h are [K, N], rng_key is
-    [K, 2], and both hist modes run through the batched level programs
-    (which require the fused split search) — so a multinomial/DRF round's
-    exact batched kernel geometry is what gets checked.
-    """
-    outs = {}
-    tm = jnp.ones((nk, F), bool) if nk > 1 else jnp.ones((F,), bool)
-    for mode in ("subtract", "full"):
-        fn = make_build_tree_fn(max_depth, nbins, F, n_padded,
-                                hist_precision, bin_counts=bin_counts,
-                                mono=mono, plan=plan, hist_mode=mode,
-                                nk=nk,
-                                split_mode="fused" if nk > 1
-                                else "separate")
-        levels, vals, cover, leaf = fn(
-            codes, g, h, w, edges_mat, rng_key, reg_lambda, min_rows,
-            min_split_improvement, learn_rate, 1.0, tm, reg_alpha, gamma,
-            min_child_weight)
-        outs[mode] = jax.device_get([[list(lv) for lv in levels], vals,
-                                     leaf])
-    lv_s, v_s, leaf_s = outs["subtract"]
-    lv_f, v_f, leaf_f = outs["full"]
-    for d, (ls, lf) in enumerate(zip(lv_s, lv_f)):
-        for name, i in (("feat", 0), ("na_left", 2), ("valid", 3)):
-            if not np.array_equal(ls[i], lf[i]):
-                raise AssertionError(
-                    f"hist_mode='check': subtraction and full builds "
-                    f"disagree on {name} at level {d}: "
-                    f"{np.asarray(ls[i])} vs {np.asarray(lf[i])}")
-        if not np.allclose(ls[1], lf[1], atol=atol, rtol=1e-5):
-            raise AssertionError(
-                f"hist_mode='check': split thresholds diverge at level {d}")
-    if not np.array_equal(leaf_s, leaf_f):
-        raise AssertionError(
-            "hist_mode='check': final leaf routing differs between the "
-            "subtraction and full histogram builds")
-    if not np.allclose(v_s, v_f, atol=atol, rtol=1e-4):
-        raise AssertionError(
-            "hist_mode='check': leaf values diverge beyond tolerance "
-            f"(max abs diff "
-            f"{np.max(np.abs(np.asarray(v_s) - np.asarray(v_f)))})")
-
-
-def run_split_crosscheck(codes, g, h, w, edges_mat, rng_keys, *, max_depth,
-                         nbins, F, n_padded, hist_precision="f32",
-                         bin_counts=None, hist_mode="subtract",
-                         tree_masks=None, reg_lambda=0.0, min_rows=1.0,
-                         min_split_improvement=1e-5, learn_rate=0.1,
-                         col_sample_rate=1.0, reg_alpha=0.0, gamma=0.0,
-                         min_child_weight=0.0, atol=1e-4):
-    """The split_mode="check" driver assert: grow ONE round of K trees
-    with the fused path (batched-K when K > 1) and with a K-loop of
-    sequential separate-oracle builds on identical inputs; raise
-    AssertionError on any divergence in split structure, row routing or
-    leaf values.
-
-    ``g``/``h``/``rng_keys``/``tree_masks`` carry a leading [K] (K=1
-    collapses to the single-tree fused-vs-best_splits check); ``w`` is
-    [N] shared or [K, N].  Runs at the caller's real padded shape so the
-    exact batched kernel geometry of the training run is validated.
-    Comparisons at invalid slots are masked: a dead node's stored
-    (feat, thr) is arbitrary — the paths may legitimately disagree there
-    when a leaf's feature draw is empty — and nothing reads it
-    (partition routes by valid).  On chip, exactly tied gains can reorder
-    under the records kernel's different cumsum association — same
-    legitimate-divergence caveat as hist_mode="check".
-    """
-    g, h = jnp.asarray(g), jnp.asarray(h)
-    if g.ndim == 1:
-        g, h = g[None], h[None]
-    K = g.shape[0]
-    rng_keys = jnp.asarray(rng_keys)
-    if rng_keys.ndim == 1:
-        rng_keys = rng_keys[None]
-    tm = jnp.asarray(tree_masks, bool) if tree_masks is not None \
-        else jnp.ones((K, F), bool)
-    if tm.ndim == 1:
-        tm = tm[None]
-    wK = jnp.broadcast_to(jnp.asarray(w), g.shape)
-    hm = hist_mode if hist_mode in ("subtract", "full") else "subtract"
-    sep = make_build_tree_fn(max_depth, nbins, F, n_padded, hist_precision,
-                             bin_counts=bin_counts, hist_mode=hm)
-    sep_out = []
-    for k in range(K):
-        levels, vals, cover, leaf = sep(
-            codes, g[k], h[k], wK[k], edges_mat, rng_keys[k], reg_lambda,
-            min_rows, min_split_improvement, learn_rate, col_sample_rate,
-            tm[k], reg_alpha, gamma, min_child_weight)
-        sep_out.append(jax.device_get([[list(lv) for lv in levels], vals,
-                                       leaf]))
-    if K > 1:
-        fus = make_build_tree_fn(max_depth, nbins, F, n_padded,
-                                 hist_precision, bin_counts=bin_counts,
-                                 hist_mode=hm, nk=K, split_mode="fused")
-        levels, vals, cover, leaf = fus(
-            codes, g, h, wK, edges_mat, rng_keys, reg_lambda, min_rows,
-            min_split_improvement, learn_rate, col_sample_rate, tm,
-            reg_alpha, gamma, min_child_weight)
-    else:
-        fus = make_build_tree_fn(max_depth, nbins, F, n_padded,
-                                 hist_precision, bin_counts=bin_counts,
-                                 hist_mode=hm, split_mode="fused")
-        levels, vals, cover, leaf = fus(
-            codes, g[0], h[0], wK[0], edges_mat, rng_keys[0], reg_lambda,
-            min_rows, min_split_improvement, learn_rate, col_sample_rate,
-            tm[0], reg_alpha, gamma, min_child_weight)
-        levels = [tuple(x[None] for x in lv) for lv in levels]
-        vals, leaf = vals[None], leaf[None]
-    lv_fus, v_fus, leaf_fus = jax.device_get(
-        [[list(lv) for lv in levels], vals, leaf])
-    for k in range(K):
-        lv_s, v_s, leaf_s = sep_out[k]
-        for d in range(len(lv_s)):
-            valid_s = np.asarray(lv_s[d][3], bool)
-            if not np.array_equal(valid_s,
-                                  np.asarray(lv_fus[d][3][k], bool)):
-                raise AssertionError(
-                    f"split_mode='check': fused and separate builds "
-                    f"disagree on valid at tree {k} level {d}")
-            for name, i in (("feat", 0), ("na_left", 2)):
-                a = np.asarray(lv_s[d][i])
-                b = np.asarray(lv_fus[d][i][k])
-                if not np.array_equal(a[valid_s], b[valid_s]):
-                    raise AssertionError(
-                        f"split_mode='check': {name} diverges at tree "
-                        f"{k} level {d}: {a} vs {b}")
-            a = np.asarray(lv_s[d][1])
-            b = np.asarray(lv_fus[d][1][k])
-            if not np.allclose(a[valid_s], b[valid_s], atol=atol,
-                               rtol=1e-5):
-                raise AssertionError(
-                    f"split_mode='check': split thresholds diverge at "
-                    f"tree {k} level {d}")
-        if not np.array_equal(leaf_s, leaf_fus[k]):
-            raise AssertionError(
-                "split_mode='check': final leaf routing differs between "
-                f"the fused and separate builds for tree {k}")
-        if not np.allclose(v_s, v_fus[k], atol=atol, rtol=1e-4):
-            raise AssertionError(
-                f"split_mode='check': leaf values diverge for tree {k} "
-                f"(max abs diff "
-                f"{np.max(np.abs(np.asarray(v_s) - np.asarray(v_fus[k])))}"
-                ")")
-
-
-def run_layout_crosscheck(codes, g, h, w, edges_mat, rng_keys, *,
-                          max_depth, nbins, F, n_padded,
-                          hist_precision="f32", bin_counts=None,
-                          sparse_depth_threshold=8, tree_masks=None,
-                          reg_lambda=0.0, min_rows=1.0,
-                          min_split_improvement=1e-5, learn_rate=0.1,
-                          col_sample_rate=1.0, reg_alpha=0.0, gamma=0.0,
-                          min_child_weight=0.0, atol=1e-4):
-    """The hist_layout="check" driver assert: grow ONE tree (or one
-    batched-K round — g/h/rng_keys with leading [K]) with the dense
-    layout and one with the node-sparse layout on identical real inputs,
-    and raise AssertionError on divergence.
-
-    Depth is clamped to the DENSE effective depth for the comparison (the
-    whole point of "sparse" is to grow past the dense memory cap, where
-    no oracle exists).  The sparse path never histograms rows on dead
-    chains, so dense candidate records on invalid slots are not
-    reproduced: valid flags and row routing are compared EXACTLY,
-    feat/na_left exactly and thresholds to tolerance WHERE VALID, and
-    leaf values to f32 tolerance everywhere (dead-chain values come from
-    the parent-side inheritance rather than a re-histogram).  A slot
-    budget overflow (alive leaves past hist.sparse_slot_budget) forces
-    children terminal on the sparse side and trips the valid compare —
-    surfacing the num_leaves-style degradation is this mode's job."""
-    md = effective_max_depth(max_depth, nbins, F, n_padded)
-    g, h = jnp.asarray(g), jnp.asarray(h)
-    squeeze = g.ndim == 1
-    if squeeze:
-        g, h = g[None], h[None]
-    K = g.shape[0]
-    rng_keys = jnp.asarray(rng_keys)
-    if rng_keys.ndim == 1:
-        rng_keys = rng_keys[None]
-    tm = jnp.asarray(tree_masks, bool) if tree_masks is not None \
-        else jnp.ones((K, F), bool)
-    if tm.ndim == 1:
-        tm = tm[None]
-    wK = jnp.broadcast_to(jnp.asarray(w), g.shape)
-    outs = {}
-    for layout in ("dense", "sparse"):
-        fn = make_build_tree_fn(
-            md, nbins, F, n_padded, hist_precision,
-            bin_counts=bin_counts, hist_mode="subtract",
-            nk=K if K > 1 else 1,
-            split_mode="fused" if K > 1 else "separate",
-            hist_layout=layout,
-            sparse_depth_threshold=sparse_depth_threshold)
-        if K > 1:
-            levels, vals, cover, leaf = fn(
-                codes, g, h, wK, edges_mat, rng_keys, reg_lambda,
-                min_rows, min_split_improvement, learn_rate,
-                col_sample_rate, tm, reg_alpha, gamma, min_child_weight)
-        else:
-            levels, vals, cover, leaf = fn(
-                codes, g[0], h[0], wK[0], edges_mat, rng_keys[0],
-                reg_lambda, min_rows, min_split_improvement, learn_rate,
-                col_sample_rate, tm[0], reg_alpha, gamma,
-                min_child_weight)
-            levels = [tuple(x[None] for x in lv) for lv in levels]
-            vals, leaf = vals[None], leaf[None]
-        outs[layout] = jax.device_get(
-            [[list(lv) for lv in levels], vals, leaf])
-    lv_d, v_d, leaf_d = outs["dense"]
-    lv_s, v_s, leaf_s = outs["sparse"]
-    for k in range(K):
-        for d in range(len(lv_d)):
-            valid_d = np.asarray(lv_d[d][3][k], bool)
-            if not np.array_equal(valid_d,
-                                  np.asarray(lv_s[d][3][k], bool)):
-                raise AssertionError(
-                    f"hist_layout='check': dense and sparse builds "
-                    f"disagree on valid at tree {k} level {d} (an alive-"
-                    f"leaf count past the slot budget forces terminal "
-                    f"leaves on the sparse side — see sparse_slot_budget)")
-            for name, i in (("feat", 0), ("na_left", 2)):
-                a = np.asarray(lv_d[d][i][k])
-                b = np.asarray(lv_s[d][i][k])
-                if not np.array_equal(a[valid_d], b[valid_d]):
-                    raise AssertionError(
-                        f"hist_layout='check': {name} diverges at tree "
-                        f"{k} level {d}")
-            a = np.asarray(lv_d[d][1][k])
-            b = np.asarray(lv_s[d][1][k])
-            if not np.allclose(a[valid_d], b[valid_d], atol=atol,
-                               rtol=1e-5):
-                raise AssertionError(
-                    f"hist_layout='check': split thresholds diverge at "
-                    f"tree {k} level {d}")
-        if not np.array_equal(leaf_d[k], leaf_s[k]):
-            raise AssertionError(
-                "hist_layout='check': final leaf routing differs "
-                f"between the dense and sparse builds for tree {k}")
-        if not np.allclose(v_d[k], v_s[k], atol=atol, rtol=1e-4):
-            raise AssertionError(
-                f"hist_layout='check': leaf values diverge for tree {k} "
-                f"(max abs diff "
-                f"{np.max(np.abs(np.asarray(v_d[k]) - np.asarray(v_s[k])))}"
-                ")")
-
-
-def run_program_crosscheck(codes, g, h, w, edges_mat, rng_keys, *,
-                           max_depth, nbins, F, n_padded,
-                           hist_precision="f32", hist_mode="subtract",
-                           split_mode="fused", tree_masks=None,
-                           reg_lambda=0.0, min_rows=1.0,
-                           min_split_improvement=1e-5, learn_rate=0.1,
-                           col_sample_rate=1.0, reg_alpha=0.0, gamma=0.0,
-                           min_child_weight=0.0, atol=1e-4):
-    """The tree_program="check" driver assert: grow ONE tree (or one
-    batched-K round — g/h/rng_keys with leading [K]) with the scan-fused
-    program and one with the per-level program on identical real inputs,
-    and raise AssertionError on divergence.
-
-    The scan runs every level at the padded width 2^(max_depth-1), so
-    the einsum histogram's row blocking can associate f32 row sums
-    differently than the level path's true-width programs once N splits
-    blocks: structure (valid flags, feat/na_left where valid, row
-    routing) is compared EXACTLY, thresholds and leaf values to f32
-    tolerance — the same contract run_layout_crosscheck enforces for the
-    node-sparse layout.  Dead-slot candidate records are masked out of
-    the compare (nothing reads them; partition routes by valid)."""
-    g, h = jnp.asarray(g), jnp.asarray(h)
-    if g.ndim == 1:
-        g, h = g[None], h[None]
-    K = g.shape[0]
-    rng_keys = jnp.asarray(rng_keys)
-    if rng_keys.ndim == 1:
-        rng_keys = rng_keys[None]
-    tm = jnp.asarray(tree_masks, bool) if tree_masks is not None \
-        else jnp.ones((K, F), bool)
-    if tm.ndim == 1:
-        tm = tm[None]
-    wK = jnp.broadcast_to(jnp.asarray(w), g.shape)
-    hm = hist_mode if hist_mode in ("subtract", "full") else "subtract"
-    sm = split_mode if split_mode in ("fused", "separate") else "fused"
-    outs = {}
-    for prog in ("level", "scan"):
-        fn = make_build_tree_fn(
-            max_depth, nbins, F, n_padded, hist_precision,
-            hist_mode=hm, nk=K if K > 1 else 1,
-            split_mode="fused" if K > 1 else sm,
-            tree_program=prog)
-        if K > 1:
-            levels, vals, cover, leaf = fn(
-                codes, g, h, wK, edges_mat, rng_keys, reg_lambda,
-                min_rows, min_split_improvement, learn_rate,
-                col_sample_rate, tm, reg_alpha, gamma, min_child_weight)
-        else:
-            levels, vals, cover, leaf = fn(
-                codes, g[0], h[0], wK[0], edges_mat, rng_keys[0],
-                reg_lambda, min_rows, min_split_improvement, learn_rate,
-                col_sample_rate, tm[0], reg_alpha, gamma,
-                min_child_weight)
-            levels = [tuple(x[None] for x in lv) for lv in levels]
-            vals, leaf = vals[None], leaf[None]
-        outs[prog] = jax.device_get(
-            [[list(lv) for lv in levels], vals, leaf])
-    lv_l, v_l, leaf_l = outs["level"]
-    lv_s, v_s, leaf_s = outs["scan"]
-    for k in range(K):
-        for d in range(len(lv_l)):
-            valid_d = np.asarray(lv_l[d][3][k], bool)
-            if not np.array_equal(valid_d,
-                                  np.asarray(lv_s[d][3][k], bool)):
-                raise AssertionError(
-                    f"tree_program='check': scan and level builds "
-                    f"disagree on valid at tree {k} level {d}")
-            for name, i in (("feat", 0), ("na_left", 2)):
-                a = np.asarray(lv_l[d][i][k])
-                b = np.asarray(lv_s[d][i][k])
-                if not np.array_equal(a[valid_d], b[valid_d]):
-                    raise AssertionError(
-                        f"tree_program='check': {name} diverges at tree "
-                        f"{k} level {d}")
-            a = np.asarray(lv_l[d][1][k])
-            b = np.asarray(lv_s[d][1][k])
-            if not np.allclose(a[valid_d], b[valid_d], atol=atol,
-                               rtol=1e-5):
-                raise AssertionError(
-                    f"tree_program='check': split thresholds diverge at "
-                    f"tree {k} level {d}")
-        if not np.array_equal(leaf_l[k], leaf_s[k]):
-            raise AssertionError(
-                "tree_program='check': final leaf routing differs "
-                f"between the scan and level builds for tree {k}")
-        if not np.allclose(v_l[k], v_s[k], atol=atol, rtol=1e-4):
-            raise AssertionError(
-                f"tree_program='check': leaf values diverge for tree {k} "
-                f"(max abs diff "
-                f"{np.max(np.abs(np.asarray(v_l[k]) - np.asarray(v_s[k])))}"
-                ")")
+    if mono is not None or plan is not None:
+        raise ValueError(
+            "tree_program='scan' does not compose with monotone "
+            "constraints or EFB bundling; use tree_program='auto' to "
+            "downgrade automatically")
+    if hist_layout == "sparse" and md > t0:
+        raise ValueError(
+            "tree_program='scan' requires the dense layout at every "
+            "level (the scan body is ONE fixed-width program; node-"
+            "sparse slot maps reshape per level); use "
+            "hist_layout='dense' or tree_program='auto'")
+    if md < 2:
+        raise ValueError(
+            "tree_program='scan' needs effective max_depth >= 2 (a "
+            "depth-1 tree is the root level only — nothing to scan); "
+            "use tree_program='auto' to downgrade automatically")
+    return "scan"
 
 
 @functools.lru_cache(maxsize=None)
 def make_tree_scan_fn(mode: str, tweedie_power: float, quantile_alpha: float,
                       huber_alpha: float, max_depth: int, nbins: int, F: int,
                       n_padded: int, hist_precision: str, sample_rate: float,
-                      col_sample_rate_per_tree: float, hier: bool = False,
+                      col_sample_rate_per_tree: float,
                       bin_counts=None, mono=None, custom_fn=None, plan=None,
                       hist_mode: str = "subtract",
                       split_mode: str = "fused",
@@ -2075,12 +1603,12 @@ def make_tree_scan_fn(mode: str, tweedie_power: float, quantile_alpha: float,
             mode, nclasses=2 if mode == "bernoulli" else 1,
             tweedie_power=tweedie_power, quantile_alpha=quantile_alpha,
             huber_alpha=huber_alpha, custom_distribution_func=custom_fn)
-    if mono is not None or plan is not None or hier:
+    if mono is not None or plan is not None:
         split_mode = "separate"          # no fused path for these builds
         hist_layout = "dense"            # nor a sparse one (resolve_*)
         tree_program = "level"           # nor a scan-fused one
     bt_fn = make_build_tree_fn(max_depth, nbins, F, n_padded, hist_precision,
-                               hier=hier, bin_counts=bin_counts, mono=mono,
+                               bin_counts=bin_counts, mono=mono,
                                plan=plan, hist_mode=hist_mode,
                                split_mode=split_mode,
                                hist_layout=hist_layout,
@@ -2133,7 +1661,7 @@ def make_multinomial_scan_fn(K: int, max_depth: int, nbins: int, F: int,
                              n_padded: int, hist_precision: str,
                              sample_rate: float,
                              col_sample_rate_per_tree: float,
-                             hier: bool = False, bin_counts=None, plan=None,
+                             bin_counts=None, plan=None,
                              hist_mode: str = "subtract",
                              split_mode: str = "fused",
                              mode: str = "multinomial",
@@ -2153,8 +1681,8 @@ def make_multinomial_scan_fn(K: int, max_depth: int, nbins: int, F: int,
     launch per level regardless of K, and the traced scan body holds one
     level program instead of K copies.  ``"separate"`` keeps the
     K-iteration Python loop of single-tree builds — the oracle the
-    batched path reproduces key-for-key (same fold_in structure), which
-    run_split_crosscheck asserts on real data.
+    batched path reproduces key-for-key (same fold_in structure;
+    tests/test_fused_splits.py).
 
     Returns (F_final [N, K], levels with leading [T, K, ...] dims, values
     [T, K, 2^depth], covers [T, K, 2^depth]) — identical layout on both
@@ -2162,7 +1690,7 @@ def make_multinomial_scan_fn(K: int, max_depth: int, nbins: int, F: int,
     """
     if mode not in ("multinomial", "drf"):
         raise ValueError(f"mode={mode!r}: use 'multinomial' or 'drf'")
-    if hier or plan is not None:
+    if plan is not None:
         split_mode = "separate"          # no fused path for these builds
         hist_layout = "dense"            # nor a sparse one (resolve_*)
         tree_program = "level"           # nor a scan-fused one
@@ -2172,7 +1700,7 @@ def make_multinomial_scan_fn(K: int, max_depth: int, nbins: int, F: int,
                                     hist_layout, sparse_depth_threshold)
     batched = split_mode == "fused" and K > 1
     bt_fn = make_build_tree_fn(max_depth, nbins, F, n_padded,
-                               hist_precision, hier=hier,
+                               hist_precision,
                                bin_counts=bin_counts, plan=plan,
                                hist_mode=hist_mode,
                                nk=K if batched else 1,
@@ -2290,7 +1818,7 @@ def make_grid_scan_fn(G: int, mode: str, tweedie_power: float,
     Unlike the single/multinomial factories the per-member params are
     call operands, not factory constants — one compiled program serves
     the whole cohort across rungs.  Fused splits + dense layout only
-    (grid cohorts gate hier/mono/EFB/sparse to the wave path).
+    (grid cohorts gate mono/EFB/sparse to the wave path).
     """
     from ..distributions import make_distribution
     if G < 2:
@@ -2428,7 +1956,7 @@ def build_tree(codes, g, h, w, edges, nbins: int, max_depth: int,
                tree_col_mask: Optional[np.ndarray] = None,
                reg_alpha: float = 0.0, gamma: float = 0.0,
                min_child_weight: float = 0.0, hist_precision: str = "bf16",
-               hier: bool = False, mono=None, hist_mode: str = "subtract",
+               mono=None, hist_mode: str = "subtract",
                split_mode: str = "fused", hist_layout: str = "dense",
                sparse_depth_threshold: int = 8,
                tree_program: str = "level"):
@@ -2446,12 +1974,12 @@ def build_tree(codes, g, h, w, edges, nbins: int, max_depth: int,
     edges_mat = jnp.asarray(edges, jnp.float32)
     tm = jnp.asarray(tree_col_mask, bool) if tree_col_mask is not None \
         else jnp.ones(F, bool)
-    if mono is not None or hier:
+    if mono is not None:
         split_mode = "separate"          # no fused path for these builds
         hist_layout = "dense"            # nor a sparse one (resolve_*)
         tree_program = "level"           # nor a scan-fused one
     fn = make_build_tree_fn(max_depth, nbins, F, N, hist_precision,
-                            hier=hier, mono=mono, hist_mode=hist_mode,
+                            mono=mono, hist_mode=hist_mode,
                             split_mode=split_mode, hist_layout=hist_layout,
                             sparse_depth_threshold=sparse_depth_threshold,
                             tree_program=tree_program)
@@ -2675,6 +2203,16 @@ class SharedTree(ModelBuilder):
     #: one batched program (models/tree/grid_batch.py); opted in per
     #: subclass — the batched trainer mirrors GBM's fused chunk loop
     _grid_batchable = False
+
+    def __init__(self, params: SharedTreeParameters):
+        # a knob value no resolver accepts is refused at construction,
+        # before any frame or device is touched; a fit runs the resolvers
+        # again, with its monotone / EFB context (autotune.resolve_tree_knobs)
+        resolve_hist_mode(params)
+        resolve_split_mode(params)
+        resolve_hist_layout(params)
+        resolve_tree_program(params)
+        super().__init__(params)
 
     def _validate(self, frame) -> None:
         super()._validate(frame)

@@ -194,8 +194,7 @@ class UpliftDRF(SharedTree):
         # level compacts the smaller siblings twice (once per arm) and
         # reconstructs the larger arm histograms from the per-shard parent
         # carries — the same <= N/2 row stream as GBM/DRF.  hist_mode="full"
-        # keeps the oracle (the old always-full build); "check" grows the
-        # first tree both ways and asserts identical splits.  "auto"
+        # keeps the oracle (the old always-full build).  "auto"
         # knobs route through the cost-model autotuner (K=2: the two
         # arms ride the batched level program as the class axis)
         from ...runtime import autotune
@@ -208,15 +207,14 @@ class UpliftDRF(SharedTree):
         hist_mode = knobs.hist_mode
         level_fns = [make_subtract_level_fn(d, F, B, N)
                      for d in range(p.max_depth)] \
-            if hist_mode in ("subtract", "check") else None
+            if hist_mode == "subtract" else None
         full_fns = [make_hist_fn(2 ** d, F, B, N)
                     for d in range(p.max_depth)] \
-            if hist_mode in ("full", "check") else None
+            if hist_mode == "full" else None
         # split_mode="fused": the two arms ride the batched level program
         # as the K axis (K=2, shared leaf routing, per-arm stat planes) —
         # one hist launch per level instead of two; the divergence split
-        # search itself stays _uplift_best_splits.  "check" grows the
-        # first tree both ways and asserts, then trains batched.
+        # search itself stays _uplift_best_splits.
         split_mode = knobs.split_mode
         bfns = [make_batched_level_fn(
                     d, 2, F, B, N, subtract=(hist_mode != "full"))
@@ -225,7 +223,7 @@ class UpliftDRF(SharedTree):
         # hist_layout="sparse": levels at/below the clamped threshold key
         # histograms by ALIVE-leaf slots [A, F, B] instead of the dense
         # [2^d, F, B] grid (both arms share one slot map — the leaf
-        # assignment is shared).  "check" grows the first tree both ways.
+        # assignment is shared).
         hist_layout = knobs.hist_layout
         # tree_program: uplift's bespoke two-arm grow_tree loop has no
         # scan-fused build (its divergence split search interleaves both
@@ -233,13 +231,8 @@ class UpliftDRF(SharedTree):
         # rides the per-level program.  The tuner never tunes the knob
         # for kind="uplift"; this covers an explicit tree_program="scan".
         tree_program = "level"
-        if hist_layout == "check" and (hist_mode == "check"
-                                       or split_mode == "check"):
-            raise ValueError(
-                "hist_layout='check' needs a resolved hist_mode/split_mode "
-                "(run one crosscheck at a time)")
         t0 = max(1, min(p.sparse_depth_threshold, dense_mem_cap(p.nbins, F)))
-        sparse_from0 = t0 if (hist_layout in ("sparse", "check")
+        sparse_from0 = t0 if (hist_layout == "sparse"
                               and p.max_depth > t0) else p.max_depth
         A_cap = sparse_slot_budget(F, B)
         A_lv = {d: min(2 ** d, A_cap)
@@ -422,81 +415,12 @@ class UpliftDRF(SharedTree):
             if p.sample_rate < 1.0:
                 wv = w * jax.random.bernoulli(ks, p.sample_rate, w.shape)
             keys = jax.random.split(km, p.max_depth)
-            hm = "full" if hist_mode == "full" else "subtract"
             if sparse_from0 < p.max_depth:
                 # kill/resume while node-sparse deep levels are live
                 failure.maybe_inject("deep_level")
-            if hist_layout == "check" and t_i == 0:
-                # driver assert: dense and node-sparse layouts must grow
-                # the same first tree (valid + routing exact; feat/thr
-                # compared where valid — dense keeps candidate records on
-                # dead slots, sparse drops the rows)
-                lv_sp, leaf_sp = grow_tree(
-                    wv, keys, hm, batched=(split_mode == "fused"),
-                    layout="sparse")
-                lv_d, leaf_d = grow_tree(
-                    wv, keys, hm, batched=(split_mode == "fused"))
-                host = jax.device_get([lv_sp, leaf_sp, lv_d, leaf_d])
-                for d, (a, b) in enumerate(zip(host[0], host[2])):
-                    va, vb = np.asarray(a[3]), np.asarray(b[3])
-                    if not np.array_equal(va, vb):
-                        raise AssertionError(
-                            f"hist_layout='check': uplift dense and sparse "
-                            f"layouts disagree on valid at level {d}")
-                    for i, nm in ((0, "feat"), (1, "thr")):
-                        if not np.allclose(np.where(va, a[i], 0),
-                                           np.where(vb, b[i], 0)):
-                            raise AssertionError(
-                                f"hist_layout='check': uplift dense and "
-                                f"sparse layouts disagree on {nm} at "
-                                f"level {d}")
-                if not np.array_equal(host[1], host[3]):
-                    raise AssertionError(
-                        "hist_layout='check': uplift final leaf routing "
-                        "differs between the dense and sparse layouts")
-                hist_layout = "sparse"
-                levels, leaf = lv_sp, leaf_sp
-            elif hist_mode == "check" and t_i == 0:
-                # driver assert: first tree grown both ways must agree
-                lv_s, leaf_s = grow_tree(wv, keys, "subtract")
-                lv_f, leaf_f = grow_tree(wv, keys, "full")
-                host = jax.device_get([lv_s, leaf_s, lv_f, leaf_f])
-                for d, (a, b) in enumerate(zip(host[0], host[2])):
-                    for i, nm in ((0, "feat"), (1, "thr"), (3, "valid")):
-                        if not np.allclose(a[i], b[i]):
-                            raise AssertionError(
-                                f"hist_mode='check': uplift subtraction "
-                                f"and full builds disagree on {nm} at "
-                                f"level {d}")
-                if not np.array_equal(host[1], host[3]):
-                    raise AssertionError(
-                        "hist_mode='check': uplift final leaf routing "
-                        "differs between histogram builds")
-                levels, leaf = lv_s, leaf_s
-            elif split_mode == "check" and t_i == 0:
-                # driver assert: the batched two-arm level program must
-                # grow the same first tree as the two-call-per-level path
-                lv_b, leaf_b = grow_tree(wv, keys, hm, batched=True)
-                lv_s, leaf_s = grow_tree(wv, keys, hm)
-                host = jax.device_get([lv_b, leaf_b, lv_s, leaf_s])
-                for d, (a, b) in enumerate(zip(host[0], host[2])):
-                    for i, nm in ((0, "feat"), (1, "thr"), (3, "valid")):
-                        if not np.allclose(a[i], b[i]):
-                            raise AssertionError(
-                                f"split_mode='check': uplift batched and "
-                                f"separate level builds disagree on {nm} "
-                                f"at level {d}")
-                if not np.array_equal(host[1], host[3]):
-                    raise AssertionError(
-                        "split_mode='check': uplift final leaf routing "
-                        "differs between the batched and separate builds")
-                split_mode = "fused"
-                levels, leaf = lv_b, leaf_b
-            else:
-                levels, leaf = grow_tree(
-                    wv, keys, hm, batched=(split_mode == "fused"),
-                    layout=("sparse" if hist_layout == "sparse"
-                            else "dense"))
+            levels, leaf = grow_tree(
+                wv, keys, hist_mode, batched=(split_mode == "fused"),
+                layout=hist_layout)
             pt_vals, pc_vals = leaf_stats(leaf, wv)
             lv = [tuple(x) if not isinstance(x, tuple) else x
                   for x in levels]

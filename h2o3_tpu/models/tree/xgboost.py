@@ -14,7 +14,7 @@ The h2o alias surface (eta/subsample/colsample_bytree/...) is accepted
 verbatim so estimator code ports 1:1.  Like gpu_hist, levels below the root
 histogram only each parent's smaller child and derive the sibling by
 subtraction (``hist_mode="subtract"``, the default; "full" is the exactness
-oracle and "check" asserts their agreement on the first tree — shared.py).
+oracle — shared.py).
 """
 
 from __future__ import annotations
@@ -23,9 +23,8 @@ import dataclasses
 from typing import Optional
 
 from ...frame.frame import Frame
-from ..base import ModelBuilder
 from .gbm import GBM, GBMModel, GBMParameters
-from .shared import SharedTreeParameters
+from .shared import SharedTree, SharedTreeParameters
 
 # h2o-py H2OXGBoostEstimator alias -> canonical field
 _ALIASES = {
@@ -109,13 +108,7 @@ class XGBoost(GBM):
             raise ValueError(
                 f"booster={params.booster!r} not supported (gbtree, dart); "
                 "gblinear maps to GLM in this framework")
-        from .shared import (resolve_hist_layout, resolve_hist_mode,
-                             resolve_split_mode, resolve_tree_program)
-        resolve_hist_mode(params)        # fail fast on a bad hist_mode
-        resolve_split_mode(params)       # ... and on a bad split_mode
-        resolve_hist_layout(params)      # ... and on a bad hist_layout
-        resolve_tree_program(params)     # ... and on a bad tree_program
-        ModelBuilder.__init__(self, params)
+        SharedTree.__init__(self, params)
 
     def train(self, frame, valid=None, warm_start=None):
         p: XGBoostParameters = self.params

@@ -48,9 +48,11 @@ The master switch is ``H2O3_TPU_AUTOTUNE`` = ``on`` (default) | ``off`` |
 ``cache_only``.  ``off`` resolves every ``"auto"`` knob to the historical
 fixed default (subtract / fused / sparse-below-threshold / hier), giving
 bit-identical kernels to the pre-tuner tree — tier-1 pins it.
-``cache_only`` serves cached + model decisions but never explores.  The
-``*="check"`` oracles remain the correctness net under every decision the
-tuner makes: checks bypass tuning entirely and crosscheck the real data.
+``cache_only`` serves cached + model decisions but never explores.
+Every value the tuner can pick is compared with its oracle value in the
+parity suites (tests/test_hist_subtract.py, test_fused_splits.py,
+test_sparse_levels.py, test_tree_scan.py) and, on the chip, by
+``chip_smoke.py``'s ``parity_*`` phases.
 """
 
 from __future__ import annotations
@@ -262,8 +264,7 @@ def _predict_tree_cost(F: int, N: int, K: int, max_depth: int, nbins: int,
 
 
 def _tree_candidates(F: int, N: int, K: int, max_depth: int, nbins: int,
-                     *, mono, plan, hier: bool,
-                     tuned: dict) -> List[dict]:
+                     *, mono, plan, tuned: dict) -> List[dict]:
     """Joint candidate configs over the knobs being tuned; knobs pinned by
     the user keep their pinned value in every candidate.  The same
     feature-compat downgrades the shared.py resolvers apply constrain the
@@ -273,7 +274,7 @@ def _tree_candidates(F: int, N: int, K: int, max_depth: int, nbins: int,
                   else (tuned.get("_hist_mode_pin", "subtract"),))
     split_modes = (("fused", "separate") if tuned.get("split_mode")
                    else (tuned.get("_split_mode_pin", "fused"),))
-    if mono is not None or plan is not None or hier:
+    if mono is not None or plan is not None:
         split_modes = ("separate",)
     # the scan-fused program composes with dense uniform kernels only,
     # and needs >= 2 effective levels.  The depth gate is conservative
@@ -281,15 +282,14 @@ def _tree_candidates(F: int, N: int, K: int, max_depth: int, nbins: int,
     # "scan" can never hit the builder's fail-fast validation.
     row_cap = max(1, int(math.ceil(math.log2(max(N, 2)))) + 1)
     from ..models.tree.shared import dense_mem_cap as _dmc
-    scan_ok = (mono is None and plan is None and not hier
+    scan_ok = (mono is None and plan is None
                and min(max_depth, row_cap, _dmc(nbins, F)) >= 2)
     progs = (("level", "scan") if tuned.get("tree_program")
              else (tuned.get("_tree_program_pin", "level"),))
     out = []
     for hm in hist_modes:
         layouts: Tuple[Tuple[str, int], ...]
-        sparse_ok = sparse_layout_active("auto", hm, mono=mono, plan=plan,
-                                         hier=hier)
+        sparse_ok = sparse_layout_active("auto", hm, mono=mono, plan=plan)
         cap = max(1, dense_mem_cap(nbins, F))
         if tuned.get("hist_layout"):
             layouts = (("dense", max_depth),)
@@ -482,22 +482,21 @@ class TreeKnobs:
     """One resolve's effective kernel-strategy knobs (builder values)."""
     hist_mode: str
     split_mode: str
-    hist_layout: str                     # dense | sparse | check
+    hist_layout: str                     # dense | sparse
     sparse_depth_threshold: int
-    tree_program: str                    # level | scan | check
+    tree_program: str                    # level | scan
     sources: dict                        # knob -> user|default|model|...
     sig: Optional[str] = None            # signature when the tuner engaged
     run_key: Optional[str] = None        # config key actually running
 
 
 def resolve_tree_knobs(params, *, kind: str, F: int, N: int, K: int = 1,
-                       mono=None, plan=None, hier: bool = False,
+                       mono=None, plan=None,
                        checkpoint: bool = False) -> TreeKnobs:
     """The drivers' single up-front knob resolution point.
 
-    Explicit knob values (anything but ``"auto"``, including the
-    ``"check"`` oracle modes) pass straight through the shared.py
-    resolvers untouched.  ``"auto"`` knobs resolve to the historical
+    Explicit knob values (anything but ``"auto"``) pass straight through
+    the shared.py resolvers untouched.  ``"auto"`` knobs resolve to the historical
     fixed defaults when the tuner is off (bit-identical kernels), or to
     the per-signature decision when it is on.  Checkpoint continuations
     pin ``sparse_depth_threshold`` to the params value so resumed trees
@@ -518,12 +517,11 @@ def resolve_tree_knobs(params, *, kind: str, F: int, N: int, K: int = 1,
     # the baseline resolution every path starts from (validation +
     # feature-compat downgrades live in shared.py, exactly as before)
     hist_mode = resolve_hist_mode(params)
-    split_mode = resolve_split_mode(params, mono=mono, plan=plan, hier=hier)
+    split_mode = resolve_split_mode(params, mono=mono, plan=plan)
     hist_layout = resolve_hist_layout(params, hist_mode=hist_mode,
-                                      mono=mono, plan=plan, hier=hier)
+                                      mono=mono, plan=plan)
     tree_program = resolve_tree_program(params, hist_layout=hist_layout,
-                                        mono=mono, plan=plan, hier=hier,
-                                        F=F)
+                                        mono=mono, plan=plan, F=F)
     sources = {
         "hist_mode": "default" if hm_raw == "auto" else "user",
         "split_mode": "default" if sm_raw == "auto" else "user",
@@ -549,9 +547,8 @@ def resolve_tree_knobs(params, *, kind: str, F: int, N: int, K: int = 1,
         "_tree_program_pin": tree_program,
     }
     mode = autotune_mode()
-    # checks bypass tuning (the oracle decides), off bypasses everything
-    if (mode == "off" or "check" in (hist_mode, split_mode, hist_layout,
-                                     tree_program)
+    # off bypasses everything; so does a fit with every knob pinned
+    if (mode == "off"
             or not any(tuned[k] for k in ("hist_mode", "split_mode",
                                           "hist_layout",
                                           "sparse_depth_threshold",
@@ -562,7 +559,7 @@ def resolve_tree_knobs(params, *, kind: str, F: int, N: int, K: int = 1,
     sig = _signature(kind, F, N, K, max_depth, nbins)
     with _lock:
         candidates = _tree_candidates(F, N, K, max_depth, nbins, mono=mono,
-                                      plan=plan, hier=hier, tuned=tuned)
+                                      plan=plan, tuned=tuned)
         if not candidates:
             return TreeKnobs(hist_mode, split_mode, hist_layout, thr_raw,
                              tree_program, sources)

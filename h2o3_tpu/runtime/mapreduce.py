@@ -24,7 +24,7 @@ behind ``reduce_mode``:
   * ``"hier"``  — staged ICI-then-DCN psum (default; H2O3_TPU_REDUCE_MODE)
   * ``"flat"``  — single psum over the flattened product axis
   * ``"check"`` — run both whole programs and raise ``ReduceParityError``
-                  on divergence (the ``hist_mode="check"`` analog)
+                  on divergence
 
 For most algorithms you don't even need ``map_reduce``: operating on
 row-sharded arrays inside ``jax.jit`` lets GSPMD insert the collectives

@@ -45,7 +45,7 @@ _lock = threading.Lock()
 
 # master switch (H2O3_TPU_METRICS / config().metrics_enabled): the
 # instrumentation fast-path — span()/observe()/inc()/set_gauge() return
-# immediately when off, which is what bench_pieces.py obs measures.  Read
+# immediately when off.  Read
 # here, so that a process started with H2O3_TPU_METRICS=0 is off from its
 # first span and not only after a config.reload()
 _enabled = bool(config().metrics_enabled)

@@ -48,7 +48,6 @@ registry:
   block-until-ready-syncs eagerly-dispatched work (every Nth call under
   ``sampled``; every call under ``full``) and records the true
   dispatch→ready wall time into ``tree_phase_device_seconds{phase}``.
-  ``bench_pieces.py xprof`` pins the ``sampled`` overhead < 2%.
 """
 
 from __future__ import annotations
@@ -517,44 +516,3 @@ def maybe_device_sync(phase: str, seq: int, started: float, out) -> bool:
     except Exception:                    # noqa: BLE001 — observer only
         pass
     return True
-
-
-def count_kernel_launches(fn, *args, **kwargs) -> int:
-    """Static kernel-dispatch sites in ``fn``'s traced program.
-
-    Traces ``fn`` on the given args (abstract evaluation only — nothing
-    executes) and counts the jaxpr eqns that dispatch a compiled kernel
-    program: ``shard_map`` (every hist/split/partition kernel seam goes
-    through one) and ``pallas_call`` (a hand-written kernel outside a
-    seam).  Sub-jaxprs of higher-order primitives (scan/cond/pjit/...)
-    are descended and each body is counted ONCE — so a level-unrolled
-    tree build reports one site per level while the scan-fused build
-    reports a depth-independent handful.  That static count is the
-    dispatch-overhead proxy the treescan bench pins: XLA launches the
-    unrolled program's kernels one by one, while a ``lax.scan`` body is
-    a single compiled loop on device.
-    """
-    import jax
-
-    jaxpr = jax.make_jaxpr(fn, **kwargs)(*args)
-
-    def _subjaxprs(v):
-        out = []
-        for x in (v if isinstance(v, (list, tuple)) else [v]):
-            if hasattr(x, "jaxpr") and hasattr(x, "consts"):
-                out.append(x.jaxpr)          # ClosedJaxpr
-            elif hasattr(x, "eqns"):
-                out.append(x)                # raw Jaxpr
-        return out
-
-    def _count(jx) -> int:
-        n = 0
-        for eqn in jx.eqns:
-            if eqn.primitive.name in ("shard_map", "pallas_call"):
-                n += 1
-            for val in eqn.params.values():
-                for sub in _subjaxprs(val):
-                    n += _count(sub)
-        return n
-
-    return _count(jaxpr.jaxpr)

@@ -138,7 +138,7 @@ def _release_compiled_programs():
     import jax as _jax
     try:
         from h2o3_tpu.models.tree import hist as _h, shared as _s
-        for fn in (_h.make_hist_fn, _h.make_fine_hist_fn,
+        for fn in (_h.make_hist_fn,
                    _h.make_varbin_hist_fn, _h.make_subtract_level_fn,
                    _h.make_batched_level_fn, _h.make_sparse_level_fn,
                    _h.make_batched_sparse_level_fn,

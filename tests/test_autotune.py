@@ -9,8 +9,9 @@ Five behaviours pin the design (see docs/operations.md "Autotuning"):
   3. a mesh rebuild (cluster_reinit epoch bump) drops every decision;
   4. a forced-wrong cost model self-corrects from measured device
      samples — the epsilon-greedy re-measure flips the choice;
-  5. the ``*="check"`` oracles still run (and still bit-match) with the
-     tuner on: checks bypass tuning entirely.
+  5. whatever the tuner picks beside a pinned knob, both values of that
+     knob still grow the same trees; a fit with every knob pinned
+     bypasses tuning entirely.
 
 The suite-wide conftest pins H2O3_TPU_AUTOTUNE=off; these tests opt back
 in per-test through the ``tuner_on`` fixture (explicit env save/restore,
@@ -115,10 +116,16 @@ def test_checkpoint_pins_sparse_threshold(tuner_on):
     assert k.sources["sparse_depth_threshold"] == "default"
 
 
-def test_check_mode_bypasses_tuner(tuner_on):
-    k = tuner_on.resolve_tree_knobs(_params(hist_mode="check"),
-                                    kind="gbm", F=8, N=4096)
-    assert k.hist_mode == "check" and k.sig is None
+def test_pinned_knobs_bypass_tuner(tuner_on):
+    """Nothing left to tune, nothing decided or recorded (chip_smoke.py's
+    parity phases pin every knob so that two fits differ in one)."""
+    k = tuner_on.resolve_tree_knobs(
+        _params(hist_mode="full", split_mode="separate",
+                hist_layout="dense", tree_program="scan"),
+        kind="gbm", F=8, N=4096)
+    assert (k.hist_mode, k.split_mode, k.hist_layout, k.tree_program) == \
+        ("full", "separate", "dense", "scan")
+    assert k.sig is None and set(k.sources.values()) <= {"user", "default"}
     assert tuner_on.decision_table()["entries"] == 0
 
 
@@ -365,15 +372,19 @@ def _tiny_frame(rng, n=600):
         {**{f"x{i}": X[:, i] for i in range(4)}, "y": y})
 
 
-def test_check_oracle_runs_clean_under_tuner(cl, rng, tuner_on):
-    """The correctness net survives the tuner: a hist_mode="check" build
-    (which crosschecks subtract against the full-build oracle on the
-    real data and raises on any bit mismatch) passes with autotune on."""
+def test_both_hist_modes_agree_under_tuner(cl, rng, tuner_on):
+    """The correctness net survives the tuner: with autotune on and the
+    other knobs left to it, a subtract fit and a full-build fit (the
+    oracle) grow the same trees."""
     from h2o3_tpu.models.tree.gbm import GBM
+    from tree_parity import assert_same_trees
     fr = _tiny_frame(rng)
-    m = GBM(response_column="y", ntrees=3, max_depth=3, nbins=16,
-            seed=7, hist_mode="check", split_mode="check").train(fr)
-    assert m.output["trees"]
+    kw = dict(response_column="y", ntrees=3, max_depth=3, nbins=16, seed=7,
+              reproducible=True)
+    m_s = GBM(hist_mode="subtract", **kw).train(fr)
+    m_f = GBM(hist_mode="full", **kw).train(fr)
+    assert tuner_on.decision_table()["entries"] >= 1      # it did engage
+    assert_same_trees(m_s, m_f)
 
 
 def test_tuned_auto_matches_pinned_choice_bitwise(cl, rng, tuner_on):
@@ -467,7 +478,7 @@ def _model_choice(at, F, N, max_depth, nbins, K=1):
     tuned = dict.fromkeys(("hist_mode", "split_mode", "hist_layout",
                            "sparse_depth_threshold", "tree_program"), True)
     cands = at._tree_candidates(F, N, K, max_depth, nbins, mono=None,
-                                plan=None, hier=False, tuned=tuned)
+                                plan=None, tuned=tuned)
     costs = at._predict_costs(F, N, K, max_depth, nbins, cands)
     return min(costs, key=costs.get), costs
 

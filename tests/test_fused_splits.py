@@ -10,10 +10,11 @@ Three layers of oracle checks for the one-launch-per-level pipeline:
    for all K class trees) vs the sequential per-class loop — same RNG
    stream, same trees, same predictions, including shared row sampling
    and per-class column-sample masks.
-3. The driver-facing ``split_mode="check"`` crosschecks
-   (``run_split_crosscheck`` / ``run_hist_crosscheck(nk=...)``) and a
-   tiny end-to-end GBM ``split_mode="check"`` train — the tier-1 smoke
-   for the whole fused pipeline.
+3. Whole builds and whole fits both ways: the batched K-tree builder
+   against a K-loop of separate builds and against the full-histogram
+   oracle, and estimators trained with ``split_mode="fused"`` and
+   ``"separate"`` compared tree by tree — the tier-1 smoke for the whole
+   fused pipeline.
 
 The dispatch-count test asserts the load-bearing property directly from
 the jaxpr: a batched level issues ONE histogram kernel launch for all K
@@ -26,6 +27,7 @@ import jax.numpy as jnp
 import pytest
 
 from h2o3_tpu.models.tree import hist, shared
+from tree_parity import MODELS, check_pair
 
 
 def _rand_hist(rng, L, F, B, na_mass=0.2):
@@ -166,10 +168,12 @@ def test_single_tree_scan_fused_bitexact(cl, rng):
     assert np.array_equal(outs[0][2], outs[1][2])      # leaf values
 
 
-def test_split_and_hist_crosschecks(cl, rng):
-    """The driver-facing check helpers: batched-K build vs K sequential
-    oracle builds (run_split_crosscheck) and batched-K histograms vs the
-    full-hist oracle (run_hist_crosscheck(nk=K))."""
+def test_batched_build_both_ways(cl, rng):
+    """One round of K trees on the same gradients, keys and column masks:
+    the batched fused build against a K-loop of separate builds, and the
+    batched build on subtracted histograms against the full-histogram
+    oracle.  Which nodes split and where the rows end agree exactly, how a
+    node splits wherever it does, leaves to float32 tolerance."""
     F, N, K, nbins, depth = 5, 1024, 3, 16, 4
     codes, edges, _, w = _tiny_problem(rng, F, N, K, nbins)
     g = jnp.asarray(rng.normal(size=(K, N)), jnp.float32)
@@ -178,17 +182,32 @@ def test_split_and_hist_crosschecks(cl, rng):
     keys = jnp.stack([jax.random.fold_in(key, k) for k in range(K)])
     tms = jnp.asarray(rng.uniform(size=(K, F)) < 0.8, bool)
     tms = tms.at[:, 0].set(True)
-    shared.run_split_crosscheck(codes, g, h, w, edges, keys,
-                                max_depth=depth, nbins=nbins, F=F,
-                                n_padded=N, tree_masks=tms,
-                                reg_lambda=0.5, col_sample_rate=0.8)
-    shared.run_split_crosscheck(codes, g[0], h[0], w, edges, keys[0],
-                                max_depth=depth, nbins=nbins, F=F,
-                                n_padded=N, reg_lambda=0.5,
-                                reg_alpha=0.2, gamma=0.1)
-    shared.run_hist_crosscheck(codes, g, h, w, edges, keys,
-                               max_depth=depth, nbins=nbins, F=F,
-                               n_padded=N, nk=K, reg_lambda=0.5)
+    scal = (0.5, 1.0, 1e-5, 0.1, 0.8)
+
+    def build(k=None, **kw):
+        fn = shared.make_build_tree_fn(depth, nbins, F, N, "f32", **kw)
+        pick = (lambda a: a) if k is None else (lambda a: a[k])
+        levels, vals, _, leaf = fn(codes, pick(g), pick(h), w, edges,
+                                   pick(keys), *scal, pick(tms), 0.2, 0.1,
+                                   0.0)
+        return jax.device_get([[tuple(lv) for lv in levels], vals, leaf])
+
+    def same(a, b, k=None):
+        pick = (lambda x: x) if k is None else (lambda x: x[k])
+        for lv_a, lv_b in zip(a[0], b[0]):
+            valid = np.asarray(pick(lv_a[3]), bool)
+            assert np.array_equal(valid, lv_b[3])
+            for i in (0, 2):
+                assert np.array_equal(pick(lv_a[i])[valid], lv_b[i][valid])
+            np.testing.assert_allclose(pick(lv_a[1])[valid],
+                                       lv_b[1][valid], atol=1e-4, rtol=1e-5)
+        assert np.array_equal(pick(a[2]), b[2])
+        np.testing.assert_allclose(pick(a[1]), b[1], atol=1e-4, rtol=1e-4)
+
+    fused = build(nk=K, split_mode="fused")
+    for k in range(K):
+        same(fused, build(k), k)
+    same(fused, build(nk=K, split_mode="fused", hist_mode="full"))
 
 
 def test_batched_level_single_hist_dispatch(cl, rng):
@@ -211,29 +230,20 @@ def test_batched_level_single_hist_dispatch(cl, rng):
     assert n_calls == 1, f"expected 1 hist launch for K={K}, got {n_calls}"
 
 
-def test_gbm_split_mode_check_smoke(cl, rng):
-    """Tier-1 smoke: a tiny multinomial GBM trained with
-    split_mode='check' runs the batched-vs-sequential crosscheck inside
-    the real driver and must train through cleanly; a bogus mode fails
-    fast at construction."""
-    from h2o3_tpu import Frame
+@pytest.mark.parametrize("model", MODELS)
+def test_estimator_fused_separate_same_trees(cl, model):
+    """Two fits through split_mode's two values grow the same trees: what
+    the in-training split_mode="check" compared on its first round (GBM's
+    K class trees and uplift's two arms ride the batched level program
+    under "fused")."""
+    check_pair(model, "split_mode", ("fused", "separate"),
+               sample_rate=0.8, col_sample_rate_per_tree=0.7)
+
+
+def test_split_mode_bogus_fails_fast(cl):
     from h2o3_tpu.models import GBM
-    n = 600
-    centers = np.array([[2, 0], [-2, 1], [0, -2]])
-    labels = rng.integers(0, 3, n)
-    X = centers[labels] + rng.normal(size=(n, 2))
-    fr = Frame.from_numpy({
-        "x0": X[:, 0], "x1": X[:, 1],
-        "y": np.array(["a", "b", "c"], dtype=object)[labels]})
-    kw = dict(response_column="y", ntrees=3, max_depth=3, seed=4,
-              sample_rate=0.8, col_sample_rate_per_tree=0.7)
-    m_chk = GBM(**kw, split_mode="check").train(fr)
-    m_sep = GBM(**kw, split_mode="separate").train(fr)
-    pc = np.stack([m_chk.predict(fr).vec(c).to_numpy() for c in "abc"], 1)
-    ps = np.stack([m_sep.predict(fr).vec(c).to_numpy() for c in "abc"], 1)
-    np.testing.assert_allclose(pc, ps, atol=1e-5)
     with pytest.raises(ValueError, match="split_mode"):
-        GBM(response_column="y", split_mode="bogus").train(fr)
+        GBM(response_column="y", split_mode="bogus")
 
 
 @pytest.mark.slow

@@ -58,117 +58,6 @@ def test_pallas_deep_fallback_matches(cl, rng):
                                atol=1e-3, rtol=1e-5)
 
 
-def test_fine_hist_parity_and_semantics(cl, rng):
-    """Fine-refinement kernel: interpret-mode Pallas vs einsum vs numpy."""
-    from h2o3_tpu.models.tree.hist import make_fine_hist_fn
-    N, F, L, K, W, nbins = 2048, 5, 4, 2, 8, 61   # nbins < S*W on purpose
-    codes_np = rng.integers(0, nbins + 1, (F, N))
-    leaf_np = rng.integers(0, L, N)
-    sel_np = rng.integers(0, 8, (L, F, K))
-    codes = jnp.asarray(codes_np, jnp.int32)
-    leaf = jnp.asarray(leaf_np, jnp.int32)
-    sel = jnp.asarray(sel_np, jnp.int32)
-    g = jnp.asarray(rng.normal(size=N), jnp.float32)
-    h = jnp.asarray(rng.random(N), jnp.float32)
-    w = jnp.ones(N, jnp.float32)
-    He = np.asarray(make_fine_hist_fn(L, F, W, K, nbins, N,
-                                      force_impl="einsum")(
-        codes, leaf, g, h, w, sel))
-    Hp = np.asarray(make_fine_hist_fn(L, F, W, K, nbins, N,
-                                      force_impl="pallas_interpret",
-                                      precision="f32")(
-        codes, leaf, g, h, w, sel))
-    np.testing.assert_allclose(He, Hp, atol=1e-3, rtol=1e-5)
-    # numpy reference: slot (l,f,k,t) sums rows with leaf l, code sel*W+t
-    gh = np.asarray(g)
-    for l in range(L):
-        for f in range(F):
-            for k in range(K):
-                s = sel_np[l, f, k]
-                for t in range(W):
-                    want = gh[(leaf_np == l)
-                              & (codes_np[f] == s * W + t)
-                              & (codes_np[f] < nbins)].sum()
-                    assert He[0, l, f, k, t] == pytest.approx(want, abs=1e-3)
-
-
-def test_hier_split_search_finds_signal_split(cl, rng):
-    """On data with a real signal split, the hierarchical search picks the
-    exact same (feature, bin) as the full pass, with matching gain and
-    child statistics."""
-    from h2o3_tpu.models.tree.hist import (
-        make_hist_fn, make_fine_hist_fn, select_superbins, best_splits,
-        best_splits_hier)
-    N, F, L, nbins, K = 8192, 6, 4, 64, 2
-    S, W = 8, 8
-    lam, alpha, gam, min_rows, mcw = 1.0, 0.0, 0.0, 5.0, 0.0
-    codes_np = rng.integers(0, nbins + 1, (F, N))
-    codes_np[0] = rng.integers(0, 8, N)        # low-cardinality feature
-    codes = jnp.asarray(codes_np, jnp.int32)
-    leaf = jnp.asarray(rng.integers(0, L, N), jnp.int32)
-    # strong signal on feature 1 at bin 36 (interior of super-bin 4)
-    g_np = np.where(codes_np[1] <= 36, -1.0, 1.0) + 0.05 * rng.normal(size=N)
-    g = jnp.asarray(g_np, jnp.float32)
-    h = jnp.asarray(np.full(N, 1.0), jnp.float32)
-    w = jnp.ones(N, jnp.float32)
-
-    Hfull = make_hist_fn(L, F, nbins + 1, N, force_impl="einsum")(
-        codes, leaf, g, h, w)
-    feat0, bin0, nal0, gain0, valid0, ch0 = best_splits(
-        Hfull, nbins, lam, min_rows, 1e-5, None, alpha, gam, mcw)
-
-    ccodes = jnp.where(codes >= nbins, S, codes // W)
-    Hc = make_hist_fn(L, F, S + 1, N, force_impl="einsum")(
-        ccodes, leaf, g, h, w)
-    sel, ub = select_superbins(Hc, nbins, W, K, lam, alpha, gam,
-                               min_rows, mcw)
-    Hf = make_fine_hist_fn(L, F, W, K, nbins, N, force_impl="einsum")(
-        codes, leaf, g, h, w, sel)
-    feat1, bin1, nal1, gain1, valid1, ch1, _ = best_splits_hier(
-        Hc, Hf, sel, ub, nbins, W, lam, min_rows, 1e-5, None, alpha, gam,
-        mcw)
-    np.testing.assert_array_equal(np.asarray(feat1), np.asarray(feat0))
-    np.testing.assert_array_equal(np.asarray(bin1), np.asarray(bin0))
-    assert list(np.asarray(feat0)) == [1] * L
-    assert list(np.asarray(bin0)) == [36] * L
-    np.testing.assert_allclose(np.asarray(gain1), np.asarray(gain0),
-                               rtol=1e-3, atol=1e-4)
-    np.testing.assert_array_equal(np.asarray(valid1), np.asarray(valid0))
-    np.testing.assert_allclose(np.asarray(ch1), np.asarray(ch0),
-                               rtol=1e-3, atol=1e-2)
-
-
-def test_build_tree_hier_equals_full_on_signal(cl, rng):
-    """Whole-tree growth: the hierarchical path reproduces the full-pass
-    tree when splits carry signal (depth-2, two planted split features)."""
-    from h2o3_tpu.models.tree.shared import build_tree
-    import jax
-    N, F, nbins, depth = 8192, 5, 64, 2
-    codes_np = rng.integers(0, nbins, (F, N))
-    codes = jnp.asarray(codes_np, jnp.int32)
-    g_np = (np.where(codes_np[2] <= 21, -2.0, 2.0)
-            + np.where(codes_np[3] <= 44, -0.7, 0.7)
-            + 0.05 * rng.normal(size=N))
-    g = jnp.asarray(g_np, jnp.float32)
-    h = jnp.asarray(np.full(N, 1.0), jnp.float32)
-    w = jnp.ones(N, jnp.float32)
-    edges = [np.sort(rng.normal(size=nbins - 1)).astype(np.float32)
-             for _ in range(F)]
-    key = jax.random.PRNGKey(7)
-    t0, leaf0 = build_tree(codes, g, h, w, edges, nbins, depth, 1.0, 5.0,
-                           1e-5, 0.1, key, hist_precision="f32", hier=False)
-    t1, leaf1 = build_tree(codes, g, h, w, edges, nbins, depth, 1.0, 5.0,
-                           1e-5, 0.1, key, hist_precision="f32", hier=True)
-    np.testing.assert_array_equal(np.asarray(leaf0), np.asarray(leaf1))
-    np.testing.assert_allclose(np.asarray(t0.values), np.asarray(t1.values),
-                               rtol=1e-3, atol=1e-4)
-    for d in range(depth):
-        np.testing.assert_array_equal(np.asarray(t0.feat[d]),
-                                      np.asarray(t1.feat[d]))
-        np.testing.assert_allclose(np.asarray(t0.thr[d]),
-                                   np.asarray(t1.thr[d]), rtol=1e-5)
-
-
 def test_varbin_hist_matches_dense(cl, rng):
     """Packed per-feature bin axis == dense histogram, bit-for-bit-ish."""
     from h2o3_tpu.models.tree.hist import (make_hist_fn, make_varbin_hist_fn,

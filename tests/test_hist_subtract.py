@@ -18,6 +18,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from tree_parity import MODELS, check_pair
 from h2o3_tpu.models.tree.hist import (make_hist_fn, make_subtract_level_fn,
                                        offset_codes)
 
@@ -154,23 +155,6 @@ def test_build_tree_subtract_equals_full(cl, rng):
                                np.asarray(t_s.values), atol=1e-5)
 
 
-def test_run_hist_crosscheck(cl, rng):
-    """The hist_mode='check' driver assert passes on real data."""
-    from h2o3_tpu.models.tree.shared import run_hist_crosscheck
-    from h2o3_tpu.models.tree.binning import edges_matrix
-    N, F, nbins = 2048, 4, 16
-    codes = jnp.asarray(rng.integers(0, nbins + 1, (F, N)), jnp.int32)
-    g = jnp.asarray(rng.normal(size=N), jnp.float32)
-    h = jnp.ones(N, jnp.float32)
-    w = jnp.ones(N, jnp.float32)
-    edges = [np.sort(rng.normal(size=nbins - 1)).astype(np.float32)
-             for _ in range(F)]
-    em = jnp.asarray(edges_matrix(edges, nbins), jnp.float32)
-    run_hist_crosscheck(codes, g, h, w, em, jax.random.PRNGKey(3),
-                        max_depth=3, nbins=nbins, F=F, n_padded=N,
-                        reg_lambda=1.0, min_rows=5.0)
-
-
 def _airlines_tiny(rng, n=800, with_na=True):
     """Tiny airlines-shaped frame: numerics + categoricals (+ NAs)."""
     from h2o3_tpu import Frame
@@ -246,13 +230,11 @@ def test_gbm_subtract_parity_higgs_numeric(cl, rng):
     _assert_same_trees(m_s, m_f)
 
 
-def test_gbm_hist_mode_check_trains(cl, rng):
-    """hist_mode='check' runs the driver crosscheck then trains normally."""
-    from h2o3_tpu.models.tree.gbm import GBM
-    fr = _airlines_tiny(rng, n=400, with_na=False)
-    m = GBM(response_column="delayed", ntrees=4, max_depth=3, nbins=16,
-            seed=3, reproducible=True, hist_mode="check").train(fr)
-    assert m.output["ntrees_trained"] == 4
+@pytest.mark.parametrize("model", MODELS)
+def test_estimator_subtract_full_same_trees(cl, model):
+    """Two fits through hist_mode's two values grow the same trees: what
+    the in-training hist_mode="check" compared on its first tree."""
+    check_pair(model, "hist_mode", ("subtract", "full"))
 
 
 def test_hist_mode_validation(cl):
@@ -300,8 +282,6 @@ def test_uplift_subtract_equals_full(cl, rng):
     m_s = UpliftDRF(hist_mode="subtract", **kw).train(fr)
     m_f = UpliftDRF(hist_mode="full", **kw).train(fr)
     _assert_same_trees(m_s, m_f)
-    m_c = UpliftDRF(hist_mode="check", **kw).train(fr)   # driver assert
-    _assert_same_trees(m_c, m_s)
 
 
 def test_isofor_determinism_regression(cl, rng):

@@ -2,7 +2,8 @@
 
 Interpret mode lies: the real Mosaic compiler rejects programs interpret
 mode accepts (f32 iotas, unit-minor-dim iota vectors).  This gate
-cross-platform-lowers every histogram-kernel geometry bench.py uses via
+cross-platform-lowers every histogram-kernel geometry of the airlines shape
+(benchmark/configs/xgb_airlines40m.json) via
 ``jax.export(..., platforms=["tpu"])`` on the CPU host: Pallas runs its
 TPU lowering + the Mosaic MLIR verifier at export time, so an illegal iota
 form / op signature in ``hist.py`` fails HERE, without a chip.  What export
@@ -25,8 +26,9 @@ def _init():
     h2o3_tpu.init()
 
 
-# bench.py's airlines shape: 8 features, nbins=256 -> B=257, depth 6.
-# bin_counts mirror fit_bins on make_airlines_like: small-cardinality
+# the airlines shape: 8 features, nbins=256 -> B=257, depth 6.
+# bin_counts mirror fit_bins on benchmark/datagen/airlines_like.py:
+# small-cardinality
 # numerics (year/month/day), full-bin numerics, a 22-level cat, capped cats.
 BENCH_BIN_COUNTS = (21, 12, 7, 256, 256, 22, 256, 256)
 F, B, NBINS = 8, 257, 256
@@ -47,8 +49,8 @@ def _stat_shapes(n):
 
 
 def test_varbin_int16_bf16_kernel_lowers_for_tpu():
-    """The exact kernel path bench.py times (varbin + int16 codes + bf16
-    stats), at every level width of a depth-6 build."""
+    """The varbin kernel path (int16 codes + bf16 stats) at every level
+    width of a depth-6 build."""
     from h2o3_tpu.models.tree.hist import make_varbin_hist_fn
     for L in BENCH_LEVELS:
         fn = make_varbin_hist_fn(L, F, BENCH_BIN_COUNTS, B, N_PADDED)
@@ -72,17 +74,6 @@ def test_uniform_kernel_lowers_for_tpu():
         codes = ((F, N_PADDED), jnp.int32)
         rest = _stat_shapes(N_PADDED)[1:]
         _lower_tpu(fn, codes, *rest)
-
-
-def test_hier_fine_kernel_lowers_for_tpu():
-    """Opt-in split_search='hier' fine-refinement kernel."""
-    from h2o3_tpu.models.tree.hist import make_fine_hist_fn
-    W, K = 16, 2
-    fn = make_fine_hist_fn(4, F, W, K, NBINS, N_PADDED)
-    codes = ((F, N_PADDED), jnp.int32)
-    leaf, g, h, w = _stat_shapes(N_PADDED)[1:]
-    sel = ((4, F, K), jnp.int32)
-    _lower_tpu(fn, codes, leaf, g, h, w, sel)
 
 
 def test_subtract_level_lowers_for_tpu():

@@ -2,8 +2,6 @@
 requeue, membership/quarantine, and restart re-admission.  (Process-kill
 variants live in test_chaos.py.)"""
 
-import importlib.util
-import os
 import threading
 import time
 
@@ -432,15 +430,3 @@ def test_scheduler_rest_status(cl):
         assert k in d
     assert d["capacity_chips"] >= 1
     assert isinstance(d["queued"], list) and isinstance(d["running"], list)
-
-
-# ------------------------------------------------------------------ bench gate
-def test_bench_gate_classifies_sched_metrics():
-    path = os.path.join(os.path.dirname(__file__), os.pardir, "tools",
-                        "bench_gate.py")
-    spec = importlib.util.spec_from_file_location("bench_gate_sched", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    assert mod.classify("sched_small_makespan_fifo_s") == "lower"
-    assert mod.classify("sched_small_makespan_fair_s") == "lower"
-    assert mod.classify("sched_fair_vs_baseline") == "higher"

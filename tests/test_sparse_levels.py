@@ -11,8 +11,7 @@ the one-alive-leaf-at-depth-10 extreme, (d) the slot-assignment math
 (atomic pair drop on overflow, determinism), (e) the dispatch-count pin
 — 2 pallas launches per sparse level (hist + fused records), and
 (f) driver-level parity: GBM / DRF / XGBoost / UpliftDRF grow IDENTICAL
-trees through hist_layout="sparse" and the dense oracle, with
-hist_layout="check" asserting it in-driver on the first tree.
+trees through hist_layout="sparse" and the dense oracle.
 """
 
 import numpy as np
@@ -22,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from h2o3_tpu.models.tree import hist, shared
+from tree_parity import MODELS, check_pair
 from h2o3_tpu.models.tree.hist import (fused_best_splits,
                                        make_hist_fn,
                                        make_sparse_level_fn,
@@ -330,24 +330,6 @@ def test_build_tree_sparse_varbin(cl, rng, monkeypatch):
     _compare_builds(outs, md)
 
 
-def test_run_layout_crosscheck(cl, rng):
-    """The in-driver crosscheck (hist_layout="check") passes on its own:
-    single tree and batched K=3, with NAs and skew in the mix."""
-    F, N, nbins, md = 5, 2048, 16, 7
-    codes, g, h, w, edges = _skewed_inputs(rng, F, N, nbins)
-    key = jax.random.PRNGKey(7)
-    shared.run_layout_crosscheck(codes, g * w, h * w, w, edges, key,
-                                 max_depth=md, nbins=nbins, F=F,
-                                 n_padded=N, sparse_depth_threshold=3)
-    K = 3
-    gK = jnp.asarray(rng.normal(size=(K, N)), jnp.float32)
-    hK = jnp.ones((K, N), jnp.float32)
-    keysK = jax.vmap(jax.random.PRNGKey)(jnp.arange(K))
-    shared.run_layout_crosscheck(codes, gK, hK, w, edges, keysK,
-                                 max_depth=md, nbins=nbins, F=F,
-                                 n_padded=N, sparse_depth_threshold=3)
-
-
 def test_effective_depth_sparse_drops_memory_cap(cl):
     """The 64 MB dense wall (depth 10 at 256 bins, 32 features — the
     Kaggle-shape workload) does not apply to the sparse layout:
@@ -419,6 +401,17 @@ _DRIVER_KW = dict(response_column="delayed", ntrees=3, max_depth=6,
                   sparse_depth_threshold=2)
 
 
+@pytest.mark.parametrize("model", MODELS)
+def test_estimator_dense_sparse_same_trees(cl, model):
+    """Two fits through hist_layout's two values (sparse from level 2 of
+    6) grow the same trees: what the in-training hist_layout="check"
+    compared on its first tree."""
+    dense, sparse = check_pair(model, "hist_layout", ("dense", "sparse"),
+                               max_depth=6, sparse_depth_threshold=2)
+    assert dense.output["hist_layout"] == "dense"
+    assert sparse.output["hist_layout"] == "sparse"
+
+
 def test_gbm_sparse_whole_model_parity(cl, rng):
     from h2o3_tpu.models.tree.gbm import GBM
     fr = _airlines(rng)
@@ -428,11 +421,6 @@ def test_gbm_sparse_whole_model_parity(cl, rng):
     assert m_d.output["hist_layout"] == "dense"
     _assert_same_routing(m_d, m_s)
     _assert_same_preds(m_d, m_s, fr, "YES")
-    # "check" trains the first tree on BOTH layouts and asserts agreement
-    # in-driver, then continues sparse
-    m_c = GBM(hist_layout="check", **_DRIVER_KW).train(fr)
-    assert m_c.output["hist_layout"] == "sparse"
-    _assert_same_preds(m_c, m_s, fr, "YES")
 
 
 def test_gbm_multinomial_sparse_parity(cl, rng):
@@ -444,8 +432,6 @@ def test_gbm_multinomial_sparse_parity(cl, rng):
     m_s = GBM(hist_layout="sparse", **_DRIVER_KW).train(fr3)
     _assert_same_routing(m_d, m_s)
     _assert_same_preds(m_d, m_s, fr3, "B")
-    m_c = GBM(hist_layout="check", **_DRIVER_KW).train(fr3)
-    _assert_same_preds(m_c, m_s, fr3, "B")
 
 
 def test_drf_sparse_whole_model_parity(cl, rng):
@@ -523,5 +509,4 @@ def test_uplift_sparse_whole_model_parity(cl, rng):
         pa = m_d.predict(fr).vec("uplift_predict").to_numpy()
         pb = m_s.predict(fr).vec("uplift_predict").to_numpy()
         np.testing.assert_allclose(pa, pb, atol=1e-4, rtol=1e-4)
-    m_c = UpliftDRF(hist_layout="check", **kw).train(fr)
-    assert m_c.output["hist_layout"] == "sparse"
+    assert m_s.output["hist_layout"] == "sparse"

@@ -10,7 +10,8 @@ Every kernel seam chooses its branch from the platform of the live mesh's
 first device, so the module boots the cluster over one described device
 (``h2o3_tpu.init(devices=...)``), which steers the real ``tpu`` branches
 with no option in the program, and puts the CPU test mesh back afterwards.
-Shapes are the airlines-10M geometry bench.py and chip_smoke.py train on.
+Shapes are the airlines geometry (8 features, 256 bins, depth 6) at the 10M
+rows chip_smoke.py trains on.
 
 The whole-program compiles (tens of seconds each) are
 ``tools/tpu_compile_rehearsal.py``.  Nothing here runs on a device: a
@@ -133,16 +134,6 @@ def test_deep_level_falls_to_einsum_by_its_size_bound(one_chip):
                        *(sds((3, n), jnp.int32, None, rows),
                          *_hist_operands(sds, rows, n, jnp.int32)[1:]))
     assert "tpu_custom_call" not in text
-
-
-def test_hier_fine_kernel_compiles(one_chip):
-    from h2o3_tpu.models.tree.hist import make_fine_hist_fn
-    _, sds, rows = one_chip
-    W, K, L = 16, 2, 4
-    _, text = _compile(make_fine_hist_fn(L, F, W, K, NBINS, N),
-                       *_hist_operands(sds, rows, N, jnp.int32),
-                       sds((L, F, K), jnp.int32))
-    assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("L", [32, 7 * 32])
@@ -376,10 +367,6 @@ KERNELS = {
     "hist_varbin": _hist_kernel(lambda hist, sds, rows: (
         hist.make_varbin_hist_fn(8, F, BIN_COUNTS, B, SMALL_N),
         _hist_operands(sds, rows, SMALL_N, jnp.int16))),
-    "hist_fine": _hist_kernel(lambda hist, sds, rows: (
-        hist.make_fine_hist_fn(4, F, 16, 2, NBINS, SMALL_N),
-        (*_hist_operands(sds, rows, SMALL_N, jnp.int32),
-         sds((4, F, 2), jnp.int32)))),
     "hist_split_records": _hist_kernel(_split_records),
     # the blocked ensemble walk inside jit_traverse, at the score cell's shape
     "traverse_block": _traverse_cell,

@@ -3,9 +3,7 @@ dispatch loop.
 
 The scan-fused build must be BITWISE identical to the per-level program
 on every knob combination it supports (padding slots are inert, masks
-are pre-drawn with the level path's exact key sequence), and must
-compile to O(1) kernel launches per tree regardless of depth — that is
-the whole point of the fusion.
+are pre-drawn with the level path's exact key sequence).
 """
 
 import jax
@@ -18,9 +16,8 @@ from h2o3_tpu import Frame
 from h2o3_tpu.models import DRF, GBM
 from h2o3_tpu.models.tree.gbm import GBMParameters
 from h2o3_tpu.models.tree.shared import (make_build_tree_fn,
-                                         resolve_tree_program,
-                                         run_program_crosscheck)
-from h2o3_tpu.runtime.xprof import count_kernel_launches
+                                         resolve_tree_program)
+from tree_parity import check_pair
 
 
 # ---------------------------------------------------------- build level
@@ -106,47 +103,12 @@ def test_scan_matches_level_batched(cl, rng, hm):
         np.testing.assert_array_equal(np.asarray(lo[i]), np.asarray(so[i]))
 
 
-def test_program_crosscheck_runs_clean(cl, rng):
-    """The tree_program="check" oracle itself (drivers call this on the
-    real first-round gradients)."""
-    codes, g, h, w, edges = _problem(rng)
-    run_program_crosscheck(
-        codes, g, h, w, edges, jax.random.PRNGKey(3),
-        max_depth=4, nbins=16, F=5, n_padded=256,
-        reg_lambda=0.0, min_rows=1.0, min_split_improvement=1e-5,
-        learn_rate=0.1, col_sample_rate=1.0)
-
-
-# --------------------------------------------------------- dispatch pin
-
-def test_launches_per_tree_is_depth_independent(cl, rng):
-    """THE acceptance pin: the scan program compiles to O(1) kernel
-    dispatch sites regardless of depth, while the level program grows
-    one hist launch per level."""
-    F, N, nbins = 5, 256, 16
-    args = _args(rng)
-    scan_counts, level_counts = [], []
-    for md in (3, 4, 6):
-        sc = make_build_tree_fn(md, nbins, F, N, "f32",
-                                tree_program="scan")
-        lv = make_build_tree_fn(md, nbins, F, N, "f32")
-        scan_counts.append(count_kernel_launches(sc, *args))
-        level_counts.append(count_kernel_launches(lv, *args))
-    assert len(set(scan_counts)) == 1, scan_counts   # depth-independent
-    assert scan_counts[0] <= 4, scan_counts          # O(1), small
-    # the level program dispatches per level: strictly increasing in depth
-    assert level_counts[0] < level_counts[1] < level_counts[2], level_counts
-    assert scan_counts[-1] < level_counts[-1]
-
-
 # ------------------------------------------------------- knob semantics
 
 def test_scan_rejects_unsupported_shapes(cl):
     p = GBMParameters(response_column="y", tree_program="scan", max_depth=5)
     with pytest.raises(ValueError, match="mono"):
         resolve_tree_program(p, mono={"x0": 1})
-    with pytest.raises(ValueError, match="hier"):
-        resolve_tree_program(p, hier=True)
     p1 = GBMParameters(response_column="y", tree_program="scan",
                        max_depth=1)
     with pytest.raises(ValueError, match="depth"):
@@ -158,24 +120,6 @@ def test_scan_rejects_unsupported_shapes(cl):
     with pytest.raises(ValueError, match="tree_program"):
         resolve_tree_program(
             GBMParameters(response_column="y", tree_program="bogus"))
-
-
-def test_check_downgrades_where_scan_cannot_grow(cl):
-    """tree_program="check" silently rides the level program on shapes
-    the scan cannot grow — never raises, never forfeits the model."""
-    deep = GBMParameters(response_column="y", tree_program="check",
-                         max_depth=12, sparse_depth_threshold=3)
-    assert resolve_tree_program(deep, hist_layout="sparse") == "level"
-    assert resolve_tree_program(
-        GBMParameters(response_column="y", tree_program="check",
-                      max_depth=5), mono={"x0": 1}) == "level"
-    assert resolve_tree_program(
-        GBMParameters(response_column="y", tree_program="check",
-                      max_depth=1)) == "level"
-    # the happy path stays "check" (the driver then runs the oracle)
-    assert resolve_tree_program(
-        GBMParameters(response_column="y", tree_program="check",
-                      max_depth=5)) == "check"
     # "auto" under H2O3_TPU_AUTOTUNE=off is the historical level path
     assert resolve_tree_program(
         GBMParameters(response_column="y", max_depth=5)) == "level"
@@ -218,18 +162,23 @@ def _pred(m, fr):
     return np.asarray(m.predict(fr).vec("predict").to_numpy())
 
 
-def test_gbm_scan_bitwise_and_check(cl):
+def test_gbm_scan_bitwise(cl):
     fr = _reg_frame()
     m_lv = GBM(**_KW, tree_program="level").train(fr)
     m_sc = GBM(**_KW, tree_program="scan").train(fr)
     np.testing.assert_array_equal(_pred(m_lv, fr), _pred(m_sc, fr))
     assert m_sc.output["tree_program"] == "scan"
     assert m_lv.output["tree_program"] == "level"
-    # "check": grow the first tree both ways on the real gradients,
-    # assert, then train on the scan path
-    m_ck = GBM(**_KW, tree_program="check").train(fr)
-    np.testing.assert_array_equal(_pred(m_lv, fr), _pred(m_ck, fr))
-    assert m_ck.output["tree_program"] == "scan"
+
+
+@pytest.mark.parametrize("model", ["gbm_binomial", "gbm_3class", "drf"])
+def test_estimator_scan_level_same_trees(cl, model):
+    """Two fits through tree_program's two values grow the same trees, bit
+    for bit (UpliftDRF always grows level-wise: no pair to compare)."""
+    scan, level = check_pair(model, "tree_program", ("scan", "level"),
+                             bitwise=True)
+    assert scan.output["tree_program"] == "scan"
+    assert level.output["tree_program"] == "level"
 
 
 def test_gbm_multinomial_scan_bitwise(cl):

@@ -33,7 +33,8 @@ import time
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# bench.py's airlines-10M geometry (fit_bins on make_airlines_like)
+# the airlines geometry of benchmark/configs/xgb_airlines40m.json at 10M rows
+# (fit_bins' bin counts on benchmark/datagen/airlines_like.py)
 BIN_COUNTS = (21, 12, 7, 256, 256, 22, 256, 256)
 F, NBINS, DEPTH = 8, 256, 6
 N_AIRLINES = 10_000_000
