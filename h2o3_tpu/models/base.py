@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import pickle
 import time
 from typing import Any, Dict, List, Optional, Sequence
@@ -30,6 +31,7 @@ from ..frame.frame import Frame
 from ..frame.vec import Vec, T_CAT, T_NUM
 from ..runtime import dkv
 from ..runtime import observability as obs
+from ..runtime.cluster import cluster
 from ..runtime.job import Job, JobCancelled
 from .datainfo import DataInfo, MEAN_IMPUTATION
 
@@ -99,6 +101,38 @@ class Parameters:
             else int(self.seed)
 
 
+@functools.partial(jax.jit, static_argnames=("classifier", "padded", "sharding"))
+def prediction_columns(raw, nrows, thr, classifier, padded, sharding):
+    """``predict``'s result columns from ``_predict_raw``'s scores, as one
+    device program: ``[n, K]`` class probabilities give the ``int32`` label
+    (``raw[:, 1] >= thr`` for K = 2, the first maximum past that) and one
+    ``float32`` column per class, ``[n]`` regression scores the one column.
+    Every column has ``padded`` rows under ``sharding`` (``raw`` is cut or
+    padded to that); rows from ``nrows`` on hold what ``Vec.from_numpy``
+    pads with, -1 in the label and NaN in the numeric columns.  ``nrows``
+    and ``thr`` are traced, so neither a frame's length within one padding
+    nor a model's own threshold compiles anything new."""
+    raw = raw.astype(jnp.float32)
+    if raw.shape[0] >= padded:
+        raw = raw[:padded]
+    else:
+        raw = jnp.pad(raw, [(0, padded - raw.shape[0])] + [(0, 0)] * (raw.ndim - 1))
+    real = jnp.arange(padded) < nrows
+
+    def column(values, padding):
+        return jax.lax.with_sharding_constraint(
+            jnp.where(real, values, padding), sharding)
+
+    if not classifier:
+        return (column(raw, jnp.nan),)
+    if raw.shape[1] == 2:
+        labels = raw[:, 1] >= thr
+    else:
+        labels = jnp.argmax(raw, axis=1)
+    return (column(labels.astype(jnp.int32), -1),) + tuple(
+        column(raw[:, k], jnp.nan) for k in range(raw.shape[1]))
+
+
 class Model:
     """A trained model: params + output + host-side learned state."""
 
@@ -143,27 +177,23 @@ class Model:
                 raw = self._predict_raw(X)
             with obs.span("predict.wait"):
                 raw = jax.block_until_ready(raw)
-            with obs.span("predict.fetch", bytes=int(raw.nbytes)):
-                raw = np.asarray(raw)
-                obs.inc("transfer_bytes_total", raw.nbytes, dir="d2h")
             with obs.span("predict.frame"):
-                return self._prediction_frame(raw[: frame.nrows])
+                return self._prediction_frame(raw, frame.nrows)
 
-    def _prediction_frame(self, raw: np.ndarray) -> Frame:
-        """Host labels and the upload of the result columns."""
+    def _prediction_frame(self, raw: jax.Array, nrows: int) -> Frame:
+        """The result columns, built on the device from the raw scores."""
         di = self.datainfo
-        if di.is_classifier:
-            dom = di.response_domain
-            labels = np.argmax(raw, axis=1)
-            if raw.shape[1] == 2:
-                thr = self.default_threshold()
-                labels = (raw[:, 1] >= thr).astype(np.int64)
-            names = ["predict"] + [str(d) for d in dom]
-            vecs = [Vec.from_numpy(labels.astype(np.int32), T_CAT,
-                                   domain=[str(d) for d in dom])]
-            vecs += [Vec.from_numpy(raw[:, k], T_NUM) for k in range(raw.shape[1])]
-            return Frame(names, vecs)
-        return Frame(["predict"], [Vec.from_numpy(raw.astype(np.float64), T_NUM)])
+        cl = cluster()
+        cols = prediction_columns(
+            raw, np.int32(nrows), np.float32(self.default_threshold()),
+            classifier=di.is_classifier, padded=cl.pad_rows(nrows),
+            sharding=cl.row_sharding)
+        if not di.is_classifier:
+            return Frame(["predict"], [Vec(cols[0], T_NUM, nrows)])
+        dom = [str(d) for d in di.response_domain]
+        vecs = [Vec(cols[0], T_CAT, nrows, domain=dom)]
+        vecs += [Vec(c, T_NUM, nrows) for c in cols[1:]]
+        return Frame(["predict"] + dom, vecs)
 
     def default_threshold(self) -> float:
         m = self.training_metrics

@@ -172,7 +172,7 @@ ENTRY = {"train", "train.validate", "train.datainfo", "job", "train.journal",
 GLM = {"glm.matrix", "glm.path", "glm.wait", "glm.finalize"}
 TREE = {"binning.sketch", "binning.encode", "tree_chunk", "tree.finalize"}
 PREDICT = ["predict.matrix", "predict.dispatch", "predict.wait",
-           "predict.fetch", "predict.frame", "predict"]
+           "predict.frame", "predict"]
 
 
 @pytest.fixture(scope="module")
@@ -203,6 +203,12 @@ def _gbm(frame):
     return GBM(response_column="y", ntrees=2, max_depth=2, nbins=16).train(frame)
 
 
+def _compile_observations(fun):
+    """Observations of ``jax_compile_seconds`` whose ``fun`` names ``fun``."""
+    return sum(s["n_obs"] for s in obs.metrics_wire()
+               if s["n"] == "jax_compile_seconds" and fun in s["l"].get("fun", ""))
+
+
 @pytest.mark.parametrize("fit,names", [(_glm, ENTRY | GLM), (_gbm, ENTRY | TREE)],
                          ids=["glm", "gbm"])
 def test_train_and_predict_open_the_spans_of_the_table(frame, fit, names):
@@ -220,19 +226,24 @@ def test_train_and_predict_open_the_spans_of_the_table(frame, fit, names):
     assert all(parent_of[k] == "train.fit" for k in names - ENTRY)
     assert [e["op"] for e in spans if e["kind"] == "train.journal"] \
         == ["start", "done"]
-    before = obs.counter("transfer_bytes_total", dir="d2h").value
-    uploaded = obs.counter("transfer_bytes_total", dir="h2d").value
+    from h2o3_tpu.models.base import prediction_columns
+    xprof.install_monitoring_listener()
+    prediction_columns.clear_cache()
+    model.predict(frame)        # whatever predict compiles, it compiles here
+    moved = {d: obs.counter("transfer_bytes_total", dir=d).value
+             for d in ("d2h", "h2d")}
+    compiles = _compile_observations("prediction_columns")
+    assert compiles > 0
     preds, spans = _spans_of(lambda: model.predict(frame))
     assert [e["kind"] for e in spans] == PREDICT           # ≤ 8, in this order
     assert len({e["trace_id"] for e in spans}) == 1
-    fetch = spans[PREDICT.index("predict.fetch")]
-    assert fetch["bytes"] > 0
-    assert obs.counter("transfer_bytes_total", dir="d2h").value \
-        == before + fetch["bytes"]
-    # three result columns of float32 / int32, padded to the mesh
-    assert obs.counter("transfer_bytes_total", dir="h2d").value \
-        >= uploaded + 3 * 4 * frame.nrows
+    # the result frame is built where the scores are: nothing crosses the
+    # host link in either direction, and a second call compiles nothing
+    assert {d: obs.counter("transfer_bytes_total", dir=d).value
+            for d in ("d2h", "h2d")} == moved
+    assert _compile_observations("prediction_columns") == compiles
     assert preds.nrows == frame.nrows
+    assert all(v.data.sharding == frame.vecs[0].data.sharding for v in preds.vecs)
 
 
 # ----------------------------------------------------- the compile listener

@@ -60,6 +60,7 @@ def main(argv=None) -> int:
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     import h2o3_tpu
+    from h2o3_tpu.models import base as base_mod
     from h2o3_tpu.models import deeplearning as dl_mod
     from h2o3_tpu.models import glm as glm_mod
     from h2o3_tpu.models.tree import hist, shared
@@ -183,6 +184,13 @@ def main(argv=None) -> int:
             levels, sds((trees, 2 ** DEPTH), jnp.float32),
             sds((n, F), jnp.float32, mat))
 
+    def prediction_columns():
+        # the same predict's result frame: the blocked walk's scores, padded
+        # to 312,576 blocks of 128 rows, cut to the frame's 40M
+        return base_mod.prediction_columns, (
+            sds((312_576 * 128, 2), jnp.float32, mat), sds((), jnp.int32),
+            sds((), jnp.float32), True, cl.pad_rows(40_000_000), rows)
+
     def glm_path():
         fam = glm_mod._make_family("binomial", glm_mod.GLMParameters())
         fn = glm_mod._make_path_runner(fam, False, 50)
@@ -230,8 +238,8 @@ def main(argv=None) -> int:
 
     programs = {f.__name__: f for f in (
         tree_build, tree_build_scan, tree_build_k7, tree_scan, sparse_level,
-        grid_scan, serve_xla, traverse, glm_path, dl_sample_copy, dl_train_steps,
-        dl_score)}
+        grid_scan, serve_xla, traverse, prediction_columns, glm_path,
+        dl_sample_copy, dl_train_steps, dl_score)}
     unknown = [p for p in args.programs if p not in programs]
     if unknown:
         ap.error(f"unknown program(s) {unknown}; known: {sorted(programs)}")
