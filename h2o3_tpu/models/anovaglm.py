@@ -48,6 +48,9 @@ class ANOVAGLMModel(Model):
             "p_value": np.asarray([r["p"] for r in rows], np.float64),
         })
 
+    def _score_matrix(self, frame: Frame):
+        return dkv.get(self.output["full_model"])._score_matrix(frame)
+
     def _predict_raw(self, X):
         return dkv.get(self.output["full_model"])._predict_raw(X)
 

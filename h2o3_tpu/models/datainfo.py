@@ -17,15 +17,17 @@ performs test adaptation, guaranteeing train/test layout agreement.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
 from ..frame.frame import Frame
 from ..frame.vec import Vec, T_CAT, T_NUM, T_TIME
-from ..runtime.cluster import cluster
+from ..runtime.cluster import ROW_AXES, cluster
 
 
 MEAN_IMPUTATION = "mean_imputation"
@@ -51,7 +53,9 @@ def expand_coded(layout: Tuple[Tuple[str, int], ...], num: jax.Array,
     ``make_matrix``'s column order.  ``layout`` is ``DataInfo.coded_layout``
     (static, hashable), so this traces inside any jitted program: a
     minibatch or a block of rows is expanded where it is used, the frame
-    never."""
+    never.  A layout of some of the runs, in their order, expands those
+    alone (the ``"cat"`` runs take the codes' columns in turn, the ``"num"``
+    runs the numerics')."""
     cols, i_num, i_cat = [], 0, 0
     for kind, width in layout:
         if kind == "num":
@@ -64,7 +68,155 @@ def expand_coded(layout: Tuple[Tuple[str, int], ...], num: jax.Array,
             i_cat += 1
         else:                           # the intercept's column of ones
             cols.append(jnp.ones((num.shape[0], width), jnp.float32))
+    if not cols:
+        return jnp.zeros((num.shape[0], 0), jnp.float32)
     return cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
+
+
+def coded_matvec(layout: Tuple[Tuple[str, int], ...], num: jax.Array,
+                 codes: jax.Array, beta: jax.Array) -> jax.Array:
+    """``expand_coded(layout, num, codes) @ beta`` [rows], run by run and
+    with no expansion: a one-hot block's product is the coefficient its
+    code selects (a compare, a select and an add a column, which fuse into
+    one pass over the codes; every float32 product exact)."""
+    out, at, i_num, i_cat = 0.0, 0, 0, 0
+    for kind, width in layout:
+        b = beta[at:at + width]
+        if kind == "num":
+            out = out + jnp.sum(num[:, i_num:i_num + width] * b, axis=1)
+            i_num += width
+        elif kind == "cat":
+            lit = codes[:, i_cat, None] == jnp.arange(width, dtype=jnp.int32)
+            out = out + jnp.sum(jnp.where(lit, b, 0.0), axis=1)
+            i_cat += 1
+        else:
+            out = out + jnp.sum(b)
+        at += width
+    return out
+
+
+def coded_rmatvec(layout: Tuple[Tuple[str, int], ...], num: jax.Array,
+                  codes: jax.Array, v: jax.Array) -> jax.Array:
+    """``expand_coded(layout, num, codes).T @ v`` [nfeatures] for a row
+    vector ``v``, run by run and with no expansion, as ``coded_matvec``."""
+    parts, i_num, i_cat = [], 0, 0
+    for kind, width in layout:
+        if kind == "num":
+            parts.append(jnp.sum(num[:, i_num:i_num + width] * v[:, None],
+                                 axis=0))
+            i_num += width
+        elif kind == "cat":
+            lit = codes[:, i_cat, None] == jnp.arange(width, dtype=jnp.int32)
+            parts.append(jnp.sum(jnp.where(lit, v[:, None], 0.0), axis=0))
+            i_cat += 1
+        else:
+            parts.append(jnp.full((width,), jnp.sum(v)))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
+# ------------------------------------------------------------- row blocks
+#
+# A client of the code form expands a BLOCK of rows where it uses it.  What
+# follows is what every such client needs: how many rows a block may hold
+# on this device, and the walk over a row-shard's blocks, for a result per
+# row (scoring) and for sums over the rows (a Gram).  Both run on a row
+# shard's own rows (``over_row_shards``, or a ``shard_map`` of the
+# caller's).
+
+@functools.cache
+def device_memory_bytes() -> int:
+    """The first local device's memory (asked once a process); where the
+    backend reports none (the CPU), a 4 GiB device is assumed."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit") or 4 << 30)
+
+
+def block_rows(row_bytes: int, rows: int, share: int = 16) -> int:
+    """Rows one block holds: as many as keep ``row_bytes`` a row (the
+    expanded row and what is computed from it) inside one ``share``-th of
+    the device, a multiple of 1,024, so that a frame which fills the chip
+    still has room for its blocks; never more than ``rows``."""
+    budget = device_memory_bytes() // share
+    block = max(budget // max(row_bytes, 1) // 1024, 1) * 1024
+    return min(block, rows)
+
+
+def over_row_shards(shard, in_specs, out_specs):
+    """``shard`` applied to every row shard's part of its arguments: a
+    ``shard_map`` over the row axes of the live mesh.  On a mesh of one row
+    shard there is nothing to map and ``shard`` itself is returned, which
+    spares a program that is traced anew on every call (GLM's path program)
+    the lowering of the map, 10 ms of a 0.17 s fit on a v5e's host."""
+    if cluster().n_row_shards == 1:
+        return shard
+    return shard_map(shard, mesh=cluster().mesh, in_specs=in_specs,
+                     out_specs=out_specs)
+
+
+def sum_over_row_shards(sums):
+    """The shards' ``sums`` (a tree) added up, inside ``over_row_shards``."""
+    if cluster().n_row_shards == 1:
+        return sums
+    return jax.lax.psum(sums, ROW_AXES)
+
+
+def _varying_like(x: jax.Array, rows: jax.Array) -> jax.Array:
+    """``x`` made to vary over the mesh axes ``rows`` varies over, so that
+    it can be the carry of a scan whose body reads ``rows``: inside a
+    ``shard_map`` the row axes, outside one nothing."""
+    axes = tuple(a for a in ROW_AXES if a in jax.typeof(rows).vma)
+    return jax.lax.pcast(x, axes, to="varying") if axes else x
+
+
+def _block_starts(rows: int, block: int) -> jax.Array:
+    """First rows of the blocks that cover ``rows`` rows, were none laid
+    back: the walks below lay the last one back over the one before it
+    (``min(start, rows - block)``), so that every block is whole."""
+    return jnp.arange(-(-rows // block)) * block
+
+
+def map_row_blocks(rows_of, block: int, *arrays: jax.Array):
+    """``rows_of(*blocks)`` for every block of ``block`` rows of a shard's
+    ``arrays``, as one array over all its rows.  ``rows_of`` returns one
+    array whose leading axis is the block's rows."""
+    rows = arrays[0].shape[0]
+    if rows <= block:
+        return rows_of(*arrays)
+
+    def one(out, start):
+        start = jnp.minimum(start, rows - block)
+        got = rows_of(*(jax.lax.dynamic_slice_in_dim(a, start, block)
+                        for a in arrays))
+        return jax.lax.dynamic_update_slice_in_dim(out, got, start, 0), None
+
+    like = jax.eval_shape(rows_of, *(a[:block] for a in arrays))
+    out = _varying_like(jnp.zeros((rows,) + like.shape[1:], like.dtype),
+                        arrays[0])
+    return jax.lax.scan(one, out, _block_starts(rows, block))[0]
+
+
+def sum_row_blocks(sums_of, block: int, w: jax.Array, *arrays: jax.Array):
+    """The sum over a shard's blocks of ``sums_of(w_block, *blocks)``, a
+    tree of arrays each of which is a sum over the block's rows in which a
+    row of weight 0 counts for nothing.  The rows that the last block,
+    laid back, shares with the one before it get weight 0 there, as the
+    frame's padding has it from ``DataInfo.weights``."""
+    rows = w.shape[0]
+    if rows <= block:
+        return sums_of(w, *arrays)
+
+    def one(total, fresh):
+        start = jnp.minimum(fresh, rows - block)
+        wb = jax.lax.dynamic_slice_in_dim(w, start, block)
+        wb = jnp.where(start + jnp.arange(block) >= fresh, wb, 0.0)
+        got = sums_of(wb, *(jax.lax.dynamic_slice_in_dim(a, start, block)
+                            for a in arrays))
+        return jax.tree.map(jnp.add, total, got), None
+
+    like = jax.eval_shape(sums_of, w[:block], *(a[:block] for a in arrays))
+    zero = jax.tree.map(
+        lambda l: _varying_like(jnp.zeros(l.shape, l.dtype), w), like)
+    return jax.lax.scan(one, zero, _block_starts(rows, block))[0]
 
 
 @dataclasses.dataclass

@@ -45,11 +45,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..frame.frame import Frame
 from ..runtime import dkv
-from ..runtime.cluster import ROW_AXES, ROW_AXIS, cluster
+from ..runtime.cluster import ROW_AXIS, cluster
 from ..runtime.job import Job
 from ..runtime import observability as obs
 from .base import Model, ModelBuilder, Parameters
-from .datainfo import CodedDesign, DataInfo, expand_coded
+from .datainfo import (CodedDesign, DataInfo, block_rows, expand_coded,
+                       map_row_blocks)
 from ..metrics.core import make_metrics
 from .scorekeeper import stop_early
 
@@ -315,16 +316,10 @@ def _train_program(p: "DeepLearningParameters", cfg: _StepConfig, batch: int,
 # ------------------------------------------------------------- scoring
 def _score_block_rows(widths: Sequence[int], rows: int) -> int:
     """Rows one scoring block holds, from the layer widths and the device's
-    memory: a block keeps its expanded rows and every layer's activations
-    (float32, counted twice over for the casts and the compiler's
-    temporaries) inside a sixteenth of the device, so that a frame which
-    fills the chip still scores.  Where the backend reports no memory (the
-    CPU), a 4 GiB device is assumed."""
-    stats = jax.local_devices()[0].memory_stats() or {}
-    budget = int(stats.get("bytes_limit") or 4 << 30) // 16
-    per_row = 2 * 4 * sum(widths)
-    block = max(budget // per_row // 1024, 1) * 1024
-    return min(block, rows)
+    memory (``datainfo.block_rows``): a block keeps its expanded rows and
+    every layer's activations (float32, counted twice over for the casts and
+    the compiler's temporaries) inside a sixteenth of the device."""
+    return block_rows(2 * 4 * sum(widths), rows)
 
 
 @functools.lru_cache(maxsize=None)
@@ -348,22 +343,8 @@ def _make_score(layout: tuple, activation: str, emit: str, block: int):
         return logits
 
     def shard(params, num, codes):
-        rows = num.shape[0]
-        if rows <= block:
-            return rows_of(params, num, codes)
-        starts = jnp.minimum(jnp.arange(-(-rows // block)) * block,
-                             rows - block)
-
-        def one(out, start):
-            got = rows_of(params,
-                          jax.lax.dynamic_slice_in_dim(num, start, block),
-                          jax.lax.dynamic_slice_in_dim(codes, start, block))
-            return jax.lax.dynamic_update_slice_in_dim(out, got, start, 0), None
-
-        like = jax.eval_shape(rows_of, params, num[:block], codes[:block])
-        out = jax.lax.pcast(jnp.zeros((rows,) + like.shape[1:], like.dtype),
-                            ROW_AXES, to="varying")
-        return jax.lax.scan(one, out, starts)[0]
+        return map_row_blocks(functools.partial(rows_of, params), block,
+                              num, codes)
 
     # the name the device trace knows the program by: jit_dl_score
     def dl_score(params, num, codes):

@@ -5,31 +5,54 @@ COD:2840), ``hex/glm/GLMTask.java`` (gradient/Hessian MRTasks),
 ``hex/gram/Gram.java:1017`` (distributed X'X accumulation, reduce = matrix
 add, Cholesky on the driver), families/links in ``hex/glm/GLMModel.java:978``.
 
-TPU-native redesign: the per-iteration hot loop — Gram accumulation — is one
-jit-compiled pass: ``X^T diag(w) X`` over the row-sharded design matrix runs
-on the MXU and GSPMD inserts the ``psum`` that replaces GramTask's MRTask
-reduce.  The small P x P solve (Cholesky for L2, coordinate descent on the
-Gram for L1 — exactly the reference's IRLSM+COD strategy) happens on host.
-Multinomial runs block-wise per-class Newton steps on softmax probabilities
-(the COD-multinomial analog, GLM.java:1643).
+TPU-native redesign: IRLSM is one device program for the whole lambda path:
+lambdas under ``lax.scan``, IRLS under ``lax.while_loop``, the small P x P
+solve inside it (a linear solve for L2, coordinate descent on the Gram for
+L1, the reference's IRLSM+COD strategy), one fetch at the end.  Its hot
+loop, the Gram ``X^T diag(w) X``, runs on the MXU, and the shards' sums meet
+in the ``psum`` that replaces GramTask's MRTask reduce.  The program has two
+forms, and ``_dense_design_fits`` chooses from the frame and the device:
+
+* ``_make_path_runner``, on the dense ``[rows, nfeatures]`` design
+  (``DataInfo.make_matrix``), where that and one copy of it fit a quarter of
+  the device: what every GLM ran until PR 36, kept to the line, because a
+  narrow fit is bound by tracing and lowering this program and pays for
+  every operation added to it.
+* ``_make_blocked_path_runner``, on the design IN CODE FORM
+  (``datainfo.CodedDesign``: numerics beside categorical codes), where the
+  dense design does not fit: Gram, score and deviance are summed over row
+  blocks, a block expanded to its one-hot columns where it is used, so a
+  categorical of any cardinality costs 4 bytes a row.  Scoring walks the
+  same blocks (``_make_score``).
+
+``reference_glm.py`` is the same mathematics in plain ``jax.numpy`` on the
+dense expansion.  L-BFGS, ordinal, multinomial (block-wise per-class Newton
+steps on softmax probabilities, the COD-multinomial analog, GLM.java:1643)
+and the ``non_negative`` host loop take the dense design only; they refuse a
+frame whose expansion would not fit the device.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
+import functools
 from typing import List, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from ..frame.frame import Frame
 from ..runtime import dkv
 from ..runtime import observability as obs
+from ..runtime.cluster import ROW_AXIS, cluster
 from ..runtime.job import Job
 from .base import Model, ModelBuilder, Parameters
-from .datainfo import DataInfo
+from .datainfo import (CodedDesign, DataInfo, block_rows, coded_matvec,
+                       coded_rmatvec, device_memory_bytes, expand_coded,
+                       map_row_blocks, over_row_shards, sum_over_row_shards,
+                       sum_row_blocks)
 from ..metrics.core import make_metrics
 
 
@@ -235,6 +258,41 @@ def _make_irls_step(family: _Family):
     return _ledger("glm_irls", jax.jit(step), orig=step)
 
 
+def _coordinate_descent(G, c, l1, l2, penalize, warm, max_inner: int):
+    """Cyclic coordinate descent on the Gram (the reference's COD,
+    GLM.java:2840) for ``0.5 b'Gb - c'b + l1'|b| + 0.5 l2'b^2`` from ``warm``,
+    under a ``while_loop`` until no coefficient of a sweep moves by 1e-8:
+    the penalized solve of both forms of the path program, traced where
+    they call it."""
+    d = jnp.diag(G)
+
+    def sweep(state):
+        beta, _, it = state
+
+        def upd(j, bd):
+            b, delta = bd
+            r = c[j] - (G[j] @ b - d[j] * b[j])
+            bj = jnp.where(
+                penalize[j] > 0,
+                jnp.sign(r) * jnp.maximum(jnp.abs(r) - l1[j], 0.0)
+                / (d[j] + l2[j] + 1e-12),
+                r / (d[j] + 1e-12))
+            delta = jnp.maximum(delta, jnp.abs(bj - b[j]))
+            return b.at[j].set(bj), delta
+
+        beta2, delta = jax.lax.fori_loop(
+            0, warm.shape[0], upd, (beta, jnp.float32(0.0)))
+        return beta2, delta, it + 1
+
+    def cond(state):
+        _, delta, it = state
+        return (it < max_inner) & (delta > 1e-8)
+
+    beta, _, _ = jax.lax.while_loop(
+        cond, sweep, (warm, jnp.float32(jnp.inf), 0))
+    return beta
+
+
 def _make_path_runner(family: _Family, l1_mode: bool, max_iter: int,
                       max_inner: int = 100):
     """The WHOLE regularization path as one device program.
@@ -261,41 +319,13 @@ def _make_path_runner(family: _Family, l1_mode: bool, max_iter: int,
 
     def run(X, y, w, offset, lambdas, alpha, penalize, beta0, n,
             beta_eps):
-        P = beta0.shape[0]
-
         def solve(G, c, lam, warm):
             l2 = lam * (1 - alpha) * penalize
             if not l1_mode:
                 A = G + jnp.diag(l2 + 1e-10)
                 return jnp.linalg.solve(A, c)
-            l1 = lam * alpha * penalize
-            d = jnp.diag(G)
-
-            def sweep(state):
-                beta, _, it = state
-
-                def upd(j, bd):
-                    b, delta = bd
-                    r = c[j] - (G[j] @ b - d[j] * b[j])
-                    bj = jnp.where(
-                        penalize[j] > 0,
-                        jnp.sign(r) * jnp.maximum(jnp.abs(r) - l1[j], 0.0)
-                        / (d[j] + l2[j] + 1e-12),
-                        r / (d[j] + 1e-12))
-                    delta = jnp.maximum(delta, jnp.abs(bj - b[j]))
-                    return b.at[j].set(bj), delta
-
-                beta2, delta = jax.lax.fori_loop(
-                    0, P, upd, (beta, jnp.float32(0.0)))
-                return beta2, delta, it + 1
-
-            def cond(state):
-                _, delta, it = state
-                return (it < max_inner) & (delta > 1e-8)
-
-            beta, _, _ = jax.lax.while_loop(
-                cond, sweep, (warm, jnp.float32(jnp.inf), 0))
-            return beta
+            return _coordinate_descent(G, c, lam * alpha * penalize, l2,
+                                       penalize, warm, max_inner)
 
         def per_lambda(beta, lam):
             def body(state):
@@ -320,6 +350,257 @@ def _make_path_runner(family: _Family, l1_mode: bool, max_iter: int,
         return betas, devs, iters, gram_fin, dev_fin
 
     return _ledger("glm_path", jax.jit(run), orig=run)
+
+
+# ------------------------------------------------- the code-form design
+#
+# Where the dense design does not fit the device, IRLSM never holds ``[rows,
+# nfeatures]``: it reads ``CodedDesign`` (the numerics beside the
+# categorical codes) a block of rows at a time.  A
+# block's products with a coefficient or a row vector need no expansion
+# (``coded_matvec`` / ``coded_rmatvec``: a one-hot column selects, every
+# float32 product exact).  The Gram is the one product that needs the MXU,
+# and the one for which the block's one-hot columns are written out.
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+@functools.lru_cache(maxsize=None)
+def _column_split(layout):
+    """(columns of the one-hot blocks, the other columns) of the expanded
+    layout, each in the layout's order, and the two layouts that expand
+    them (``expand_coded`` takes a layout of some of the runs)."""
+    cat, rest, at = [], [], 0
+    for kind, width in layout:
+        (cat if kind == "cat" else rest).extend(range(at, at + width))
+        at += width
+    return (np.asarray(cat, np.int32), np.asarray(rest, np.int32),
+            tuple(r for r in layout if r[0] == "cat"),
+            tuple(r for r in layout if r[0] != "cat"))
+
+
+def _bf16_pieces(a):
+    """``a`` (float32) as a sum of three bfloat16 arrays, which hold all 24
+    bits of a float32 mantissa.  ``reduce_precision`` and not a cast there
+    and back, which the compiler may take for the identity."""
+    out = []
+    for _ in range(3):
+        hi = jax.lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7)
+        out.append(hi.astype(jnp.bfloat16))
+        a = a - hi
+    return out
+
+
+def _gram_parts(layout, xr, nb, cb, wi):
+    """X' diag(wi) X of one block, as the parts the MXU forms: ``top``
+    [one-hot columns, one-hot columns then the others] and ``rest`` [the
+    others, the others]; ``_gram_of_parts`` puts them in the layout's
+    order.  ``xr`` are the block's other columns, expanded.
+
+    A one-hot column is exact in bfloat16, so ``top`` is bfloat16 products
+    of the one-hot block with ``wi o X`` in three bfloat16 pieces, summed in
+    float32: each float32 product exact, three passes of the MXU where the
+    float32 product at ``highest`` takes six.  Of ``wi o X`` the one-hot
+    part is the pieces of ``wi`` itself where the column is lit.  Beside
+    one-hot blocks the few other columns (numerics, intercept) multiply at
+    ``highest``, a few percent of the block's work; a frame with no
+    categorical (``top`` is None) keeps the product it always had, the
+    compiler's default for float32 (six passes there would be the whole of
+    its Gram)."""
+    cat_layout = _column_split(layout)[2]
+    yr = xr * wi[:, None]
+    if not cat_layout:
+        return None, jnp.dot(xr.T, yr)
+    hot = expand_coded(cat_layout, nb, cb).astype(jnp.bfloat16)
+    top = sum(
+        jnp.dot(hot.T, jnp.concatenate([hot * wk[:, None], yk], axis=1),
+                preferred_element_type=jnp.float32)
+        for wk, yk in zip(_bf16_pieces(wi), _bf16_pieces(yr)))
+    return top, jnp.dot(xr.T, yr, precision=_HIGHEST)
+
+
+def _gram_of_parts(layout, top, rest):
+    """The Gram in the layout's column order from ``_gram_parts``' two
+    (summed over blocks and shards first: this runs once a pass)."""
+    if top is None:
+        return rest
+    cat, other, _, _ = _column_split(layout)
+    nc = len(cat)
+    both = jnp.concatenate([
+        top, jnp.concatenate([top[:, nc:].T, rest], axis=1)], axis=0)
+    back = np.argsort(np.concatenate([cat, other]))
+    return both[back][:, back]
+
+
+def _make_irls_gram(family: _Family, layout: tuple, block: int):
+    """``irls_gram(num, codes, y, w, offset, beta) -> (X'WX, X's, deviance)``
+    at ``beta``, over the row blocks of the code-form design: every
+    row-shard walks its rows in blocks of ``block`` (``sum_row_blocks``),
+    expands one, and the shards' sums meet in a ``psum``
+    (``sum_over_row_shards``).  ``W`` are the
+    IRLS weights and ``s = w g (y - mu) / var`` the score's, so that
+    ``X'Wz = X'WX beta + X's`` for the working response ``z``."""
+    rest_layout = _column_split(layout)[3]
+
+    def sums_of(beta, wb, nb, cb, yb, ob):
+        eta = coded_matvec(layout, nb, cb, beta) + ob
+        mu = family.linkinv(eta)
+        g = jnp.maximum(family.dlinkinv(eta, mu), 1e-10)
+        var = jnp.maximum(family.variance(mu), 1e-10)
+        score = coded_rmatvec(layout, nb, cb, wb * g * (yb - mu) / var)
+        xr = expand_coded(rest_layout, nb, cb)
+        return (_gram_parts(layout, xr, nb, cb, wb * g * g / var), score,
+                family.deviance(yb, mu, wb))
+
+    def shard(num, codes, y, w, offset, beta):
+        return sum_over_row_shards(sum_row_blocks(
+            functools.partial(sums_of, beta), block, w, num, codes, y, offset))
+
+    def irls_gram(num, codes, y, w, offset, beta):
+        rows, mat = P(ROW_AXIS), P(ROW_AXIS, None)
+        (top, rest), score, dev = over_row_shards(
+            shard, in_specs=(mat, mat, rows, rows, rows, P()),
+            out_specs=P())(num, codes, y, w, offset, beta)
+        return _gram_of_parts(layout, top, rest), score, dev
+
+    return irls_gram
+
+
+def _fit_block_rows(layout: tuple, padded_rows: int) -> int:
+    """Rows of one block of the IRLSM walk over a frame of ``padded_rows``
+    (a shard walks its share of them): the expanded row and its weighted
+    copy in float32, and where the layout has one-hot blocks their bfloat16
+    operands (three pieces and the block itself), inside a quarter of the
+    device: a frame whose expansion fits there is one block."""
+    width = sum(w for _, w in layout)
+    hot = sum(w for kind, w in layout if kind == "cat")
+    return block_rows(4 * 2 * width + 2 * (3 * width + hot if hot else 0),
+                      padded_rows // cluster().n_row_shards, share=4)
+
+
+def _make_blocked_path_runner(family: _Family, l1_mode: bool, max_iter: int,
+                              layout: tuple, block: int,
+                              max_inner: int = 100):
+    """``_make_path_runner``'s program on the design in code form: the same
+    scan over lambdas, ``while_loop`` of IRLS passes, solve on the device and
+    one fetch of the same five results.  It departs in two things.
+
+    Every IRLS pass reads the design in row blocks of ``block``
+    (``_make_irls_gram``), and the pass of the final Gram is a closing step
+    of the scan, which updates nothing: the program holds, and a fit
+    traces, one IRLS pass and one solve.
+
+    The L2 solve is for the Newton STEP, ``(G + D) delta = X's/n - D beta``:
+    algebraically the IRLS update ``(G + D) beta' = X'Wz/n``, but a float32
+    solve's error then scales with the step and vanishes at convergence,
+    where solved for ``beta'`` it scales with cond(G) |beta'| and stays (a
+    wide one-hot design beside an intercept is not well conditioned).
+    """
+    irls_gram = _make_irls_gram(family, layout, block)
+
+    def run(num, codes, y, w, offset, lambdas, alpha, penalize, beta0, n,
+            beta_eps):
+        n_coef = beta0.shape[0]
+
+        def solve(G, score, lam, beta):
+            l2 = lam * (1 - alpha) * penalize
+            if not l1_mode:
+                ridge = l2 + 1e-10
+                return beta + jnp.linalg.solve(G + jnp.diag(ridge),
+                                               score - ridge * beta)
+            c = jnp.dot(G, beta, precision=_HIGHEST) + score    # X'Wz / n
+            return _coordinate_descent(G, c, lam * alpha * penalize, l2,
+                                       penalize, beta, max_inner)
+
+        def per_lambda(carry, step):
+            beta, _ = carry
+            lam, closing = step
+
+            def body(state):
+                beta, _, it, _, _ = state
+                gram, score, dev = irls_gram(num, codes, y, w, offset, beta)
+                nb = jnp.where(closing, beta,
+                               solve(gram / n, score / n, lam, beta))
+                delta = jnp.max(jnp.abs(nb - beta))
+                return nb, delta, it + 1, dev, gram
+
+            def cond(state):
+                _, delta, it, _, _ = state
+                return (it < jnp.where(closing, 1, max_iter)) \
+                    & (delta >= beta_eps)
+
+            beta, _, iters, dev, gram = jax.lax.while_loop(
+                cond, body, (beta, jnp.float32(jnp.inf), 0, jnp.float32(0.0),
+                             jnp.zeros((n_coef, n_coef), jnp.float32)))
+            return (beta, gram), (beta, dev, iters)
+
+        # the path's lambdas, then the closing step: ONE pass at the final
+        # beta, whose Gram (p-values) and deviance are the program's last two
+        steps = (jnp.concatenate([lambdas, lambdas[-1:]]),
+                 jnp.arange(lambdas.shape[0] + 1) == lambdas.shape[0])
+        (_, gram_fin), (betas, devs, iters) = jax.lax.scan(
+            per_lambda, (beta0, jnp.zeros((n_coef, n_coef), jnp.float32)),
+            steps)
+        return betas[:-1], devs[:-1], iters[:-1], gram_fin, devs[-1]
+
+    return _ledger("glm_path", jax.jit(run), orig=run)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_xtv(layout: tuple, block: int):
+    """Compiled ``X'v`` for a row vector ``v`` (zero on padded rows) over
+    the row blocks of the code-form design."""
+    def sums_of(vb, nb, cb):
+        return coded_rmatvec(layout, nb, cb, vb)
+
+    def shard(v, num, codes):
+        return sum_over_row_shards(
+            sum_row_blocks(sums_of, block, v, num, codes))
+
+    def glm_xtv(v, num, codes):
+        return over_row_shards(
+            shard, in_specs=(P(ROW_AXIS), P(ROW_AXIS, None),
+                             P(ROW_AXIS, None)),
+            out_specs=P())(v, num, codes)
+
+    return jax.jit(glm_xtv)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_score(layout: tuple, family: str, classifier: bool, block: int):
+    """Compiled scoring program, cached on what it closes over: every
+    row-shard walks its own rows in blocks of ``block``
+    (``map_row_blocks``), expands one and keeps what ``_predict_raw``
+    returns for it.  ``beta`` is [P] or, multinomial, [P, K]; ``thetas``
+    the ordinal thresholds (empty for every other family)."""
+    def rows_of(beta, thetas, nb, cb):
+        if family == "multinomial":
+            return jax.nn.softmax(jnp.dot(expand_coded(layout, nb, cb), beta,
+                                          precision=_HIGHEST), axis=1)
+        eta = coded_matvec(layout, nb, cb, beta)
+        if family == "ordinal":              # the intercept's beta is 0
+            cdf = jax.nn.sigmoid(thetas[None, :] - eta[:, None])
+            cdf = jnp.concatenate(
+                [jnp.zeros((cdf.shape[0], 1)), cdf,
+                 jnp.ones((cdf.shape[0], 1))], axis=1)
+            return jnp.clip(jnp.diff(cdf, axis=1), 0.0, 1.0)
+        # no link reads the family's own parameters
+        mu = _make_family(family, GLMParameters()).linkinv(eta)
+        return jnp.stack([1 - mu, mu], axis=1) if classifier else mu
+
+    def shard(beta, thetas, num, codes):
+        return map_row_blocks(functools.partial(rows_of, beta, thetas),
+                              block, num, codes)
+
+    # the name the device trace knows the program by: jit_glm_score
+    def glm_score(beta, thetas, num, codes):
+        regression = family not in ("multinomial", "ordinal") and not classifier
+        return over_row_shards(
+            shard, in_specs=(P(), P(), P(ROW_AXIS, None), P(ROW_AXIS, None)),
+            out_specs=P(ROW_AXIS) if regression else P(ROW_AXIS, None))(
+                beta, thetas, num, codes)
+
+    return jax.jit(glm_score)
 
 
 def _make_softmax_stats(nclasses: int):
@@ -399,6 +680,32 @@ def _solve_penalized(gram: np.ndarray, xtwz: np.ndarray, n: float,
     return beta
 
 
+def _dense_design_fits(frame: Frame, di: DataInfo) -> bool:
+    """Whether a device holds its rows of the dense ``[rows, nfeatures]``
+    design and one copy for a solver's products inside a quarter of its
+    memory.  Where it does, IRLSM and scoring run on the dense design as they
+    always have (the narrow GLM fit is the same program, to the line); where
+    it does not, they read the design in code form, block by block."""
+    rows = frame.padded_rows // cluster().n_row_shards
+    return 2 * rows * di.nfeatures * 4 <= device_memory_bytes() // 4
+
+
+def _refuse_dense_design(what: str, frame: Frame, di: DataInfo) -> None:
+    """Raise, before anything is allocated, where a solver that takes the
+    dense ``[rows, nfeatures]`` design meets a frame whose expansion (with
+    one copy for the solver's products) passes the device's memory."""
+    rows = frame.padded_rows // cluster().n_row_shards
+    nbytes, have = rows * di.nfeatures * 4, device_memory_bytes()
+    if 2 * nbytes > have:
+        raise ValueError(
+            f"GLM with {what} takes the dense one-hot design: {rows:,} rows "
+            f"x {di.nfeatures:,} columns x 4 B = {nbytes:,} bytes a device, "
+            f"of {have:,} it has.  solver='irlsm' (binomial, gaussian, "
+            f"poisson, gamma, tweedie, negativebinomial; lambda path and "
+            f"elastic net included, without non_negative) reads the design "
+            f"in code form and never expands the frame.")
+
+
 # ---------------------------------------------------------------- parameters
 @dataclasses.dataclass
 class GLMParameters(Parameters):
@@ -428,7 +735,33 @@ class GLMParameters(Parameters):
 class GLMModel(Model):
     algo = "glm"
 
-    def _predict_raw(self, X: jax.Array) -> jax.Array:
+    def _score_matrix(self, frame: Frame):
+        """The design ``_predict_raw`` expects: the dense one where it fits
+        the device beside its products, else in code form."""
+        if _dense_design_fits(frame, self.datainfo):
+            return self.datainfo.make_matrix(frame)
+        return self.datainfo.make_coded(frame)
+
+    def _predict_raw(self, X) -> jax.Array:
+        """Scores of every row of ``X``: of a dense design in eager
+        programs, of a code-form design in row blocks sized from the
+        expanded width and the device's memory, so that the dense rows
+        exist for the block in hand only."""
+        if not isinstance(X, CodedDesign):
+            return self._predict_dense(X)
+        di, family = self.datainfo, self.output["family"]
+        beta = jnp.asarray(self.output["beta_std"], jnp.float32)
+        thetas = jnp.asarray(self.output.get("ordinal_thresholds", ()),
+                             jnp.float32)
+        rows = X.num.shape[0] // cluster().n_row_shards
+        width = beta.shape[0] + (beta.shape[1] if beta.ndim == 2
+                                 else thetas.shape[0] + 2)
+        score = _make_score(di.coded_layout(), family, di.is_classifier,
+                            block_rows(2 * 4 * width, rows))
+        return score(beta, thetas, X.num, X.codes)
+
+    def _predict_dense(self, X: jax.Array) -> jax.Array:
+        """Scores of the dense design's rows, in eager programs."""
         beta = jnp.asarray(self.output["beta_std"])
         family = self.output["family"]
         if family == "multinomial":
@@ -487,14 +820,6 @@ class GLM(ModelBuilder):
              valid: Optional[Frame]) -> GLMModel:
         p: GLMParameters = self.params
         fam_name = self._resolve_family(di)
-        with obs.span("glm.matrix"):
-            X = di.make_matrix(frame)
-            y = di.response(frame)
-            w = di.weights(frame)
-            y = jnp.nan_to_num(y)
-            offset = di.offsets(frame)
-            offset = offset if offset is not None else jnp.zeros_like(y)
-            n = float(jnp.sum(w))
         P = di.nfeatures
         penalize = np.ones(P)
         if di.add_intercept:
@@ -520,11 +845,31 @@ class GLM(ModelBuilder):
                 raise ValueError(
                     f"non_negative names not in the design: "
                     f"{sorted(want - matched)}")
-        if nonneg.any() and (fam_name in ("multinomial", "ordinal")
-                             or p.solver.lower() in ("l_bfgs", "lbfgs")):
+        lbfgs = p.solver.lower() in ("l_bfgs", "lbfgs")
+        if nonneg.any() and (fam_name in ("multinomial", "ordinal") or lbfgs):
             raise ValueError("non_negative requires the IRLSM/COD solver "
                              "on a non-multinomial family")
         self._nonneg = nonneg if nonneg.any() else None
+
+        # IRLSM's device program can read the design in code form, and does
+        # where the dense expansion would not fit; the other solvers take
+        # the dense one or refuse
+        dense = ("family=" + fam_name if fam_name in ("multinomial", "ordinal")
+                 else "solver=l_bfgs" if lbfgs
+                 else "non_negative" if nonneg.any() else None)
+        with obs.span("glm.matrix"):
+            if dense is None and not _dense_design_fits(frame, di):
+                X = di.make_coded(frame)
+            else:
+                if dense is not None:
+                    _refuse_dense_design(dense, frame, di)
+                X = di.make_matrix(frame)
+            y = di.response(frame)
+            w = di.weights(frame)
+            y = jnp.nan_to_num(y)
+            offset = di.offsets(frame)
+            offset = offset if offset is not None else jnp.zeros_like(y)
+            n = float(jnp.sum(w))
 
         if fam_name == "ordinal":
             lam0 = 0.0 if p.lambda_ is None else float(np.max(p.lambda_))
@@ -549,7 +894,13 @@ class GLM(ModelBuilder):
         fam = _make_family(fam_name, p)
         eta0 = fam.init_eta(y, w)
         mu0 = fam.linkinv(eta0)
-        grad = np.asarray(jnp.abs((X * w[:, None]).T @ (y - mu0)))
+        v = w * (y - mu0)
+        if isinstance(X, CodedDesign):
+            layout = di.coded_layout()
+            xtv = _make_xtv(layout, _fit_block_rows(layout, v.shape[0]))
+            grad = np.asarray(jnp.abs(xtv(v, *X)))
+        else:
+            grad = np.asarray(jnp.abs(X.T @ v))
         if di.add_intercept:
             grad = grad[:-1]
         n = max(float(jnp.sum(w)), 1.0)
@@ -618,7 +969,7 @@ class GLM(ModelBuilder):
         step = _make_irls_step(fam)
         gram, _, dev = step(X, y, w, jnp.asarray(beta, jnp.float32), offset)
         model = GLMModel(job.dest_key or dkv.make_key(self.algo), p, di)
-        self._finalize(model, di, beta, fam_name, X, y, w, offset, n,
+        self._finalize(model, di, beta, fam_name, X, y, w, n,
                        float(dev), hist, lamf, frame, valid,
                        gram_last=np.asarray(gram, np.float64))
         return model
@@ -718,7 +1069,6 @@ class GLM(ModelBuilder):
         model.scoring_history = [
             {"iteration": i, "deviance": float(v) * 2 * n}
             for i, v in enumerate(np.asarray(values[-5:]))]
-        from ..metrics.core import make_metrics
         raw = model._predict_raw(X)
         model.training_metrics = make_metrics(di, raw, y, w)
         if valid is not None:
@@ -750,11 +1100,19 @@ class GLM(ModelBuilder):
             from ..runtime import failure
             failure.maybe_inject("glm_lambda")
             with obs.span("glm.path", lambdas=len(lambdas)):
-                runner = _make_path_runner(
-                    fam, l1_mode=p.alpha > 0 and float(np.max(lambdas)) > 0,
-                    max_iter=p.max_iterations)
+                l1_mode = p.alpha > 0 and float(np.max(lambdas)) > 0
+                if isinstance(X, CodedDesign):
+                    layout = di.coded_layout()
+                    runner = _make_blocked_path_runner(
+                        fam, l1_mode, p.max_iterations, layout,
+                        _fit_block_rows(layout, y.shape[0]))
+                    design = tuple(X)
+                else:
+                    runner = _make_path_runner(fam, l1_mode=l1_mode,
+                                               max_iter=p.max_iterations)
+                    design = (X,)
                 out = runner(
-                    X, y, w, offset, jnp.asarray(lambdas, jnp.float32),
+                    *design, y, w, offset, jnp.asarray(lambdas, jnp.float32),
                     jnp.float32(p.alpha), jnp.asarray(penalize, jnp.float32),
                     jnp.asarray(beta, jnp.float32), jnp.float32(n),
                     jnp.float32(p.beta_epsilon))
@@ -766,6 +1124,10 @@ class GLM(ModelBuilder):
                 obs.inc("transfer_bytes_total",
                         sum(a.nbytes for a in fetched), dir="d2h")
             betas, devs, iters, gram_fin, dev_fin = fetched
+            obs.inc("glm_path_launches_total")
+            # every IRLS iteration of every lambda and the pass of the final
+            # Gram
+            obs.inc("glm_irls_passes_total", int(np.sum(iters)) + 1)
             hist = [{"lambda": float(lam), "iteration": int(iters[li]),
                      "deviance": float(devs[li]), "delta": float("nan")}
                     for li, lam in enumerate(lambdas)]
@@ -774,7 +1136,7 @@ class GLM(ModelBuilder):
                            f"lambda={lam:.3g} dev={float(devs[li]):.4g}")
             model = GLMModel(job.dest_key or dkv.make_key(self.algo), p, di)
             self._finalize(model, di, np.asarray(betas[-1], np.float64),
-                           fam_name, X, y, w, offset, n, float(devs[-1]),
+                           fam_name, X, y, w, n, float(devs[-1]),
                            hist, lambdas[-1], frame, valid,
                            gram_last=np.asarray(gram_fin, np.float64))
             return model
@@ -817,7 +1179,7 @@ class GLM(ModelBuilder):
             best = beta.copy()
 
         model = GLMModel(job.dest_key or dkv.make_key(self.algo), p, di)
-        self._finalize(model, di, best, fam_name, X, y, w, offset, n,
+        self._finalize(model, di, best, fam_name, X, y, w, n,
                        dev, hist, lambdas[-1], frame, valid,
                        gram_last=gram)
         return model
@@ -858,13 +1220,13 @@ class GLM(ModelBuilder):
                 break
             ll_prev = ll
         model = GLMModel(job.dest_key or dkv.make_key(self.algo), p, di)
-        self._finalize(model, di, beta, "multinomial", X, y, w, offset, n,
+        self._finalize(model, di, beta, "multinomial", X, y, w, n,
                        2 * ll, hist, lam, frame, valid)
         return model
 
     # ------------------------------------------------------------ finalize
     @obs.span("glm.finalize")       # a span is a decorator too: one per call
-    def _finalize(self, model, di, beta_std, fam_name, X, y, w, offset, n,
+    def _finalize(self, model, di, beta_std, fam_name, X, y, w, n,
                   deviance, hist, lam, frame, valid, gram_last=None):
         p: GLMParameters = self.params
         # de-standardize coefficients back to the original data scale
@@ -900,10 +1262,14 @@ class GLM(ModelBuilder):
         # p-values for unpenalized fits (GLM.java compute_p_values path)
         if p.compute_p_values and lam == 0.0 and not multi and gram_last is not None:
             try:
-                inv = np.linalg.inv(gram_last)
+                # a column no row lights (the NA column of a categorical
+                # without NAs) is a zero row of the Gram: no standard error
+                lit = np.diag(gram_last) > 0
+                inv = np.linalg.inv(gram_last[np.ix_(lit, lit)])
                 disp = (deviance / max(n - len(b), 1.0)
                         if fam_name in ("gaussian", "gamma", "tweedie") else 1.0)
-                se = np.sqrt(np.maximum(np.diag(inv) * disp, 0.0))
+                se = np.full(len(b), np.nan)
+                se[lit] = np.sqrt(np.maximum(np.diag(inv) * disp, 0.0))
                 zval = np.where(se > 0, b / np.maximum(se, 1e-30), np.nan)
                 from scipy.stats import norm  # pragma: no cover
                 pval = 2 * (1 - norm.cdf(np.abs(zval)))
@@ -912,7 +1278,9 @@ class GLM(ModelBuilder):
             if se is not None:
                 model.output.update({"std_errs": se, "z_values": zval,
                                      "p_values": pval})
-        # training + validation metrics
+        if gram_last is not None and not multi:
+            model.output["gram"] = gram_last     # X'WX at the final beta
+        # training + validation metrics, on the design the solver took
         raw = model._predict_raw(X)
         model.training_metrics = make_metrics(di, raw, y, w)
         if valid is not None:

@@ -1,0 +1,328 @@
+"""GLM's IRLSM on the code-form design (categoricals as codes, one block of
+rows expanded at a time) against the plain reference
+(``models/reference_glm.py``) on the dense expansion: one pass's Gram,
+X'Wz and deviance, whole fits, blocked scoring, p-values, one device
+against the mesh, and a frame whose dense design passes the device.
+``GLM.train`` takes the code form only where the dense design would not fit
+the device (``glm._dense_design_fits``), so the tests that go through it
+shrink the device the code reads (``small_device``), never a parameter."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import h2o3_tpu
+from h2o3_tpu import Frame
+from h2o3_tpu.frame.vec import T_CAT
+from h2o3_tpu.models import datainfo, glm
+from h2o3_tpu.models import reference_glm as ref
+from h2o3_tpu.models.datainfo import DataInfo
+from h2o3_tpu.models.glm import GLM, GLMParameters
+
+DOMAINS = {"c5": list("abcde"), "c9": [f"L{i}" for i in range(9)]}
+
+# float32 sums over a few hundred rows in another order (blocks, then the
+# mesh's shards) than the reference's one product: a few ulp of the summed
+# absolute terms; every comparison of sums below is relative to that
+SUM_RTOL = 2e-5
+
+
+def _frame(seed, n=600, family="binomial", na=True, domains=DOMAINS,
+           weights=False):
+    """Two numerics and two categoricals around each other, NA in both
+    kinds (if asked), a response that depends on all four."""
+    rng = np.random.default_rng(seed)
+    cols = {"x0": rng.normal(size=n).astype(np.float32),
+            "c5": rng.integers(0, len(domains["c5"]), n).astype(np.int32),
+            "x1": rng.normal(2.0, 3.0, size=n).astype(np.float32),
+            "c9": rng.integers(0, len(domains["c9"]), n).astype(np.int32)}
+    eta = 0.8 * cols["x0"] + 0.3 * (cols["c5"] % 2) - 0.2 * (cols["c9"] % 3) \
+        + 0.1 * (cols["x1"] - 2.0)
+    cat = dict(domains)
+    if family == "binomial":
+        cols["y"] = (eta + rng.logistic(size=n) > 0).astype(np.int32)
+        cat["y"] = ["no", "yes"]
+    elif family == "poisson":
+        cols["y"] = rng.poisson(np.exp(0.5 * eta)).astype(np.float32)
+    else:
+        cols["y"] = (eta + 0.5 * rng.normal(size=n)).astype(np.float32)
+    if weights:
+        cols["wt"] = rng.uniform(0.5, 2.0, n).astype(np.float32)
+    if na:
+        cols["x0"][::41] = np.nan
+        cols["c5"][::29] = -1
+        cols["c9"][::53] = -1
+    return Frame.from_numpy(cols, types={k: T_CAT for k in cat}, domains=cat)
+
+
+def _unseen_frame(seed, n=200, **kw):
+    """A frame whose ``c5`` has another order and a level the training
+    frame never had."""
+    return _frame(seed, n, domains=dict(DOMAINS, c5=["e", "zz", "a", "c", "b", "d"]),
+                  **kw)
+
+
+def _dense(di, fr):
+    """The frame's dense rows, expanded from the code form (equal to
+    ``make_matrix``'s: tests/test_dl_coded.py), response and weights."""
+    n = fr.nrows
+    return (np.asarray(datainfo.expand_coded(di.coded_layout(),
+                                             *di.make_coded(fr)))[:n],
+            np.nan_to_num(np.asarray(di.response(fr))[:n]),
+            np.asarray(di.weights(fr))[:n])
+
+
+def _blocked_pass(di, fr, beta, family, block):
+    """(Gram, X's, deviance) of the system's one IRLS pass over row blocks
+    of ``block`` rows a shard."""
+    fam = glm._make_family(family, GLMParameters())
+    irls_gram = jax.jit(glm._make_irls_gram(fam, di.coded_layout(), block))
+    y = jnp.nan_to_num(di.response(fr))
+    return irls_gram(*di.make_coded(fr), y, di.weights(fr), jnp.zeros_like(y),
+                     jnp.asarray(beta, jnp.float32))
+
+
+@pytest.fixture()
+def small_device(monkeypatch):
+    """A device of 16 KB, as the code reads it: no frame of these tests has a
+    dense design that fits a quarter of it, so ``GLM.train`` and scoring take
+    the code form (a block is 1,024 rows, the least ``block_rows`` gives).  At
+    the memory the CPU is assumed to have they would all take the dense
+    design, as a frame that fits a chip does."""
+    monkeypatch.setattr(datainfo, "device_memory_bytes", lambda: 1 << 14)
+    monkeypatch.setattr(glm, "device_memory_bytes", lambda: 1 << 14)
+    monkeypatch.setattr(DataInfo, "make_matrix", lambda *a, **k: pytest.fail(
+        "the dense design was built"))
+    glm._make_score.cache_clear()
+    yield
+    glm._make_score.cache_clear()
+
+
+def _assert_sums_close(got, want, terms):
+    """``got`` equals ``want`` to the float32 summation tolerance of sums
+    whose absolute terms add up to ``terms``."""
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=SUM_RTOL * float(np.max(np.abs(terms))))
+
+
+# ------------------------------------------------------- (a) one IRLS pass
+@pytest.mark.parametrize("standardize", [True, False])
+@pytest.mark.parametrize("all_levels", [False, True])
+@pytest.mark.parametrize("which", ["clean", "na_unseen"])
+@pytest.mark.parametrize("rows", [640, 601])    # 640: 8 shards x 2 blocks of 40
+def test_blocked_pass_equals_reference(cl, standardize, all_levels, which, rows):
+    train = _frame(1, rows, na=False)
+    di = DataInfo.fit(train, response_column="y", standardize=standardize,
+                      use_all_factor_levels=all_levels)
+    fr = train if which == "clean" else _unseen_frame(2, rows)
+    X, y, w = _dense(di, fr)
+    beta = np.random.default_rng(3).normal(0, 0.3, di.nfeatures).astype(np.float32)
+    want_gram, want_xtwz, want_dev = ref.irls_stats(X, y, w, beta, 0.0, "binomial")
+    gram, score, dev = _blocked_pass(di, fr, beta, "binomial", block=40)
+    _assert_sums_close(gram, want_gram, np.abs(X).T @ np.abs(X))
+    # X'Wz = X'WX beta + X's: the system keeps the score apart (glm.py)
+    xtwz = np.asarray(gram, np.float64) @ beta + np.asarray(score)
+    _assert_sums_close(xtwz, want_xtwz, np.abs(X).T @ (np.abs(X) @ np.abs(beta) + 1.0))
+    np.testing.assert_allclose(float(dev), float(want_dev), rtol=SUM_RTOL)
+    if which == "na_unseen":
+        # the unseen level and the NAs light each block's last column
+        c5 = next(s for s in di.specs if s.name == "c5")
+        assert np.asarray(gram)[c5.offset + c5.width - 1,
+                                c5.offset + c5.width - 1] > 0
+
+
+# ------------------------------------------------------------ (b) the fit
+@pytest.mark.parametrize("family", ["binomial", "gaussian", "poisson"])
+def test_fit_equals_reference(cl, small_device, family):
+    fr = _frame(4, 900, family, weights=True)
+    model = GLM(family=family, lambda_=0.0, response_column="y",
+                weights_column="wt").train(fr)
+    di = model.datainfo
+    X, y, w = _dense(di, fr)
+    betas, devs, passes = ref.fit(X, y, w, None, family, [0.0], alpha=0.5)
+    # both stop when no coefficient moves by beta_epsilon = 1e-5: what is
+    # left of each one's last step, and float32 solves of another form (the
+    # system solves for the Newton step), are inside ten times that
+    np.testing.assert_allclose(model.output["beta_std"], betas[-1], atol=1e-4)
+    # the deviance is read one pass BEFORE the last update, in both
+    np.testing.assert_allclose(model.output["residual_deviance"], devs[-1],
+                               rtol=1e-4)
+    assert abs(model.scoring_history[-1]["iteration"] - passes[-1]) <= 1
+
+
+def test_elastic_net_path_equals_reference(cl, small_device):
+    fr = _frame(5, 900, "binomial")
+    lambdas = [0.05, 0.02, 0.01, 0.003, 0.001]
+    model = GLM(family="binomial", lambda_=lambdas, alpha=0.5,
+                response_column="y").train(fr)
+    di = model.datainfo
+    X, y, w = _dense(di, fr)
+    betas, devs, _ = ref.fit(X, y, w, None, "binomial", lambdas, alpha=0.5)
+    # coordinate descent stops at 1e-8 a sweep in both, IRLS at 1e-5
+    np.testing.assert_allclose(model.output["beta_std"], betas[-1], atol=2e-4)
+    assert (np.abs(betas[0]) < 1e-7).sum() >= 3     # the path starts sparse
+    got = [h["deviance"] for h in model.scoring_history]
+    np.testing.assert_allclose(got, devs, rtol=2e-4)
+
+
+# ------------------------------------------------- (c) one block, many blocks
+@pytest.mark.parametrize("family", ["binomial", "poisson"])
+def test_one_block_equals_many(cl, family):
+    fr = _frame(6, 1000, family)
+    di = DataInfo.fit(fr, response_column="y")
+    X, _, _ = _dense(di, fr)
+    beta = np.random.default_rng(7).normal(0, 0.2, di.nfeatures).astype(np.float32)
+    rows = fr.padded_rows // cl.n_row_shards
+    one = _blocked_pass(di, fr, beta, family, block=rows)
+    many = _blocked_pass(di, fr, beta, family, block=24)    # the last laid back
+    assert rows % 24
+    _assert_sums_close(many[0], one[0], np.abs(X).T @ np.abs(X))
+    _assert_sums_close(many[1], one[1], np.abs(X).sum(0))
+    np.testing.assert_allclose(float(many[2]), float(one[2]), rtol=SUM_RTOL)
+
+
+# ------------------------------------------------------------ (d) scoring
+def test_predict_and_performance_equal_reference_with_unseen_level(cl, small_device):
+    fr = _frame(8, 700)
+    model = GLM(family="binomial", lambda_=0.0, response_column="y").train(fr)
+    on = _unseen_frame(9, 333)
+    di = model.datainfo
+    X, y, w = _dense(di, on)
+    want = np.asarray(ref.predict(X, model.output["beta_std"], "binomial"))
+    got = model.predict(on)
+    np.testing.assert_allclose(got.vec("yes").to_numpy(), want, atol=2e-6)
+    np.testing.assert_allclose(got.vec("no").to_numpy(), 1.0 - want, atol=2e-6)
+    perf = model.model_performance(on)
+    p = np.clip(want.astype(np.float64), 1e-15, 1 - 1e-15)
+    logloss = -np.sum(w * (y * np.log(p) + (1 - y) * np.log1p(-p))) / w.sum()
+    np.testing.assert_allclose(perf.logloss, logloss, rtol=1e-5)
+    # and in blocks: 20,000 rows are 2,500 a shard, three blocks of 1,024
+    # with the last laid back
+    big = _unseen_frame(10, 20_000)
+    Xb, _, _ = _dense(di, big)
+    assert big.padded_rows // cl.n_row_shards > 2 * 1024
+    assert isinstance(model._score_matrix(big), datainfo.CodedDesign)
+    got = np.asarray(model._predict_raw(model._score_matrix(big)))[:20_000, 1]
+    np.testing.assert_allclose(
+        got, np.asarray(ref.predict(Xb, model.output["beta_std"], "binomial")),
+        atol=2e-6)
+
+
+# ----------------------------------------------------------- (e) p-values
+def test_p_values_come_from_the_final_gram(cl, small_device):
+    from scipy.stats import norm
+    fr = _frame(11, 900, na=False)
+    model = GLM(family="binomial", lambda_=0.0, response_column="y",
+                compute_p_values=True).train(fr)
+    di = model.datainfo
+    X, y, w = _dense(di, fr)
+    beta = np.asarray(model.output["beta_std"], np.float32)
+    gram, _, _ = ref.irls_stats(X, y, w, beta, 0.0, "binomial")
+    _assert_sums_close(model.output["gram"], gram, np.abs(X).T @ np.abs(X))
+    # the NA columns of a frame without NAs are zero rows of the Gram:
+    # standard errors of the columns some row lights
+    lit = np.abs(X).sum(0) > 0
+    se = np.sqrt(np.diag(np.linalg.inv(np.asarray(gram, np.float64)[lit][:, lit])))
+    np.testing.assert_allclose(model.output["std_errs"][lit], se, rtol=1e-3)
+    z = beta[lit] / se
+    np.testing.assert_allclose(model.output["p_values"][lit],
+                               2 * (1 - norm.cdf(np.abs(z))), atol=1e-4)
+
+
+# ------------------------------------------------- (f) the mesh, one device
+def test_mesh_equals_one_device(cl, small_device):
+    cols_fr = _frame(12, 1000, weights=True)
+
+    def fit_and_pass():
+        fr = Frame.from_numpy(
+            {n: cols_fr.vec(n).to_numpy() for n in cols_fr.names},
+            types={"c5": T_CAT, "c9": T_CAT, "y": T_CAT},
+            domains=dict(DOMAINS, y=["no", "yes"]))
+        model = GLM(family="binomial", lambda_=0.0, response_column="y",
+                    weights_column="wt").train(fr)
+        return (np.asarray(model.output["beta_std"]), model.output["gram"],
+                np.asarray(model.predict(fr).vec("yes").to_numpy()))
+
+    assert cl.n_row_shards > 1
+    mesh = fit_and_pass()
+    try:
+        h2o3_tpu.init(devices=jax.devices()[:1])
+        one = fit_and_pass()
+    finally:
+        h2o3_tpu.init(devices=jax.devices())
+    np.testing.assert_allclose(mesh[0], one[0], atol=2e-5)
+    np.testing.assert_allclose(mesh[1], one[1], rtol=0,
+                               atol=SUM_RTOL * np.abs(one[1]).max())
+    np.testing.assert_allclose(mesh[2], one[2], atol=1e-5)
+
+
+# ------------------------------------ (g) a design wider than the device
+def _wide_frame(seed, n=2000, levels=150):
+    rng = np.random.default_rng(seed)
+    domains = {"a": [f"a{i}" for i in range(levels)],
+               "b": [f"b{i}" for i in range(levels)], "y": ["no", "yes"]}
+    cols = {"x": rng.normal(size=n).astype(np.float32),
+            "a": rng.integers(0, levels, n).astype(np.int32),
+            "b": rng.integers(0, levels, n).astype(np.int32)}
+    effect = rng.normal(0, 0.5, levels)
+    eta = cols["x"] + effect[cols["a"]] - effect[cols["b"]]
+    cols["y"] = (eta + rng.logistic(size=n) > 0).astype(np.int32)
+    return Frame.from_numpy(cols, types={k: T_CAT for k in domains},
+                            domains=domains)
+
+
+def test_trains_where_the_dense_design_passes_the_device(cl, small_device):
+    """2,000 rows x 302 columns is 2.4 MB dense (0.3 MB a shard of the
+    mesh); the device is said to have 16 KB, so the dense design is never
+    asked for (``small_device`` fails the test if it is) and a block of the
+    walk is 1,024 rows at most."""
+    fr = _wide_frame(13)
+    model = GLM(family="binomial", lambda_=1e-3, alpha=0.0,
+                response_column="y").train(fr)
+    assert model.datainfo.nfeatures == 302
+    assert model.training_metrics.auc > 0.75
+    assert np.isfinite(model.output["beta_std"]).all()
+    pred = model.predict(fr).vec("yes").to_numpy()
+    assert pred.shape == (2000,) and np.isfinite(pred).all()
+
+
+def test_a_frame_that_fits_takes_the_dense_design_and_agrees(cl, monkeypatch):
+    """The same fit on the dense design (the frame fits the 4 GiB the CPU is
+    assumed to have) and on the code form (a 16 KB device): two programs,
+    one model."""
+    fr = _frame(15, 900, weights=True)
+    kw = dict(family="binomial", lambda_=0.0, response_column="y",
+              weights_column="wt")
+    dense = GLM(**kw).train(fr)
+    assert not isinstance(dense._score_matrix(fr), datainfo.CodedDesign)
+    monkeypatch.setattr(datainfo, "device_memory_bytes", lambda: 1 << 14)
+    monkeypatch.setattr(glm, "device_memory_bytes", lambda: 1 << 14)
+    coded = GLM(**kw).train(fr)
+    assert isinstance(coded._score_matrix(fr), datainfo.CodedDesign)
+    np.testing.assert_allclose(coded.output["beta_std"],
+                               dense.output["beta_std"], atol=1e-4)
+    np.testing.assert_allclose(coded.training_metrics.logloss,
+                               dense.training_metrics.logloss, rtol=1e-5)
+    np.testing.assert_allclose(coded.predict(fr).vec("yes").to_numpy(),
+                               dense.predict(fr).vec("yes").to_numpy(),
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("params,what", [
+    ({"solver": "l_bfgs"}, "solver=l_bfgs"),
+    ({"non_negative": True}, "non_negative"),
+])
+def test_dense_solvers_refuse_a_design_that_passes_the_device(cl, monkeypatch,
+                                                              params, what):
+    fr = _wide_frame(14)
+    monkeypatch.setattr(glm, "device_memory_bytes", lambda: 1 << 18)
+    monkeypatch.setattr(datainfo, "device_memory_bytes", lambda: 1 << 18)
+    monkeypatch.setattr(DataInfo, "make_matrix", lambda *a, **k: pytest.fail(
+        "allocated before the refusal"))
+    with pytest.raises(ValueError) as e:
+        GLM(family="binomial", lambda_=0.0, response_column="y",
+            **params).train(fr)
+    message = str(e.value)
+    assert what in message and "solver='irlsm'" in message
+    assert f"{fr.padded_rows // cl.n_row_shards * 302 * 4:,} bytes" in message

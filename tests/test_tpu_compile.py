@@ -167,21 +167,105 @@ def test_serve_traversal_auto_resolves_to_compiles(one_chip):
         _compile(jax.jit(pallas), *operands)
 
 
-def test_glm_irls_path_compiles_at_higgs_shape(one_chip):
-    """The whole IRLS path program at 10M x 28 (+ intercept) fits one
-    chip's 16 GB with room for the frame beside it."""
+V5E_BYTES = 16_900_000_000      # what one v5e reports as its bytes_limit
+HIGGS_LAYOUT = (("num", 28), ("one", 1))
+AIRLINES_LAYOUT = (("num", 5), ("cat", 22), ("cat", 300), ("cat", 300),
+                   ("one", 1))
+
+
+@pytest.fixture()
+def v5e_memory(monkeypatch):
+    """Blocks sized as on the chip: the code reads the memory of the first
+    LOCAL device, which here is a CPU (4 GiB assumed)."""
+    from h2o3_tpu.models import datainfo
+    monkeypatch.setattr(datainfo, "device_memory_bytes", lambda: V5E_BYTES)
+
+
+def _glm_path_dense(sds, rows, n=None):
+    """GLM's IRLSM path program on the dense design, 28 numerics and the
+    intercept: what a frame whose expansion fits the device runs."""
     from h2o3_tpu.models import glm
-    _, sds, rows = one_chip
+    n = SMALL_N if n is None else n
     p = 29
     fam = glm._make_family("binomial", glm.GLMParameters())
-    vec, coef = sds((N,), jnp.float32, rows), sds((p,), jnp.float32)
+    vec, coef = sds((n,), jnp.float32, rows), sds((p,), jnp.float32)
     scalar = sds((), jnp.float32)
-    compiled, _ = _compile(
-        glm._make_path_runner(fam, False, 50),
-        sds((N, p), jnp.float32, rows, None), vec, vec, vec,
+    return glm._make_path_runner(fam, False, 50), (
+        sds((n, p), jnp.float32, rows, None), vec, vec, vec,
         sds((1,), jnp.float32), scalar, coef, coef, scalar, scalar)
+
+
+def _glm_path(sds, rows, layout=AIRLINES_LAYOUT, n=None):
+    """GLM's IRLSM path program on the code-form design of ``layout``."""
+    from h2o3_tpu.models import glm
+    n = SMALL_N if n is None else n
+    p = sum(width for _, width in layout)
+    nums = sum(w for kind, w in layout if kind == "num")
+    cats = sum(1 for kind, _ in layout if kind == "cat")
+    fam = glm._make_family("binomial", glm.GLMParameters())
+    vec, coef = sds((n,), jnp.float32, rows), sds((p,), jnp.float32)
+    scalar = sds((), jnp.float32)
+    fn = glm._make_blocked_path_runner(fam, False, 50, layout,
+                                       glm._fit_block_rows(layout, n))
+    return fn, (sds((n, nums), jnp.float32, rows, None),
+                sds((n, cats), jnp.int32, rows, None), vec, vec, vec,
+                sds((1,), jnp.float32), scalar, coef, coef, scalar, scalar)
+
+
+def _glm_score(sds, rows, layout=AIRLINES_LAYOUT, n=None):
+    from h2o3_tpu.models import datainfo, glm
+    n = SMALL_N if n is None else n
+    p = sum(width for _, width in layout)
+    fn = glm._make_score(layout, "binomial", True,
+                         datainfo.block_rows(2 * 4 * (p + 2), n))
+    return fn, (sds((p,), jnp.float32), sds((0,), jnp.float32),
+                sds((n, 5), jnp.float32, rows, None),
+                sds((n, 3), jnp.int32, rows, None))
+
+
+def test_glm_irls_path_compiles_at_higgs_shape(one_chip):
+    """The whole IRLS path program on the dense design at 10M x 28 (+
+    intercept) fits one chip's 16 GB with room for the frame beside it."""
+    _, sds, rows = one_chip
+    fn, operands = _glm_path_dense(sds, rows, N)
+    compiled, _ = _compile(fn, *operands)
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 8e9
+
+
+def test_glm_blocked_path_is_one_block_where_the_expansion_fits(one_chip,
+                                                                v5e_memory):
+    """The code-form program on an all-numeric frame of the same size is
+    ONE block (no scan), and compiles."""
+    from h2o3_tpu.models import glm
+    _, sds, rows = one_chip
+    assert glm._fit_block_rows(HIGGS_LAYOUT, N) == N
+    fn, operands = _glm_path(sds, rows, HIGGS_LAYOUT, N)
+    compiled, _ = _compile(fn, *operands)
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 8e9
+
+
+def test_glm_programs_hold_no_frame_sized_expansion(one_chip, v5e_memory):
+    """The IRLSM path and the scoring program at 40M rows of the airlines
+    shape (5 numerics + 3 categoricals, 628 expanded columns): beside the
+    code-form design with y, w and the offset (2.4 GB as the chip tiles
+    it) neither holds the [rows, 628] expansion (100 GB); the walk's block
+    and its operands stay inside a quarter of the chip, a scoring block
+    inside a sixteenth."""
+    _, sds, rows = one_chip
+    n = 40_000_000
+    fn, operands = _glm_path(sds, rows, AIRLINES_LAYOUT, n)
+    path, _ = _compile(fn, *operands)
+    ma = path.memory_analysis()
+    assert ma.argument_size_in_bytes < 2.5e9
+    assert ma.temp_size_in_bytes < V5E_BYTES / 4
+    fn, operands = _glm_score(sds, rows, AIRLINES_LAYOUT, n)
+    score, _ = _compile(fn, *operands)
+    ma = score.memory_analysis()
+    assert ma.argument_size_in_bytes < 2.0e9
+    assert ma.temp_size_in_bytes < V5E_BYTES / 16
+    assert ma.output_size_in_bytes == n * 2 * 4
 
 
 # ----------------------------------------------------- names in the trace
@@ -203,17 +287,6 @@ def _tree_build_operands(sds, rows, nk=1):
             sds((SMALL_N,), jnp.float32, rows), sds((F, NBINS), jnp.float32),
             sds(lead + (2,), jnp.uint32),
             *SCALARS[:5], sds(lead + (F,), jnp.bool_), *SCALARS[5:])
-
-
-def _glm_path(sds, rows):
-    from h2o3_tpu.models import glm
-    p = 29
-    fam = glm._make_family("binomial", glm.GLMParameters())
-    vec, coef = sds((SMALL_N,), jnp.float32, rows), sds((p,), jnp.float32)
-    scalar = sds((), jnp.float32)
-    return glm._make_path_runner(fam, False, 50), (
-        sds((SMALL_N, p), jnp.float32, rows, None), vec, vec, vec,
-        sds((1,), jnp.float32), scalar, coef, coef, scalar, scalar)
 
 
 def _tree_scan(sds, rows):
@@ -349,11 +422,13 @@ def test_deeplearning_programs_hold_no_frame_sized_expansion(one_chip):
 
 
 MODULES = {
-    "jit_run": _glm_path, "jit_scan_fn": _tree_scan,
+    "jit_run": _glm_path_dense, "jit_run@blocked": _glm_path,
+    "jit_scan_fn": _tree_scan,
     "jit_build": _tree_build,
     "jit_buildK": functools.partial(_tree_build, nk=3),
     "jit_traverse": _traverse, "jit_sketch": _sketch, "jit_encode": _encode,
     "jit_dl_train_steps": _dl_train, "jit_dl_score": _dl_score,
+    "jit_glm_score": _glm_score,
 }
 KERNELS = {
     "hist_uniform": _hist_kernel(lambda hist, sds, rows: (
@@ -397,7 +472,7 @@ def test_names_the_trace_reductions_match(one_chip, kind, name):
         fn, operands = MODULES[name](sds, rows)
         hlo = getattr(fn, "jitted", fn).lower(*operands) \
             .compiler_ir("hlo").as_hlo_text()
-        assert re.match(r"HloModule (\w+)", hlo).group(1) == name
+        assert re.match(r"HloModule (\w+)", hlo).group(1) == name.split("@")[0]
     elif name == "serve_traverse":
         # Mosaic refuses this kernel (see above), so nothing is emitted
         # to read a name from: its pallas_call carries name=

@@ -61,6 +61,7 @@ def main(argv=None) -> int:
 
     import h2o3_tpu
     from h2o3_tpu.models import base as base_mod
+    from h2o3_tpu.models import datainfo as datainfo_mod
     from h2o3_tpu.models import deeplearning as dl_mod
     from h2o3_tpu.models import glm as glm_mod
     from h2o3_tpu.models.tree import hist, shared
@@ -191,7 +192,13 @@ def main(argv=None) -> int:
             sds((312_576 * 128, 2), jnp.float32, mat), sds((), jnp.int32),
             sds((), jnp.float32), True, cl.pad_rows(40_000_000), rows)
 
+    # GLM's programs read the design in code form and size their row blocks
+    # from the device's memory, which the code asks of the first LOCAL
+    # device: here a CPU, so say what one v5e reports
+    datainfo_mod.device_memory_bytes = lambda: 16_900_000_000
+
     def glm_path():
+        # the dense design, as a frame whose expansion fits the device runs it
         fam = glm_mod._make_family("binomial", glm_mod.GLMParameters())
         fn = glm_mod._make_path_runner(fam, False, 50)
         nh = cl.pad_rows(N_HIGGS)
@@ -200,6 +207,21 @@ def main(argv=None) -> int:
                     sds((1,), jnp.float32), sds((), jnp.float32),
                     sds((P_HIGGS,), jnp.float32), sds((P_HIGGS,), jnp.float32),
                     sds((), jnp.float32), sds((), jnp.float32))
+
+    def glm_path_blocked(layout, n):
+        fam = glm_mod._make_family("binomial", glm_mod.GLMParameters())
+        n = cl.pad_rows(n)
+        fn = glm_mod._make_blocked_path_runner(
+            fam, False, 50, layout, glm_mod._fit_block_rows(layout, n))
+        width = sum(w for _, w in layout)
+        vec, coef = sds((n,), jnp.float32, rows), sds((width,), jnp.float32)
+        return fn, (sds((n, sum(w for k, w in layout if k == "num")),
+                        jnp.float32, mat),
+                    sds((n, sum(k == "cat" for k, _ in layout)), jnp.int32,
+                        mat),
+                    vec, vec, vec, sds((1,), jnp.float32),
+                    sds((), jnp.float32), coef, coef, sds((), jnp.float32),
+                    sds((), jnp.float32))
 
     # DeepLearning at the benchmark's dl_airlines40m geometry: 5 numerics,
     # categoricals of 22 / 300 / 300 levels (first level dropped, NA column
@@ -236,10 +258,23 @@ def main(argv=None) -> int:
         fn = dl_mod._make_score(dl_layout, "rectifier", "softmax", block)
         return fn, (dl_params, *dl_design(cl.pad_rows(n_dl)))
 
+    def glm_path_airlines():
+        # glm_airlines40m.fit: 96 blocks of 419,840 rows a pass on one chip
+        return glm_path_blocked(dl_layout, n_dl)
+
+    def glm_score():
+        n = cl.pad_rows(n_dl)
+        block = datainfo_mod.block_rows(2 * 4 * (dl_sizes[0] + 2),
+                                        n // cl.n_row_shards)
+        fn = glm_mod._make_score(dl_layout, "binomial", True, block)
+        return fn, (sds((dl_sizes[0],), jnp.float32), sds((0,), jnp.float32),
+                    *dl_design(n))
+
     programs = {f.__name__: f for f in (
         tree_build, tree_build_scan, tree_build_k7, tree_scan, sparse_level,
         grid_scan, serve_xla, traverse, prediction_columns, glm_path,
-        dl_sample_copy, dl_train_steps, dl_score)}
+        glm_path_airlines, glm_score, dl_sample_copy, dl_train_steps,
+        dl_score)}
     unknown = [p for p in args.programs if p not in programs]
     if unknown:
         ap.error(f"unknown program(s) {unknown}; known: {sorted(programs)}")
