@@ -329,14 +329,17 @@ def run_one_chip(args, S):
     fr_hs = higgs_frame(Xh[:n_small], yh[:n_small])
     pins = dict(hist_mode="subtract", split_mode="fused", hist_layout="dense",
                 tree_program="level")
-    for knob, pair, frame, resp in (
-            ("hist_mode", ("subtract", "full"), fr_s, "dep_delayed_15min"),
-            ("split_mode", ("fused", "separate"), fr_s, "dep_delayed_15min"),
-            # the scan program composes with the uniform kernels only, so
-            # its pair needs a frame on which the varbin kernel does not
-            # engage
-            ("tree_program", ("scan", "level"), fr_hs, "y")):
-        with S.phase(f"parity_{knob}", rows=n_small) as out:
+    for knob, pair, frame, resp, kernel in (
+            ("hist_mode", ("subtract", "full"), fr_s, "dep_delayed_15min", ""),
+            ("split_mode", ("fused", "separate"), fr_s, "dep_delayed_15min",
+             ""),
+            # both programs under both histogram kernels: the higgs frame's
+            # columns all use every bin (uniform kernels), the airlines
+            # frame packs (the variable-bin kernel, in the scan since PR 38)
+            ("tree_program", ("scan", "level"), fr_hs, "y", ""),
+            ("tree_program", ("scan", "level"), fr_s, "dep_delayed_15min",
+             "_varbin")):
+        with S.phase(f"parity_{knob}{kernel}", rows=n_small) as out:
             fits = [XGBoost(response_column=resp, ntrees=2, seed=1,
                             **{**pins, knob: value},
                             **args.tree_kw).train(frame) for value in pair]

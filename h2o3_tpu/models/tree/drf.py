@@ -28,7 +28,8 @@ from ..datainfo import DataInfo
 from ..scorekeeper import stop_early, metric_direction
 from .binning import fit_bins, edges_matrix
 from .shared import (SharedTree, SharedTreeModel, SharedTreeParameters,
-                     StackedTrees, TreeList, chunk_schedule, dense_mem_cap,
+                     StackedTrees, TreeList, chunk_schedule,
+                     count_hist_kernel, dense_mem_cap,
                      make_multinomial_scan_fn, make_tree_scan_fn,
                      traverse_jit)
 from ...metrics.core import make_metrics
@@ -184,6 +185,11 @@ class DRF(SharedTree):
         # per-tree keys are reused across classes so every class sees the
         # same bootstrap sample per iteration (DRF.java samples once/tree).
         model.output["tree_program"] = tree_program
+        count_hist_kernel(
+            tree_program, p.max_depth, p.nbins, Fw, N,
+            bin_counts=wbin_counts, hist_mode=hist_mode,
+            hist_layout=hist_layout,
+            sparse_depth_threshold=p.sparse_depth_threshold)
         # batched multiclass: one K-tree build per round (one hist + one
         # split launch per level for all K class trees) instead of K
         # sequential scans — identical keys (same fold_in structure), so

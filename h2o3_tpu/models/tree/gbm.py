@@ -34,8 +34,9 @@ from ..scorekeeper import stop_early, metric_direction
 from .binning import fit_bins, edges_matrix
 from .shared import (SharedTree, SharedTreeModel, SharedTreeParameters,
                      StackedTrees, Tree, TreeList, build_tree,
-                     chunk_schedule, dense_mem_cap, make_build_tree_fn,
-                     make_tree_scan_fn, stack_trees, traverse_jit)
+                     chunk_schedule, count_hist_kernel, dense_mem_cap,
+                     make_build_tree_fn, make_tree_scan_fn, stack_trees,
+                     traverse_jit)
 from ...metrics.core import make_metrics
 
 
@@ -273,6 +274,12 @@ class GBM(SharedTree):
         fused = not multinomial and not dart
         fused_multi = multinomial and not dart
         model.output["tree_program"] = tree_program
+        # the DART rounds below build without bin_counts (uniform kernels)
+        count_hist_kernel(
+            tree_program, p.max_depth, p.nbins, Fw, N,
+            bin_counts=wbin_counts if fused or fused_multi else None,
+            hist_mode=hist_mode, hist_layout=hist_layout,
+            sparse_depth_threshold=p.sparse_depth_threshold)
 
         if fused_multi:
             # multinomial fast path: K class trees per round, a whole

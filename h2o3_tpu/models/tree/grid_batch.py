@@ -176,7 +176,8 @@ def train_cohort(builder_cls, base_params: dict, combos: Sequence[dict],
     from ..scorekeeper import METRIC_MAXIMIZE, metric_direction
     from .binning import edges_matrix, fit_bins
     from .shared import (StackedTrees, TreeList, chunk_schedule,
-                         effective_max_depth, make_grid_scan_fn,
+                         count_hist_kernel, effective_max_depth,
+                         make_grid_scan_fn,
                          maybe_bundle, record_effective_depth,
                          traverse_jit, tree_snapshot_state)
 
@@ -259,6 +260,9 @@ def train_cohort(builder_cls, base_params: dict, combos: Sequence[dict],
             tree_program=tree_program)
     except ValueError as e:
         raise CohortFallback(str(e))
+    # one program grows the whole cohort: one count (no bin_counts here)
+    count_hist_kernel(tree_program, p0.max_depth, p0.nbins, Fw, N,
+                      hist_mode=knobs.hist_mode)
 
     algo = rep.algo
     obs.set_gauge("grid_cohort_size", float(G), algo=algo)

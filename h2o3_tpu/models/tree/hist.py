@@ -449,6 +449,39 @@ def _make_einsum_hist(L: int, F: int, B: int, n_local: int):
     return local
 
 
+# the whole [F*B, 3L] float32 result a Pallas histogram kernel may stage
+# through VMEM for its custom call
+_HIST_RESULT_VMEM_BYTES = 12 * 1024 * 1024
+
+
+def hist_kernel_kind(L: int, F: int, B: int, *, varbin: bool,
+                     on_tpu: bool) -> str:
+    """The kernel that histograms ONE site of ``L`` slots: ``"varbin"`` |
+    ``"uniform"`` | ``"einsum"``.
+
+    The one rule: the tree builders ask it for every level of the
+    level-unrolled program and for the scan program's width
+    (shared.make_build_tree_fn), the factories below for their einsum
+    fallback, and the tree driver's counter for what it reports
+    (``tree_hist_kernel_total``), so a bound moved here moves them all.
+
+    ``varbin`` says the frame's packed per-feature bins may carry the site
+    (shared.varbin_kernel_engages: on the TPU, or forced, and packing
+    pays).  The packed kernel has no einsum fallback, its minimum row block
+    must keep the ``[R, 3L]`` A-build intermediates inside scoped VMEM
+    (``3L <= 1024``) and its whole result stages through VMEM.  Past that,
+    and for frames that do not pack, the uniform kernel; the portable
+    einsum off the TPU, where the result passes the VMEM bound, or where
+    even the uniform kernel's minimum row block's ``[R, 3L]`` overflows the
+    16M scoped-VMEM stack (``3L > 2048``)."""
+    fits_vmem = F * B * 3 * L * 4 <= _HIST_RESULT_VMEM_BYTES
+    if varbin and 3 * L <= 1024 and fits_vmem:
+        return "varbin"
+    if not on_tpu or not fits_vmem or 3 * L > 2048:
+        return "einsum"
+    return "uniform"
+
+
 @functools.lru_cache(maxsize=None)
 def _make_hist_fn(L: int, F: int, B: int, n_padded: int,
                   force_impl: str = "", precision: str = "bf16",
@@ -464,16 +497,12 @@ def _make_hist_fn(L: int, F: int, B: int, n_padded: int,
     cl = cluster()
     n_local = n_padded // cl.n_row_shards
     platform = cl.mesh.devices.flat[0].platform
-    # very deep levels: the [F*B, 3L] result exceeds what XLA will stage
-    # through VMEM for the custom call — take the portable path there
-    hist_bytes = F * B * 3 * L * 4
     if force_impl == "pallas_interpret":
         inner = _make_pallas_hist(L, F, B, n_local, interpret=True,
                                   precision=precision)
-    elif force_impl == "einsum" or platform != "tpu" \
-            or hist_bytes > 12 * 1024 * 1024 or 3 * L > 2048:
-        # 3L > 2048: even the minimum row block's [R, 3L]
-        # A-build intermediates overflow the 16M scoped-VMEM stack
+    elif force_impl == "einsum" or hist_kernel_kind(
+            L, F, B, varbin=False, on_tpu=platform == "tpu") == "einsum":
+        # off the TPU and at very deep levels: the portable path
         inner = _make_einsum_hist(L, F, B, n_local)
     else:
         inner = _make_pallas_hist(L, F, B, n_local, precision=precision)
@@ -503,7 +532,7 @@ def _local_hist_impl(L: int, F: int, B: int, n_local: int, bin_counts=None,
     varbin kernel is used (codes must be pre-offset packed ids) and the
     packed [Q8, 3L] result is re-expanded to the dense [3, L, F, B]
     contract; otherwise the uniform Pallas kernel with the einsum fallback
-    (CPU mesh, deep levels — same bounds as make_hist_fn).
+    (CPU mesh, deep levels: ``hist_kernel_kind``, as make_hist_fn).
     ``force_impl="pallas"`` pins the REAL (non-interpret) kernel off-TPU —
     the AOT Mosaic export gate needs it to lower the true code path from a
     CPU host (tests/test_mosaic_lowering.py).
@@ -525,13 +554,12 @@ def _local_hist_impl(L: int, F: int, B: int, n_local: int, bin_counts=None,
             return H.reshape(F, B, L, 3).transpose(3, 2, 0, 1)
 
         return inner
-    hist_bytes = F * B * 3 * L * 4
     if force_impl == "pallas_interpret":
         return _make_pallas_hist(L, F, B, n_local, interpret=True,
                                  precision=precision)
     if force_impl != "pallas" and (
-            force_impl == "einsum" or platform != "tpu"
-            or hist_bytes > 12 * 1024 * 1024 or 3 * L > 2048):
+            force_impl == "einsum" or hist_kernel_kind(
+                L, F, B, varbin=False, on_tpu=platform == "tpu") == "einsum"):
         return _make_einsum_hist(L, F, B, n_local)
     return _make_pallas_hist(L, F, B, n_local, precision=precision)
 
@@ -743,8 +771,8 @@ make_batched_level_fn = _reduce_mode_dispatch(_make_batched_level_fn)
 
 @functools.lru_cache(maxsize=None)
 def _make_scan_level_fn(W: int, F: int, B: int, n_padded: int,
-                        force_impl: str = "", precision: str = "bf16",
-                        reduce_mode: str = "hier"):
+                        bin_counts=None, force_impl: str = "",
+                        precision: str = "bf16", reduce_mode: str = "hier"):
     """Depth-generic subtract-level histogram for the scan-fused build.
 
     The per-level factory (make_subtract_level_fn) closes over the level
@@ -752,9 +780,11 @@ def _make_scan_level_fn(W: int, F: int, B: int, n_padded: int,
     The whole-tree ``lax.scan`` needs ONE program whose shapes do not
     change across iterations, so this variant runs the identical
     smaller-sibling compaction at a FIXED child width ``W`` (the deepest
-    scanned level's 2^d) with parent width ``W // 2``.  Shallower levels
-    simply leave their padding slots empty: a slot with zero local rows
-    has ``cnt == 0`` on both children, contributes an all-False chosen
+    scanned level's 2^d) with parent width ``W // 2``, through the packed
+    variable-bin kernel where ``bin_counts`` is given (``codes`` are then
+    the pre-offset packed ids, as for make_subtract_level_fn).  Shallower
+    levels simply leave their padding slots empty: a slot with zero local
+    rows has ``cnt == 0`` on both children, contributes an all-False chosen
     mask (exact +0.0 histogram), and reconstructs to exact +0.0 on the
     large side (``0 - 0`` clamped) — so padded slots are bitwise inert
     and the live prefix matches the per-level program (see the blocking
@@ -779,8 +809,8 @@ def _make_scan_level_fn(W: int, F: int, B: int, n_padded: int,
     n_local = n_padded // cl.n_row_shards
     Wp = W // 2
     cap = n_local // 2
-    inner = _local_hist_impl(Wp, F, B, cap, force_impl=force_impl,
-                             precision=precision)
+    inner = _local_hist_impl(Wp, F, B, cap, bin_counts=bin_counts,
+                             force_impl=force_impl, precision=precision)
     specs_row = (P(None, ROW_AXIS), P(ROW_AXIS), P(ROW_AXIS), P(ROW_AXIS),
                  P(ROW_AXIS))
 
@@ -835,7 +865,8 @@ make_scan_level_fn = _reduce_mode_dispatch(_make_scan_level_fn)
 
 @functools.lru_cache(maxsize=None)
 def _make_batched_scan_level_fn(W: int, K: int, F: int, B: int,
-                                n_padded: int, force_impl: str = "",
+                                n_padded: int, bin_counts=None,
+                                force_impl: str = "",
                                 precision: str = "bf16",
                                 reduce_mode: str = "hier"):
     """K-tree batched variant of ``make_scan_level_fn`` — one launch per
@@ -853,8 +884,8 @@ def _make_batched_scan_level_fn(W: int, K: int, F: int, B: int,
     n_local = n_padded // cl.n_row_shards
     Wp = W // 2
     cap = n_local // 2
-    inner = _local_hist_impl(Wp, F, B, cap, force_impl=force_impl,
-                             precision=precision)
+    inner = _local_hist_impl(Wp, F, B, cap, bin_counts=bin_counts,
+                             force_impl=force_impl, precision=precision)
     specs_k = (P(None, ROW_AXIS),) * 5
 
     def locald(codes, leafK, gK, hK, wK, carry, dead):

@@ -37,7 +37,7 @@ from .hist import (_ledger, make_hist_fn, make_varbin_hist_fn,
                    make_subtract_level_fn, make_batched_level_fn,
                    make_scan_level_fn, make_batched_scan_level_fn,
                    make_sparse_level_fn, make_batched_sparse_level_fn,
-                   sparse_slot_budget, sparse_slot_maps,
+                   sparse_slot_budget, sparse_slot_maps, hist_kernel_kind,
                    offset_codes, best_splits,
                    fused_best_splits, fused_best_splits_batched,
                    partition, partition_right,
@@ -139,9 +139,11 @@ class SharedTreeParameters(Parameters):
     #     depth (and a far smaller program to compile for deep trees);
     #   "auto"  (default) — autotuner-decided, as with hist_mode ("level"
     #     with the tuner off — bit-identical to the pre-scan pipeline).
-    # Monotone constraints, EFB bundling, node-sparse deep levels, the
-    # variable-bin kernel and depth-1 trees stay on the level path ("auto"
-    # downgrades automatically; uplift always grows level-wise).
+    # Monotone constraints, EFB bundling, node-sparse deep levels and
+    # depth-1 trees stay on the level path ("auto" downgrades
+    # automatically; uplift always grows level-wise).  Both programs run
+    # the variable-bin kernel where the frame packs and the width fits
+    # (hist_site_kernel): "scan" forfeits no kernel.
     tree_program: str = "auto"
     # probability calibration (hex/tree CalibrationHelper)
     calibrate_model: bool = False
@@ -518,6 +520,66 @@ def _per_k(x, extra_dims: int):
         if getattr(x, "ndim", 0) else x
 
 
+def _level_geometry(max_depth: int, nbins: int, F: int, n_padded: int,
+                    hist_mode: str, hist_layout: str,
+                    sparse_depth_threshold: int):
+    """The slot geometry of a build's levels: ``(effective max_depth,
+    sparse_from, A_lv, Ap_lv, kern_L)``.  ``kern_L[d]`` is the slot count
+    level ``d``'s histogram kernel runs at, never narrower than the level
+    above it: the subtract path histograms at the PARENT slot count
+    (2^(d-1)), the full oracle at the child count, a node-sparse level in
+    its previous level's slot space.  The scan program's two sites are the
+    first and the last of them."""
+    max_depth = effective_max_depth(max_depth, nbins, F, n_padded,
+                                    hist_layout, sparse_depth_threshold)
+    # first node-sparse level: the threshold clamps to the dense memory
+    # cap so every dense level above it fits the budget, and to >= 1 so
+    # the root level (whose carry seeds the chain) is always dense
+    t0 = max(1, min(sparse_depth_threshold, dense_mem_cap(nbins, F)))
+    sparse_from = t0 if (hist_layout == "sparse" and max_depth > t0) \
+        else max_depth
+    A_cap = sparse_slot_budget(F, nbins + 1)
+    # slot capacity per sparse level, and the PREVIOUS level's slot space
+    # (the carry/compaction geometry) — at the boundary that is the dense
+    # parent id space, so the first sparse level consumes the dense
+    # subtract carry unchanged
+    A_lv = {d: min(2 ** d, A_cap) for d in range(sparse_from, max_depth)}
+    Ap_lv = {d: (2 ** (d - 1) if d == sparse_from else A_lv[d - 1])
+             for d in range(sparse_from, max_depth)}
+    kern_L = [Ap_lv[d] if d >= sparse_from
+              else (2 ** d if hist_mode == "full" else 2 ** max(d - 1, 0))
+              for d in range(max_depth)]
+    return max_depth, sparse_from, A_lv, Ap_lv, kern_L
+
+
+def hist_site_kernel(L: int, F: int, nbins: int, bin_counts=None) -> str:
+    """``"varbin"`` | ``"uniform"`` | ``"einsum"``: the kernel that
+    histograms a site of ``L`` slots of this frame on the live mesh.  A
+    level of the level-unrolled build, the scan build's width and the
+    driver's counter all ask here, with what a builder can observe in its
+    input: the frame's ``bin_counts``, ``nbins``, ``F`` and the slot count
+    (the bounds themselves are hist.hist_kernel_kind's)."""
+    return hist_kernel_kind(
+        L, F, nbins + 1, varbin=varbin_kernel_engages(bin_counts, nbins, F),
+        on_tpu=_on_tpu())
+
+
+def count_hist_kernel(tree_program: str, max_depth: int, nbins: int, F: int,
+                      n_padded: int, *, bin_counts=None,
+                      hist_mode: str = "subtract",
+                      hist_layout: str = "dense",
+                      sparse_depth_threshold: int = 8) -> None:
+    """The tree drivers' once-a-fit record of the kernel their build's
+    WIDEST histogram level runs, and under which program:
+    ``tree_hist_kernel_total{program, kernel}``.  The scan program is one
+    width, so its widest level's kernel is the whole build's."""
+    from ...runtime import observability as obs
+    *_, kern_L = _level_geometry(max_depth, nbins, F, n_padded, hist_mode,
+                                 hist_layout, sparse_depth_threshold)
+    obs.inc("tree_hist_kernel_total", program=tree_program,
+            kernel=hist_site_kernel(kern_L[-1], F, nbins, bin_counts))
+
+
 @functools.lru_cache(maxsize=None)
 def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
                        hist_precision: str = "bf16", bin_counts=None,
@@ -577,10 +639,10 @@ def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
     a scan-carried on-device ``dead`` predicate (hist.make_scan_level_fn
     skips the histogram kernel and the builder skips partition on dead
     levels — both skips are bitwise the live computation).  Composes
-    with hist_mode subtract/full, split_mode separate/fused and the
-    batched K-tree build; NOT with mono/EFB/sparse layout (raises)
-    or the variable-bin kernel (silently uses the uniform kernels —
-    "auto" keeps per-level programs where varbin wins).
+    with hist_mode subtract/full, split_mode separate/fused, the
+    batched K-tree build and the variable-bin kernel (``bin_counts``
+    reach the scan build, which asks the level path's rule at its own
+    width: _make_scan_build); NOT with mono/EFB/sparse layout (raises).
     """
     B = nbins + 1
     if hist_layout not in ("dense", "sparse"):
@@ -627,14 +689,9 @@ def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
             "tree_program='scan' does not compose with monotone "
             "constraints or EFB bundling; tree_program='auto' downgrades "
             "to 'level' automatically")
-    max_depth = effective_max_depth(max_depth, nbins, F, n_padded,
-                                    hist_layout, sparse_depth_threshold)
-    # first node-sparse level: the threshold clamps to the dense memory
-    # cap so every dense level above it fits the budget, and to >= 1 so
-    # the root level (whose carry seeds the chain) is always dense
-    t0 = max(1, min(sparse_depth_threshold, dense_mem_cap(nbins, F)))
-    sparse_from = t0 if (hist_layout == "sparse" and max_depth > t0) \
-        else max_depth
+    max_depth, sparse_from, A_lv, Ap_lv, kern_L = _level_geometry(
+        max_depth, nbins, F, n_padded, hist_mode, hist_layout,
+        sparse_depth_threshold)
     if tree_program == "scan":
         if sparse_from < max_depth:
             raise ValueError(
@@ -648,39 +705,22 @@ def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
                 "depth-1 tree is the root level only — nothing to scan); "
                 "tree_program='auto' downgrades to 'level' automatically")
         return _make_scan_build(max_depth, nbins, F, n_padded,
-                                hist_precision, hist_mode, nk, split_mode)
-    A_cap = sparse_slot_budget(F, B)
-    # slot capacity per sparse level, and the PREVIOUS level's slot space
-    # (the carry/compaction geometry) — at the boundary that is the dense
-    # parent id space, so the first sparse level consumes the dense
-    # subtract carry unchanged
-    A_lv = {d: min(2 ** d, A_cap) for d in range(sparse_from, max_depth)}
-    Ap_lv = {d: (2 ** (d - 1) if d == sparse_from else A_lv[d - 1])
-             for d in range(sparse_from, max_depth)}
+                                hist_precision, hist_mode, nk, split_mode,
+                                bin_counts)
     # per-feature packed bins (DHistogram-style): only the TPU Pallas path
     # has the ragged kernel; dense einsum covers CPU tests.  The packed
     # result has the exact same [3, L, F, B] contract, so split search is
     # byte-identical — this is a pure kernel-cost optimization.
     # H2O3_TPU_HIST_IMPL=varbin forces the varbin path off-TPU (interpret
     # Pallas) so the multichip dryrun exercises the varbin kernel's code.
-    on_tpu = _on_tpu()
-    use_varbin = varbin_kernel_engages(bin_counts, nbins, F)
-    # Per-LEVEL kernel choice: the varbin Pallas kernel has no einsum
-    # fallback, its minimum row block must keep [R, 3L] A-build
-    # intermediates inside scoped VMEM (3L <= 1024), and its whole-
-    # histogram output block must stage through VMEM (12 MB).  Deeper
-    # levels take the uniform path, which falls back to einsum past its
-    # own bound — the gate is per level so a deep tree keeps the fast
-    # kernel on its shallow levels.  The subtract path histograms at the
-    # PARENT slot count (2^(d-1)); the full oracle at the child count.
-    kern_L = [Ap_lv[d] if d >= sparse_from
-              else (2 ** d if hist_mode == "full" else 2 ** max(d - 1, 0))
-              for d in range(max_depth)]
+    # Per-LEVEL kernel choice (hist_site_kernel, the scan build's rule
+    # too): deeper levels take the uniform path, which falls back to
+    # einsum past its own bound — the gate is per level so a deep tree
+    # keeps the fast kernel on its shallow levels.
     varbin_level = [
-        use_varbin and 3 * kern_L[d] <= 1024
-        and F * B * 3 * kern_L[d] * 4 <= 12 * 1024 * 1024
-        for d in range(max_depth)]
-    force = "" if on_tpu else "pallas_interpret"
+        hist_site_kernel(L, F, nbins, bin_counts) == "varbin"
+        for L in kern_L]
+    force = "" if _on_tpu() else "pallas_interpret"
 
     # ---- node-sparse deep levels (hist_layout="sparse", d >= sparse_from)
     # Per-tree helpers shared by build()/buildK() (buildK vmaps them).
@@ -1123,7 +1163,7 @@ def make_build_tree_fn(max_depth: int, nbins: int, F: int, n_padded: int,
 
 def _make_scan_build(max_depth: int, nbins: int, F: int, n_padded: int,
                      hist_precision: str, hist_mode: str, nk: int,
-                     split_mode: str):
+                     split_mode: str, bin_counts=None):
     """The ``tree_program="scan"`` build: one lax.scan over levels.
 
     Level 0 runs OUTSIDE the scan on the existing depth-0 machinery (the
@@ -1154,36 +1194,50 @@ def _make_scan_build(max_depth: int, nbins: int, F: int, n_padded: int,
     the level path's true 2^d the row accumulation can associate
     differently once N is large enough to split blocks — structure stays
     exact, leaf values agree to f32 tolerance (tests/test_tree_scan.py's
-    contract).  The variable-bin kernel is never used here (uniform
-    kernels only); resolve_tree_program keeps "auto" on the level path
-    when varbin would engage.
+    contract).
+
+    The histogram kernel is the one the level path's rule
+    (hist_site_kernel) gives the scan's width: the kernel runs at W slots
+    under ``hist_mode="full"`` and at W // 2 under ``"subtract"``, as the
+    level path's deepest level does.  Where that is the variable-bin
+    kernel (a frame whose ``bin_counts`` pack, a width inside the kernel's
+    bounds: 32 slots at depth 6 cost it what one slot does, its MXU
+    product pads ``3 * slots`` to 128 lanes), the root and the scan body
+    both run it on ``offset_codes``' packed ids, built once a tree outside
+    the scan; ``partition`` keeps reading ``codes``.  The scan is ONE
+    program at one width, so the root follows the body (the rule's bounds
+    only tighten with the width): a frame that does not pack, or a width
+    past the bounds, leaves the whole build the uniform-kernel program it
+    was.
     """
     B = nbins + 1
     W = 2 ** (max_depth - 1)
     Wp = W // 2
+    varbin = hist_site_kernel(W if hist_mode == "full" else Wp, F, nbins,
+                              bin_counts) == "varbin"
+    bc = tuple(bin_counts) if varbin else None
+    force = "pallas_interpret" if varbin and not _on_tpu() else ""
+    kw = dict(bin_counts=bc, force_impl=force, precision=hist_precision)
     if nk > 1:
         lev0 = make_batched_level_fn(0, nk, F, B, n_padded,
-                                     precision=hist_precision,
-                                     subtract=(hist_mode == "subtract"))
+                                     subtract=(hist_mode == "subtract"),
+                                     **kw)
         if hist_mode == "subtract":
             scan_lev = make_batched_scan_level_fn(W, nk, F, B, n_padded,
-                                                  precision=hist_precision)
+                                                  **kw)
         else:
             scan_lev = make_batched_level_fn(max_depth - 1, nk, F, B,
-                                             n_padded,
-                                             precision=hist_precision,
-                                             subtract=False)
+                                             n_padded, subtract=False,
+                                             **kw)
+    elif hist_mode == "subtract":
+        lev0 = make_subtract_level_fn(0, F, B, n_padded, **kw)
+        scan_lev = make_scan_level_fn(W, F, B, n_padded, **kw)
     else:
-        if hist_mode == "subtract":
-            lev0 = make_subtract_level_fn(0, F, B, n_padded,
-                                          precision=hist_precision)
-            scan_lev = make_scan_level_fn(W, F, B, n_padded,
-                                          precision=hist_precision)
-        else:
-            lev0 = make_hist_fn(1, F, B, n_padded,
-                                precision=hist_precision)
-            scan_lev = make_hist_fn(W, F, B, n_padded,
-                                    precision=hist_precision)
+        lev0, scan_lev = (
+            make_varbin_hist_fn(L, F, bc, B, n_padded, force_impl=force,
+                                precision=hist_precision) if varbin
+            else make_hist_fn(L, F, B, n_padded, precision=hist_precision)
+            for L in (1, W))
 
     def _collapse(valid, ch):
         # the level path's dead-slot stat collapse (axis=-1 indexing
@@ -1221,13 +1275,16 @@ def _make_scan_build(max_depth: int, nbins: int, F: int, n_padded: int,
                                min_split_improvement, mask, reg_alpha,
                                gamma, min_child_weight)
 
+        # the histogram sites' code plane: the packed ids where the
+        # variable-bin kernel reads them, built once a tree
+        hcodes = offset_codes(codes, bc, nbins) if varbin else codes
         # ---- level 0 outside the scan (root: no carry, no sibling)
         if hist_mode == "subtract":
-            H, Hc = lev0(codes, leaf, g, h, w)
+            H, Hc = lev0(hcodes, leaf, g, h, w)
             H_carry = jnp.pad(Hc, ((0, 0), (0, 0), (0, Wp - 1), (0, 0),
                                    (0, 0)))
         else:
-            H = lev0(codes, leaf, g, h, w)
+            H = lev0(hcodes, leaf, g, h, w)
         feat, bin_, na_left, gain, valid, children = _split(H, draw_mask(0))
         thr = edges_mat[feat, jnp.clip(bin_, 0, nbins - 1)]
         leaf = partition(codes, leaf, feat, bin_, na_left, valid,
@@ -1247,9 +1304,9 @@ def _make_scan_build(max_depth: int, nbins: int, F: int, n_padded: int,
                 leaf, alive, children = carry
             dead = ~jnp.any(alive)
             if hist_mode == "subtract":
-                H, H_carry = scan_lev(codes, leaf, g, h, w, H_carry, dead)
+                H, H_carry = scan_lev(hcodes, leaf, g, h, w, H_carry, dead)
             else:
-                H = scan_lev(codes, leaf, g, h, w)
+                H = scan_lev(hcodes, leaf, g, h, w)
             feat, bin_, na_left, gain, valid, ch = _split(H, mask)
             valid = valid & alive
             children = _collapse(valid, ch)
@@ -1311,12 +1368,13 @@ def _make_scan_build(max_depth: int, nbins: int, F: int, n_padded: int,
                 (ps.any(axis=2) & ps[:, :, 0]) | ~ps.any(axis=2))
             return ps & tree_mask[:, None, :]
 
+        hcodes = offset_codes(codes, bc, nbins) if varbin else codes
         if hist_mode == "subtract":
-            H, Hc = lev0(codes, leaf, g, h, wK)
+            H, Hc = lev0(hcodes, leaf, g, h, wK)
             H_carry = jnp.pad(Hc, ((0, 0), (0, 0), (0, 0), (0, Wp - 1),
                                    (0, 0), (0, 0)))
         else:
-            H = lev0(codes, leaf, g, h, wK)
+            H = lev0(hcodes, leaf, g, h, wK)
         feat, bin_, na_left, gain, valid, children = \
             fused_best_splits_batched(
                 H, nbins, reg_lambda, min_rows, min_split_improvement,
@@ -1343,10 +1401,10 @@ def _make_scan_build(max_depth: int, nbins: int, F: int, n_padded: int,
             # and routing are the identity for it)
             dead = ~jnp.any(alive)
             if hist_mode == "subtract":
-                H, H_carry = scan_lev(codes, leaf, g, h, wK, H_carry,
+                H, H_carry = scan_lev(hcodes, leaf, g, h, wK, H_carry,
                                       dead)
             else:
-                H = scan_lev(codes, leaf, g, h, wK)
+                H = scan_lev(hcodes, leaf, g, h, wK)
             feat, bin_, na_left, gain, valid, ch = \
                 fused_best_splits_batched(
                     H, nbins, reg_lambda, min_rows,
@@ -1515,11 +1573,12 @@ def resolve_hist_layout(params, *, hist_mode=None, mono=None,
 
 
 def varbin_kernel_engages(bin_counts, nbins: int, F: int) -> bool:
-    """Whether the variable-bin packed kernel would carry this frame's
-    histogram levels — make_build_tree_fn's gate, factored out so
-    resolve_tree_program shares it: the scan build composes with the
-    uniform kernels only, so tree_program="auto" keeps per-level
-    programs where varbin wins (the autotuner arbitrates the rest)."""
+    """Whether the variable-bin packed kernel may carry this frame's
+    histogram levels: on the TPU (or forced off it, interpret Pallas, by
+    H2O3_TPU_HIST_IMPL=varbin) and where packing pays, every feature's
+    8-padded segment with its NA and spare slots under the uniform
+    ``F * (nbins + 1)`` one-hot rows.  The frame's half of the kernel
+    rule; hist_site_kernel adds the site's width for both tree programs."""
     if bin_counts is None:
         return False
     if not (_on_tpu() or os.environ.get("H2O3_TPU_HIST_IMPL", "") == "varbin"):
@@ -1538,12 +1597,12 @@ def resolve_tree_program(params, *, hist_layout: str = "dense", mono=None,
     that route through ``autotune.resolve_tree_knobs`` get the tuned
     choice instead, so with ``H2O3_TPU_AUTOTUNE=off`` the pipeline stays
     bit-identical to the pre-scan per-level path.  The scan composes
-    with the dense layout, uniform kernels and the plain (non-mono /
-    non-EFB) split search at effective depth >= 2; an EXPLICIT "scan"
-    raises for missing features (mono / EFB / engaged sparse levels /
-    depth < 2) but is allowed to forfeit
-    the variable-bin kernel (the one-launch program vs the packed
-    per-feature kernel is a cost tradeoff, not a correctness one)."""
+    with the dense layout and the plain (non-mono / non-EFB) split
+    search at effective depth >= 2; an EXPLICIT "scan" raises for
+    missing features (mono / EFB / engaged sparse levels / depth < 2).
+    It forfeits no kernel: the scan runs the variable-bin kernel wherever
+    the level path's rule gives it to the scan's width
+    (hist_site_kernel)."""
     prog = str(getattr(params, "tree_program", "auto")).lower()
     if prog not in ("level", "scan", "auto"):
         raise ValueError(

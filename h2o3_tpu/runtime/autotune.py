@@ -276,8 +276,10 @@ def _tree_candidates(F: int, N: int, K: int, max_depth: int, nbins: int,
                    else (tuned.get("_split_mode_pin", "fused"),))
     if mono is not None or plan is not None:
         split_modes = ("separate",)
-    # the scan-fused program composes with dense uniform kernels only,
-    # and needs >= 2 effective levels.  The depth gate is conservative
+    # the scan-fused program composes with the dense layout only (under
+    # either histogram kernel, uniform or variable-bin: the builder asks
+    # shared.hist_site_kernel at the scan's width) and needs >= 2
+    # effective levels.  The depth gate is conservative
     # w.r.t. the builder (row cap from N <= n_padded), so a tuner-picked
     # "scan" can never hit the builder's fail-fast validation.
     row_cap = max(1, int(math.ceil(math.log2(max(N, 2)))) + 1)

@@ -42,7 +42,7 @@ def test_chip_smoke_rehearsal_runs_every_phase_and_never_reports_ok():
     assert [ln["phase"] for ln in lines] == [
         "init", "sync", "ingest", "frame_airlines", "train_xgboost",
         "parity_hist_mode", "parity_split_mode", "parity_tree_program",
-        "train_gbm_7class", "frame_higgs", "train_glm",
+        "parity_tree_program_varbin", "train_gbm_7class", "frame_higgs", "train_glm",
         "train_deeplearning", "score", "serve"]
     assert not any(ln.get("ok") for ln in lines)
     assert all(ln["platform"] == "cpu" and ln["device_kind"]

@@ -344,13 +344,15 @@ def _hist_kernel(builder):
     return program
 
 
-def _scan_level(hist, sds, rows, K=0):
+def _scan_level(hist, sds, rows, K=0, bin_counts=None):
     W, n = 4, 1 << 12       # the compaction around the kernel compiles slowly
     lead = (K,) if K else ()
     row_k = (None, rows) if K else (rows,)
     make = hist.make_batched_scan_level_fn if K else hist.make_scan_level_fn
-    fn = make(W, *lead, F, B, n)
-    return fn, (sds((F, n), jnp.int32, None, rows),
+    fn = make(W, *lead, F, B, n, bin_counts=bin_counts)
+    # the packed kernel reads offset_codes' int16 ids
+    return fn, (sds((F, n), jnp.int16 if bin_counts else jnp.int32, None,
+                    rows),
                 sds(lead + (n,), jnp.int32, *row_k),
                 *(sds(lead + (n,), jnp.float32, *row_k),) * 3,
                 sds((1,) + lead + (3, W // 2, F, B), jnp.float32, rows),
@@ -456,6 +458,12 @@ KERNELS = {
     # under vmap the scope of name= alone reads vmap(hist_uniform)
     "hist_uniform@vmap": _hist_kernel(
         functools.partial(_scan_level, K=3)),
+    # the scan program's body under the packed kernel (PR 38): the same two
+    # scopes, so that hist_kernel_share goes on finding it
+    "hist_varbin@cond": _hist_kernel(
+        functools.partial(_scan_level, bin_counts=BIN_COUNTS)),
+    "hist_varbin@vmap": _hist_kernel(
+        functools.partial(_scan_level, K=3, bin_counts=BIN_COUNTS)),
 }
 
 

@@ -6,6 +6,8 @@ on every knob combination it supports (padding slots are inert, masks
 are pre-drawn with the level path's exact key sequence).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -103,6 +105,108 @@ def test_scan_matches_level_batched(cl, rng, hm):
         np.testing.assert_array_equal(np.asarray(lo[i]), np.asarray(so[i]))
 
 
+# ------------------------------------------- the variable-bin kernel
+
+_RAGGED = (32, 5, 7, 32, 3)     # per-feature bins in use: the frame packs
+
+
+def _ragged_problem(rng, bin_counts=_RAGGED, N=512, nbins=32, K=0):
+    """Codes with ragged per-feature bin counts and NAs, as a frame with
+    categoricals gives them; ``K`` > 0 for the batched K-tree build."""
+    F = len(bin_counts)
+    codes = jnp.asarray(np.stack([
+        np.where(rng.random(N) < 0.1, nbins, rng.integers(0, bc, N))
+        for bc in bin_counts]), jnp.int32)
+    lead = (K,) if K else ()
+    g = jnp.asarray(rng.normal(size=lead + (N,)), jnp.float32)
+    h = jnp.ones(lead + (N,), jnp.float32)
+    w = jnp.asarray(rng.random(N) > 0.1, jnp.float32)
+    edges = jnp.sort(jnp.asarray(rng.normal(size=(F, nbins)), jnp.float32),
+                     axis=1)
+    key = jax.random.split(jax.random.PRNGKey(11), K) if K \
+        else jax.random.PRNGKey(7)
+    tm = jnp.ones(lead + (F,), bool)
+    return (codes, g, h, w, edges, key, 0.5, 2.0, 1e-5, 0.1, 0.8, tm, 0.0,
+            0.0, 0.0)
+
+
+def _pallas_kernels(fn, args):
+    """Names of the Pallas kernels in a build's jaxpr."""
+    return set(re.findall(r"name=(hist_\w+)",
+                          str(jax.make_jaxpr(fn.orig)(*args))))
+
+
+@pytest.mark.parametrize("nk", [1, 3])
+@pytest.mark.parametrize("hm", ["full", "subtract"])
+def test_scan_matches_level_varbin(cl, rng, monkeypatch, hm, nk):
+    """With the variable-bin kernel forced off-TPU (interpret Pallas) on a
+    frame with ragged bin counts, the scan runs it at both of its sites
+    and grows the level program's tree, bit for bit."""
+    monkeypatch.setenv("H2O3_TPU_HIST_IMPL", "varbin")
+    F, N, nbins, md = len(_RAGGED), 512, 32, 4
+    args = _ragged_problem(rng, K=nk if nk > 1 else 0)
+    kw = dict(bin_counts=_RAGGED, hist_mode=hm, nk=nk, split_mode="fused")
+    lv = make_build_tree_fn(md, nbins, F, N, "f32", **kw)
+    sc = make_build_tree_fn(md, nbins, F, N, "f32", tree_program="scan",
+                            **kw)
+    assert _pallas_kernels(sc, args) == {"hist_varbin"}
+    _assert_trees_equal(lv(*args), sc(*args))
+
+
+def _same_program(a, b, args):
+    return str(jax.make_jaxpr(a.orig)(*args)) == \
+        str(jax.make_jaxpr(b.orig)(*args))
+
+
+def test_scan_width_past_varbin_bounds_is_the_uniform_program(
+        cl, rng, monkeypatch):
+    """A scan whose width passes the rule's bounds (here the kernel's
+    result no longer fits the VMEM bound, set low enough for a CPU test)
+    is the build it is without ``bin_counts``, operation for operation,
+    while the level program keeps the packed kernel on the levels that
+    fit; both grow the same tree (a frame whose sums are exact in float32,
+    since two kernels add in two orders)."""
+    from h2o3_tpu.models.tree import hist, shared
+    monkeypatch.setenv("H2O3_TPU_HIST_IMPL", "varbin")
+    F, N, nbins, md = len(_RAGGED), 512, 32, 5
+    B = nbins + 1
+    # 4 slots fit, the scan's 16 (hist_mode="full", depth 5) do not
+    monkeypatch.setattr(hist, "_HIST_RESULT_VMEM_BYTES", F * B * 3 * 4 * 4)
+    assert shared.hist_site_kernel(4, F, nbins, _RAGGED) == "varbin"
+    assert shared.hist_site_kernel(16, F, nbins, _RAGGED) == "einsum"
+    args = list(_ragged_problem(rng))
+    args[1] = jnp.round(args[1] * 8) / 8        # exact sums in float32
+    kw = dict(hist_mode="full", split_mode="fused")
+    sc = make_build_tree_fn(md, nbins, F, N, "f32", bin_counts=_RAGGED,
+                            tree_program="scan", **kw)
+    plain = make_build_tree_fn(md, nbins, F, N, "f32", tree_program="scan",
+                               **kw)
+    assert _same_program(sc, plain, args)
+    assert _pallas_kernels(sc, args) == set()
+    lv = make_build_tree_fn(md, nbins, F, N, "f32", bin_counts=_RAGGED,
+                            **kw)
+    assert _pallas_kernels(lv, args) == {"hist_varbin"}
+    _assert_trees_equal(lv(*args), sc(*args))
+
+
+@pytest.mark.parametrize("hm", ["full", "subtract"])
+def test_scan_without_varbin_is_the_uniform_program(cl, rng, monkeypatch,
+                                                    hm):
+    """On a frame whose columns all use every bin the packed kernel does
+    not engage, forced or not: the scan build is the program it is without
+    ``bin_counts`` (the parent's form), operation for operation."""
+    monkeypatch.setenv("H2O3_TPU_HIST_IMPL", "varbin")
+    F, N, nbins, md = 5, 512, 32, 4
+    full_bins = (nbins,) * F
+    args = _ragged_problem(rng, bin_counts=full_bins)
+    kw = dict(hist_mode=hm, split_mode="fused", tree_program="scan")
+    sc = make_build_tree_fn(md, nbins, F, N, "f32", bin_counts=full_bins,
+                            **kw)
+    plain = make_build_tree_fn(md, nbins, F, N, "f32", **kw)
+    assert _same_program(sc, plain, args)
+    assert _pallas_kernels(sc, args) == set()
+
+
 # ------------------------------------------------------- knob semantics
 
 def test_scan_rejects_unsupported_shapes(cl):
@@ -179,6 +283,56 @@ def test_estimator_scan_level_same_trees(cl, model):
                              bitwise=True)
     assert scan.output["tree_program"] == "scan"
     assert level.output["tree_program"] == "level"
+
+
+def _hist_kernel_counts():
+    from h2o3_tpu.runtime import observability as obs
+    return {(p, k): obs.counter("tree_hist_kernel_total", program=p,
+                                kernel=k).value
+            for p in ("scan", "level")
+            for k in ("varbin", "uniform", "einsum")}
+
+
+def _one_rise(before, after):
+    return {k: after[k] - before[k] for k in after if after[k] != before[k]}
+
+
+def test_hist_kernel_counter_scan_varbin(cl, monkeypatch):
+    """``tree_hist_kernel_total{program, kernel}``: one increment a fit.
+    Under ``tree_program="scan"`` with the variable-bin kernel forced on a
+    frame with categoricals it names the scan and the packed kernel, and
+    the fit grows the level program's trees."""
+    monkeypatch.setenv("H2O3_TPU_HIST_IMPL", "varbin")
+    r = np.random.default_rng(3)
+    n = 400
+    x0 = r.normal(size=n)
+    c1 = r.integers(0, 3, n)
+    c2 = r.integers(0, 5, n)
+    fr = Frame.from_numpy(
+        {"x0": x0,
+         "c1": np.array(["a", "b", "c"], dtype=object)[c1],
+         "c2": np.array(list("vwxyz"), dtype=object)[c2],
+         "y": x0 + (c1 == 1) - 0.5 * (c2 == 3) + 0.1 * r.normal(size=n)},
+        key="scan_counter_cat")
+    kw = dict(response_column="y", ntrees=2, max_depth=3, nbins=32, seed=5,
+              reproducible=True)
+    before = _hist_kernel_counts()
+    m_sc = GBM(**kw, tree_program="scan").train(fr)
+    mid = _hist_kernel_counts()
+    assert _one_rise(before, mid) == {("scan", "varbin"): 1}
+    m_lv = GBM(**kw, tree_program="level").train(fr)
+    assert _one_rise(mid, _hist_kernel_counts()) == {("level", "varbin"): 1}
+    np.testing.assert_array_equal(_pred(m_lv, fr), _pred(m_sc, fr))
+
+
+def test_hist_kernel_counter_level_einsum(cl):
+    """Off the TPU, nothing forced: the level program's widest level runs
+    the einsum."""
+    fr = _reg_frame(key="scan_counter_num")
+    before = _hist_kernel_counts()
+    GBM(**_KW, tree_program="level").train(fr)
+    assert _one_rise(before, _hist_kernel_counts()) == \
+        {("level", "einsum"): 1}
 
 
 def test_gbm_multinomial_scan_bitwise(cl):
