@@ -140,7 +140,7 @@ class Frame:
             if v.data is None:
                 out.append(Vec.from_numpy(v.host_data[: v.nrows][index], v.type))
             else:
-                col = np.asarray(v.data)[: v.nrows][index]
+                col = v.to_numpy()[index]
                 out.append(Vec.from_numpy(col, v.type, domain=v.domain,
                                           time_base=v.time_base))
         from . import lineage
@@ -192,7 +192,7 @@ class Frame:
             v = self.vec(c)
             if v.data is None:
                 raise TypeError(f"column {c!r} of type {v.type} is host-only")
-            parts.append(v.data.astype(dtype))
+            parts.append(v.values().astype(dtype))
         mat = jnp.stack(parts, axis=1)
         from ..runtime.cluster import put_sharded
         mat = put_sharded(mat, cl.matrix_sharding)

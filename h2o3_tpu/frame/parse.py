@@ -31,7 +31,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from .frame import Frame
-from .vec import Vec, T_CAT, T_NUM, T_STR, T_TIME
+from .vec import Vec, T_CAT, T_NUM, T_STR, T_TIME, takes_exact_int
 from ..runtime import dkv
 
 _NA = {"", "na", "n/a", "nan", "null", "none", "?", "-", "NA", "NaN", "NULL", "None"}
@@ -92,7 +92,7 @@ def _column_to_vec(values: np.ndarray, name: str,
     """Type-guess one parsed column and build its Vec (ParseSetup analog)."""
     values = np.asarray(values)
     if values.dtype.kind in "ifb" and coltype in (None, T_NUM):
-        return Vec.from_numpy(values.astype(np.float32), T_NUM)
+        return Vec.from_numpy(values, T_NUM)    # uncast: the payload rule reads them
     if values.dtype.kind == "M":  # datetime64 from pandas
         ms = values.astype("datetime64[ms]").astype("int64").astype(np.float64)
         ms[np.isnat(values)] = np.nan
@@ -277,7 +277,9 @@ def _parse_csv_native(path_or_buf, header, sep, col_names,
         for j in range(ncols):
             if dev_chunks[j] is None:
                 continue
-            if Ft[:, j].any():           # text seen: column is host-bound
+            # text seen, or whole numbers float32 would round (the payload
+            # rule reads a column whole): the column is host-bound
+            if Ft[:, j].any() or takes_exact_int(Vt[:, j]):
                 dev_chunks[j] = None
                 continue
             dev_chunks[j].append(
@@ -544,7 +546,7 @@ def parse_files(paths: Sequence[str],
             arr = np.asarray(raw_col)
             want = col_types.get(n)
             if arr.dtype.kind in "if" and want in (None, T_NUM) \
-                    and not host_chunks[n]:
+                    and not host_chunks[n] and not takes_exact_int(arr):
                 dev_chunks[n].append(jnp.asarray(arr, jnp.float32))
             else:
                 if dev_chunks[n]:      # late type widening: pull back

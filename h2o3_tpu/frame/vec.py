@@ -8,8 +8,13 @@ compression codecs chosen at write time (fvec/NewChunk.java:1133).
 
 TPU-native redesign: a Vec's payload is ONE row-sharded ``jax.Array`` padded
 to the cluster row multiple — XLA wants flat dtypes and static shapes, so the
-codec zoo collapses to dtype narrowing (float32 for numeric/time, int32 codes
-for categoricals).  Missing values are NaN (numeric) or code -1 (categorical).
+codec zoo collapses to three payloads: float32 for numeric/time, int32 codes
+for categoricals, and int32 values for a numeric column of whole numbers past
+float32's exact range (``takes_exact_int``: the ``C4Chunk`` analog; a row id
+or a join key over 100M rows needs 27 bits, and float32 has 24).  Missing
+values are NaN (float32), code -1 (categorical) or ``INT_NA`` (exact
+integers); ``Vec.isna()`` is the one accessor that knows which.  One payload
+a column: ``numeric_data()`` is the float32 view of any of them.
 Strings/UUIDs stay host-side (numpy object arrays) — they never participate in
 device compute (SURVEY.md §7 "keep string columns host-side only").
 Rollups are computed lazily in a single fused XLA pass and cached, exactly
@@ -38,6 +43,37 @@ T_STR = "str"
 T_UUID = "uuid"
 
 _DEVICE_TYPES = (T_NUM, T_CAT, T_TIME, T_BAD)
+
+# an exact-integer column's missing value: no value the rule admits
+INT_NA = np.int32(-(1 << 31))
+_F32_EXACT = 1 << 24            # float32 holds every integer up to here
+_I32_LIMIT = 1 << 31
+
+
+def takes_exact_int(arr: np.ndarray) -> bool:
+    """The one rule for a numeric column's payload: int32, exact, where
+    every value is a whole number (or NA) and the largest magnitude is over
+    2^24 and under 2^31; float32, as ever, for all else (fractions, whole
+    numbers float32 already holds exactly, and magnitudes from 2^31 on,
+    which still round: ROADMAP "Cannot run yet").  It reads the values as
+    the caller holds them, before any cast."""
+    if arr.size == 0 or arr.dtype.kind not in "iuf":
+        return False
+    if arr.dtype.kind in "iu":
+        top = max(abs(int(arr.min())), abs(int(arr.max())))
+        return _F32_EXACT < top < _I32_LIMIT
+    with np.errstate(invalid="ignore"):
+        lo, hi = np.fmin.reduce(arr), np.fmax.reduce(arr)   # NaN-skipping
+        top = max(-float(lo), float(hi))
+        if not _F32_EXACT < top < _I32_LIMIT:                # NaN compares False
+            return False
+        return bool(np.all((arr == np.rint(arr)) | np.isnan(arr)))
+
+
+def exact_int_host(payload: np.ndarray) -> np.ndarray:
+    """An exact-integer payload as host values: float64, which holds every
+    int32 exactly, with NaN for NA."""
+    return np.where(payload == INT_NA, np.nan, payload.astype(np.float64))
 
 
 def encode_domain(svals: np.ndarray, domain: Sequence[str],
@@ -221,6 +257,8 @@ class Vec:
         n = len(arr)
         if vtype in (T_STR, T_UUID):
             return Vec(None, vtype, n, host_data=np.asarray(arr, dtype=object))
+        from ..runtime import observability as obs
+        from ..runtime.cluster import put_sharded
         padded = cl.pad_rows(n)
         host_data = None
         if vtype == T_CAT:
@@ -231,6 +269,14 @@ class Vec:
                 domain = labels
             buf = np.full(padded, -1, dtype=np.int32)
             buf[:n] = arr.astype(np.int32)
+        elif vtype == T_NUM and takes_exact_int(arr):
+            buf = np.full(padded, INT_NA, dtype=np.int32)
+            if arr.dtype.kind == "f":
+                with np.errstate(invalid="ignore"):
+                    buf[:n] = np.where(np.isnan(arr), INT_NA, arr).astype(np.int32)
+            else:
+                buf[:n] = arr.astype(np.int32)
+            obs.inc("vec_exact_int_columns_total")
         else:
             vals = arr.astype(np.float64)
             if vtype == T_TIME:
@@ -241,8 +287,6 @@ class Vec:
                 vals = (vals - time_base) / 1000.0
             buf = np.full(padded, np.nan, dtype=np.float32)
             buf[:n] = vals.astype(np.float32)
-        from ..runtime import observability as obs
-        from ..runtime.cluster import put_sharded
         data = put_sharded(buf, cl.row_sharding)
         obs.inc("transfer_bytes_total", buf.nbytes, dir="h2d")
         return Vec(data, vtype, n, domain=domain, host_data=host_data,
@@ -258,6 +302,15 @@ class Vec:
         return self.type == T_CAT
 
     @property
+    def is_exact_int(self) -> bool:
+        """Whether this numeric column holds int32 values (``takes_exact_int``)
+        and not float32; read off the payload, so there is no second flag
+        to drift."""
+        payload = self._spill if self._spill is not None else self._device
+        return (self.type == T_NUM and payload is not None
+                and payload.dtype == np.int32)
+
+    @property
     def cardinality(self) -> int:
         return len(self.domain) if self.domain is not None else -1
 
@@ -271,6 +324,18 @@ class Vec:
         """Boolean [padded] mask of real (non-padding) rows."""
         idx = jnp.arange(self.padded_len)
         return idx < self.nrows
+
+    def isna(self) -> jax.Array:
+        """Boolean [padded] mask of missing values (padding rows hold the
+        payload's NA too): the one place that knows each payload's
+        sentinel."""
+        if self.data is None:
+            raise TypeError(f"Vec of type {self.type} has no device payload")
+        if self.type == T_CAT:
+            return self.data < 0
+        if self.is_exact_int:
+            return self.data == INT_NA
+        return jnp.isnan(self.data)
 
     # --------------------------------------------------------------- rollups
     def rollups(self) -> RollupStats:
@@ -306,12 +371,20 @@ class Vec:
         return self._rollups
 
     def numeric_data(self) -> jax.Array:
-        """Payload as float32 with NaN missing (cat codes -1 -> NaN)."""
+        """Payload as float32 with NaN missing (cat codes -1 -> NaN, exact
+        integers rounded to float32): what models, rollups and ``DataInfo``
+        read."""
         if self.data is None:
             raise TypeError(f"Vec of type {self.type} has no device payload")
-        if self.type == T_CAT:
-            return jnp.where(self.data < 0, jnp.nan, self.data.astype(jnp.float32))
+        if self.type == T_CAT or self.is_exact_int:
+            return jnp.where(self.isna(), jnp.nan, self.data.astype(jnp.float32))
         return self.data
+
+    def values(self) -> jax.Array:
+        """The payload as a model's raw-value design reads it: a numeric
+        column in float32 whichever form it is held in, a categorical's
+        int32 codes (-1 NA) as they are."""
+        return self.numeric_data() if self.is_exact_int else self.data
 
     def mean(self) -> float:
         return self.rollups().mean
@@ -332,23 +405,29 @@ class Vec:
     def to_numpy(self) -> np.ndarray:
         """Materialize the logical (unpadded) column on host.
 
-        TIME returns the exact float64 ms-since-epoch kept host-side.
+        TIME returns the exact float64 ms-since-epoch kept host-side, an
+        exact-integer column its integers in float64 (NaN for NA).
         """
         if self.type == T_TIME and self.host_data is not None:
             return self.host_data[: self.nrows]
-        if self._spill is not None:          # serve from host, no restore
-            return self._spill[: self.nrows]
-        if self.data is None:
+        if self._spill is None and self.data is None:
             return self.host_data[: self.nrows]
-        from ..runtime.cluster import fetch
-        return fetch(self.data)[: self.nrows]
+        if self._spill is not None:          # serve from host, no restore
+            host = self._spill[: self.nrows]
+        else:
+            from ..runtime.cluster import fetch
+            host = fetch(self.data)[: self.nrows]
+        return exact_int_host(host) if self.is_exact_int else host
 
     def canonical_host(self) -> np.ndarray:
         """Engine-independent host form for lineage hashing/replicas:
-        num -> float32, cat -> int32 codes (-1 NA), time -> float64
+        num -> float32 (int32 where the column is held exactly), cat ->
+        int32 codes (-1 NA), time -> float64
         ms-since-epoch, str/uuid -> object (None NA).  A re-materialized
         shard is correct iff its canonical bytes match the original's."""
         arr = self.to_numpy()
+        if self.is_exact_int:                # the payload itself, NA as INT_NA
+            return np.where(np.isnan(arr), INT_NA, arr).astype(np.int32)
         if self.type == T_CAT:
             return np.ascontiguousarray(arr, dtype=np.int32)
         if self.type == T_TIME:

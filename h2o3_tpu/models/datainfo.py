@@ -234,7 +234,7 @@ class ColumnSpec:
 def _numeric_values(vec: Vec, s: ColumnSpec) -> jax.Array:
     """A numeric column's device values, a time column moved onto the
     training frame's time base."""
-    x = vec.data
+    x = vec.values()
     if s.type == T_TIME and abs(vec.time_base - s.time_base) > 0:
         x = x + (vec.time_base - s.time_base) / 1000.0
     return x
@@ -486,7 +486,7 @@ class DataInfo:
         """Map a (possibly differently-coded) cat Vec onto training codes."""
         if vec.type != T_CAT:
             # numeric column where a cat was expected: treat values as codes
-            return jnp.where(jnp.isnan(vec.data), -1,
+            return jnp.where(vec.isna(), -1,
                              vec.data).astype(jnp.int32)
         if vec.domain == s.domain:
             return vec.data
@@ -524,7 +524,7 @@ class DataInfo:
             vals = np.array([float(v) for v in self.response_domain],
                             dtype=np.float32)
             vals_dev = jnp.asarray(vals)
-            x = rv.data
+            x = rv.numeric_data()
             code = jnp.argmin(jnp.abs(x[:, None] - vals_dev[None, :]), axis=1)
             exact = jnp.any(x[:, None] == vals_dev[None, :], axis=1)
             return jnp.where(exact, code, -1).astype(jnp.float32)
@@ -559,7 +559,7 @@ class DataInfo:
                 if s.type == T_CAT:
                     w = w * (self._aligned_codes(vec, s) >= 0)
                 else:
-                    w = w * ~jnp.isnan(vec.data)
+                    w = w * ~vec.isna()
         return w
 
     def offsets(self, frame: Frame) -> Optional[jax.Array]:

@@ -242,7 +242,7 @@ def _sketch_edges(vecs, num_idx, n: int, nbins: int, sample: int, weights,
         budget_rows = max(int(2e9) // (4 * len(num_idx)), sample)
         stride = 1 if full_padded <= budget_rows \
             else -(-full_padded // max(sample, 1))
-        X = jnp.stack([vecs[f].data[::stride].astype(jnp.float32)
+        X = jnp.stack([vecs[f].values()[::stride].astype(jnp.float32)
                        for f in num_idx], axis=0)
         padded = int(X.shape[1])
         n_eff = min(-(-n // stride), padded)
@@ -277,7 +277,7 @@ def _sketch_edges(vecs, num_idx, n: int, nbins: int, sample: int, weights,
                 if len(idx) > sample:
                     idx = idx[:: -(-len(idx) // sample)]
                 idx_d = jnp.asarray(idx, jnp.int32)
-                X2 = jnp.stack([jnp.take(vecs[f].data, idx_d)
+                X2 = jnp.stack([jnp.take(vecs[f].values(), idx_d)
                                 .astype(jnp.float32) for f in num_idx],
                                axis=0)
                 sk2 = _make_sketch_fn(len(idx), len(idx), len(num_idx),
@@ -321,7 +321,7 @@ def encode_bins(frame: Frame, features: List[str], edges_list, is_cat,
     """Encode columns as bin codes — ONE cached device program per
     geometry (padded length, feature count, edge width, cat pattern)."""
     vecs = [frame.vec(name) for name in features]
-    X = jnp.stack([v.data.astype(jnp.float32) for v in vecs], axis=0)
+    X = jnp.stack([v.values().astype(jnp.float32) for v in vecs], axis=0)
     ecounts = tuple(len(e) for e in edges_list)
     # E width covers every NUMERIC group bucket (next pow-4 of the widest
     # numeric) AND every categorical edge row stored alongside

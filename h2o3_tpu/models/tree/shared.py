@@ -2161,7 +2161,7 @@ class SharedTreeModel(Model):
                 cols.append(jnp.where(codes < 0, jnp.nan,
                                       codes.astype(jnp.float32)))
             else:
-                cols.append(vec.data)
+                cols.append(vec.values())
         return jnp.stack(cols, axis=1)
 
     def _raw_scores(self, X: jax.Array):

@@ -179,7 +179,7 @@ class UpliftDRF(SharedTree):
             treat = (tvec.data == (len(tvec.domain) - 1)) \
                 .astype(jnp.float32)
         else:
-            treat = (jnp.nan_to_num(tvec.data) > 0).astype(jnp.float32)
+            treat = (jnp.nan_to_num(tvec.numeric_data()) > 0).astype(jnp.float32)
         binned = fit_bins(frame, [s.name for s in di.specs], nbins=p.nbins,
                           histogram_type=p.histogram_type,
                           seed=p.effective_seed())

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..frame.frame import Frame
-from ..frame.vec import Vec, T_CAT, T_NUM
+from ..frame.vec import Vec, T_CAT, T_NUM, INT_NA
 from ..runtime import dkv
 from . import ops
 
@@ -122,8 +122,32 @@ def _numeric(fr: Frame) -> jnp.ndarray:
     return jnp.stack([v.numeric_data() for v in fr.vecs], axis=1)
 
 
+_COMPARE = ("<", "<=", ">", ">=", "==", "!=")
+
+
+def _exact_operand(x):
+    """``(int32 values, NA mask)`` where int32 holds ``x`` exactly: a frame or
+    vec whose columns all have the exact-integer payload, or a whole scalar
+    under 2^31.  Else None."""
+    if not isinstance(x, (Frame, Vec)):
+        whole = isinstance(x, (int, float, np.number)) \
+            and float(x).is_integer() and abs(float(x)) < 2.0 ** 31
+        return (jnp.int32(int(x)), False) if whole else None
+    vecs = x.vecs if isinstance(x, Frame) else [x]
+    if not vecs or any(v.data is None or not v.is_exact_int for v in vecs):
+        return None
+    data = jnp.stack([v.data for v in vecs], axis=1)
+    return data, data == INT_NA
+
+
 def _binop(op, l, r):
-    """Elementwise arithmetic over frames/vecs/scalars — fused on device."""
+    """Elementwise arithmetic over frames/vecs/scalars — fused on device.
+
+    Operands are float32 (``numeric_data()``), so arithmetic on an
+    exact-integer column rounds past 2^24.  A comparison whose two sides are
+    both exact (int32 columns, whole scalars) is made in int32, NA comparing
+    as NaN does: ``(== id 99999999)`` keeps one id, not the eight that share
+    its float32."""
     if not isinstance(l, (Frame, Vec)) and not isinstance(r, (Frame, Vec)):
         import operator as _o
         fn = {"+": _o.add, "-": _o.sub, "*": _o.mul, "/": _o.truediv,
@@ -140,7 +164,6 @@ def _binop(op, l, r):
         if isinstance(x, Vec):
             return x.numeric_data()[:, None]
         return x
-    la, ra = arr(l), arr(r)
     fn = {
         "+": jnp.add, "-": jnp.subtract, "*": jnp.multiply,
         "/": jnp.divide, "^": jnp.power, "%": jnp.mod,
@@ -149,7 +172,12 @@ def _binop(op, l, r):
         ">=": jnp.greater_equal, "==": jnp.equal, "!=": jnp.not_equal,
         "&": jnp.logical_and, "|": jnp.logical_or,
     }[op]
-    out = fn(la, ra)
+    exact = [_exact_operand(x) for x in (l, r)] if op in _COMPARE else [None]
+    if all(e is not None for e in exact):
+        (la, lna), (ra, rna) = exact
+        out = jnp.where(lna | rna, op == "!=", fn(la, ra))
+    else:
+        out = fn(arr(l), arr(r))
     out = out.astype(jnp.float32)
     ref = l if isinstance(l, (Frame, Vec)) else r
     nrows = ref.nrows

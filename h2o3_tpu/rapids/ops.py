@@ -1,12 +1,19 @@
 """Munging primitives over sharded Frames (the water/rapids Ast* analogs).
 
-sort/merge/group_by/filter run device-side (see device.py for the
-RadixOrder/BinaryMerge redesign); host round-trips are limited to O(1)
-scalars, group-count-sized arrays, and string-typed payloads.
+sort/merge/group_by/filter run device-side, as the named programs of
+device.py (the RadixOrder/BinaryMerge redesign).  ``sort`` is one program
+and no host sync; ``merge`` two programs around the one sync its output's row
+count needs (``rapids_host_syncs_total{op}`` counts them, and one more where
+a frame has host-only columns to gather); ``filter_rows`` one sync for its
+row count; ``group_by`` fetches group-count-sized arrays.  Spans:
+``rapids.sort`` (``sort.order``, ``sort.gather``) and ``rapids.merge``
+(``merge.keys``, ``merge.match``, ``merge.count``, ``merge.gather``);
+``rapids_rows_total{op, side}`` counts rows in and out.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -15,40 +22,53 @@ import numpy as np
 
 from ..frame.frame import Frame
 from ..frame import lineage
-from ..frame.vec import Vec, T_CAT, T_NUM, T_STR, T_TIME
+from ..frame.vec import Vec, T_CAT, T_NUM, T_STR, T_TIME, exact_int_host
+from ..runtime import observability as obs
 from ..runtime.cluster import cluster, fetch
 from . import device as dev
 
 
 def sort(frame: Frame, by: Union[str, Sequence[str]],
          ascending: Union[bool, Sequence[bool]] = True) -> Frame:
-    """Multi-key sort — AstSort / RadixOrder analog, fully on device."""
+    """Multi-key sort — AstSort / RadixOrder analog: one device program
+    (``jit_sort_rows``), no host sync.  Stable; keys compared in their own
+    dtype; NA last under either direction."""
     by = [by] if isinstance(by, str) else list(by)
     asc = [ascending] * len(by) if isinstance(ascending, bool) \
         else list(ascending)
     if len(asc) != len(by):
         raise ValueError("ascending must match by")
-    keys = [dev.sort_key(frame.vec(c)) for c in by]
-    order = dev.lex_order(keys, asc)
-    return lineage.derive(dev.gather_rows(frame, order, frame.nrows), frame,
-                          {"op": "sort", "by": by,
-                           "ascending": [bool(a) for a in asc]})
+    with obs.trace("rapids.sort", rows=frame.nrows, keys=len(by)):
+        with obs.span("sort.order"):
+            keys = tuple(frame.vec(c).data for c in by)
+            kinds = tuple(dev.column_kind(frame.vec(c)) for c in by)
+            cols, _ = dev.device_columns(frame)
+            order, moved = dev.sort_rows(
+                keys, cols, kinds=kinds, ascending=tuple(bool(a) for a in asc),
+                sharding=cluster().row_sharding)
+        with obs.span("sort.gather"):
+            def host_index():
+                idx, = dev.note_host_index("sort", order)
+                return idx[: frame.nrows], np.zeros(frame.nrows, bool)
+            out = dev.wrap_rows(frame, moved, frame.nrows, host_index)
+        obs.inc("rapids_rows_total", frame.nrows, op="sort", side="in")
+        obs.inc("rapids_rows_total", frame.nrows, op="sort", side="out")
+    return lineage.derive(out, frame, {"op": "sort", "by": by,
+                                       "ascending": [bool(a) for a in asc]})
 
 
 def filter_rows(frame: Frame, mask) -> Frame:
-    """Boolean row filter — AstRowSlice analog (device compaction)."""
+    """Boolean row filter — AstRowSlice analog (device compaction: the kept
+    rows first by one sort, then the shared gather)."""
     if isinstance(mask, Vec):
-        m = (mask.data != 0) & mask.valid_mask()
-        if mask.type != T_CAT:
-            m = m & ~jnp.isnan(mask.data)
+        m = (mask.data != 0) & ~mask.isna()
     else:
         host = np.zeros(frame.padded_rows, bool)
         host[: frame.nrows] = np.asarray(mask)[: frame.nrows].astype(bool)
         m = jnp.asarray(host)
     m = m & (jnp.arange(frame.padded_rows) < frame.nrows)
     n_out = int(jnp.sum(m))
-    order = jnp.argsort(~m, stable=True)          # kept rows first, in order
-    return dev.gather_rows(frame, order, n_out)
+    return dev.gather_rows(frame, dev.kept_first(m), n_out, op="filter")
 
 
 def rbind(*frames: Frame) -> Frame:
@@ -109,9 +129,8 @@ def unique(vec: Vec) -> np.ndarray:
     if vec.type == T_CAT:
         codes = np.unique(vec.to_numpy())
         return np.asarray([vec.domain[c] for c in codes if c >= 0])
-    x = np.asarray(jnp.sort(dev.sort_key(vec)))[: vec.nrows]
-    x = x[np.isfinite(x)]
-    return np.unique(x)
+    x = vec.to_numpy()
+    return np.unique(x[~np.isnan(x)])
 
 
 def table(vec: Vec, weights: Optional[Vec] = None) -> Dict[str, float]:
@@ -133,9 +152,9 @@ def table(vec: Vec, weights: Optional[Vec] = None) -> Dict[str, float]:
 
 def ifelse(cond, yes, no) -> Vec:
     """Vectorized conditional — AstIfElse analog."""
-    c = cond.data if isinstance(cond, Vec) else jnp.asarray(cond)
-    y = yes.data if isinstance(yes, Vec) else yes
-    n = no.data if isinstance(no, Vec) else no
+    c = cond.values() if isinstance(cond, Vec) else jnp.asarray(cond)
+    y = yes.values() if isinstance(yes, Vec) else yes
+    n = no.values() if isinstance(no, Vec) else no
     nrows = cond.nrows if isinstance(cond, Vec) else len(np.asarray(cond))
     out = jnp.where(c != 0, y, n)
     return Vec(out.astype(jnp.float32), T_NUM, nrows)
@@ -148,7 +167,7 @@ def hist(vec: Vec, breaks: int = 20) -> Tuple[np.ndarray, np.ndarray]:
     if not np.isfinite(lo) or not np.isfinite(hi) or hi <= lo:
         return np.zeros(breaks), np.linspace(0, 1, breaks + 1)
     edges = np.linspace(lo, hi, breaks + 1)
-    x = vec.data
+    x = vec.numeric_data()
     idx = jnp.clip(((x - lo) / (hi - lo) * breaks).astype(jnp.int32),
                    0, breaks - 1)
     valid = vec.valid_mask() & ~jnp.isnan(x)
@@ -239,7 +258,8 @@ def impute(frame: Frame, column: str, method: str = "mean",
             if np.isfinite(x).any() else 0.0
     else:
         fill = v.mean()
-    data = jnp.where(jnp.isnan(v.data), jnp.float32(fill), v.data)
+    x = v.numeric_data()     # a mean or a median need not be whole: float32
+    data = jnp.where(jnp.isnan(x), jnp.float32(fill), x)
     return _impute_lin(frame.with_vec(column, Vec(data, v.type, v.nrows)),
                        frame, column, method, combine_method)
 
@@ -256,7 +276,7 @@ def cut(vec: Vec, breaks: Sequence[float],
         include_lowest: bool = False, right: bool = True) -> Vec:
     """Numeric -> categorical by interval — AstCut analog."""
     edges = jnp.asarray(list(breaks), jnp.float32)
-    x = vec.data
+    x = vec.numeric_data()
     idx = jnp.searchsorted(edges, x, side="left" if right else "right") - 1
     nb = len(breaks) - 1
     if include_lowest:
@@ -283,7 +303,7 @@ def scale(frame: Frame, center: bool = True,
             r = v.rollups()
             mu = r.mean if center else 0.0
             sd = r.sigma if (scale_ and r.sigma and r.sigma > 0) else 1.0
-            vecs.append(Vec((v.data - mu) / sd, T_NUM, v.nrows))
+            vecs.append(Vec((v.numeric_data() - mu) / sd, T_NUM, v.nrows))
         else:
             vecs.append(v)
     return lineage.derive(Frame(frame.names, vecs), frame,
@@ -295,38 +315,13 @@ def scale(frame: Frame, center: bool = True,
 _AGGS = ("count", "sum", "mean", "min", "max", "var", "sd")
 
 
-def _device_keys(frame: Frame, by: List[str],
-                 cat_remap: Optional[Dict[str, Dict[str, int]]] = None
-                 ) -> List[jax.Array]:
-    """Key columns as float32 device arrays; NA and padding -> +inf."""
-    keys = []
-    for name in by:
-        v = frame.vec(name)
-        if v.type == T_CAT:
-            if cat_remap is not None and name in cat_remap:
-                remap = cat_remap[name]
-                tbl = jnp.asarray(np.array(
-                    [remap[lbl] for lbl in (v.domain or [])] or [0],
-                    np.float32))
-                k = tbl[jnp.clip(v.data, 0, None)]
-                k = jnp.where(v.data < 0, jnp.inf, k)
-            else:
-                k = dev.sort_key(v)
-        elif v.data is None:
-            raise TypeError(f"column {name!r} is host-only (string key)")
-        else:
-            k = jnp.where(jnp.isnan(v.data), jnp.inf, v.data)
-        pad = jnp.arange(frame.padded_rows) >= frame.nrows
-        keys.append(jnp.where(pad, jnp.inf, k))
-    return keys
-
-
 def group_by(frame: Frame, by: Union[str, Sequence[str]],
              aggs: Dict[str, Sequence[str]]) -> Frame:
     """Grouped aggregation — AstGroup analog, device segment-sums.
 
     ``aggs``: {column: [agg, ...]} with aggs from count/sum/mean/min/max/
-    var/sd.  Group ids come from a device lexicographic dense-rank; every
+    var/sd.  Group ids come from a device lexicographic dense-rank
+    (``jit_dense_rank``, keys in their own dtype); every
     aggregate is a ``segment_sum``/``segment_min``/``segment_max`` with the
     rank as segment id (O(N) HBM, no [N, G] one-hot).  Rows with NA in any
     key column are dropped, mirroring AstGroup's default NA handling.
@@ -336,24 +331,17 @@ def group_by(frame: Frame, by: Union[str, Sequence[str]],
         for fn in fns:
             if fn not in _AGGS:
                 raise ValueError(f"unknown agg {fn!r} (have {_AGGS})")
-    keys = _device_keys(frame, by)
-    valid = jnp.ones(frame.padded_rows, bool)
-    for k in keys:
-        valid = valid & jnp.isfinite(k)
-    # collapse ALL columns of any-NA rows to +inf before ranking: a
-    # partial-NA tuple must not consume a dense rank below G (it would
-    # leave a phantom empty group behind when its rows are rerouted)
-    keys = [jnp.where(valid, k, jnp.inf) for k in keys]
-    rank = dev.dense_rank(keys)
-    G = int(jnp.max(jnp.where(valid, rank, -1))) + 1
+    keys = tuple(frame.vec(c).data for c in by)
+    kinds = tuple(dev.column_kind(frame.vec(c)) for c in by)
+    # rows with an NA in any key take the overflow rank G (AstGroup drops
+    # them); a partial-NA tuple consumes no rank below it
+    gid, G = dev.dense_rank(keys, np.int32(frame.nrows), kinds=kinds)
+    G = int(G)
     if G <= 0:
         return Frame.from_numpy(
             {**{n: np.array([], object) for n in by},
              **{f"{fn}_{c}": np.array([]) for c, fns in aggs.items()
                 for fn in fns}})
-    # any-NA-key rows -> overflow segment (AstGroup drops them); minimum()
-    # alone would keep partially-NA tuples that rank below G
-    gid = jnp.where(valid, jnp.minimum(rank, G), G)
     nseg = G + 1
 
     # one representative row per group, for key decode
@@ -369,6 +357,8 @@ def group_by(frame: Frame, by: Union[str, Sequence[str]],
             out_cols[name] = codes.astype(np.int32)
             types[name] = T_CAT
             domains[name] = v.domain or []
+        elif v.is_exact_int:
+            out_cols[name] = exact_int_host(np.asarray(fetch(v.data[rep])))
         else:
             out_cols[name] = np.asarray(fetch(v.data[rep]), np.float64)
 
@@ -428,48 +418,74 @@ def _na_vec(template: Vec, n: int) -> Vec:
     return Vec.from_numpy(np.full(n, np.nan), template.type)
 
 
-def _unmatched_right(left: Frame, right: Frame, by: List[str]) -> Frame:
-    """Right rows whose key matches NO left row (device rank membership)."""
-    cat_remap: Dict[str, Dict[str, int]] = {}
+# a join's output is refused past this many rows: ``expand_counts`` doubles
+# slot numbers in int32, and no column of such a length fits a chip
+_MERGE_MAX_ROWS = 1 << 30
+
+
+def _merge_keys(left: Frame, right: Frame, by: List[str]):
+    """Both sides' keys as ``jit_merge_match`` takes them: its arguments
+    (keys, remaps and row counts of both sides) and the static kinds.
+    A categorical key whose domains differ gets, per side, a small table
+    from its codes onto the union of the two domains (built on the host,
+    applied on the device); an exact-integer key meeting a float32 one
+    makes the float32 side compare as integers."""
+    lkeys, rkeys, lkinds, rkinds, lremaps, rremaps = [], [], [], [], [], []
     for name in by:
         lv, rv = left.vec(name), right.vec(name)
-        if lv.type == T_CAT:
+        if (lv.data is None) or (rv.data is None):
+            raise TypeError(f"merge key {name!r} is a string column; "
+                            "convert to categorical first")
+        if (lv.type == T_CAT) != (rv.type == T_CAT):
+            raise TypeError(f"merge key {name!r} has mismatched types")
+        lk, rk = dev.column_kind(lv), dev.column_kind(rv)
+        lt = rt = None
+        if lk == dev.CAT and (lv.domain or []) != (rv.domain or []):
             shared: Dict[str, int] = {}
             for lbl in (lv.domain or []) + (rv.domain or []):
-                if lbl not in shared:
-                    shared[lbl] = len(shared)
-            cat_remap[name] = shared
-    lkeys = _device_keys(left, by, cat_remap)
-    rkeys = _device_keys(right, by, cat_remap)
-    pl, pr = left.padded_rows, right.padded_rows
-    rank = dev.dense_rank([jnp.concatenate([l, r])
-                           for l, r in zip(lkeys, rkeys)])
-    lrank, rrank = rank[:pl], rank[pl:]
-    lvalid = jnp.ones(pl, bool)
-    for k in lkeys:
-        lvalid &= jnp.isfinite(k)
-    rvalid = jnp.ones(pr, bool)
-    for k in rkeys:
-        rvalid &= jnp.isfinite(k)
-    nseg = pl + pr + 2
-    big = jnp.int32(nseg - 1)
-    lcount = jax.ops.segment_sum(
-        jnp.where(lvalid, 1, 0), jnp.where(lvalid, lrank, big),
-        num_segments=nseg)
-    unmatched = rvalid & (lcount[rrank] == 0)
-    return filter_rows(right, Vec(unmatched.astype(jnp.float32), T_NUM,
+                shared.setdefault(lbl, len(shared))
+            lt, rt = (jnp.asarray(np.array(
+                [shared[lbl] for lbl in (v.domain or [])] or [0], np.int32))
+                for v in (lv, rv))
+        elif {lk, rk} == {dev.INT, dev.F32}:
+            lk, rk = (dev.F32_AS_INT if k == dev.F32 else k for k in (lk, rk))
+        lkeys.append(lv.data), rkeys.append(rv.data)
+        lkinds.append(lk), rkinds.append(rk)
+        lremaps.append(lt), rremaps.append(rt)
+    return (tuple(lkeys), tuple(rkeys), tuple(lremaps), tuple(rremaps),
+            np.int32(left.nrows), np.int32(right.nrows)), \
+        {"lkinds": tuple(lkinds), "rkinds": tuple(rkinds)}
+
+
+def _unmatched_right(left: Frame, right: Frame, by: List[str]) -> Frame:
+    """Right rows whose key (no NA in it) matches NO left row, in right-row
+    order: the match program with the sides exchanged."""
+    args, kinds = _merge_keys(right, left, by)
+    cnt = dev.merge_match(*args, **kinds, how="inner")[0]
+    return filter_rows(right, Vec((cnt == 0).astype(jnp.float32), T_NUM,
                                   right.nrows))
 
 
 def merge(left: Frame, right: Frame, by: Union[str, Sequence[str]],
           how: str = "inner") -> Frame:
-    """Join — AstMerge / BinaryMerge analog, device sort-merge.
+    """Join — AstMerge / BinaryMerge analog, a device sort-merge.
 
-    Single- or multi-key equi-join.  Keys from both frames are dense-ranked
-    together on device; match ranges come from per-rank segment tables and
-    duplicate expansion from a prefix-sum ownership scan (device.py).  Output
-    keeps left-row order with duplicate matches adjacent.  NA keys never
-    match (BinaryMerge semantics).
+    Single- or multi-key equi-join on keys compared in their own dtype
+    (exact integers and categorical levels exactly, float32 as float32).
+    Semantics: NA keys never match; the output is in left-row order;
+    a left row's several matches are adjacent, in right-row order;
+    many-to-many keys are expanded (every pair).  ``left`` keeps every left
+    row (NA in the right columns where nothing matched); ``right`` is the
+    left join from the other side (so in right-row order) with the columns
+    laid out as usual; ``outer`` is the left join followed by the right rows
+    that matched nothing (those with an NA key are dropped), in right-row
+    order.
+
+    Two device programs around the one host sync that the output's row count
+    needs: ``jit_merge_match`` and ``jit_merge_gather`` (device.py).  The
+    gather runs at the coarse ``dev.merge_padded_rows(m, ...)``;
+    ``jit_merge_trim`` cuts its columns to ``pad_rows(m)``, like every other
+    column of ``m`` rows.
     """
     by = [by] if isinstance(by, str) else list(by)
     if how == "right":
@@ -497,70 +513,46 @@ def merge(left: Frame, right: Frame, by: Union[str, Sequence[str]],
         return rbind(li, Frame(cols, aligned))
     if how not in ("inner", "left"):
         raise ValueError("merge supports how='inner'|'left'|'right'|'outer'")
-    # unify categorical key domains host-side (small); codes remap on device
-    cat_remap: Dict[str, Dict[str, int]] = {}
-    for name in by:
-        lv, rv = left.vec(name), right.vec(name)
-        if (lv.data is None) or (rv.data is None):
-            raise TypeError(f"merge key {name!r} is a string column; "
-                            "convert to categorical first")
-        if (lv.type == T_CAT) != (rv.type == T_CAT):
-            raise TypeError(f"merge key {name!r} has mismatched types")
-        if lv.type == T_CAT:
-            shared: Dict[str, int] = {}
-            for lbl in (lv.domain or []) + (rv.domain or []):
-                if lbl not in shared:
-                    shared[lbl] = len(shared)
-            cat_remap[name] = shared
-    lkeys = _device_keys(left, by, cat_remap)
-    rkeys = _device_keys(right, by, cat_remap)
-    pl, pr = left.padded_rows, right.padded_rows
-    rank = dev.dense_rank([jnp.concatenate([l, r])
-                           for l, r in zip(lkeys, rkeys)])
-    lrank, rrank = rank[:pl], rank[pl:]
-    lvalid = jnp.ones(pl, bool)
-    for k in lkeys:
-        lvalid &= jnp.isfinite(k)
-    rvalid = jnp.ones(pr, bool)
-    for k in rkeys:
-        rvalid &= jnp.isfinite(k)
-    nseg = pl + pr + 2
-    big = jnp.int32(nseg - 1)
-    lrank = jnp.where(lvalid, lrank, big)
-    rrank = jnp.where(rvalid, rrank, big)
-
-    rorder = jnp.argsort(rrank, stable=True)
-    rsorted = rrank[rorder]
-    # per-rank [start, count) into rsorted — replaces per-row binary search
-    rstart = jax.ops.segment_min(jnp.arange(pr, dtype=jnp.int32), rsorted,
-                                 num_segments=nseg)
-    rcount = jax.ops.segment_sum(jnp.ones(pr, jnp.int32), rsorted,
-                                 num_segments=nseg)
-    lo = rstart[lrank]
-    counts = jnp.where(lvalid, rcount[lrank], 0)
-    if how == "left":
-        out_counts = jnp.where(jnp.arange(pl) < left.nrows,
-                               jnp.maximum(counts, 1), 0)
-    else:
-        out_counts = counts
-    starts = jnp.cumsum(out_counts) - out_counts
-    m = int(starts[-1] + out_counts[-1]) if pl else 0
     cl = cluster()
-    p_out = cl.pad_rows(m)
+    with obs.trace("rapids.merge", how=how, left_rows=left.nrows,
+                   right_rows=right.nrows):
+        with obs.span("merge.keys"):
+            rsub = right[[n for n in right.names if n not in by]]
+            lcols, lfills = dev.device_columns(left)
+            rcols, rfills = dev.device_columns(rsub)
+            args, kinds = _merge_keys(left, right, by)
+        with obs.span("merge.match"):
+            cnt, start, srow, total, total_f = dev.merge_match(
+                *args, **kinds, how=how)
+        with obs.span("merge.count"):
+            # the one host sync: the output's length is a shape
+            m, m_f = (a.item() for a in jax.device_get((total, total_f)))
+            obs.inc("rapids_host_syncs_total", op="merge")
+            obs.inc("transfer_bytes_total", total.nbytes + total_f.nbytes, dir="d2h")
+            if m_f >= _MERGE_MAX_ROWS:
+                raise ValueError(
+                    f"merge: the output would have about {m_f:.3g} rows; a "
+                    f"join is materialised whole and stops at {_MERGE_MAX_ROWS}")
+        with obs.span("merge.gather"):
+            lout, rout, li, ri = dev.merge_gather(
+                cnt, start, srow, lcols, rcols, np.int32(left.nrows),
+                np.int32(m), how=how, lfills=lfills, rfills=rfills,
+                p_out=dev.merge_padded_rows(m, left.padded_rows),
+                sharding=cl.row_sharding)
+            lout, rout = dev.merge_trim(
+                (lout, rout), p=cl.pad_rows(m), sharding=cl.row_sharding)
+            @functools.lru_cache(None)
+            def fetched():          # once, and only if a host-only column asks
+                return [a[:m] for a in dev.note_host_index("merge", li, ri)]
 
-    li = dev.expand_starts(starts, out_counts, p_out)
-    li = jnp.clip(li, 0, max(pl - 1, 0))
-    off = jnp.arange(p_out) - starts[li]
-    matched = counts[li] > 0
-    rpos = jnp.clip(lo[li] + jnp.where(matched, off, 0), 0, max(pr - 1, 0))
-    ridx = jnp.where(matched, rorder[rpos], -1)
-
-    out = dev.gather_rows(left, li, m)
-    rcols = [n for n in right.names if n not in by]
-    if rcols:
-        rsub = dev.gather_rows(right[rcols], jnp.where(ridx >= 0, ridx, 0),
-                               m, na_mask=ridx < 0)
-        out = cbind(out, rsub)
+            def host_index(side):
+                return fetched()[side], fetched()[side] < 0
+            out = dev.wrap_rows(left, lout, m, lambda: host_index(0))
+            if rsub.ncols:
+                out = cbind(out, dev.wrap_rows(rsub, rout, m, lambda: host_index(1)))
+        obs.inc("rapids_rows_total", left.nrows, op="merge", side="in")
+        obs.inc("rapids_rows_total", right.nrows, op="merge", side="in")
+        obs.inc("rapids_rows_total", m, op="merge", side="out")
     return out
 
 

@@ -270,11 +270,39 @@ def main(argv=None) -> int:
         return fn, (sds((dl_sizes[0],), jnp.float32), sds((0,), jnp.float32),
                     *dl_design(n))
 
+    # the merge gate's tables (benchmark/configs/munge_100m_x2.json): 100M
+    # rows a side, an int32 key and a float32 value, some 91M rows out
+    from h2o3_tpu.rapids import device as munge
+    n_munge = cl.pad_rows(100_000_000)
+    p_munge = munge.merge_padded_rows(91_000_000, n_munge)
+    key, val, count = (sds((n_munge,), t, rows) for t in (jnp.int32, jnp.float32, jnp.int32))
+
+    def merge_match():
+        return munge.merge_match, (
+            (key,), (key,), (None,), (None,), sds((), jnp.int32), sds((), jnp.int32)), \
+            dict(lkinds=("int",), rkinds=("int",), how="inner")
+
+    def merge_gather():
+        return munge.merge_gather, (
+            count, count, sds((2 * n_munge,), jnp.int32), (key, val), (val,),
+            sds((), jnp.int32), sds((), jnp.int32)), dict(
+                how="inner", p_out=p_munge, lfills=("int", "f32"), rfills=("f32",),
+                sharding=rows)
+
+    def merge_trim():
+        out_key, out_val = (sds((p_munge,), t, rows) for t in (jnp.int32, jnp.float32))
+        return munge.merge_trim, (((out_key, out_val), (out_val,)),), dict(
+            p=cl.pad_rows(91_000_000), sharding=rows)
+
+    def sort_rows():
+        return munge.sort_rows, ((key, val), (key, val)), dict(
+            kinds=("int", "f32"), ascending=(False, True), sharding=rows)
+
     programs = {f.__name__: f for f in (
         tree_build, tree_build_scan, tree_build_k7, tree_scan, sparse_level,
         grid_scan, serve_xla, traverse, prediction_columns, glm_path,
         glm_path_airlines, glm_score, dl_sample_copy, dl_train_steps,
-        dl_score)}
+        dl_score, merge_match, merge_gather, merge_trim, sort_rows)}
     unknown = [p for p in args.programs if p not in programs]
     if unknown:
         ap.error(f"unknown program(s) {unknown}; known: {sorted(programs)}")
@@ -286,8 +314,9 @@ def main(argv=None) -> int:
                "attached": False}
         t0 = time.perf_counter()
         try:
-            fn, operands = programs[name]()
-            compiled = getattr(fn, "jitted", fn).lower(*operands).compile()
+            fn, operands, *static = programs[name]()
+            compiled = getattr(fn, "jitted", fn).lower(
+                *operands, **(static[0] if static else {})).compile()
         except Exception as e:              # noqa: BLE001 — report, go on
             rec.update(ok=False, error=f"{type(e).__name__}: {e}"[:600])
             failed += 1
