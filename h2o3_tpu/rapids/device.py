@@ -4,11 +4,13 @@ Reference semantics: ``water/rapids/RadixOrder.java`` (distributed MSB radix
 sort over 100M rows) and ``water/rapids/BinaryMerge.java`` (per-MSB-bucket
 binary merge with row expansion).  TPU redesign: every step is a named jitted
 program built from what the chip does well — ``lax.sort`` carrying its
-payload, cumulative sums and maxima, flat gathers — and from nothing it does
-badly: no frame-sized scatter (some 165 ns a row on a v5e, PERF.md), no
-``segment_*`` table with a segment per row, no per-row binary search
-(log N dependent gathers a row).  Where a scatter would invert a permutation
-or spread counts over output slots, a second sort does.
+payload, cumulative sums and maxima, one stacked gather per row index — and
+from nothing it does badly: no frame-sized scatter (some 165 ns a row on a
+v5e, PERF.md), no ``segment_*`` table with a segment per row, no per-row
+binary search (log N dependent gathers a row), no gather per value (a TPU
+gather is paid per index, not per value moved: ``gather_columns``).  Where a
+scatter would invert a permutation or spread counts over output slots, a
+second sort does.
 
 Keys are compared in their own dtype (``typed_key``): int32 for
 exact-integer and categorical columns, float32 for the rest, so two keys
@@ -18,13 +20,14 @@ either direction and never match in a join.
 The programs, by the names the device trace shows:
 
 - ``jit_sort_rows``: the order of one stable ``lax.sort`` over all key
-  columns (``lex_order``) and the gather of every device column.
+  columns (``lex_order``) and the stacked gather of the device columns.
 - ``jit_dense_rank``: group ids for ``group_by``: a sort, a cumulative sum
   over the group boundaries, a second sort back to row order.
 - ``jit_merge_match``: both tables' keys -> per left row the number of
   matching right rows and where they start, and the output's row count.
-- ``jit_merge_gather``: the expansion of those counts into output slots and
-  the gather of every output column, at a coarse padded length
+- ``jit_merge_gather``: the expansion of those counts into output slots,
+  one stacked gather of the left side's values by the slot's left row, the
+  right row, and one of the right columns by it, at a coarse padded length
   (``merge_padded_rows``) that joins of nearby sizes share.
 - ``jit_merge_trim``: those columns cut to ``pad_rows`` of the output's rows,
   the padded length every other column of as many rows has.
@@ -34,6 +37,8 @@ The programs, by the names the device trace shows:
 Host syncs are counted, not claimed: ``ops`` raises
 ``rapids_host_syncs_total{op}`` at each (one a merge: the output's row count;
 none a sort; a frame with host-only columns pays one more for their index).
+So are gathers: ``rapids_gathers_total{op}`` beside
+``rapids_gathered_columns_total{op}`` (``note_gathers``).
 """
 
 from __future__ import annotations
@@ -113,6 +118,53 @@ def _constrain(x: jax.Array, sharding) -> jax.Array:
     return x if sharding is None else jax.lax.with_sharding_constraint(x, sharding)
 
 
+# the most columns one gather moves: a stack's columns are the sublanes an
+# index fetches, and a tile has eight of 4 bytes.  On a v5e, 46M ascending
+# indices into 100M rows (tools/chip_gather_check.py, PR 40): a stack of 8
+# costs 15.2 ns an index, one of 3 13.5, a column alone 23.9; ONE stack of
+# 16 did not compile beside the tables, so 16 has no reading
+_GATHER_GROUP = 8
+
+
+def _gather_groups(cols) -> list:
+    """The positions in ``cols`` that each gather of ``gather_columns``
+    moves: the 4-byte payloads in runs of at most ``_GATHER_GROUP``, any
+    other payload alone."""
+    wide = [i for i, c in enumerate(cols) if c.dtype.itemsize == 4]
+    return [wide[at: at + _GATHER_GROUP]
+            for at in range(0, len(wide), _GATHER_GROUP)] + \
+        [[i] for i, c in enumerate(cols) if c.dtype.itemsize != 4]
+
+
+def note_gathers(op: str, *column_sets) -> None:
+    """Count what a program of ``op`` was dispatched to gather, one
+    ``gather_columns`` per set of columns: the gathers, and the values they
+    move.  The ratio of the two counters is how far the stacking engages."""
+    obs.inc("rapids_gathers_total",
+            sum(len(_gather_groups(cols)) for cols in column_sets), op=op)
+    obs.inc("rapids_gathered_columns_total",
+            sum(len(cols) for cols in column_sets), op=op)
+
+
+def gather_columns(cols, index: jax.Array) -> tuple:
+    """Every column of ``cols`` (1-D, of one length) at ``index``, moved bit
+    for bit.  A TPU gather costs per index, not per value, so the 4-byte
+    payloads (int32 as they are, float32 reinterpreted as int32 and back:
+    NaN payloads, -0.0 and ``INT_NA`` pass untouched) are stacked as
+    ``[rows, k]``, gathered ONCE and unstacked; one column alone is
+    ``c[index]``.  Traceable."""
+    out = list(cols)
+    for group in _gather_groups(cols):
+        if len(group) == 1:
+            out[group[0]] = cols[group[0]][index]
+            continue
+        moved = jnp.stack([jax.lax.bitcast_convert_type(cols[i], jnp.int32)
+                           for i in group], axis=1)[index]
+        for j, i in enumerate(group):
+            out[i] = jax.lax.bitcast_convert_type(moved[:, j], cols[i].dtype)
+    return tuple(out)
+
+
 @functools.partial(jax.jit, static_argnames=("kinds", "ascending", "sharding"))
 def sort_rows(keys, cols, *, kinds, ascending, sharding=None):
     """(row order, every column in that order).  Padding rows hold NA keys
@@ -120,7 +172,8 @@ def sort_rows(keys, cols, *, kinds, ascending, sharding=None):
     column."""
     order = lex_order([typed_key(k, kind) for k, kind in zip(keys, kinds)],
                       ascending)
-    return order, tuple(_constrain(c[order], sharding) for c in cols)
+    return order, tuple(_constrain(c, sharding)
+                        for c in gather_columns(cols, order))
 
 
 @functools.partial(jax.jit, static_argnames=("kinds",))
@@ -161,8 +214,8 @@ def take_rows(cols, index, n_out, forced_na, *, fills, p_out, sharding=None):
     if cols:
         index = jnp.clip(index, 0, cols[0].shape[0] - 1)
     return index, live, tuple(
-        _constrain(jnp.where(live, c[index], _FILL[f]), sharding)
-        for c, f in zip(cols, fills))
+        _constrain(jnp.where(live, c, _FILL[f]), sharding)
+        for c, f in zip(gather_columns(cols, index), fills))
 
 
 @jax.jit
@@ -230,6 +283,7 @@ def gather_rows(frame: Frame, order: jax.Array, n_out: int,
     index, live, out = take_rows(
         cols, order.astype(jnp.int32), np.int32(n_out), na_mask, fills=fills,
         p_out=cl.pad_rows(n_out), sharding=cl.row_sharding)
+    note_gathers(op, cols)
 
     def host_index():
         idx, ok = note_host_index(op, index, live)
@@ -323,26 +377,38 @@ def expand_counts(counts: jax.Array, p_out: int) -> Tuple[jax.Array, jax.Array]:
     return owner[:p_out], offset[:p_out]
 
 
+def merge_left_carried(start, cnt, lcols, how) -> tuple:
+    """What ``jit_merge_gather`` moves by the output slot's left row."""
+    return (start, *lcols) + ((cnt,) if how == "left" else ())
+
+
 @functools.partial(jax.jit, static_argnames=(
     "how", "p_out", "lfills", "rfills", "sharding"))
 def merge_gather(cnt, start, srow, lcols, rcols, nl, m, *, how, p_out,
                  lfills, rfills, sharding=None):
     """The join's output columns, ``p_out`` padded rows of which ``m`` are
     real: each left row's slots from ``expand_counts``, then per slot the
-    left row, the right row ``srow[start + offset]`` (none, and NA in the
-    right columns, where a left join's row matched nothing) and a gather of
-    every column.  Also returns the two row indices (-1: no right row),
+    left row, ONE gather by it of all the left side carries (``start``, the
+    left columns and, for a left join alone, ``cnt``: an inner join gives
+    slots only to rows that matched, so its every live slot is matched), the
+    right row ``srow[start + offset]`` (none, and NA in the right columns,
+    where a left join's row matched nothing) and one gather of the right
+    columns by it.  Also returns the two row indices (-1: no right row),
     which host-only columns are gathered by."""
     pl = cnt.shape[0]
     owner, offset = expand_counts(_out_counts(cnt, nl, how), p_out)
     live = jnp.arange(p_out) < m
     li = jnp.where(live, jnp.minimum(owner, pl - 1), 0)
-    matched = live & (cnt[li] > 0)
-    ri = srow[jnp.where(matched, start[li] + offset, 0)]
-    lout = tuple(_constrain(jnp.where(live, c[li], _FILL[f]), sharding)
-                 for c, f in zip(lcols, lfills))
-    rout = tuple(_constrain(jnp.where(matched, c[ri], _FILL[f]), sharding)
-                 for c, f in zip(rcols, rfills))
+    start_l, *lmoved = gather_columns(
+        merge_left_carried(start, cnt, lcols, how), li)
+    matched = live
+    if how == "left":
+        matched = live & (lmoved.pop() > 0)
+    ri = srow[jnp.where(matched, start_l + offset, 0)]
+    lout = tuple(_constrain(jnp.where(live, c, _FILL[f]), sharding)
+                 for c, f in zip(lmoved, lfills))
+    rout = tuple(_constrain(jnp.where(matched, c, _FILL[f]), sharding)
+                 for c, f in zip(gather_columns(rcols, ri), rfills))
     return lout, rout, li, jnp.where(matched, ri, -1)
 
 

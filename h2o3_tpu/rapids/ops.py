@@ -8,7 +8,9 @@ a frame has host-only columns to gather); ``filter_rows`` one sync for its
 row count; ``group_by`` fetches group-count-sized arrays.  Spans:
 ``rapids.sort`` (``sort.order``, ``sort.gather``) and ``rapids.merge``
 (``merge.keys``, ``merge.match``, ``merge.count``, ``merge.gather``);
-``rapids_rows_total{op, side}`` counts rows in and out.
+``rapids_rows_total{op, side}`` counts rows in and out,
+``rapids_gathers_total{op}`` the device gathers a program was dispatched with
+and ``rapids_gathered_columns_total{op}`` the values they move.
 """
 
 from __future__ import annotations
@@ -46,6 +48,7 @@ def sort(frame: Frame, by: Union[str, Sequence[str]],
             order, moved = dev.sort_rows(
                 keys, cols, kinds=kinds, ascending=tuple(bool(a) for a in asc),
                 sharding=cluster().row_sharding)
+            dev.note_gathers("sort", cols)
         with obs.span("sort.gather"):
             def host_index():
                 idx, = dev.note_host_index("sort", order)
@@ -539,6 +542,8 @@ def merge(left: Frame, right: Frame, by: Union[str, Sequence[str]],
                 np.int32(m), how=how, lfills=lfills, rfills=rfills,
                 p_out=dev.merge_padded_rows(m, left.padded_rows),
                 sharding=cl.row_sharding)
+            dev.note_gathers("merge", dev.merge_left_carried(
+                start, cnt, lcols, how), (srow,), rcols)
             lout, rout = dev.merge_trim(
                 (lout, rout), p=cl.pad_rows(m), sharding=cl.row_sharding)
             @functools.lru_cache(None)

@@ -9,8 +9,9 @@ import numpy as np
 import pytest
 
 from h2o3_tpu import Frame
-from h2o3_tpu.frame.vec import T_CAT, INT_NA, takes_exact_int
+from h2o3_tpu.frame.vec import T_CAT, T_STR, INT_NA, takes_exact_int
 import reference_munge as ref
+from h2o3_tpu.rapids import device as dev, filter_rows
 from h2o3_tpu.runtime import observability as obs
 
 HOWS = ("inner", "left", "right", "outer")
@@ -53,6 +54,12 @@ def frame_of(cols, keep):
     return fr
 
 
+def read(name, **labels):
+    """A counter's value, summed over the series whose labels match."""
+    return sum(s["v"] for s in obs.metrics_wire() if s["n"] == name
+               and all(s["l"].get(k) == v for k, v in labels.items()))
+
+
 def assert_same(frame, want):
     """Every column of the frame equals the reference's, row for row: labels
     as labels (None NA), numbers bit for bit (NaN where NaN)."""
@@ -64,6 +71,8 @@ def assert_same(frame, want):
         if v.type == T_CAT:
             got = v.decoded()
             assert [g for g in got] == [c for c in col], name
+        elif v.data is None:        # host-only: strings as they are, None NA
+            assert list(v.host_data) == list(col), name
         elif col.dtype == np.float32:
             got = v.to_numpy()
             assert got.dtype == np.float32, name
@@ -123,10 +132,6 @@ def test_merge_one_host_sync_and_rows_counted(cl):
     left, right = tables(5, na=False)
     L, R = frame_of(left, ["key", "v1"]), frame_of(right, ["key", "v2"])
     L.merge(R, "key")                   # compiled
-
-    def read(name, **labels):
-        return sum(s["v"] for s in obs.metrics_wire() if s["n"] == name
-                   and all(s["l"].get(k) == v for k, v in labels.items()))
     before = {k: read(*k[:1], **dict(k[1:])) for k in [
         ("rapids_host_syncs_total", ("op", "merge")),
         ("transfer_bytes_total", ("dir", "d2h")),
@@ -139,6 +144,105 @@ def test_merge_one_host_sync_and_rows_counted(cl):
     kinds = [e["kind"] for e in obs.timeline_events(50)]
     assert [k for k in kinds if k.startswith(("merge.", "rapids."))][-5:] == [
         "merge.keys", "merge.match", "merge.count", "merge.gather", "rapids.merge"]
+
+
+def counted(call, op):
+    """What one ``call`` adds to the two gather counters of ``op``."""
+    names = ("rapids_gathers_total", "rapids_gathered_columns_total")
+    before = [read(n, op=op) for n in names]
+    call()
+    return [read(n, op=op) - b for n, b in zip(names, before)]
+
+
+@pytest.mark.parametrize("how,gathers,values", [
+    ("inner", 3, 5),        # left: start, key, v1 in 1; right: srow, then v2
+    ("left", 3, 6)])        # cnt rides the left stack too
+def test_merge_gathers_counted_on_the_cell_shape(cl, how, gathers, values):
+    left, right = tables(5, na=False)
+    L, R = frame_of(left, ["key", "v1"]), frame_of(right, ["key", "v2"])
+    assert counted(lambda: L.merge(R, "key", how=how), "merge") == [gathers, values]
+
+
+def test_sort_gathers_counted(cl):
+    left, _ = tables(8)
+    L = frame_of(left, ["key", "k2", "lab", "v1"])
+    assert counted(lambda: L.sort("key"), "sort") == [1, 4]
+    wide = Frame.from_numpy({f"c{i}": np.arange(20.0) + i for i in range(9)})
+    assert counted(lambda: wide.sort("c0", ascending=False), "sort") == [2, 9]
+    assert counted(lambda: filter_rows(wide, np.arange(20) % 3 == 0), "filter") == [2, 9]
+
+
+def wide_tables(seed, nl=700, nr=400):
+    """Tables of 11 device columns a side (exact-integer, float32 and
+    categorical payloads, NaN and NA among them) and a string column, which
+    stays on the host, on an integer key with duplicates, misses and NAs."""
+    rng = np.random.default_rng(seed)
+
+    def side(n, lo, tag):
+        cols = {"key": (BASE - rng.integers(lo, lo + 300, n)).astype(np.float64)}
+        cols["key"][rng.integers(0, n, 9)] = np.nan
+        for i in range(4):
+            f = rng.standard_normal(n).astype(np.float32)
+            f[rng.integers(0, n, 5)] = np.nan
+            cols[f"{tag}f{i}"] = f
+            cols[f"{tag}i{i}"] = (BASE + rng.integers(0, 1000, n)).astype(np.float64)
+        cols[f"{tag}n"] = rng.integers(0, 50, n).astype(np.float64)
+        cols[f"{tag}lab"] = np.array(["ant", "bee", "cat"], object)[rng.integers(0, 3, n)]
+        cols[f"{tag}s"] = np.array([f"{tag}{j}" for j in range(n)], object)
+        return cols
+    return side(nl, 0, "l"), side(nr, 100, "r")
+
+
+@pytest.mark.parametrize("how", HOWS)
+def test_merge_wide_frames_with_a_host_only_column(cl, how):
+    left, right = wide_tables(11)
+    L = Frame.from_numpy(left, types={"ls": T_STR})
+    R = Frame.from_numpy(right, types={"rs": T_STR})
+    assert len(dev.device_columns(L)[0]) == 11 and L.vec("ls").data is None
+    assert L.vec("li0").is_exact_int and not L.vec("ln").is_exact_int
+    assert_same(L.merge(R, "key", how=how), ref.reference_merge(left, right, "key", how))
+
+
+def payload_columns(k, n, seed):
+    """``k`` columns of ``n`` rows, int32 and float32 in turn, holding what a
+    move must not touch: NaNs of two bit patterns, -0.0, ``INT_NA``."""
+    rng = np.random.default_rng(seed)
+    cols = []
+    for i in range(k):
+        if i % 2:
+            c = rng.integers(-2 ** 31, 2 ** 31, n, dtype=np.int64).astype(np.int32)
+            c[rng.integers(0, n, 6)] = INT_NA
+        else:
+            c = rng.standard_normal(n).astype(np.float32)
+            bits = c.view(np.int32)
+            bits[rng.integers(0, n, 6)] = 0x7FC00000                # the quiet NaN
+            bits[rng.integers(0, n, 6)] = np.int32(-0x3FFFF)        # 0xFFFC0001: sign and payload set
+            bits[rng.integers(0, n, 6)] = np.int32(-2 ** 31)        # -0.0
+        cols.append(c)
+    return cols
+
+
+@pytest.mark.parametrize("index_kind", ["ascending", "random"])
+@pytest.mark.parametrize("k", [1, 2, 8, 9, 17])
+def test_gather_columns_moves_every_bit(cl, k, index_kind):
+    import jax
+    import jax.numpy as jnp
+    n, rng = 5000, np.random.default_rng(k)
+    cols = payload_columns(k, n, seed=100 + k)
+    if index_kind == "ascending":       # repeats and skips, as a join's left rows
+        index = np.sort(rng.integers(0, n, 6000))
+        assert (np.diff(index) == 0).any() and (np.diff(index) > 1).any()
+    else:
+        index = rng.integers(0, n, 6000)
+    index = index.astype(np.int32)
+    on_device = [jnp.asarray(c) for c in cols]
+    moved = jax.jit(dev.gather_columns)(on_device, jnp.asarray(index))
+    assert len(moved) == k
+    for c, m in zip(cols, moved):
+        assert m.dtype == c.dtype
+        np.testing.assert_array_equal(np.asarray(m).view(np.int32), c[index].view(np.int32))
+    traced = str(jax.make_jaxpr(dev.gather_columns)(on_device, jnp.asarray(index)))
+    assert traced.count(" gather[") == -(-k // 8)      # groups of at most 8
 
 
 @pytest.mark.parametrize("meets", ["binop", "ifelse", "cbind"])
@@ -213,13 +317,9 @@ def test_sort_makes_no_host_sync(cl):
     left, _ = tables(8)
     L = frame_of(left, ["key", "v1"])
     L.sort("key")
-
-    def syncs():
-        return sum(s["v"] for s in obs.metrics_wire()
-                   if s["n"] == "rapids_host_syncs_total")
-    before = syncs()
+    before = read("rapids_host_syncs_total")
     L.sort("key", ascending=False)
-    assert syncs() == before
+    assert read("rapids_host_syncs_total") == before
     kinds = [e["kind"] for e in obs.timeline_events(20)]
     assert kinds[-3:] == ["sort.order", "sort.gather", "rapids.sort"]
 

@@ -11,7 +11,7 @@ refuse on the chip, it refuses here.  Nothing runs: this says nothing about
 results or times on the device.
 
 One JSON line per program: seconds to lower + compile ON THIS HOST, the
-``tpu_custom_call`` and ``all-reduce`` counts in the compiled text, and the
+``tpu_custom_call``, ``all-reduce`` and ``gather`` counts in the compiled text, and the
 compiler's memory analysis (bytes per device).
 
     JAX_PLATFORMS=cpu python tools/tpu_compile_rehearsal.py            # all
@@ -327,6 +327,7 @@ def main(argv=None) -> int:
                        host_compile_s=round(time.perf_counter() - t0, 1),
                        tpu_custom_calls=text.count("tpu_custom_call"),
                        all_reduces=text.count("all-reduce("),
+                       gathers=text.count(" gather("),
                        temp_gb=round(ma.temp_size_in_bytes / 1e9, 3),
                        argument_gb=round(ma.argument_size_in_bytes / 1e9, 3),
                        output_gb=round(ma.output_size_in_bytes / 1e9, 3))
