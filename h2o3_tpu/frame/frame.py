@@ -26,6 +26,7 @@ import numpy as np
 
 from ..runtime.cluster import cluster
 from ..runtime import dkv
+from ..runtime import observability as obs
 from .vec import Vec, T_CAT, T_NUM, T_STR, T_TIME
 
 
@@ -91,18 +92,20 @@ class Frame:
         types = types or {}
         domains = domains or {}
         names, vecs = [], []
-        for name, arr in arrays.items():
-            arr = np.asarray(arr)
-            vtype = types.get(name)
-            domain = domains.get(name)
-            if vtype is None:
-                if arr.dtype == object or arr.dtype.kind in "US":
-                    labels, codes = np.unique(arr.astype(str), return_inverse=True)
-                    vtype, domain, arr = T_CAT, [str(l) for l in labels], codes
-                else:
-                    vtype = T_NUM
-            names.append(name)
-            vecs.append(Vec.from_numpy(arr, vtype, domain=domain))
+        rows = len(next(iter(arrays.values()))) if arrays else 0
+        with obs.span("frame.upload", rows=rows, cols=len(arrays)):
+            for name, arr in arrays.items():
+                arr = np.asarray(arr)
+                vtype = types.get(name)
+                domain = domains.get(name)
+                if vtype is None:
+                    if arr.dtype == object or arr.dtype.kind in "US":
+                        labels, codes = np.unique(arr.astype(str), return_inverse=True)
+                        vtype, domain, arr = T_CAT, [str(l) for l in labels], codes
+                    else:
+                        vtype = T_NUM
+                names.append(name)
+                vecs.append(Vec.from_numpy(arr, vtype, domain=domain))
         return Frame(names, vecs, key=key)
 
     # --------------------------------------------------------------- munging

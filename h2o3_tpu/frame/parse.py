@@ -33,6 +33,7 @@ import numpy as np
 from .frame import Frame
 from .vec import Vec, T_CAT, T_NUM, T_STR, T_TIME, takes_exact_int
 from ..runtime import dkv
+from ..runtime import observability as obs
 
 _NA = {"", "na", "n/a", "nan", "null", "none", "?", "-", "NA", "NaN", "NULL", "None"}
 
@@ -392,11 +393,14 @@ def parse_csv(path_or_buf, destination_frame: Optional[str] = None,
                 if raw is not None else path_or_buf
             names, cols = _parse_csv_stdlib(sd, header, sep, col_names)
     t0 = time.perf_counter()
-    vecs = [_assemble_vec(cols[n], n, col_types.get(n)) for n in names]
+    first = cols[names[0]] if names else ()
+    rows = sum(len(piece) for piece in first) \
+        if isinstance(first, _DeviceChunks) else len(first)
+    with obs.span("frame.upload", rows=rows, cols=len(names)):
+        vecs = [_assemble_vec(cols[n], n, col_types.get(n)) for n in names]
     if last_parse_stats:
         last_parse_stats["vec_s"] = round(time.perf_counter() - t0, 4)
-        from ..runtime.observability import record
-        record("parse", **last_parse_stats)
+        obs.record("parse", **last_parse_stats)
     key = destination_frame or dkv.make_key(
         os.path.basename(str(path_or_buf)) if isinstance(path_or_buf, str)
         else "frame")

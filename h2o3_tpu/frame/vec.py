@@ -252,6 +252,7 @@ class Vec:
         for modeling; ~seconds resolution over year ranges) while the exact
         float64 ms stay host-side for round-trips.
         """
+        t_entry = time.perf_counter_ns()
         cl = cluster()
         arr = np.asarray(arr)
         n = len(arr)
@@ -287,8 +288,17 @@ class Vec:
                 vals = (vals - time_base) / 1000.0
             buf = np.full(padded, np.nan, dtype=np.float32)
             buf[:n] = vals.astype(np.float32)
+        t_put = time.perf_counter_ns()
         data = put_sharded(buf, cl.row_sharding)
+        t_done = time.perf_counter_ns()
         obs.inc("transfer_bytes_total", buf.nbytes, dir="h2d")
+        # prepare: the host's numpy from entry to the padded buffer; put: the
+        # call that hands the buffer to the runtime (docs/operations.md says
+        # how much of the copy that call waits for)
+        obs.inc("transfer_seconds_total", (t_put - t_entry) / 1e9,
+                dir="h2d", stage="prepare")
+        obs.inc("transfer_seconds_total", (t_done - t_put) / 1e9,
+                dir="h2d", stage="put")
         return Vec(data, vtype, n, domain=domain, host_data=host_data,
                    time_base=time_base or 0.0)
 

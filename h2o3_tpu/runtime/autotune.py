@@ -205,7 +205,7 @@ def _ledger_calibration() -> float:
     time exists, trust achieved bandwidth over the roofline constant."""
     try:
         from . import xprof
-        snap = xprof.ledger_snapshot()["programs"]
+        snap = xprof.ledger_programs()
         for name in ("tree_scan", "tree_scan_multinomial", "tree_build"):
             ent = snap.get(name)
             if ent and ent.get("bytes_accessed"):

@@ -467,6 +467,20 @@ def current_trace() -> Optional[Dict[str, str]]:
     return {"trace_id": ctx["trace_id"], "span_id": ctx["span_id"]}
 
 
+def open_span() -> Dict[str, str]:
+    """The fields that put an event which is no span under the span open
+    on this context: ``trace_id``, ``parent_span`` (that span's id) and
+    ``span`` (its kind; a context adopted from the wire has none).  Empty
+    outside any trace."""
+    ctx = _trace_ctx.get()
+    if not ctx:
+        return {}
+    out = {"trace_id": ctx["trace_id"], "parent_span": ctx["span_id"]}
+    if "kind" in ctx:
+        out["span"] = ctx["kind"]
+    return out
+
+
 @contextlib.contextmanager
 def trace_context(wire: Optional[Dict[str, str]]):
     """Adopt a remote trace context (the RPC handler side): spans opened
@@ -512,7 +526,8 @@ def _timed_event(kind: str, root: bool, fields: dict):
     ``child_ns`` accumulator in its context dict and adds its own
     duration to its parent's when it closes.  Under one root the self
     seconds of all spans therefore sum to the root's duration, so any set
-    of span names adds up without counting an interval twice."""
+    of span names adds up without counting an interval twice.  The dict
+    carries the span's ``kind`` too, for ``open_span()``."""
     if not _enabled:
         yield
         return
@@ -525,7 +540,8 @@ def _timed_event(kind: str, root: bool, fields: dict):
         ids = {"trace_id": trace_id, "span_id": span_id}
         if parent and parent.get("span_id"):
             ids["parent_span"] = parent["span_id"]
-        ctx = {"trace_id": trace_id, "span_id": span_id, "child_ns": 0}
+        ctx = {"trace_id": trace_id, "span_id": span_id, "child_ns": 0,
+               "kind": kind}
         token = _trace_ctx.set(ctx)
     annotation = _trace_annotation("h2o3." + kind)
     if annotation is not None:

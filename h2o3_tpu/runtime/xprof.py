@@ -33,9 +33,21 @@ registry:
 
 * **jax.monitoring listener** — a duration listener on
   ``/jax/core/compile/*`` records every jaxpr trace, lowering and backend
-  compile (or cache retrieval) jax performs, including seams the ledger
-  does not wrap, into ``jax_compile_seconds{event, fun}``: ``fun`` is the
-  traced function's name, so the series says what a fit re-traces.
+  compile jax performs, including seams the ledger does not wrap, into
+  ``jax_compile_seconds{event, fun}``: ``fun`` is the traced function's
+  name, so the series says what a fit re-traces.  A backend compile
+  encloses jax's persistent cache, whose own events arrive on the same
+  thread inside that interval; an event listener pairs them with it, and
+  the ``backend_compile_duration`` series alone carries a third label,
+  ``cache``: ``hit`` (retrieved), ``stored`` (compiled here and written),
+  ``unstored`` (asked, nothing retrieved and nothing written: under
+  jax's floors of compile time and entry size, so every process compiles
+  it again) or ``off`` (the cache was not asked).  A hit's
+  ``compile_time_saved_sec`` goes to ``jax_cache_saved_seconds{fun}``:
+  what a cold process would pay for that function, read from a warm one.
+  A compile that is not a hit also leaves a ``compile`` event on the
+  ring, stamped with the span open on its thread.  ``ledger_snapshot()``
+  lists all of it by function under ``jax``.
 
 * **Idle time by span** — ``idle_by_span(xplane_path)`` reads a profiler
   trace: the gaps between the programs on the first TPU's ``XLA Modules``
@@ -178,11 +190,11 @@ def invalidate(reason: str = "cluster_reinit") -> None:
     obs.record("xprof_invalidate", reason=reason)
 
 
-def ledger_snapshot() -> dict:
-    """Plain-data view of the compile ledger (bench compile-vs-steady
-    split, the tier-1 compile-stats artifact, /metrics cross-checks)."""
+def ledger_programs() -> dict:
+    """Plain-data view of the registered seams: name -> compiles, seconds,
+    reasons and the executable's costs (what the autotuner reads)."""
     with _lock:
-        programs = {
+        return {
             name: {
                 "compiles": ent["compiles"],
                 "compile_s": round(ent["compile_s"], 6),
@@ -194,14 +206,51 @@ def ledger_snapshot() -> dict:
             }
             for name, ent in _LEDGER.items()
         }
-        epoch = _EPOCH
+
+
+def ledger_snapshot() -> dict:
+    """Plain-data view of the compile ledger (the tier-1 compile-stats
+    artifact, /metrics cross-checks, ``GET /3/Profiler/compiles``):
+    ``programs``, the registered seams, and ``jax``, every function the
+    monitoring listener heard of.  It serialises the registry: not for a
+    path that is timed."""
+    programs = ledger_programs()
     return {
         "programs": programs,
-        "epoch": epoch,
+        "epoch": _EPOCH,
         "total_compiles": sum(p["compiles"] for p in programs.values()),
         "total_compile_s": round(
             sum(p["compile_s"] for p in programs.values()), 6),
+        "jax": _jax_table(),
     }
+
+
+def _jax_table() -> list:
+    """What the monitoring listener heard, one row a ``fun`` label: its
+    seconds in all, ``[events, seconds]`` by ``event`` and, of the backend
+    compiles, by ``cache``, and ``saved_s``, the compile seconds the
+    persistent cache spared it; the row with the most seconds first.  Read
+    from the registry, so it holds what ``/metrics`` holds."""
+    rows: Dict[str, dict] = {}
+    for s in obs.metrics_wire():
+        labels = s["l"]
+        if s["n"] not in ("jax_compile_seconds", "jax_cache_saved_seconds") \
+                or "fun" not in labels:     # a series someone else registered
+            continue
+        row = rows.setdefault(labels["fun"], {
+            "fun": labels["fun"], "seconds": 0.0, "by_event": {},
+            "by_cache": {}, "saved_s": 0.0})
+        if s["n"] == "jax_cache_saved_seconds":
+            row["saved_s"] += s["s"]
+            continue
+        row["seconds"] += s["s"]
+        for group, key in (("by_event", labels["event"]),
+                           ("by_cache", labels.get("cache"))):
+            if key is not None:
+                cell = row[group].setdefault(key, [0, 0.0])
+                cell[0] += s["n_obs"]
+                cell[1] += s["s"]
+    return sorted(rows.values(), key=lambda r: -r["seconds"])
 
 
 def reset_ledger() -> None:
@@ -362,26 +411,72 @@ def _fun_label(fun_name) -> str:
     return "other"
 
 
+# jax's persistent-cache events, which arrive on the compiling thread inside
+# the enclosing backend_compile_duration -> the ``cache`` label they decide
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "unstored",
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "stored",
+}
+_SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
+_BACKEND_COMPILE = "backend_compile_duration"
+
+# what the cache said of the backend compile in flight on this thread:
+# ``cache`` and ``saved_s``, set by the events above, read and cleared when
+# the compile's own duration arrives
+_pending = threading.local()
+
+
+def _on_event(event: str, **kw) -> None:
+    cache = _CACHE_EVENTS.get(event)
+    if cache is not None and obs.enabled():
+        _pending.cache = cache
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    if not obs.enabled():
+        return
+    if event == _SAVED_EVENT:
+        # less than 0 where the retrieval took longer than the compile
+        _pending.saved_s = max(duration, 0.0)
+        return
+    if not event.startswith("/jax/core/compile"):
+        return
+    name = event.rsplit("/", 1)[-1]
+    fun = _fun_label(kw.get("fun_name"))
+    if name != _BACKEND_COMPILE:
+        obs.observe("jax_compile_seconds", duration, event=name, fun=fun)
+        return
+    said = vars(_pending)
+    cache, saved_s = said.pop("cache", "off"), said.pop("saved_s", 0.0)
+    obs.observe("jax_compile_seconds", duration, event=name, fun=fun,
+                cache=cache)
+    if cache != "off":
+        # a function that asked the cache has the series, empty until a
+        # hit: a cold process reads 0 saved seconds, not nothing
+        saved = obs.histogram("jax_cache_saved_seconds", fun=fun)
+        if cache == "hit":
+            saved.observe(saved_s)
+    if cache != "hit":
+        obs.record("compile", fun=fun, cache=cache, duration_s=duration,
+                   **obs.open_span())
+
+
 def install_monitoring_listener() -> None:
     """Record every jaxpr trace, lowering and backend compile jax performs
     into ``jax_compile_seconds{event, fun}`` via ``jax.monitoring`` — the
-    complete per-program source (the ledger sees wrapped seams only).
-    ``fun`` is the ``fun_name`` jax passes to its duration listeners, cut
-    to 64 characters; past 256 distinct values it reads ``other``.
-    Idempotent."""
+    complete per-program source (the ledger sees wrapped seams only) —
+    and what the persistent cache did with each backend compile (module
+    doc).  ``fun`` is the ``fun_name`` jax passes to its duration
+    listeners, cut to 64 characters; past 256 distinct values it reads
+    ``other``.  Idempotent."""
     global _listener_installed
     from jax import monitoring
     with _lock:
         if _listener_installed:
             return
         _listener_installed = True
-
-    def _on_duration(event: str, duration: float, **kw) -> None:
-        if event.startswith("/jax/core/compile"):
-            obs.observe("jax_compile_seconds", duration,
-                        event=event.rsplit("/", 1)[-1],
-                        fun=_fun_label(kw.get("fun_name")))
-
+    monitoring.register_event_listener(_on_event)
     monitoring.register_event_duration_secs_listener(_on_duration)
 
 

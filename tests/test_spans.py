@@ -1,8 +1,9 @@
 """The span primitive and its call sites: one ``span()`` feeds the event
 ring, a profiler annotation and ``span_seconds`` / ``span_self_seconds``;
 ``train()`` and ``predict()`` open the spans of docs/operations.md's table
-under one trace; the compile listener says what was traced; a profiler
-trace's idle time is attributed to spans."""
+under one trace; the compile listener says what was traced and what the
+persistent cache did with each backend compile; an upload is timed where it
+happens; a profiler trace's idle time is attributed to spans."""
 
 import time
 
@@ -274,6 +275,206 @@ def test_the_fun_label_is_cut_and_capped(monkeypatch):
     assert "other" in labels and len(labels) == 256 - 2 + 1
     assert xprof._fun_label("fn0") == "fn0"          # a known one stays itself
     assert xprof._fun_label("never_seen") == "other"
+
+
+# --------------------------------- what the cache did with a backend compile
+
+CACHE = "/jax/compilation_cache/"
+BACKEND = "/jax/core/compile/backend_compile_duration"
+
+
+def _backend_compile(fun, cache, seconds=0.25, saved=5.0):
+    """What jax emits, in its order, around one backend compile that the
+    persistent cache answered so."""
+    from jax import monitoring
+    if cache != "off":
+        monitoring.record_event(CACHE + "compile_requests_use_cache")
+    if cache == "hit":
+        monitoring.record_event(CACHE + "cache_hits")
+        monitoring.record_event_duration_secs(CACHE + "compile_time_saved_sec", saved)
+        monitoring.record_event_duration_secs(CACHE + "cache_retrieval_time_sec", 0.01)
+    if cache == "stored":
+        monitoring.record_event(CACHE + "cache_misses")
+    monitoring.record_event_duration_secs(BACKEND, seconds, fun_name=fun)
+
+
+def _backend_series(fun):
+    """{cache label: (observations, seconds)} of ``fun``'s backend compiles."""
+    return {s["l"]["cache"]: (s["n_obs"], s["s"]) for s in obs.metrics_wire()
+            if s["n"] == "jax_compile_seconds" and s["l"]["fun"] == fun
+            and s["l"]["event"] == "backend_compile_duration"}
+
+
+def _saved(fun):
+    return [(s["n_obs"], s["s"]) for s in obs.metrics_wire()
+            if s["n"] == "jax_cache_saved_seconds" and s["l"] == {"fun": fun}]
+
+
+def _compile_events(mark, fun):
+    return [e for e in _ring_since(mark)
+            if e["kind"] == "compile" and e["fun"] == fun]
+
+
+@pytest.mark.parametrize("cache", ["hit", "stored", "unstored", "off"])
+def test_the_listener_says_what_the_cache_did(cache):
+    xprof.install_monitoring_listener()
+    fun = f"jit(unit_{cache})"
+    mark = time.time()
+    with obs.trace("unit_root"):
+        with obs.span("unit_compiles"):
+            _backend_compile(fun, cache)
+    assert _backend_series(fun) == {cache: (1, 0.25)}
+    # the saved seconds of a hit; a function that asked in vain has the
+    # series, empty; one that never asked has none
+    assert _saved(fun) == {"hit": [(1, 5.0)], "off": []}.get(cache, [(0, 0.0)])
+    ring = {e["kind"]: e for e in _ring_since(mark)}
+    if cache == "hit":          # a retrieval is no event: the ring holds 2,000
+        assert "compile" not in ring
+    else:
+        (ev,) = _compile_events(mark, fun)
+        assert (ev["cache"], ev["duration_s"], ev["span"]) == (cache, 0.25, "unit_compiles")
+        assert ev["parent_span"] == ring["unit_compiles"]["span_id"]
+        assert ev["trace_id"] == ring["unit_root"]["trace_id"]
+        assert "span_id" not in ev          # not a span: no node of the trace's tree
+    # ... and nothing of a span's: self seconds mean what they meant
+    assert set(_span_series("span_self_seconds")) == {"unit_root", "unit_compiles"}
+    # the record is read once: the next compile on this thread starts clean
+    _backend_compile(fun, "off")
+    assert _backend_series(fun).get("off", (0, 0))[0] == (2 if cache == "off" else 1)
+
+
+def test_a_slow_retrieval_saves_nothing_and_no_span_stamps_nothing():
+    xprof.install_monitoring_listener()
+    mark = time.time()
+    _backend_compile("jit(unit_slow_hit)", "hit", saved=-0.5)
+    assert _saved("jit(unit_slow_hit)") == [(1, 0.0)]
+    _backend_compile("jit(unit_bare)", "unstored")
+    (ev,) = _compile_events(mark, "jit(unit_bare)")
+    assert not {"trace_id", "parent_span", "span"} & set(ev)
+    # trace and lower events keep their two labels and leave the ring alone
+    from jax import monitoring
+    monitoring.record_event_duration_secs(
+        "/jax/core/compile/jaxpr_trace_duration", 0.1, fun_name="unit_bare")
+    (traced,) = [s for s in obs.metrics_wire() if s["l"].get("fun") == "unit_bare"]
+    assert set(traced["l"]) == {"event", "fun"}
+    assert [e["kind"] for e in _ring_since(mark)] == ["compile"]
+
+
+def test_two_threads_compiling_at_once_keep_their_own_records():
+    from concurrent.futures import ThreadPoolExecutor
+    from jax import monitoring
+    xprof.install_monitoring_listener()
+    with ThreadPoolExecutor(1) as a, ThreadPoolExecutor(1) as b:
+        def on(pool, fn, *args, **kw):
+            pool.submit(fn, *args, **kw).result(timeout=30)
+
+        # a asks and hits; b asks, compiles and stores; each ends after the
+        # other has spoken
+        on(a, monitoring.record_event, CACHE + "compile_requests_use_cache")
+        on(b, monitoring.record_event, CACHE + "compile_requests_use_cache")
+        on(a, monitoring.record_event, CACHE + "cache_hits")
+        on(a, monitoring.record_event_duration_secs, CACHE + "compile_time_saved_sec", 3.0)
+        on(b, monitoring.record_event, CACHE + "cache_misses")
+        on(a, monitoring.record_event_duration_secs, BACKEND, 0.5, fun_name="jit(unit_a)")
+        on(b, monitoring.record_event_duration_secs, BACKEND, 2.0, fun_name="jit(unit_b)")
+        # a third compile on a, with no word from the cache
+        on(a, monitoring.record_event_duration_secs, BACKEND, 1.0, fun_name="jit(unit_a)")
+    assert _backend_series("jit(unit_a)") == {"hit": (1, 0.5), "off": (1, 1.0)}
+    assert _backend_series("jit(unit_b)") == {"stored": (1, 2.0)}
+    assert _saved("jit(unit_a)") == [(1, 3.0)] and _saved("jit(unit_b)") == [(0, 0.0)]
+
+
+def test_with_telemetry_off_the_listeners_hear_nothing():
+    xprof.install_monitoring_listener()
+    obs.set_enabled(False)
+    mark = time.time()
+    for cache in ("hit", "stored", "unstored", "off"):
+        _backend_compile("jit(unit_silent)", cache)
+    assert obs.metrics_wire() == [] and _ring_since(mark) == []
+    assert vars(xprof._pending) == {}         # nothing kept for a later compile
+    obs.set_enabled(True)
+    _backend_compile("jit(unit_silent)", "off")
+    assert _backend_series("jit(unit_silent)") == {"off": (1, 0.25)}
+
+
+def test_a_real_compile_is_stored_and_then_a_hit(tmp_path):
+    """One jit under a cache directory of its own, with jax's floors at
+    nothing: compiled and written, then (a second function object of the
+    same name and body: the same cache key) retrieved."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    xprof.install_monitoring_listener()
+    knobs = {"jax_enable_compilation_cache": True,
+             "jax_compilation_cache_dir": str(tmp_path),
+             "jax_persistent_cache_min_compile_time_secs": 0.0,
+             "jax_persistent_cache_min_entry_size_bytes": -1}
+    was = {k: getattr(jax.config, k) for k in knobs}
+
+    def make():
+        def unit_cached_fn(x):
+            return x * 3 + 2
+        return jax.jit(unit_cached_fn)
+
+    fun = "jit(unit_cached_fn)"
+    mark = time.time()
+    try:
+        for k, v in knobs.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+        x = np.ones(5, np.float32)
+        jax.block_until_ready(make()(x))
+        first = _backend_series(fun)
+        assert set(first) == {"stored"} and first["stored"][0] == 1
+        assert _saved(fun) == [(0, 0.0)]
+        jax.block_until_ready(make()(x))
+    finally:
+        for k, v in was.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    assert {c: n for c, (n, _) in _backend_series(fun).items()} == {"stored": 1, "hit": 1}
+    ((hits, saved_s),) = _saved(fun)
+    assert hits == 1 and saved_s >= 0.0
+    assert [e["cache"] for e in _compile_events(mark, fun)] == ["stored"]
+    # GET /3/Profiler/compiles: one row a function, the most seconds first
+    table = xprof.ledger_snapshot()["jax"]
+    assert [r["seconds"] for r in table] == sorted((r["seconds"] for r in table), reverse=True)
+    (row,) = [r for r in table if r["fun"] == fun]
+    assert {c: n for c, (n, _) in row["by_cache"].items()} == {"stored": 1, "hit": 1}
+    assert set(row["by_event"]) == {"jaxpr_to_mlir_module_duration", "backend_compile_duration"}
+    assert row["by_event"]["backend_compile_duration"][0] == 2 and row["saved_s"] == saved_s
+    assert row["seconds"] == pytest.approx(sum(s for _, s in row["by_event"].values()))
+
+
+# ------------------------------------------------------------------- uploads
+
+def test_an_upload_is_timed_where_it_happens(cl):
+    from h2o3_tpu.frame.frame import Frame
+    from h2o3_tpu.frame.vec import T_STR, Vec
+
+    def seconds():
+        return {s["l"]["stage"]: s["v"] for s in obs.metrics_wire()
+                if s["n"] == "transfer_seconds_total" and s["l"]["dir"] == "h2d"}
+
+    Vec.from_numpy(np.arange(1000, dtype=np.float64))
+    one = seconds()
+    assert set(one) == {"prepare", "put"} and all(v > 0 for v in one.values())
+    Vec.from_numpy(np.array(["a", "b"], dtype=object), T_STR)     # stays on the host
+    assert seconds() == one
+    mark = time.time()
+    Frame.from_numpy({"a": np.arange(50.0), "b": np.arange(50), "c": ["x", "y"] * 25})
+    two = seconds()
+    assert all(two[k] > one[k] for k in one)
+    (ev,) = [e for e in _ring_since(mark) if e["kind"] == "frame.upload"]
+    assert (ev["rows"], ev["cols"], ev["ok"]) == (50, 3, True)
+    assert obs.counter("transfer_bytes_total", dir="h2d").value > 0
+
+
+def test_parse_opens_the_same_upload_span(cl):
+    from h2o3_tpu.frame.parse import parse_csv
+    mark = time.time()
+    fr = parse_csv(b"a,b\n1,x\n2,y\n3,x\n")
+    (ev,) = [e for e in _ring_since(mark) if e["kind"] == "frame.upload"]
+    assert (ev["rows"], ev["cols"]) == (fr.nrows, 2) == (3, 2)
 
 
 # ------------------------------------------------------- idle time by span
