@@ -54,6 +54,7 @@ from .datainfo import (CodedDesign, DataInfo, block_rows, coded_matvec,
                        map_row_blocks, over_row_shards, sum_over_row_shards,
                        sum_row_blocks)
 from ..metrics.core import make_metrics
+from . import glm_gram
 
 
 # ------------------------------------------------------------------- families
@@ -359,8 +360,9 @@ def _make_path_runner(family: _Family, l1_mode: bool, max_iter: int,
 # categorical codes) a block of rows at a time.  A
 # block's products with a coefficient or a row vector need no expansion
 # (``coded_matvec`` / ``coded_rmatvec``: a one-hot column selects, every
-# float32 product exact).  The Gram is the one product that needs the MXU,
-# and the one for which the block's one-hot columns are written out.
+# float32 product exact).  The Gram is the one product that needs the MXU:
+# on a TPU the kernel of ``glm_gram`` forms it from the codes, elsewhere
+# XLA's product of the block's one-hot columns, written out.
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -401,7 +403,9 @@ def _gram_parts(layout, xr, nb, cb, wi):
     of the one-hot block with ``wi o X`` in three bfloat16 pieces, summed in
     float32: each float32 product exact, three passes of the MXU where the
     float32 product at ``highest`` takes six.  Of ``wi o X`` the one-hot
-    part is the pieces of ``wi`` itself where the column is lit.  Beside
+    part is the pieces of ``wi`` itself where the column is lit.  Where
+    ``glm_gram.engages`` (a TPU), its kernel forms the same products from
+    the codes, and ``top`` is the tuple of its sums.  Beside
     one-hot blocks the few other columns (numerics, intercept) multiply at
     ``highest``, a few percent of the block's work; a frame with no
     categorical (``top`` is None) keeps the product it always had, the
@@ -411,12 +415,17 @@ def _gram_parts(layout, xr, nb, cb, wi):
     yr = xr * wi[:, None]
     if not cat_layout:
         return None, jnp.dot(xr.T, yr)
+    rest = jnp.dot(xr.T, yr, precision=_HIGHEST)
+    if glm_gram.engages(layout):
+        return glm_gram.gram_parts(
+            layout, cb, _bf16_pieces(jnp.concatenate([wi[:, None], yr],
+                                                     axis=1))), rest
     hot = expand_coded(cat_layout, nb, cb).astype(jnp.bfloat16)
     top = sum(
         jnp.dot(hot.T, jnp.concatenate([hot * wk[:, None], yk], axis=1),
                 preferred_element_type=jnp.float32)
         for wk, yk in zip(_bf16_pieces(wi), _bf16_pieces(yr)))
-    return top, jnp.dot(xr.T, yr, precision=_HIGHEST)
+    return top, rest
 
 
 def _gram_of_parts(layout, top, rest):
@@ -424,6 +433,8 @@ def _gram_of_parts(layout, top, rest):
     (summed over blocks and shards first: this runs once a pass)."""
     if top is None:
         return rest
+    if isinstance(top, tuple):                  # the kernel's sums
+        top = glm_gram.top_of_parts(layout, top)
     cat, other, _, _ = _column_split(layout)
     nc = len(cat)
     both = jnp.concatenate([
@@ -1107,10 +1118,14 @@ class GLM(ModelBuilder):
                         fam, l1_mode, p.max_iterations, layout,
                         _fit_block_rows(layout, y.shape[0]))
                     design = tuple(X)
+                    kernel = "pallas" if glm_gram.engages(layout) else "xla"
                 else:
                     runner = _make_path_runner(fam, l1_mode=l1_mode,
                                                max_iter=p.max_iterations)
                     design = (X,)
+                    kernel = "xla"
+                # what forms the path's Grams: the kernel or XLA's product
+                obs.inc("glm_gram_kernel_total", kernel=kernel)
                 out = runner(
                     *design, y, w, offset, jnp.asarray(lambdas, jnp.float32),
                     jnp.float32(p.alpha), jnp.asarray(penalize, jnp.float32),

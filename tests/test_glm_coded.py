@@ -15,7 +15,7 @@ import pytest
 import h2o3_tpu
 from h2o3_tpu import Frame
 from h2o3_tpu.frame.vec import T_CAT
-from h2o3_tpu.models import datainfo, glm
+from h2o3_tpu.models import datainfo, glm, glm_gram
 from h2o3_tpu.models import reference_glm as ref
 from h2o3_tpu.models.datainfo import DataInfo
 from h2o3_tpu.models.glm import GLM, GLMParameters
@@ -83,6 +83,15 @@ def _blocked_pass(di, fr, beta, family, block):
                      jnp.asarray(beta, jnp.float32))
 
 
+def _force_gram(monkeypatch, kernel):
+    """``kernel="pallas"``: the Gram kernel engages off the TPU as it does on
+    one, wherever the layout has a one-hot block, and runs in Pallas'
+    interpreter (``glm_gram.gram_parts``); ``"xla"``: what the CPU runs."""
+    if kernel == "pallas":
+        monkeypatch.setattr(glm_gram, "engages",
+                            lambda layout: glm_gram._plan(layout) is not None)
+
+
 @pytest.fixture()
 def small_device(monkeypatch):
     """A device of 16 KB, as the code reads it: no frame of these tests has a
@@ -111,7 +120,10 @@ def _assert_sums_close(got, want, terms):
 @pytest.mark.parametrize("all_levels", [False, True])
 @pytest.mark.parametrize("which", ["clean", "na_unseen"])
 @pytest.mark.parametrize("rows", [640, 601])    # 640: 8 shards x 2 blocks of 40
-def test_blocked_pass_equals_reference(cl, standardize, all_levels, which, rows):
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_blocked_pass_equals_reference(cl, monkeypatch, standardize,
+                                       all_levels, which, rows, kernel):
+    _force_gram(monkeypatch, kernel)
     train = _frame(1, rows, na=False)
     di = DataInfo.fit(train, response_column="y", standardize=standardize,
                       use_all_factor_levels=all_levels)
@@ -130,6 +142,67 @@ def test_blocked_pass_equals_reference(cl, standardize, all_levels, which, rows)
         c5 = next(s for s in di.specs if s.name == "c5")
         assert np.asarray(gram)[c5.offset + c5.width - 1,
                                 c5.offset + c5.width - 1] > 0
+
+
+def _coded_frame(seed, n, widths, numerics):
+    """``numerics`` normal columns and categoricals of ``widths`` levels,
+    NA in both kinds, a binary response that depends on each."""
+    rng = np.random.default_rng(seed)
+    cols, domains, eta = {}, {"y": ["no", "yes"]}, np.zeros(n)
+    for i in range(numerics):
+        cols[f"x{i}"] = rng.normal(size=n).astype(np.float32)
+        eta += 0.5 * cols[f"x{i}"]
+        cols[f"x{i}"][::37] = np.nan
+    for i, width in enumerate(widths):
+        domains[f"c{i}"] = [f"L{k}" for k in range(width)]
+        cols[f"c{i}"] = rng.integers(0, width, n).astype(np.int32)
+        eta += rng.normal(0, 0.5, width)[cols[f"c{i}"]]
+        cols[f"c{i}"][::31 + i] = -1
+    cols["y"] = (eta + rng.logistic(size=n) > 0).astype(np.int32)
+    return Frame.from_numpy(cols, types={k: T_CAT for k in domains},
+                            domains=domains)
+
+
+@pytest.mark.parametrize("widths,numerics,all_levels", [
+    ((3, 22, 130), 2, False),       # three pieces of the later blocks packed
+    ((130,), 0, False),             # one categorical and the intercept
+    ((22, 3), 0, True),
+    ((60, 130, 5), 1, True),        # 130 meets 60 + 5 in a piece a product
+], ids=["3_22_130", "one_cat", "cats_only_all_levels", "60_130_5"])
+def test_gram_kernel_equals_xla_and_reference(cl, monkeypatch, widths,
+                                              numerics, all_levels):
+    """The Gram kernel (Pallas' interpreter, the 8-shard mesh) against the
+    XLA product and the reference, on layouts whose widths are no multiple
+    of 128; 601 rows in blocks of 40 a shard, the last laid back over
+    zero-weight rows and the frame's padding."""
+    fr = _coded_frame(17, 601, widths, numerics)
+    di = DataInfo.fit(fr, response_column="y",
+                      use_all_factor_levels=all_levels)
+    X, y, w = _dense(di, fr)
+    beta = np.random.default_rng(3).normal(0, 0.3, di.nfeatures).astype(np.float32)
+    want, _, _ = ref.irls_stats(X, y, w, beta, 0.0, "binomial")
+    xla = _blocked_pass(di, fr, beta, "binomial", block=40)
+    _force_gram(monkeypatch, "pallas")
+    assert glm_gram.engages(di.coded_layout())
+    got = _blocked_pass(di, fr, beta, "binomial", block=40)
+    terms = np.abs(X).T @ np.abs(X)
+    _assert_sums_close(got[0], xla[0], terms)
+    _assert_sums_close(got[0], want, terms)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(xla[1]))
+
+
+def test_gram_kernel_plan():
+    """The airlines layout's plan: the 300-level groups first, the 22-level
+    block a later side only, whose three pieces share one 128-lane product;
+    a layout whose accumulators pass the VMEM budget has no plan and keeps
+    the XLA product."""
+    plan = glm_gram._plan((("num", 5), ("cat", 22), ("cat", 300),
+                           ("cat", 300), ("one", 1)))
+    assert [(g.cat, g.rows, g.lanes, g.packed) for g in plan.groups] == [
+        (1, 304, 384, False), (2, 304, 128, True), (0, 32, 0, False)]
+    assert (plan.n_other, plan.side_rows, plan.tile) == (6, 32, 1024)
+    assert glm_gram._plan((("cat", 3000), ("cat", 3000))) is None
+    assert glm_gram._plan((("num", 3), ("one", 1))) is None
 
 
 # ------------------------------------------------------------ (b) the fit
@@ -231,7 +304,9 @@ def test_p_values_come_from_the_final_gram(cl, small_device):
 
 
 # ------------------------------------------------- (f) the mesh, one device
-def test_mesh_equals_one_device(cl, small_device):
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_mesh_equals_one_device(cl, small_device, monkeypatch, kernel):
+    _force_gram(monkeypatch, kernel)
     cols_fr = _frame(12, 1000, weights=True)
 
     def fit_and_pass():
@@ -326,3 +401,46 @@ def test_dense_solvers_refuse_a_design_that_passes_the_device(cl, monkeypatch,
     message = str(e.value)
     assert what in message and "solver='irlsm'" in message
     assert f"{fr.padded_rows // cl.n_row_shards * 302 * 4:,} bytes" in message
+
+
+# -------------------------------------- (h) which Gram a fit's passes form
+def _gram_kernel_counts():
+    from h2o3_tpu.runtime import observability as obs
+    return {k: obs.counter("glm_gram_kernel_total", kernel=k).value
+            for k in ("pallas", "xla")}
+
+
+def _one_rise(before, after):
+    return {k: after[k] - before[k] for k in after if after[k] != before[k]}
+
+
+def test_gram_kernel_counter_blocked(cl, small_device, monkeypatch):
+    """``glm_gram_kernel_total{kernel}``: one increment a fit of the blocked
+    runner, ``pallas`` where the kernel forms its Grams (forced here, as a
+    TPU engages it), ``xla`` off the TPU and for a frame with no
+    categorical."""
+    fr = _frame(18, 700)
+    kw = dict(family="binomial", lambda_=0.0, response_column="y")
+    before = _gram_kernel_counts()
+    xla = GLM(**kw).train(fr)
+    mid = _gram_kernel_counts()
+    assert _one_rise(before, mid) == {"xla": 1}
+    _force_gram(monkeypatch, "pallas")
+    kernel = GLM(**kw).train(fr)
+    after = _gram_kernel_counts()
+    assert _one_rise(mid, after) == {"pallas": 1}
+    np.testing.assert_allclose(kernel.output["beta_std"],
+                               xla.output["beta_std"], atol=2e-5)
+    # 2,000 rows x 3 columns do not fit the 16 KB device dense either
+    GLM(**kw).train(_coded_frame(18, 2000, (), 2))
+    assert _one_rise(after, _gram_kernel_counts()) == {"xla": 1}
+
+
+def test_gram_kernel_counter_dense(cl, monkeypatch):
+    """The dense design's program forms its Gram by XLA's product."""
+    _force_gram(monkeypatch, "pallas")
+    fr = _frame(19, 500)
+    before = _gram_kernel_counts()
+    model = GLM(family="binomial", lambda_=0.0, response_column="y").train(fr)
+    assert not isinstance(model._score_matrix(fr), datainfo.CodedDesign)
+    assert _one_rise(before, _gram_kernel_counts()) == {"xla": 1}

@@ -256,10 +256,13 @@ def test_glm_programs_hold_no_frame_sized_expansion(one_chip, v5e_memory):
     _, sds, rows = one_chip
     n = 40_000_000
     fn, operands = _glm_path(sds, rows, AIRLINES_LAYOUT, n)
-    path, _ = _compile(fn, *operands)
+    path, text = _compile(fn, *operands)
     ma = path.memory_analysis()
     assert ma.argument_size_in_bytes < 2.5e9
     assert ma.temp_size_in_bytes < V5E_BYTES / 4
+    # the Gram kernel builds the one-hot tiles in VMEM: no block of bf16
+    # operands [rows, 622] goes through HBM (PR 42)
+    assert "glm_gram" in text and not re.search(r"bf16\[\d+,622\]", text)
     fn, operands = _glm_score(sds, rows, AIRLINES_LAYOUT, n)
     score, _ = _compile(fn, *operands)
     ma = score.memory_analysis()
@@ -433,6 +436,8 @@ MODULES = {
     "jit_glm_score": _glm_score,
 }
 KERNELS = {
+    # the blocked IRLSM program's Gram (PR 42)
+    "glm_gram": _glm_path,
     "hist_uniform": _hist_kernel(lambda hist, sds, rows: (
         hist.make_hist_fn(1, F, B, SMALL_N),
         _hist_operands(sds, rows, SMALL_N, jnp.int32))),
