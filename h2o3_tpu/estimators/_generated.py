@@ -1167,14 +1167,15 @@ class H2ORuleFitEstimator(_RemoteEstimator):
     max_num_rules : int, default -1
     model_type : str, default 'rules_and_linear'
     rule_generation_ntrees : int, default 30
+    remove_duplicates : bool, default True
     lambda_ : Optional[float], default None
     """
 
     algo = 'rulefit'
-    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'algorithm', 'min_rule_length', 'max_rule_length', 'max_num_rules', 'model_type', 'rule_generation_ntrees', 'lambda_')
-    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': True, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'algorithm': 'gbm', 'min_rule_length': 1, 'max_rule_length': 3, 'max_num_rules': -1, 'model_type': 'rules_and_linear', 'rule_generation_ntrees': 30, 'lambda_': None}
+    _param_names = ('response_column', 'ignored_columns', 'weights_column', 'offset_column', 'seed', 'max_iterations', 'standardize', 'missing_values_handling', 'stopping_rounds', 'stopping_metric', 'stopping_tolerance', 'checkpoint', 'export_checkpoints_dir', 'snapshot_interval', 'balance_classes', 'class_sampling_factors', 'nfolds', 'fold_column', 'fold_assignment', 'keep_cross_validation_predictions', 'custom_metric_func', 'parallelism', 'priority', 'device_budget', 'retry_budget', 'stream', 'warm_start', 'algorithm', 'min_rule_length', 'max_rule_length', 'max_num_rules', 'model_type', 'rule_generation_ntrees', 'remove_duplicates', 'lambda_')
+    _param_defaults = {'response_column': None, 'ignored_columns': [], 'weights_column': None, 'offset_column': None, 'seed': -1, 'max_iterations': 50, 'standardize': True, 'missing_values_handling': 'mean_imputation', 'stopping_rounds': 0, 'stopping_metric': 'auto', 'stopping_tolerance': 0.001, 'checkpoint': None, 'export_checkpoints_dir': None, 'snapshot_interval': -1.0, 'balance_classes': False, 'class_sampling_factors': None, 'nfolds': 0, 'fold_column': None, 'fold_assignment': 'auto', 'keep_cross_validation_predictions': False, 'custom_metric_func': None, 'parallelism': 0, 'priority': None, 'device_budget': None, 'retry_budget': 0, 'stream': False, 'warm_start': None, 'algorithm': 'gbm', 'min_rule_length': 1, 'max_rule_length': 3, 'max_num_rules': -1, 'model_type': 'rules_and_linear', 'rule_generation_ntrees': 30, 'remove_duplicates': True, 'lambda_': None}
 
-    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, algorithm=None, min_rule_length=None, max_rule_length=None, max_num_rules=None, model_type=None, rule_generation_ntrees=None, lambda_=None):
+    def __init__(self, *, response_column=None, ignored_columns=None, weights_column=None, offset_column=None, seed=None, max_iterations=None, standardize=None, missing_values_handling=None, stopping_rounds=None, stopping_metric=None, stopping_tolerance=None, checkpoint=None, export_checkpoints_dir=None, snapshot_interval=None, balance_classes=None, class_sampling_factors=None, nfolds=None, fold_column=None, fold_assignment=None, keep_cross_validation_predictions=None, custom_metric_func=None, parallelism=None, priority=None, device_budget=None, retry_budget=None, stream=None, warm_start=None, algorithm=None, min_rule_length=None, max_rule_length=None, max_num_rules=None, model_type=None, rule_generation_ntrees=None, remove_duplicates=None, lambda_=None):
         self._init_from(locals())
 
 class H2OAggregatorEstimator(_RemoteEstimator):

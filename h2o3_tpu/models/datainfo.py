@@ -229,6 +229,10 @@ class ColumnSpec:
     time_base: float = 0.0
     offset: int = 0                 # first output column index
     width: int = 1                  # number of output columns
+    # a categorical with a column for EVERY level of ``domain`` and none for
+    # missing values, whatever ``use_all_factor_levels`` says: a group of
+    # RuleFit's rules, whose codes its caller builds and never miss
+    all_levels: bool = False
 
 
 def _numeric_values(vec: Vec, s: ColumnSpec) -> jax.Array:
@@ -275,7 +279,9 @@ class DataInfo:
     def coef_names(self) -> List[str]:
         names = []
         for s in self.specs:
-            if s.type == T_CAT:
+            if s.all_levels:
+                names += [f"{s.name}.{lbl}" for lbl in s.domain]
+            elif s.type == T_CAT:
                 lo = 0 if self.use_all_factor_levels else 1
                 names += [f"{s.name}.{lbl}" for lbl in s.domain[lo:]]
                 names.append(f"{s.name}.missing(NA)")
@@ -352,6 +358,27 @@ class DataInfo:
                         offset_column, standardize, use_all_factor_levels,
                         missing_values_handling, add_intercept, nfeat,
                         response_mean=rmean, response_sigma=rsigma)
+
+    def with_groups(self, groups: Sequence[Tuple[str, List[str]]],
+                    keep_specs: bool = True) -> "DataInfo":
+        """This layout with categoricals of all their levels in front
+        (``ColumnSpec.all_levels``), one per ``(name, level names)`` of
+        ``groups``, and this layout's columns after them unless
+        ``keep_specs`` is False.  Its code form is the caller's: the groups'
+        codes come first in ``CodedDesign.codes``, then ``make_coded``'s of
+        this layout (RuleFit's rule design)."""
+        specs, at = [], 0
+        for name, levels in groups:
+            specs.append(ColumnSpec(name, T_CAT, list(levels), 0.0, 1.0,
+                                    offset=at, width=len(levels),
+                                    all_levels=True))
+            at += len(levels)
+        if keep_specs:
+            specs += [dataclasses.replace(s, offset=s.offset + at)
+                      for s in self.specs]
+            at = self.nfeatures - int(self.add_intercept) + at
+        return dataclasses.replace(self, specs=specs,
+                                   nfeatures=at + int(self.add_intercept))
 
     # ---------------------------------------------------------- application
     def make_matrix(self, frame: Frame, standardize: Optional[bool] = None) -> jax.Array:

@@ -294,6 +294,135 @@ def _coordinate_descent(G, c, l1, l2, penalize, warm, max_inner: int):
     return beta
 
 
+# a sweep of ``_group_coordinate_descent`` ends it when no move of the sweep
+# changed a gradient entry by more: the float32 noise of a row's product
+# with coefficients of order 1 (PERF.md, section 6)
+CD_TOLERANCE = 1e-6
+
+
+def _group_coordinate_descent(G, c, l1, l2, penalize, warm, max_inner: int,
+                              runs: tuple, partition):
+    """``_coordinate_descent``'s problem on a design of one-hot runs, a run
+    at a time: RuleFit's lasso.  Returns the coefficients and the sweeps
+    it ran.
+
+    ``runs``, (first, width) of runs of one-hot columns: a row lights at
+    most one column of a run, so the Gram is 0 between two columns of one
+    run, and updating a run's coordinates together is updating them one
+    after the other.  A sweep takes each run in one step (a [width, P]
+    slice of the Gram), then every other coordinate alone: RuleFit's 400
+    rule columns in 50 steps where one at a time took 400, each step
+    costing about the same on a TPU.  A row's product with ``b`` is a
+    float32 multiply and sum, which a TPU does not round to bfloat16 as it
+    does a float32 ``dot`` at the default precision.
+
+    A sweep ends the descent when no move of it is worth more than
+    ``CD_TOLERANCE`` of the gradient (``G_jj`` times the move), at most
+    ``max_inner`` sweeps.  The gradient and not the coefficient measures a
+    move, because a float32 sweep over coefficients of order 1 moves some
+    by an ulp each time (1e-7, where a limit of 1e-8 on the coefficient had
+    every solve run its 100 sweeps), and a column that few rows light (a
+    rule's raw 0/1 column) moves far for a small gradient.
+
+    ``partition`` [runs] bool, on the device: the runs whose rows partition
+    the frame's.  Such a run's columns sum to the intercept's (the last),
+    so adding t to the run and taking t from the intercept leaves the
+    design's products, and ``0.5 b'Gb - c'b``, as they are.  One
+    coordinate or one run at a time moves along no such line, and on a
+    design of many the sweeps stall short of the optimum.  So after every
+    sweep each such run moves along its line to the least L1 penalty
+    there: t at a weighted median of the run's -b_j, weights l1_j, the best
+    of the run's own candidates (``l2`` is 0 on such runs: a lasso's)."""
+    P = warm.shape[0]
+    width = max(w for _, w in runs)
+    slot = np.full((len(runs), width), P)       # P: a coordinate of zeros
+    for g, (first, w) in enumerate(runs):
+        slot[g, :w] = np.arange(first, first + w)
+    lit = slot < P
+    single = np.setdiff1d(np.arange(P), slot[lit])
+    order = np.concatenate([slot.ravel(), single])
+    back = np.empty(P, np.int64)
+    back[order[order < P]] = np.flatnonzero(order < P)
+    hot = slot.size
+
+    def arranged(v):
+        return jnp.concatenate([v, jnp.zeros((1,), v.dtype)])[order]
+
+    Gp = jnp.pad(G, ((0, 1), (0, 1)))[order][:, order]
+    cp, l1p, l2p, pen = (arranged(v) for v in (c, l1, l2, penalize))
+    d = jnp.diag(Gp)
+
+    def step(lo, size, bd):
+        b, delta = bd
+
+        def at(v):
+            return jax.lax.dynamic_slice(v, (lo,), (size,))
+
+        rows = jax.lax.dynamic_slice(Gp, (lo, 0), (size, Gp.shape[1]))
+        bb, dd = at(b), at(d)
+        r = at(cp) - (jnp.sum(rows * b[None, :], axis=1) - dd * bb)
+        new = jnp.where(
+            at(pen) > 0,
+            jnp.sign(r) * jnp.maximum(jnp.abs(r) - at(l1p), 0.0)
+            / (dd + at(l2p) + 1e-12),
+            r / (dd + 1e-12))
+        delta = jnp.maximum(delta, jnp.max(dd * jnp.abs(new - bb)))
+        return jax.lax.dynamic_update_slice(b, new, (lo,)), delta
+
+    def along_null_lines(b):
+        B = b[:hot].reshape(slot.shape)
+        w1 = l1p[:hot].reshape(slot.shape)
+        cost = jnp.where(lit, jnp.sum(
+            w1[:, None, :] * jnp.abs(B[:, None, :] - B[:, :, None]), axis=2),
+            jnp.inf)
+        best = jnp.argmin(cost, axis=1)
+        shift = jnp.where(partition, jnp.take_along_axis(
+            B, best[:, None], axis=1)[:, 0], 0.0)
+        lower = jnp.max(jnp.where(
+            partition, jnp.sum(w1 * jnp.abs(B), axis=1) - jnp.min(cost, axis=1),
+            0.0))
+        B = B - jnp.where(lit, shift[:, None], 0.0)
+        b = b.at[:hot].set(B.reshape(-1)).at[back[P - 1]].add(jnp.sum(shift))
+        return b, lower
+
+    def sweep(state):
+        b, _, it = state
+        bd = jax.lax.fori_loop(0, len(runs), lambda g, bd: step(
+            g * width, width, bd), (b, jnp.float32(0.0)))
+        b, delta = jax.lax.fori_loop(hot, hot + single.size, lambda j, bd: step(
+            j, 1, bd), bd)
+        b, lower = along_null_lines(b)
+        return b, jnp.maximum(delta, lower), it + 1
+
+    def cond(state):
+        _, delta, it = state
+        return (it < max_inner) & (delta > CD_TOLERANCE)
+
+    b, _, sweeps = jax.lax.while_loop(
+        cond, sweep, (arranged(warm), jnp.float32(jnp.inf), jnp.int32(0)))
+    return b[back], sweeps
+
+
+def _l1_change(nb, beta, penalize, sweeps):
+    """How far a pass of ``_group_coordinate_descent`` moved the
+    coefficients, for ``beta_epsilon``.
+
+    A coefficient's change is measured on the scale its penalty factor
+    gives its column (1 for a standardised column, a raw 0/1 rule
+    column's deviation; 1 where it is not penalized), so ``beta_epsilon``
+    means what it means on a standardised design.  And the change is 0
+    where the pass's coordinate descent ended after ONE sweep: no
+    coordinate of the pass's starting beta was off its optimum by
+    ``CD_TOLERANCE`` of the gradient, which the pass's Gram and score give
+    exactly at that beta, so the lasso's optimality conditions hold there
+    and IRLS stops.  Along a flat direction of the design (rules that
+    light nearly the same rows) the coefficients move a little at every
+    pass long after the gradient has settled, and ``beta_epsilon`` alone
+    ran the passes to ``max_iterations``."""
+    scale = jnp.where(penalize > 0, penalize, 1.0)
+    return jnp.where(sweeps <= 1, 0.0, jnp.max(jnp.abs(nb - beta) * scale))
+
+
 def _make_path_runner(family: _Family, l1_mode: bool, max_iter: int,
                       max_inner: int = 100):
     """The WHOLE regularization path as one device program.
@@ -491,7 +620,7 @@ def _fit_block_rows(layout: tuple, padded_rows: int) -> int:
 
 def _make_blocked_path_runner(family: _Family, l1_mode: bool, max_iter: int,
                               layout: tuple, block: int,
-                              max_inner: int = 100):
+                              max_inner: int = 100, runs: tuple = ()):
     """``_make_path_runner``'s program on the design in code form: the same
     scan over lambdas, ``while_loop`` of IRLS passes, solve on the device and
     one fetch of the same five results.  It departs in two things.
@@ -506,11 +635,18 @@ def _make_blocked_path_runner(family: _Family, l1_mode: bool, max_iter: int,
     solve's error then scales with the step and vanishes at convergence,
     where solved for ``beta'`` it scales with cond(G) |beta'| and stays (a
     wide one-hot design beside an intercept is not well conditioned).
+
+    With ``runs`` (RuleFit's rule groups: ``_group_coordinate_descent``) an
+    L1 program takes one more argument, the runs' ``partition`` flags,
+    solves by ``_group_coordinate_descent``, ends a lambda's passes by
+    ``_l1_change`` and returns a sixth result, the sweeps of all its
+    solves.
     """
     irls_gram = _make_irls_gram(family, layout, block)
+    grouped = l1_mode and bool(runs)
 
     def run(num, codes, y, w, offset, lambdas, alpha, penalize, beta0, n,
-            beta_eps):
+            beta_eps, *partition):
         n_coef = beta0.shape[0]
 
         def solve(G, score, lam, beta):
@@ -520,6 +656,10 @@ def _make_blocked_path_runner(family: _Family, l1_mode: bool, max_iter: int,
                 return beta + jnp.linalg.solve(G + jnp.diag(ridge),
                                                score - ridge * beta)
             c = jnp.dot(G, beta, precision=_HIGHEST) + score    # X'Wz / n
+            if grouped:
+                return _group_coordinate_descent(
+                    G, c, lam * alpha * penalize, l2, penalize, beta,
+                    max_inner, runs, partition[0])
             return _coordinate_descent(G, c, lam * alpha * penalize, l2,
                                        penalize, beta, max_inner)
 
@@ -528,31 +668,40 @@ def _make_blocked_path_runner(family: _Family, l1_mode: bool, max_iter: int,
             lam, closing = step
 
             def body(state):
-                beta, _, it, _, _ = state
+                beta, _, it, _, _ = state[:5]
                 gram, score, dev = irls_gram(num, codes, y, w, offset, beta)
-                nb = jnp.where(closing, beta,
-                               solve(gram / n, score / n, lam, beta))
-                delta = jnp.max(jnp.abs(nb - beta))
-                return nb, delta, it + 1, dev, gram
+                if not grouped:
+                    nb = jnp.where(closing, beta,
+                                   solve(gram / n, score / n, lam, beta))
+                    delta = jnp.max(jnp.abs(nb - beta))
+                    return nb, delta, it + 1, dev, gram
+                # the closing pass solves nothing: no sweeps are run
+                nb, sweeps = jax.lax.cond(
+                    closing, lambda: (beta, jnp.int32(0)),
+                    lambda: solve(gram / n, score / n, lam, beta))
+                return (nb, _l1_change(nb, beta, penalize, sweeps), it + 1,
+                        dev, gram, state[5] + sweeps)
 
             def cond(state):
-                _, delta, it, _, _ = state
+                _, delta, it, _, _ = state[:5]
                 return (it < jnp.where(closing, 1, max_iter)) \
                     & (delta >= beta_eps)
 
-            beta, _, iters, dev, gram = jax.lax.while_loop(
+            beta, _, iters, dev, gram, *sweeps = jax.lax.while_loop(
                 cond, body, (beta, jnp.float32(jnp.inf), 0, jnp.float32(0.0),
-                             jnp.zeros((n_coef, n_coef), jnp.float32)))
-            return (beta, gram), (beta, dev, iters)
+                             jnp.zeros((n_coef, n_coef), jnp.float32)) + (
+                                 (jnp.int32(0),) if grouped else ()))
+            return (beta, gram), (beta, dev, iters, *sweeps)
 
         # the path's lambdas, then the closing step: ONE pass at the final
         # beta, whose Gram (p-values) and deviance are the program's last two
         steps = (jnp.concatenate([lambdas, lambdas[-1:]]),
                  jnp.arange(lambdas.shape[0] + 1) == lambdas.shape[0])
-        (_, gram_fin), (betas, devs, iters) = jax.lax.scan(
+        (_, gram_fin), (betas, devs, iters, *sweeps) = jax.lax.scan(
             per_lambda, (beta0, jnp.zeros((n_coef, n_coef), jnp.float32)),
             steps)
-        return betas[:-1], devs[:-1], iters[:-1], gram_fin, devs[-1]
+        return (betas[:-1], devs[:-1], iters[:-1], gram_fin, devs[-1]) \
+            + tuple(jnp.sum(s) for s in sweeps)
 
     return _ledger("glm_path", jax.jit(run), orig=run)
 
@@ -896,12 +1045,37 @@ class GLM(ModelBuilder):
         return model
 
     # -------------------------------------------------------- lambda path
-    def _lambda_path(self, p: GLMParameters, X, y, w, di, fam_name) -> List[float]:
+    def fit_coded(self, job: Job, frame: Frame, di: DataInfo,
+                  X: CodedDesign, y, w, offset, penalize: np.ndarray,
+                  runs: tuple, partition: np.ndarray) -> GLMModel:
+        """IRLSM's path on a design its caller built in code form, under
+        ``di`` (whose ``coded_layout`` it has), with the caller's response,
+        weights, offset and per-coefficient penalty factors: the blocked
+        runner whatever the design's size, and the same ``_fit_single``,
+        spans and ``_finalize`` as ``train``.  ``runs``, (first, width) of
+        the design's runs of one-hot columns, and ``partition``, which of
+        them partition the frame's rows, go to a lasso's
+        ``_group_coordinate_descent``; the lambda path is taken on the
+        penalty factors (``_lambda_path``).  RuleFit fits its rules so."""
+        p: GLMParameters = self.params
+        fam_name = self._resolve_family(di)
+        with obs.span("glm.matrix"):
+            n = float(jnp.sum(w))
+        lambdas = self._lambda_path(p, X, y, w, di, fam_name,
+                                    factors=penalize)
+        return self._fit_single(job, frame, di, X, y, w, offset, n, penalize,
+                                lambdas, fam_name, None, runs=runs,
+                                partition=partition)
+
+    def _lambda_path(self, p: GLMParameters, X, y, w, di, fam_name,
+                     factors: Optional[np.ndarray] = None) -> List[float]:
         if p.lambda_ is not None and not p.lambda_search:
             return list(np.atleast_1d(np.asarray(p.lambda_, dtype=np.float64)))
         if not p.lambda_search:
             return [0.0]
-        # lambda_max: smallest lambda zeroing all coefs = max |X'(y-ybar)|/(n*alpha)
+        # lambda_max: smallest lambda zeroing all coefs = max |X'(y-ybar)|/(n*alpha);
+        # with penalty ``factors``, max_j |X_j'(y-ybar)| / (n * alpha * factor
+        # j) over the penalized coefficients (glmnet's convention)
         fam = _make_family(fam_name, p)
         eta0 = fam.init_eta(y, w)
         mu0 = fam.linkinv(eta0)
@@ -912,10 +1086,13 @@ class GLM(ModelBuilder):
             grad = np.asarray(jnp.abs(xtv(v, *X)))
         else:
             grad = np.asarray(jnp.abs(X.T @ v))
-        if di.add_intercept:
+        if factors is not None:
+            factor = np.asarray(factors, np.float64)
+            grad = grad[factor > 0] / factor[factor > 0]
+        elif di.add_intercept:
             grad = grad[:-1]
         n = max(float(jnp.sum(w)), 1.0)
-        lmax = float(grad.max()) / max(p.alpha, 1e-3) / n
+        lmax = float(grad.max(initial=0.0)) / max(p.alpha, 1e-3) / n
         lmin = lmax * p.lambda_min_ratio
         return list(np.geomspace(lmax, lmin, p.nlambdas))
 
@@ -1088,7 +1265,8 @@ class GLM(ModelBuilder):
 
     # ------------------------------------------------------- single-class
     def _fit_single(self, job, frame, di, X, y, w, offset, n, penalize,
-                    lambdas, fam_name, valid) -> GLMModel:
+                    lambdas, fam_name, valid,
+                    runs: tuple = (), partition=None) -> GLMModel:
         p: GLMParameters = self.params
         if p.solver.lower() in ("l_bfgs", "lbfgs"):
             return self._fit_lbfgs(job, frame, di, X, y, w, offset, n,
@@ -1116,7 +1294,7 @@ class GLM(ModelBuilder):
                     layout = di.coded_layout()
                     runner = _make_blocked_path_runner(
                         fam, l1_mode, p.max_iterations, layout,
-                        _fit_block_rows(layout, y.shape[0]))
+                        _fit_block_rows(layout, y.shape[0]), runs=runs)
                     design = tuple(X)
                     kernel = "pallas" if glm_gram.engages(layout) else "xla"
                 else:
@@ -1126,11 +1304,15 @@ class GLM(ModelBuilder):
                     kernel = "xla"
                 # what forms the path's Grams: the kernel or XLA's product
                 obs.inc("glm_gram_kernel_total", kernel=kernel)
+                # RuleFit's lasso: its runs' partition flags, and the sweeps
+                # of its coordinate descent come back sixth
+                grouped = l1_mode and bool(runs)
                 out = runner(
                     *design, y, w, offset, jnp.asarray(lambdas, jnp.float32),
                     jnp.float32(p.alpha), jnp.asarray(penalize, jnp.float32),
                     jnp.asarray(beta, jnp.float32), jnp.float32(n),
-                    jnp.float32(p.beta_epsilon))
+                    jnp.float32(p.beta_epsilon),
+                    *((jnp.asarray(partition, bool),) if grouped else ()))
             with obs.span("glm.wait"):
                 # the wait for the device and the fetch of its few KB in
                 # one call, as device_get queues the copies behind the
@@ -1138,11 +1320,14 @@ class GLM(ModelBuilder):
                 fetched = jax.device_get(out)
                 obs.inc("transfer_bytes_total",
                         sum(a.nbytes for a in fetched), dir="d2h")
-            betas, devs, iters, gram_fin, dev_fin = fetched
+            betas, devs, iters, gram_fin, dev_fin = fetched[:5]
             obs.inc("glm_path_launches_total")
             # every IRLS iteration of every lambda and the pass of the final
             # Gram
             obs.inc("glm_irls_passes_total", int(np.sum(iters)) + 1)
+            if grouped:
+                # the coordinate-descent sweeps of all the path's solves
+                obs.inc("glm_cd_sweeps_total", int(fetched[5]))
             hist = [{"lambda": float(lam), "iteration": int(iters[li]),
                      "deviance": float(devs[li]), "delta": float("nan")}
                     for li, lam in enumerate(lambdas)]

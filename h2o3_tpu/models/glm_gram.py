@@ -122,9 +122,21 @@ def _plan(layout: tuple) -> Optional[_Plan]:
 
 def engages(layout: tuple) -> bool:
     """Whether a pass of the blocked IRLSM forms its Gram by the kernel:
-    the layout has a one-hot block that fits the kernel's plan, on a TPU.
-    Everywhere else ``glm._gram_parts`` keeps the XLA product."""
-    return _plan(layout) is not None and _on_tpu()
+    the layout has a one-hot block that fits the kernel's plan and is at
+    least a row tile of the MXU wide (``_LANES`` levels), on a TPU.
+    Everywhere else ``glm._gram_parts`` keeps the XLA product.
+
+    The width is the rule's reading of the layout.  Where every group is
+    narrower, as RuleFit's many groups of 2^depth rules are, a group's
+    one-hot tile fills a few of the MXU's rows and its cross columns are
+    built by one compare per later group: 50 groups of 8 make 1,225
+    compares of a 512-lane row for every row of the block, and Mosaic's
+    register allocator spills 158 MB of them into VMEM, so the kernel
+    does not compile there (one v5e, PERF.md section 6), where XLA's
+    product of the expanded block is one pass of the MXU over its 400
+    one-hot columns."""
+    return (_plan(layout) is not None and _on_tpu()
+            and max(w for kind, w in layout if kind == "cat") >= _LANES)
 
 
 def _kernel(plan: _Plan, codes_ref, side_ref, *out_refs):

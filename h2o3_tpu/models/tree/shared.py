@@ -2152,6 +2152,10 @@ class SharedTreeModel(Model):
 
     def _design(self, frame: Frame) -> jax.Array:
         """Raw-value matrix [padded, F]: numerics as-is, cats as codes."""
+        return jnp.stack(self._design_columns(frame), axis=1)
+
+    def _design_columns(self, frame: Frame) -> List[jax.Array]:
+        """``_design``'s columns, [padded] each, unstacked."""
         di = self.datainfo
         cols = []
         for s in di.specs:
@@ -2162,7 +2166,7 @@ class SharedTreeModel(Model):
                                       codes.astype(jnp.float32)))
             else:
                 cols.append(vec.values())
-        return jnp.stack(cols, axis=1)
+        return cols
 
     def _raw_scores(self, X: jax.Array):
         from ...runtime import observability as obs

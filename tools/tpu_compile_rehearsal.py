@@ -17,6 +17,7 @@ compiler's memory analysis (bytes per device).
     JAX_PLATFORMS=cpu python tools/tpu_compile_rehearsal.py            # all
     JAX_PLATFORMS=cpu python tools/tpu_compile_rehearsal.py tree_build glm_path
     JAX_PLATFORMS=cpu python tools/tpu_compile_rehearsal.py --chips 4 tree_build
+    JAX_PLATFORMS=cpu python tools/tpu_compile_rehearsal.py rule_codes glm_path_rulefit
 
 The cheap single-kernel compiles live in tests/test_tpu_compile.py (tier-1);
 these take tens of seconds each and are run by hand before a chip call.
@@ -208,11 +209,12 @@ def main(argv=None) -> int:
                     sds((P_HIGGS,), jnp.float32), sds((P_HIGGS,), jnp.float32),
                     sds((), jnp.float32), sds((), jnp.float32))
 
-    def glm_path_blocked(layout, n):
+    def glm_path_blocked(layout, n, l1_mode=False, runs=()):
         fam = glm_mod._make_family("binomial", glm_mod.GLMParameters())
         n = cl.pad_rows(n)
         fn = glm_mod._make_blocked_path_runner(
-            fam, False, 50, layout, glm_mod._fit_block_rows(layout, n))
+            fam, l1_mode, 50, layout, glm_mod._fit_block_rows(layout, n),
+            runs=runs)
         width = sum(w for _, w in layout)
         vec, coef = sds((n,), jnp.float32, rows), sds((width,), jnp.float32)
         return fn, (sds((n, sum(w for k, w in layout if k == "num")),
@@ -221,7 +223,8 @@ def main(argv=None) -> int:
                         mat),
                     vec, vec, vec, sds((1,), jnp.float32),
                     sds((), jnp.float32), coef, coef, sds((), jnp.float32),
-                    sds((), jnp.float32))
+                    sds((), jnp.float32)) + (
+                        (sds((len(runs),), jnp.bool_),) if runs else ())
 
     # DeepLearning at the benchmark's dl_airlines40m geometry: 5 numerics,
     # categoricals of 22 / 300 / 300 levels (first level dropped, NA column
@@ -270,6 +273,30 @@ def main(argv=None) -> int:
         return fn, (sds((dl_sizes[0],), jnp.float32), sds((0,), jnp.float32),
                     *dl_design(n))
 
+    # RuleFit at rulefit_higgs11m's geometry: 50 depth-3 trees over 11M x 28,
+    # their rules as 50 groups of 8 levels beside the 28 numerics, every
+    # group a partition of the rows (the lasso's null lines)
+    n_rf, trees_rf = 11_000_000, 50
+    rf_layout = (("cat", 8),) * trees_rf + (("num", 28), ("one", 1))
+
+    def rule_codes():
+        from h2o3_tpu.models import rulefit as rulefit_mod
+        n = cl.pad_rows(n_rf)
+        levels = [(sds((trees_rf, 2 ** d), jnp.int32),
+                   sds((trees_rf, 2 ** d), jnp.float32),
+                   sds((trees_rf, 2 ** d), jnp.bool_),
+                   sds((trees_rf, 2 ** d), jnp.bool_)) for d in range(3)]
+        return rulefit_mod.jit_rule_codes, (
+            tuple(sds((n,), jnp.float32, rows) for _ in range(28)), levels,
+            sds((trees_rf, 1, 8), jnp.int32), sds((), jnp.int32))
+
+    def glm_path_rulefit():
+        # the lasso path in L1 mode, a rule group a step, with the groups'
+        # null-line moves
+        return glm_path_blocked(rf_layout, n_rf, l1_mode=True,
+                                runs=tuple((8 * g, 8)
+                                           for g in range(trees_rf)))
+
     # the merge gate's tables (benchmark/configs/munge_100m_x2.json): 100M
     # rows a side, an int32 key and a float32 value, some 91M rows out
     from h2o3_tpu.rapids import device as munge
@@ -301,7 +328,8 @@ def main(argv=None) -> int:
     programs = {f.__name__: f for f in (
         tree_build, tree_build_scan, tree_build_k7, tree_scan, sparse_level,
         grid_scan, serve_xla, traverse, prediction_columns, glm_path,
-        glm_path_airlines, glm_score, dl_sample_copy, dl_train_steps,
+        glm_path_airlines, glm_score, rule_codes, glm_path_rulefit,
+        dl_sample_copy, dl_train_steps,
         dl_score, merge_match, merge_gather, merge_trim, sort_rows)}
     unknown = [p for p in args.programs if p not in programs]
     if unknown:
