@@ -15,15 +15,17 @@ forms, and ``_dense_design_fits`` chooses from the frame and the device:
 
 * ``_make_path_runner``, on the dense ``[rows, nfeatures]`` design
   (``DataInfo.make_matrix``), where that and one copy of it fit a quarter of
-  the device: what every GLM ran until PR 36, kept to the line, because a
-  narrow fit is bound by tracing and lowering this program and pays for
-  every operation added to it.
+  the device: what every GLM ran before the code form, kept to the line.
 * ``_make_blocked_path_runner``, on the design IN CODE FORM
   (``datainfo.CodedDesign``: numerics beside categorical codes), where the
   dense design does not fit: Gram, score and deviance are summed over row
   blocks, a block expanded to its one-hot columns where it is used, so a
   categorical of any cardinality costs 4 bytes a row.  Scoring walks the
   same blocks (``_make_score``).
+
+Both are cached on what their programs close over, so a fit that repeats
+an earlier fit's signature compiles nothing; ``cluster.init`` clears them
+with the mesh.
 
 ``reference_glm.py`` is the same mathematics in plain ``jax.numpy`` on the
 dense expansion.  L-BFGS, ordinal, multinomial (block-wise per-class Newton
@@ -59,7 +61,20 @@ from . import glm_gram
 
 # ------------------------------------------------------------------- families
 class _Family:
+    """A family and its link.  Two families of one class and parameters are
+    equal and hash alike: the compiled programs that close over one
+    (``_make_path_runner`` and its kin) are cached on it, so every fit of a
+    family reaches the same program."""
     name = "gaussian"
+
+    def _key(self):
+        return type(self), tuple(sorted(vars(self).items()))
+
+    def __eq__(self, other):
+        return isinstance(other, _Family) and self._key() == other._key()
+
+    def __hash__(self):
+        return hash(self._key())
 
     def linkinv(self, eta):
         return eta
@@ -243,6 +258,13 @@ _gram_kernel = _ledger("glm_gram", jax.jit(_gram_kernel_impl),
                        orig=_gram_kernel_impl)
 
 
+# compiled GLM programs kept, each with its executables: a grid over a
+# family's parameters, or RuleFit's fits of other rules, would otherwise
+# hold every one of them for the life of the process
+_PROGRAMS = 32
+
+
+@functools.lru_cache(maxsize=_PROGRAMS)
 def _make_irls_step(family: _Family):
     def step(X, y, w, beta, offset):
         eta = X @ beta + offset
@@ -423,6 +445,7 @@ def _l1_change(nb, beta, penalize, sweeps):
     return jnp.where(sweeps <= 1, 0.0, jnp.max(jnp.abs(nb - beta) * scale))
 
 
+@functools.lru_cache(maxsize=_PROGRAMS)
 def _make_path_runner(family: _Family, l1_mode: bool, max_iter: int,
                       max_inner: int = 100):
     """The WHOLE regularization path as one device program.
@@ -618,11 +641,14 @@ def _fit_block_rows(layout: tuple, padded_rows: int) -> int:
                       padded_rows // cluster().n_row_shards, share=4)
 
 
+@functools.lru_cache(maxsize=_PROGRAMS)
 def _make_blocked_path_runner(family: _Family, l1_mode: bool, max_iter: int,
                               layout: tuple, block: int,
                               max_inner: int = 100, runs: tuple = ()):
-    """``_make_path_runner``'s program on the design in code form: the same
-    scan over lambdas, ``while_loop`` of IRLS passes, solve on the device and
+    """``_make_path_runner``'s program on the design in code form, cached
+    as it is (the Gram's kernel, ``glm_gram.engages``, follows from the
+    layout and the mesh, whose rebuild clears the cache): the same scan
+    over lambdas, ``while_loop`` of IRLS passes, solve on the device and
     one fetch of the same five results.  It departs in two things.
 
     Every IRLS pass reads the design in row blocks of ``block``

@@ -184,12 +184,13 @@ def _invalidate_compiled_caches() -> None:
     # or a rebuilt mesh could be served a choice tuned for the dead one
     from . import autotune
     autotune.invalidate("cluster_reinit")
-    # every cached builder of the tree engine, found by what it is and not
-    # by name: a list of names drifts (the scan-level and grid builders
-    # were missing from it, and a mesh rebuilt at the same padded row count
-    # was then handed a level program bound to the dead mesh)
+    # every cached builder of the tree engine and of GLM, found by what it
+    # is and not by name: a list of names drifts (the scan-level and grid
+    # builders were missing from it, and a mesh rebuilt at the same padded
+    # row count was then handed a level program bound to the dead mesh)
     import importlib
-    for mod_name in ("..models.tree.hist", "..models.tree.shared"):
+    for mod_name in ("..models.tree.hist", "..models.tree.shared",
+                     "..models.glm"):
         mod = importlib.import_module(mod_name, package=__package__)
         for obj in list(vars(mod).values()):
             clear = getattr(obj, "cache_clear", None)

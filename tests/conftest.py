@@ -146,7 +146,48 @@ def _release_compiled_programs():
                    _s.make_build_tree_fn, _s.make_tree_scan_fn,
                    _s.make_multinomial_scan_fn, _s.make_grid_scan_fn):
             fn.cache_clear()
+        from h2o3_tpu.models import glm as _g
+        for fn in (_g._make_path_runner, _g._make_blocked_path_runner,
+                   _g._make_irls_step):
+            fn.cache_clear()
     except Exception:
         pass
     _jax.clear_caches()
     gc.collect()
+
+
+@pytest.fixture()
+def path_compiles(monkeypatch):
+    """``read()`` -> (the rise of ``recompiles_total{program="glm_path"}``
+    by reason, observations of ``jax_compile_seconds`` for the path program
+    ``run``), counted from a start with no path runner cached, telemetry on
+    and the compile listener installed.  The listener's ``fun`` labels start
+    from an empty set: in a worker that has traced 256 functions every new
+    one would read ``other``."""
+    from h2o3_tpu.models import glm
+    from h2o3_tpu.runtime import observability as obs
+    from h2o3_tpu.runtime import xprof
+    monkeypatch.setattr(xprof, "_funs", set())
+    prev = obs.set_enabled(True)
+    xprof.install_monitoring_listener()
+    glm._make_path_runner.cache_clear()
+    glm._make_blocked_path_runner.cache_clear()
+
+    def counts():
+        wire = obs.metrics_wire()
+        reasons = {s["l"]["reason"]: s["v"] for s in wire
+                   if s["n"] == "recompiles_total"
+                   and s["l"].get("program") == "glm_path"}
+        traced = sum(s["n_obs"] for s in wire
+                     if s["n"] == "jax_compile_seconds"
+                     and s["l"].get("fun") in ("run", "jit(run)"))
+        return reasons, traced
+
+    start, start_traced = counts()
+
+    def read():
+        reasons, traced = counts()
+        return ({k: v - start.get(k, 0) for k, v in reasons.items()
+                 if v != start.get(k, 0)}, traced - start_traced)
+    yield read
+    obs.set_enabled(prev)

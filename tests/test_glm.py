@@ -233,3 +233,109 @@ def test_lambda_path_fused_matches_host(cl):
                  alpha=0.5, seed=1).train(fr)
     for name in ("x0", "x1", "x2"):
         assert np.isclose(coefs[name], m_host.coef[name], atol=5e-3), name
+
+
+# ------------------------------------------ one path program a signature
+def _counts_frame(seed, n=600):
+    """Three numerics and a positive count response, which every family
+    below can fit."""
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, 3))
+    y = rng.poisson(np.exp(0.4 * X[:, 0] - 0.3 * X[:, 1] + 0.5)) + 0.5
+    return {"x0": X[:, 0], "x1": X[:, 1], "x2": X[:, 2], "y": y}
+
+
+def _rise(before, after):
+    return {k: after[k] - before.get(k, 0) for k in after
+            if after[k] != before.get(k, 0)}
+
+
+@pytest.mark.parametrize("params", [
+    dict(family="gaussian", lambda_=0.0),
+    dict(family="poisson", lambda_=1e-3, alpha=0.5),
+], ids=["l2", "l1"])
+def test_a_second_fit_reuses_the_path_program(cl, path_compiles, params):
+    """The dense design: the first fit traces, lowers and compiles the path
+    program once; the second, of the same signature, dispatches it and
+    gives the same coefficients bit for bit."""
+    fr = Frame.from_numpy(_counts_frame(1))
+    first = GLM(response_column="y", **params).train(fr)
+    once = path_compiles()
+    assert sum(once[0].values()) == 1 and once[1] > 0
+    second = GLM(response_column="y", **params).train(fr)
+    assert path_compiles() == once
+    np.testing.assert_array_equal(second.output["beta_std"],
+                                  first.output["beta_std"])
+
+
+@pytest.mark.parametrize("a,b", [
+    (dict(family="tweedie", tweedie_variance_power=1.2),
+     dict(family="tweedie", tweedie_variance_power=1.6)),
+    (dict(family="negativebinomial", theta=0.5),
+     dict(family="negativebinomial", theta=2.0)),
+    (dict(family="poisson", max_iterations=3),
+     dict(family="poisson", max_iterations=4)),
+    (dict(family="poisson", lambda_=1e-3, alpha=0.0),
+     dict(family="poisson", lambda_=1e-3, alpha=0.5)),
+], ids=["tweedie_power", "nb_theta", "max_iterations", "l1_on"])
+def test_fits_that_differ_get_their_own_program(cl, path_compiles, a, b):
+    """Arguments of one shape, a program each: a fit that differs in what
+    the path program closes over compiles its own, and a fit like the
+    first takes the first's again, with its coefficients bit for bit."""
+    fr = Frame.from_numpy(_counts_frame(2))
+    kw = dict(response_column="y", lambda_=0.0)
+    first = GLM(**{**kw, **a}).train(fr)
+    one = path_compiles()[0]
+    other = GLM(**{**kw, **b}).train(fr)
+    two = path_compiles()[0]
+    again = GLM(**{**kw, **a}).train(fr)
+    assert sum(_rise(one, two).values()) == 1
+    assert path_compiles()[0] == two
+    assert not np.array_equal(other.output["beta_std"],
+                              first.output["beta_std"])
+    np.testing.assert_array_equal(again.output["beta_std"],
+                                  first.output["beta_std"])
+
+
+def test_families_are_equal_by_class_and_parameters():
+    from h2o3_tpu.models import glm
+    fam = glm._make_family
+    p = GLMParameters
+    assert fam("tweedie", p(tweedie_variance_power=1.5)) \
+        == fam("tweedie", p(tweedie_variance_power=1.5))
+    assert len({fam("tweedie", p(tweedie_variance_power=1.5)),
+                fam("tweedie", p(tweedie_variance_power=1.6)),
+                fam("negativebinomial", p(theta=1.0)),
+                fam("negativebinomial", p(theta=1.0)),
+                fam("binomial", p()), fam("binomial", p()),
+                fam("quasibinomial", p())}) == 5
+
+
+def test_a_rebuilt_mesh_compiles_the_path_program_again(cl, path_compiles):
+    """``cluster.init`` with another geometry clears the runners: the next
+    fit compiles with ``reason="cluster_reinit"``, on one device and again
+    back on the mesh, where it gives the first fit's coefficients."""
+    import jax
+    from h2o3_tpu.models import glm
+    cols = _counts_frame(3)
+
+    def fit():
+        return np.asarray(GLM(family="poisson", lambda_=0.0,
+                              response_column="y").train(
+            Frame.from_numpy(cols)).output["beta_std"])
+
+    first = fit()
+    start = path_compiles()[0]
+    try:
+        h2o3_tpu.init(devices=jax.devices()[:1])
+        assert glm._make_path_runner.cache_info().currsize == 0
+        one = fit()
+        mid = path_compiles()[0]
+    finally:
+        h2o3_tpu.init(devices=jax.devices())
+    assert glm._make_path_runner.cache_info().currsize == 0
+    again = fit()
+    assert _rise(start, mid) == {"cluster_reinit": 1}
+    assert _rise(mid, path_compiles()[0]) == {"cluster_reinit": 1}
+    np.testing.assert_allclose(one, first, atol=1e-5)
+    np.testing.assert_array_equal(again, first)

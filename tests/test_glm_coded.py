@@ -86,10 +86,14 @@ def _blocked_pass(di, fr, beta, family, block):
 def _force_gram(monkeypatch, kernel):
     """``kernel="pallas"``: the Gram kernel engages off the TPU as it does on
     one, wherever the layout has a one-hot block, and runs in Pallas'
-    interpreter (``glm_gram.gram_parts``); ``"xla"``: what the CPU runs."""
+    interpreter (``glm_gram.gram_parts``); ``"xla"``: what the CPU runs.
+    The path runner's cache knows the kernel by the layout and the mesh, so
+    a forced kernel builds its runners past it."""
     if kernel == "pallas":
         monkeypatch.setattr(glm_gram, "engages",
                             lambda layout: glm_gram._plan(layout) is not None)
+        monkeypatch.setattr(glm, "_make_blocked_path_runner",
+                            glm._make_blocked_path_runner.__wrapped__)
 
 
 @pytest.fixture()
@@ -444,3 +448,44 @@ def test_gram_kernel_counter_dense(cl, monkeypatch):
     model = GLM(family="binomial", lambda_=0.0, response_column="y").train(fr)
     assert not isinstance(model._score_matrix(fr), datainfo.CodedDesign)
     assert _one_rise(before, _gram_kernel_counts()) == {"xla": 1}
+
+
+# --------------------------------- (i) one path program a signature
+@pytest.mark.parametrize("params", [
+    dict(lambda_=0.0), dict(lambda_=1e-3, alpha=0.5)], ids=["l2", "l1"])
+def test_a_second_fit_reuses_the_path_program(cl, small_device, path_compiles,
+                                              params):
+    """The code form: the second fit of one signature compiles nothing and
+    gives the first's coefficients bit for bit."""
+    fr = _frame(20, 700)
+    kw = dict(family="binomial", response_column="y", **params)
+    first = GLM(**kw).train(fr)
+    once = path_compiles()
+    assert sum(once[0].values()) == 1 and once[1] > 0
+    second = GLM(**kw).train(fr)
+    assert path_compiles() == once
+    np.testing.assert_array_equal(second.output["beta_std"],
+                                  first.output["beta_std"])
+
+
+def test_another_layout_of_the_same_shapes_gets_its_own_program(
+        cl, small_device, path_compiles):
+    """The two categoricals' widths swapped: the same arguments' shapes, a
+    layout of its own and so a program of its own; the first frame again
+    takes the first program."""
+    a = _frame(21, 700)
+    b = _frame(21, 700, domains={"c5": DOMAINS["c9"], "c9": DOMAINS["c5"]})
+    kw = dict(family="binomial", lambda_=0.0, response_column="y")
+    first = GLM(**kw).train(a)
+    one = path_compiles()[0]
+    other = GLM(**kw).train(b)
+    two = path_compiles()[0]
+    again = GLM(**kw).train(a)
+    di_a, di_b = first.datainfo, other.datainfo
+    assert di_a.coded_layout() != di_b.coded_layout()
+    assert [x.shape for x in di_a.make_coded(a)] \
+        == [x.shape for x in di_b.make_coded(b)]
+    assert sum(two.values()) == sum(one.values()) + 1
+    assert path_compiles()[0] == two
+    np.testing.assert_array_equal(again.output["beta_std"],
+                                  first.output["beta_std"])

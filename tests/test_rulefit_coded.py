@@ -323,6 +323,26 @@ def test_fit_in_code_form_spans_and_counters(cl, monkeypatch):
     assert obs.counter("glm_cd_sweeps_total").value > sweeps
 
 
+def test_a_second_fit_reuses_the_lasso_program(cl, path_compiles):
+    """RuleFit's grouped L1 path: a second fit of the same frame and seed
+    grows the same rules, and so the same runs and layout, compiles no path
+    program and gives the first's coefficients bit for bit."""
+    fr = _frame(_columns(10, 400))
+    kw = dict(response_column="y", algorithm="drf", min_rule_length=3,
+              max_rule_length=3, rule_generation_ntrees=4, seed=2)
+    sweeps = obs.counter("glm_cd_sweeps_total").value
+    first = RuleFit(**kw).train(fr)
+    assert obs.counter("glm_cd_sweeps_total").value > sweeps    # grouped
+    once = path_compiles()
+    assert sum(once[0].values()) == 1 and once[1] > 0
+    second = RuleFit(**kw).train(fr)
+    assert path_compiles() == once
+    assert second.output["rules"] == first.output["rules"]
+    np.testing.assert_array_equal(
+        dkv.get(second.output["glm_key"]).output["beta_std"],
+        dkv.get(first.output["glm_key"]).output["beta_std"])
+
+
 def test_rules_only_and_linear_only(cl):
     """``model_type="rules"`` fits the rule groups alone, ``"linear"`` the
     linear terms alone and grows no forest; both score through the code

@@ -15,7 +15,10 @@ from h2o3_tpu.runtime import xprof
 
 
 @pytest.fixture(autouse=True)
-def _clean_registry():
+def _clean_registry(monkeypatch):
+    # an empty set of ``fun`` labels: in a process that has traced 256
+    # functions already, every new one would read "other"
+    monkeypatch.setattr(xprof, "_funs", set())
     prev = obs.set_enabled(True)
     obs.reset_metrics()
     yield
@@ -266,8 +269,7 @@ def test_the_compile_listener_names_the_traced_function():
                     ("backend_compile_duration", "jit(unit_probe_fn)")}
 
 
-def test_the_fun_label_is_cut_and_capped(monkeypatch):
-    monkeypatch.setattr(xprof, "_funs", set())
+def test_the_fun_label_is_cut_and_capped():
     assert xprof._fun_label("f" * 100) == "f" * 64
     assert xprof._fun_label(None) == "unknown"
     labels = {xprof._fun_label(f"fn{i}") for i in range(400)}
