@@ -1268,8 +1268,8 @@ class H2OGAMEstimator(_RemoteEstimator):
     compute_p_values : bool, default False
     intercept : bool, default True
     gam_columns : Sequence, default []
-    num_knots : int, default 8
-    scale : float, default 1.0
+    num_knots : object, default 8
+    scale : object, default 1.0
     bs : object, default 'cr'
     splines_non_negative : bool, default True
     """

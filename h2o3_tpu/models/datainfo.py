@@ -38,29 +38,93 @@ class CodedDesign(NamedTuple):
     """The design in code form: what ``make_matrix`` expands, unexpanded.
 
     ``num``: [padded, P_num] float32, the numeric columns imputed and
-    standardised as ``make_matrix`` does it.  ``codes``: [padded, P_cat]
-    int32, per categorical the column WITHIN its one-hot block that the row
-    lights (its NA column is the block's last), or -1 where the row lights
-    none (the dropped first level, a code past the domain).
-    ``expand_coded(layout, num, codes)`` gives ``make_matrix``'s rows."""
+    standardised as ``make_matrix`` does it (a spline's column imputed
+    alone: its basis is built from the raw value).  ``codes``: [padded,
+    P_cat] int32, per categorical the column WITHIN its one-hot block that
+    the row lights (its NA column is the block's last), or -1 where the row
+    lights none (the dropped first level, a code past the domain).
+    ``expand_coded(layout, num, codes, DataInfo.spline_tables())`` gives
+    ``make_matrix``'s rows."""
     num: jax.Array
     codes: jax.Array
 
 
+def spline_rows(x: jax.Array, knots: jax.Array, G: jax.Array,
+                FG: jax.Array) -> jax.Array:
+    """[rows, S M] basis rows of S cubic regression splines of a run, smooth
+    by smooth, from the raw values ``x`` [rows, S].  ``knots`` [S, K] are
+    each smooth's ascending knots; a row's value at knot interval j (clipped
+    to the knots' range, as a natural spline extends no further) is
+    ``a_m G[j] + a_p G[j+1] + c_m FG[j] + c_p FG[j+1]`` with the cardinal
+    spline's linear and cubic weights, so ``G`` [S, K, M] maps the knots'
+    values to the columns and ``FG`` [S, K, M] their second derivatives
+    (``F G``).  ``G`` the identity and ``FG`` ``F`` give the cardinal basis
+    itself.  Every value comes by selects over the K knots and float32
+    products: no gather, and no product on the MXU.
+
+    Every step works on [rows, S M] arrays, column s M + m of smooth s,
+    with a smooth's raw value and knots repeated M times: a [rows, S, M]
+    array's minor dimension of M (padded to a tile of 8 rows) costs a
+    relayout copy of each such array on its way to [rows, S M] (four of
+    them took 1.75 of a GAM fit's 8.4 s on a v5e)."""
+    S, K = knots.shape
+    M = G.shape[2]
+    xc = jnp.repeat(x, M, axis=1)
+    kw = jnp.repeat(knots, M, axis=0).T                 # [K, S M]
+    xc = jnp.clip(xc, kw[0], kw[-1])
+    lo = jnp.broadcast_to(kw[0], xc.shape)
+    hi = jnp.broadcast_to(kw[1], xc.shape)
+    past = []
+    for i in range(1, K - 1):
+        p = xc >= kw[i]
+        lo = jnp.where(p, kw[i], lo)
+        hi = jnp.where(p, kw[i + 1], hi)
+        past.append(p)
+    h = hi - lo
+    am, ap = (hi - xc) / h, (xc - lo) / h
+    cm = ((hi - xc) ** 3 / h - h * (hi - xc)) / 6.0
+    cp = ((xc - lo) ** 3 / h - h * (xc - lo)) / 6.0
+
+    def at(table, shift):        # table[s, j + shift, m] for every row
+        rows = table.transpose(1, 0, 2).reshape(K, S * M)
+        got = jnp.broadcast_to(rows[shift], xc.shape)
+        for i, p in enumerate(past, 1):
+            got = jnp.where(p, rows[i + shift], got)
+        return got
+
+    return (am * at(G, 0) + ap * at(G, 1) + cm * at(FG, 0)
+            + cp * at(FG, 1))
+
+
+def spline_run(num, i_num, tables, i_spl):
+    """The basis rows of the spline run whose tables are ``tables[i_spl]``
+    and whose raw columns start at ``num``'s column ``i_num``, and the
+    number of those columns."""
+    knots, G, FG = tables[i_spl]
+    S = knots.shape[0]
+    return spline_rows(num[:, i_num:i_num + S], knots, G, FG), S
+
+
 def expand_coded(layout: Tuple[Tuple[str, int], ...], num: jax.Array,
-                 codes: jax.Array) -> jax.Array:
+                 codes: jax.Array, tables: tuple = ()) -> jax.Array:
     """[rows, nfeatures] dense rows of a code-form design, in
     ``make_matrix``'s column order.  ``layout`` is ``DataInfo.coded_layout``
     (static, hashable), so this traces inside any jitted program: a
     minibatch or a block of rows is expanded where it is used, the frame
     never.  A layout of some of the runs, in their order, expands those
     alone (the ``"cat"`` runs take the codes' columns in turn, the ``"num"``
-    runs the numerics')."""
-    cols, i_num, i_cat = [], 0, 0
+    and ``"spline"`` runs the numerics', each ``"spline"`` run the next of
+    ``tables``, ``DataInfo.spline_tables()``: operands of the program and
+    no constants of its trace)."""
+    cols, i_num, i_cat, i_spl = [], 0, 0, 0
     for kind, width in layout:
         if kind == "num":
             cols.append(num[:, i_num:i_num + width])
             i_num += width
+        elif kind == "spline":
+            rows, used = spline_run(num, i_num, tables, i_spl)
+            cols.append(rows)
+            i_num, i_spl = i_num + used, i_spl + 1
         elif kind == "cat":
             block = jnp.arange(width, dtype=jnp.int32)
             cols.append((codes[:, i_cat, None] == block[None, :])
@@ -74,17 +138,23 @@ def expand_coded(layout: Tuple[Tuple[str, int], ...], num: jax.Array,
 
 
 def coded_matvec(layout: Tuple[Tuple[str, int], ...], num: jax.Array,
-                 codes: jax.Array, beta: jax.Array) -> jax.Array:
-    """``expand_coded(layout, num, codes) @ beta`` [rows], run by run and
-    with no expansion: a one-hot block's product is the coefficient its
-    code selects (a compare, a select and an add a column, which fuse into
-    one pass over the codes; every float32 product exact)."""
-    out, at, i_num, i_cat = 0.0, 0, 0, 0
+                 codes: jax.Array, beta: jax.Array,
+                 tables: tuple = ()) -> jax.Array:
+    """``expand_coded(layout, num, codes, tables) @ beta`` [rows], run by
+    run and with no expansion of a one-hot block: its product is the
+    coefficient its code selects (a compare, a select and an add a column,
+    which fuse into one pass over the codes; every float32 product exact).
+    A spline run's rows are built and multiplied elementwise."""
+    out, at, i_num, i_cat, i_spl = 0.0, 0, 0, 0, 0
     for kind, width in layout:
         b = beta[at:at + width]
         if kind == "num":
             out = out + jnp.sum(num[:, i_num:i_num + width] * b, axis=1)
             i_num += width
+        elif kind == "spline":
+            rows, used = spline_run(num, i_num, tables, i_spl)
+            out = out + jnp.sum(rows * b, axis=1)
+            i_num, i_spl = i_num + used, i_spl + 1
         elif kind == "cat":
             lit = codes[:, i_cat, None] == jnp.arange(width, dtype=jnp.int32)
             out = out + jnp.sum(jnp.where(lit, b, 0.0), axis=1)
@@ -96,15 +166,20 @@ def coded_matvec(layout: Tuple[Tuple[str, int], ...], num: jax.Array,
 
 
 def coded_rmatvec(layout: Tuple[Tuple[str, int], ...], num: jax.Array,
-                  codes: jax.Array, v: jax.Array) -> jax.Array:
-    """``expand_coded(layout, num, codes).T @ v`` [nfeatures] for a row
-    vector ``v``, run by run and with no expansion, as ``coded_matvec``."""
-    parts, i_num, i_cat = [], 0, 0
+                  codes: jax.Array, v: jax.Array,
+                  tables: tuple = ()) -> jax.Array:
+    """``expand_coded(layout, num, codes, tables).T @ v`` [nfeatures] for a
+    row vector ``v``, run by run, as ``coded_matvec``."""
+    parts, i_num, i_cat, i_spl = [], 0, 0, 0
     for kind, width in layout:
         if kind == "num":
             parts.append(jnp.sum(num[:, i_num:i_num + width] * v[:, None],
                                  axis=0))
             i_num += width
+        elif kind == "spline":
+            rows, used = spline_run(num, i_num, tables, i_spl)
+            parts.append(jnp.sum(rows * v[:, None], axis=0))
+            i_num, i_spl = i_num + used, i_spl + 1
         elif kind == "cat":
             lit = codes[:, i_cat, None] == jnp.arange(width, dtype=jnp.int32)
             parts.append(jnp.sum(jnp.where(lit, v[:, None], 0.0), axis=0))
@@ -233,6 +308,18 @@ class ColumnSpec:
     # missing values, whatever ``use_all_factor_levels`` says: a group of
     # RuleFit's rules, whose codes its caller builds and never miss
     all_levels: bool = False
+    # a numeric column that enters the design as a cubic regression spline's
+    # ``width`` basis columns (GAM's cr smooth): (knots [K], G [K, width],
+    # FG [K, width]) as ``spline_rows`` reads them; its values are imputed
+    # by ``mean`` and never standardised (``sigma`` 1)
+    spline: Optional[tuple] = None
+
+
+def _same_spline_run(prev: Optional[ColumnSpec], s: ColumnSpec) -> bool:
+    """Whether spline ``s`` joins the run of the spec before it: both are
+    splines of one width."""
+    return prev is not None and prev.spline is not None \
+        and prev.width == s.width
 
 
 def _numeric_values(vec: Vec, s: ColumnSpec) -> jax.Array:
@@ -247,13 +334,17 @@ def _numeric_values(vec: Vec, s: ColumnSpec) -> jax.Array:
 def _standardized(specs, arrs, standardize: bool) -> jax.Array:
     """[C, padded] float32: a run of numeric columns imputed with their
     training means and, if asked, standardised — ONE batched block, since
-    per-column eager ops cost a dispatch each."""
+    per-column eager ops cost a dispatch each.  A spline's column is
+    imputed and left as it is."""
     X = jnp.stack(arrs, axis=0).astype(jnp.float32)
     means = jnp.asarray([s.mean for s in specs], jnp.float32)[:, None]
     X = jnp.where(jnp.isnan(X), means, X)
     if standardize:
         sigmas = jnp.asarray([s.sigma for s in specs], jnp.float32)[:, None]
-        X = (X - means) / sigmas
+        centres = means if all(s.spline is None for s in specs) else \
+            jnp.asarray([0.0 if s.spline is not None else s.mean
+                         for s in specs], jnp.float32)[:, None]
+        X = (X - centres) / sigmas
     return X
 
 
@@ -281,6 +372,8 @@ class DataInfo:
         for s in self.specs:
             if s.all_levels:
                 names += [f"{s.name}.{lbl}" for lbl in s.domain]
+            elif s.spline is not None:
+                names += [f"{s.name}_gam{j}" for j in range(s.width)]
             elif s.type == T_CAT:
                 lo = 0 if self.use_all_factor_levels else 1
                 names += [f"{s.name}.{lbl}" for lbl in s.domain[lo:]]
@@ -290,6 +383,10 @@ class DataInfo:
         if self.add_intercept:
             names.append("Intercept")
         return names
+
+    @property
+    def has_splines(self) -> bool:
+        return any(s.spline is not None for s in self.specs)
 
     @property
     def nclasses(self) -> int:
@@ -380,6 +477,42 @@ class DataInfo:
         return dataclasses.replace(self, specs=specs,
                                    nfeatures=at + int(self.add_intercept))
 
+    def with_splines(self, splines: Dict[str, tuple]) -> "DataInfo":
+        """This layout with each numeric column named in ``splines`` entering
+        as a cubic regression spline's basis: name -> (knots, G, FG), as
+        ``ColumnSpec.spline`` holds them (GAM's cr smooths)."""
+        specs, at = [], 0
+        for s in self.specs:
+            t = splines.get(s.name)
+            if t is not None:
+                s = dataclasses.replace(s, sigma=1.0, spline=t,
+                                        width=int(t[1].shape[1]))
+            specs.append(dataclasses.replace(s, offset=at))
+            at += s.width
+        return dataclasses.replace(self, specs=specs,
+                                   nfeatures=at + int(self.add_intercept))
+
+    def spline_groups(self) -> List[List[ColumnSpec]]:
+        """The spline columns as ``coded_layout``'s ``"spline"`` runs have
+        them: side by side and of one width."""
+        groups: List[List[ColumnSpec]] = []
+        for s, prev in zip(self.specs, [None] + self.specs[:-1]):
+            if s.spline is not None:
+                if _same_spline_run(prev, s):
+                    groups[-1].append(s)
+                else:
+                    groups.append([s])
+        return groups
+
+    def spline_tables(self) -> tuple:
+        """Per ``"spline"`` run of ``coded_layout``, its (knots [S, K],
+        G [S, K, M], FG [S, K, M]) as float32 device arrays: the operands
+        that ``expand_coded`` and the programs built on it take.  Empty for
+        a layout with no spline."""
+        return tuple(tuple(jnp.asarray(np.stack([s.spline[k] for s in g]),
+                                       jnp.float32) for k in range(3))
+                     for g in self.spline_groups())
+
     # ---------------------------------------------------------- application
     def make_matrix(self, frame: Frame, standardize: Optional[bool] = None) -> jax.Array:
         """[padded_rows, nfeatures] float32 design matrix, row-sharded.
@@ -397,6 +530,10 @@ class DataInfo:
         columns.
         """
         standardize = self.standardize if standardize is None else standardize
+        if self.has_splines:
+            return expand_coded(self.coded_layout(),
+                                *self.make_coded(frame, standardize),
+                                self.spline_tables())
         key = ("__design__", standardize, self._design_signature())
         hit = frame._matrix_cache.get(key)
         if hit is not None:
@@ -437,11 +574,18 @@ class DataInfo:
     def coded_layout(self) -> Tuple[Tuple[str, int], ...]:
         """The expanded layout as runs in column order: ``("num", k)`` for
         k numeric columns side by side, ``("cat", width)`` for one
-        categorical's block (NA column included), ``("one", 1)`` for the
+        categorical's block (NA column included), ``("spline", S M)`` for S
+        splines of M columns each side by side (``spline_tables`` holds a
+        run's tables, which give S and M), ``("one", 1)`` for the
         intercept.  The widths sum to ``nfeatures``."""
         runs: List[Tuple[str, int]] = []
-        for s in self.specs:
-            if s.type == T_CAT:
+        for s, prev in zip(self.specs, [None] + self.specs[:-1]):
+            if s.spline is not None:
+                if runs[-1:] and _same_spline_run(prev, s):
+                    runs[-1] = ("spline", runs[-1][1] + s.width)
+                else:
+                    runs.append(("spline", s.width))
+            elif s.type == T_CAT:
                 runs.append(("cat", s.width))
             elif runs and runs[-1][0] == "num":
                 runs[-1] = ("num", runs[-1][1] + 1)
@@ -502,7 +646,8 @@ class DataInfo:
         if sig is None:
             sig = (
                 tuple((s.name, s.type, tuple(s.domain or ()), s.mean,
-                       s.sigma, s.time_base, s.offset, s.width)
+                       s.sigma, s.time_base, s.offset, s.width,
+                       s.spline is not None)
                       for s in self.specs),
                 self.use_all_factor_levels, self.add_intercept,
                 self.missing_values_handling)

@@ -596,34 +596,62 @@ def _gram_of_parts(layout, top, rest):
 
 
 def _make_irls_gram(family: _Family, layout: tuple, block: int):
-    """``irls_gram(num, codes, y, w, offset, beta) -> (X'WX, X's, deviance)``
-    at ``beta``, over the row blocks of the code-form design: every
-    row-shard walks its rows in blocks of ``block`` (``sum_row_blocks``),
-    expands one, and the shards' sums meet in a ``psum``
-    (``sum_over_row_shards``).  ``W`` are the
+    """``irls_gram(num, codes, y, w, offset, beta, tables) -> (X'WX, X's,
+    deviance)`` at ``beta``, over the row blocks of the code-form design:
+    every row-shard walks its rows in blocks of ``block``
+    (``sum_row_blocks``), expands one, and the shards' sums meet in a
+    ``psum`` (``sum_over_row_shards``).  ``W`` are the
     IRLS weights and ``s = w g (y - mu) / var`` the score's, so that
-    ``X'Wz = X'WX beta + X's`` for the working response ``z``."""
-    rest_layout = _column_split(layout)[3]
+    ``X'Wz = X'WX beta + X's`` for the working response ``z``.  ``tables``
+    are the layout's spline tables (``DataInfo.spline_tables``), empty for
+    a layout without splines."""
+    cat, rest, cat_layout, rest_layout = _column_split(layout)
+    spline = any(kind == "spline" for kind, _ in layout)
+    back = np.argsort(np.concatenate([cat, rest]))
 
-    def sums_of(beta, wb, nb, cb, yb, ob):
-        eta = coded_matvec(layout, nb, cb, beta) + ob
+    def sums_of(beta, tables, wb, nb, cb, yb, ob):
+        if spline:
+            return spline_sums_of(beta, tables, wb, nb, cb, yb, ob)
+        eta = coded_matvec(layout, nb, cb, beta, tables) + ob
         mu = family.linkinv(eta)
         g = jnp.maximum(family.dlinkinv(eta, mu), 1e-10)
         var = jnp.maximum(family.variance(mu), 1e-10)
-        score = coded_rmatvec(layout, nb, cb, wb * g * (yb - mu) / var)
-        xr = expand_coded(rest_layout, nb, cb)
+        score = coded_rmatvec(layout, nb, cb, wb * g * (yb - mu) / var,
+                              tables)
+        xr = expand_coded(rest_layout, nb, cb, tables)
         return (_gram_parts(layout, xr, nb, cb, wb * g * g / var), score,
                 family.deviance(yb, mu, wb))
 
-    def shard(num, codes, y, w, offset, beta):
-        return sum_over_row_shards(sum_row_blocks(
-            functools.partial(sums_of, beta), block, w, num, codes, y, offset))
+    def spline_sums_of(beta, tables, wb, nb, cb, yb, ob):
+        # a spline's rows are built ONCE a block, written out, and read by
+        # the product with beta, the score and the Gram alike (left to
+        # itself the compiler builds them again for each)
+        xr = jax.lax.optimization_barrier(
+            expand_coded(rest_layout, nb, cb, tables))
+        eta = jnp.sum(xr * beta[rest], axis=1) + ob
+        if cat_layout:
+            eta = eta + coded_matvec(cat_layout, nb, cb, beta[cat])
+        mu = family.linkinv(eta)
+        g = jnp.maximum(family.dlinkinv(eta, mu), 1e-10)
+        var = jnp.maximum(family.variance(mu), 1e-10)
+        v = wb * g * (yb - mu) / var
+        score = jnp.sum(xr * v[:, None], axis=0)
+        if cat_layout:
+            score = jnp.concatenate(
+                [coded_rmatvec(cat_layout, nb, cb, v), score])[back]
+        return (_gram_parts(layout, xr, nb, cb, wb * g * g / var), score,
+                family.deviance(yb, mu, wb))
 
-    def irls_gram(num, codes, y, w, offset, beta):
+    def shard(num, codes, y, w, offset, beta, tables):
+        return sum_over_row_shards(sum_row_blocks(
+            functools.partial(sums_of, beta, tables), block, w, num, codes,
+            y, offset))
+
+    def irls_gram(num, codes, y, w, offset, beta, tables=()):
         rows, mat = P(ROW_AXIS), P(ROW_AXIS, None)
         (top, rest), score, dev = over_row_shards(
-            shard, in_specs=(mat, mat, rows, rows, rows, P()),
-            out_specs=P())(num, codes, y, w, offset, beta)
+            shard, in_specs=(mat, mat, rows, rows, rows, P(), P()),
+            out_specs=P())(num, codes, y, w, offset, beta, tables)
         return _gram_of_parts(layout, top, rest), score, dev
 
     return irls_gram
@@ -637,7 +665,10 @@ def _fit_block_rows(layout: tuple, padded_rows: int) -> int:
     device: a frame whose expansion fits there is one block."""
     width = sum(w for _, w in layout)
     hot = sum(w for kind, w in layout if kind == "cat")
-    return block_rows(4 * 2 * width + 2 * (3 * width + hot if hot else 0),
+    # a spline's rows are built from four selected tables and its weights
+    spline = sum(w for kind, w in layout if kind == "spline")
+    return block_rows(4 * 2 * width + 2 * (3 * width + hot if hot else 0)
+                      + 4 * 6 * spline,
                       padded_rows // cluster().n_row_shards, share=4)
 
 
@@ -662,8 +693,11 @@ def _make_blocked_path_runner(family: _Family, l1_mode: bool, max_iter: int,
     where solved for ``beta'`` it scales with cond(G) |beta'| and stays (a
     wide one-hot design beside an intercept is not well conditioned).
 
+    ``tables``, the layout's spline tables (GAM's), follow ``beta_eps``:
+    operands, so that a fit on other knots runs the same executable.
     With ``runs`` (RuleFit's rule groups: ``_group_coordinate_descent``) an
-    L1 program takes one more argument, the runs' ``partition`` flags,
+    L1 program takes one more argument after them, the runs' ``partition``
+    flags,
     solves by ``_group_coordinate_descent``, ends a lambda's passes by
     ``_l1_change`` and returns a sixth result, the sweeps of all its
     solves.
@@ -672,7 +706,7 @@ def _make_blocked_path_runner(family: _Family, l1_mode: bool, max_iter: int,
     grouped = l1_mode and bool(runs)
 
     def run(num, codes, y, w, offset, lambdas, alpha, penalize, beta0, n,
-            beta_eps, *partition):
+            beta_eps, tables=(), *partition):
         n_coef = beta0.shape[0]
 
         def solve(G, score, lam, beta):
@@ -695,7 +729,8 @@ def _make_blocked_path_runner(family: _Family, l1_mode: bool, max_iter: int,
 
             def body(state):
                 beta, _, it, _, _ = state[:5]
-                gram, score, dev = irls_gram(num, codes, y, w, offset, beta)
+                gram, score, dev = irls_gram(num, codes, y, w, offset, beta,
+                                             tables)
                 if not grouped:
                     nb = jnp.where(closing, beta,
                                    solve(gram / n, score / n, lam, beta))
@@ -736,18 +771,18 @@ def _make_blocked_path_runner(family: _Family, l1_mode: bool, max_iter: int,
 def _make_xtv(layout: tuple, block: int):
     """Compiled ``X'v`` for a row vector ``v`` (zero on padded rows) over
     the row blocks of the code-form design."""
-    def sums_of(vb, nb, cb):
-        return coded_rmatvec(layout, nb, cb, vb)
+    def sums_of(tables, vb, nb, cb):
+        return coded_rmatvec(layout, nb, cb, vb, tables)
 
-    def shard(v, num, codes):
-        return sum_over_row_shards(
-            sum_row_blocks(sums_of, block, v, num, codes))
+    def shard(v, num, codes, tables):
+        return sum_over_row_shards(sum_row_blocks(
+            functools.partial(sums_of, tables), block, v, num, codes))
 
-    def glm_xtv(v, num, codes):
+    def glm_xtv(v, num, codes, tables=()):
         return over_row_shards(
             shard, in_specs=(P(ROW_AXIS), P(ROW_AXIS, None),
-                             P(ROW_AXIS, None)),
-            out_specs=P())(v, num, codes)
+                             P(ROW_AXIS, None), P()),
+            out_specs=P())(v, num, codes, tables)
 
     return jax.jit(glm_xtv)
 
@@ -758,12 +793,13 @@ def _make_score(layout: tuple, family: str, classifier: bool, block: int):
     row-shard walks its own rows in blocks of ``block``
     (``map_row_blocks``), expands one and keeps what ``_predict_raw``
     returns for it.  ``beta`` is [P] or, multinomial, [P, K]; ``thetas``
-    the ordinal thresholds (empty for every other family)."""
-    def rows_of(beta, thetas, nb, cb):
+    the ordinal thresholds (empty for every other family); ``tables`` the
+    layout's spline tables."""
+    def rows_of(beta, thetas, tables, nb, cb):
         if family == "multinomial":
-            return jax.nn.softmax(jnp.dot(expand_coded(layout, nb, cb), beta,
-                                          precision=_HIGHEST), axis=1)
-        eta = coded_matvec(layout, nb, cb, beta)
+            return jax.nn.softmax(jnp.dot(expand_coded(layout, nb, cb, tables),
+                                          beta, precision=_HIGHEST), axis=1)
+        eta = coded_matvec(layout, nb, cb, beta, tables)
         if family == "ordinal":              # the intercept's beta is 0
             cdf = jax.nn.sigmoid(thetas[None, :] - eta[:, None])
             cdf = jnp.concatenate(
@@ -774,17 +810,18 @@ def _make_score(layout: tuple, family: str, classifier: bool, block: int):
         mu = _make_family(family, GLMParameters()).linkinv(eta)
         return jnp.stack([1 - mu, mu], axis=1) if classifier else mu
 
-    def shard(beta, thetas, num, codes):
-        return map_row_blocks(functools.partial(rows_of, beta, thetas),
+    def shard(beta, thetas, num, codes, tables):
+        return map_row_blocks(functools.partial(rows_of, beta, thetas, tables),
                               block, num, codes)
 
     # the name the device trace knows the program by: jit_glm_score
-    def glm_score(beta, thetas, num, codes):
+    def glm_score(beta, thetas, num, codes, tables=()):
         regression = family not in ("multinomial", "ordinal") and not classifier
         return over_row_shards(
-            shard, in_specs=(P(), P(), P(ROW_AXIS, None), P(ROW_AXIS, None)),
+            shard, in_specs=(P(), P(), P(ROW_AXIS, None), P(ROW_AXIS, None),
+                             P()),
             out_specs=P(ROW_AXIS) if regression else P(ROW_AXIS, None))(
-                beta, thetas, num, codes)
+                beta, thetas, num, codes, tables)
 
     return jax.jit(glm_score)
 
@@ -923,8 +960,10 @@ class GLMModel(Model):
 
     def _score_matrix(self, frame: Frame):
         """The design ``_predict_raw`` expects: the dense one where it fits
-        the device beside its products, else in code form."""
-        if _dense_design_fits(frame, self.datainfo):
+        the device beside its products, else, and wherever the layout has a
+        spline, in code form."""
+        if not self.datainfo.has_splines \
+                and _dense_design_fits(frame, self.datainfo):
             return self.datainfo.make_matrix(frame)
         return self.datainfo.make_coded(frame)
 
@@ -944,7 +983,7 @@ class GLMModel(Model):
                                  else thetas.shape[0] + 2)
         score = _make_score(di.coded_layout(), family, di.is_classifier,
                             block_rows(2 * 4 * width, rows))
-        return score(beta, thetas, X.num, X.codes)
+        return score(beta, thetas, X.num, X.codes, di.spline_tables())
 
     def _predict_dense(self, X: jax.Array) -> jax.Array:
         """Scores of the dense design's rows, in eager programs."""
@@ -1109,7 +1148,7 @@ class GLM(ModelBuilder):
         if isinstance(X, CodedDesign):
             layout = di.coded_layout()
             xtv = _make_xtv(layout, _fit_block_rows(layout, v.shape[0]))
-            grad = np.asarray(jnp.abs(xtv(v, *X)))
+            grad = np.asarray(jnp.abs(xtv(v, *X, di.spline_tables())))
         else:
             grad = np.asarray(jnp.abs(X.T @ v))
         if factors is not None:
@@ -1322,11 +1361,12 @@ class GLM(ModelBuilder):
                         fam, l1_mode, p.max_iterations, layout,
                         _fit_block_rows(layout, y.shape[0]), runs=runs)
                     design = tuple(X)
+                    tables = (di.spline_tables(),)
                     kernel = "pallas" if glm_gram.engages(layout) else "xla"
                 else:
                     runner = _make_path_runner(fam, l1_mode=l1_mode,
                                                max_iter=p.max_iterations)
-                    design = (X,)
+                    design, tables = (X,), ()
                     kernel = "xla"
                 # what forms the path's Grams: the kernel or XLA's product
                 obs.inc("glm_gram_kernel_total", kernel=kernel)
@@ -1337,7 +1377,7 @@ class GLM(ModelBuilder):
                     *design, y, w, offset, jnp.asarray(lambdas, jnp.float32),
                     jnp.float32(p.alpha), jnp.asarray(penalize, jnp.float32),
                     jnp.asarray(beta, jnp.float32), jnp.float32(n),
-                    jnp.float32(p.beta_epsilon),
+                    jnp.float32(p.beta_epsilon), *tables,
                     *((jnp.asarray(partition, bool),) if grouped else ()))
             with obs.span("glm.wait"):
                 # the wait for the device and the fetch of its few KB in
@@ -1460,7 +1500,7 @@ class GLM(ModelBuilder):
         sigmas = np.ones(di.nfeatures)
         i = 0
         for s in di.specs:
-            if s.type == "cat":
+            if s.type == "cat" or s.spline is not None:
                 i += s.width
             else:
                 if di.standardize:
