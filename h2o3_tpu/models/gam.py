@@ -247,7 +247,7 @@ def _device_knots(frame: Frame, smooths: List[dict]) -> List[np.ndarray]:
     exact quantile program a knot count over the stacked columns (the tree
     binning's sketch: min, max and the interior quantiles) and one fetch of
     its small result."""
-    from .tree.binning import _make_sketch_fn
+    from .tree.binning import run_sketch
     out: List[Optional[np.ndarray]] = [None] * len(smooths)
     by_k: Dict[int, List[int]] = {}
     for i, s in enumerate(smooths):
@@ -256,8 +256,9 @@ def _device_knots(frame: Frame, smooths: List[dict]) -> List[np.ndarray]:
     for k, idx in by_k.items():
         X = jnp.stack([frame.vec(smooths[i]["cols"][0]).values()
                        .astype(jnp.float32) for i in idx], axis=0)
-        sketch = _make_sketch_fn(frame.nrows, int(X.shape[1]), len(idx), k - 2)
-        fetched.append((idx, sketch(X, jnp.ones((X.shape[1],), jnp.float32))))
+        fetched.append((idx, run_sketch(
+            X, jnp.ones((X.shape[1],), jnp.float32), frame.nrows, k - 2,
+            "gam_knots")))
     for idx, got in jax.device_get(fetched):     # ONE fetch of every result
         edges, lo, hi, m = (np.asarray(a, np.float64) for a in got)
         for row, i in enumerate(idx):

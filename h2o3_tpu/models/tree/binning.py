@@ -71,6 +71,22 @@ class BinnedFrame:
         return tuple(out)
 
 
+def _order_key(x):
+    """float32 -> int32 keys whose signed order is the floats' order
+    (-0.0 just below +0.0): a negative float's magnitude bits are flipped,
+    so a larger magnitude gives a smaller key.  Its own inverse up to the
+    bitcast: ``_order_value``."""
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return b ^ ((b >> 31) & 0x7FFFFFFF)
+
+
+def _order_value(k):
+    """``_order_key``'s inverse: the key's sign is the float's, so the
+    same xor restores its bits."""
+    return jax.lax.bitcast_convert_type(k ^ ((k >> 31) & 0x7FFFFFFF),
+                                        jnp.float32)
+
+
 @functools.lru_cache(maxsize=None)
 def _make_sketch_fn(n: int, padded: int, ncols: int, nq: int):
     """One compiled program: exact masked quantiles + min/max for a stacked
@@ -81,6 +97,13 @@ def _make_sketch_fn(n: int, padded: int, ncols: int, nq: int):
     interpolates positions q_k * (m_c - 1) within each column's m_c valid
     rows (numpy's default interpolation, so edges match the old host
     np.quantile sketch on unweighted data).
+
+    The sort orders one int32 key a value (``_order_key``), unstably: a
+    stable float sort is lowered with an s32 iota beside the values as a
+    tie-breaker, twice the bytes every compare-exchange stage moves, and
+    only the sorted values are read.  The keys order -0.0 before +0.0,
+    which a float comparison holds equal, so an edge can differ from a
+    float sort's only in the sign of a zero.
     """
 
     def sketch(X, w):
@@ -88,20 +111,31 @@ def _make_sketch_fn(n: int, padded: int, ncols: int, nq: int):
         valid = (iota < n) & jnp.isfinite(X) & (w[None, :] > 0)
         m = jnp.sum(valid, axis=1)                       # [C] valid counts
         Xm = jnp.where(valid, X, jnp.inf)
-        Xs = jnp.sort(Xm, axis=1)                        # invalid -> tail
+        Ks = jax.lax.sort(_order_key(Xm), dimension=1,   # invalid -> tail
+                          is_stable=False)
         lo = jnp.min(jnp.where(valid, X, jnp.inf), axis=1)
         hi = jnp.max(jnp.where(valid, X, -jnp.inf), axis=1)
         qs = jnp.arange(1, nq + 1, dtype=jnp.float32) / (nq + 1)
         pos = qs[None, :] * jnp.maximum(m[:, None] - 1, 0)   # [C, nq]
         p0 = jnp.floor(pos).astype(jnp.int32)
         frac = pos - p0
-        v0 = jnp.take_along_axis(Xs, p0, axis=1)
-        v1 = jnp.take_along_axis(
-            Xs, jnp.minimum(p0 + 1, jnp.maximum(m[:, None] - 1, 0)), axis=1)
+        v0 = _order_value(jnp.take_along_axis(Ks, p0, axis=1))
+        v1 = _order_value(jnp.take_along_axis(
+            Ks, jnp.minimum(p0 + 1, jnp.maximum(m[:, None] - 1, 0)), axis=1))
         edges = v0 * (1 - frac) + v1 * frac
         return edges, lo, hi, m
 
     return jax.jit(sketch)
+
+
+def run_sketch(X, w, n: int, nq: int, caller: str):
+    """Dispatch ``_make_sketch_fn``'s program on the stacked [C, padded]
+    block ``X`` (weights ``w``, ``n`` real rows, ``nq`` interior
+    quantiles); ``binning_sketch_values_total{caller}`` counts the values
+    its sort orders.  Returns the device ``(edges, lo, hi, m)``."""
+    ncols, padded = (int(d) for d in X.shape)
+    obs.inc("binning_sketch_values_total", ncols * padded, caller=caller)
+    return _make_sketch_fn(n, padded, ncols, nq)(X, w)
 
 
 @functools.lru_cache(maxsize=None)
@@ -253,9 +287,9 @@ def _sketch_edges(vecs, num_idx, n: int, nbins: int, sample: int, weights,
             wv = wv[:padded]
         else:
             wv = jnp.ones((padded,), jnp.float32)
-        sk = _make_sketch_fn(n_eff, padded, len(num_idx), nbins - 1)
         edges_q, lo, hi, m = (np.asarray(a, np.float64) for a in
-                              jax.device_get(sk(X, wv)))  # ONE batched fetch
+                              jax.device_get(run_sketch(  # ONE batched fetch
+                                  X, wv, n_eff, nbins - 1, "bins")))
         if weights is not None and stride > 1:
             # The strided subsample ran BEFORE the w>0 mask; when live rows
             # are rare or correlated with row order (stacked CV folds,
@@ -280,11 +314,10 @@ def _sketch_edges(vecs, num_idx, n: int, nbins: int, sample: int, weights,
                 X2 = jnp.stack([jnp.take(vecs[f].values(), idx_d)
                                 .astype(jnp.float32) for f in num_idx],
                                axis=0)
-                sk2 = _make_sketch_fn(len(idx), len(idx), len(num_idx),
-                                      nbins - 1)
                 edges_q, lo, hi, m = (
                     np.asarray(a, np.float64) for a in jax.device_get(
-                        sk2(X2, jnp.ones((len(idx),), jnp.float32))))
+                        run_sketch(X2, jnp.ones((len(idx),), jnp.float32),
+                                   len(idx), nbins - 1, "bins")))
         for i, f in enumerate(num_idx):
             if m[i] == 0:
                 e = np.zeros(0, dtype=np.float32)

@@ -332,6 +332,27 @@ def _sketch(sds, rows):
         sds((SMALL_N,), jnp.float32, rows))
 
 
+@pytest.mark.parametrize("backend", ["test", "tpu"])
+def test_sketch_sorts_one_int32_key_unstably(request, backend):
+    """The quantile sketch's one sort carries a single s32 operand and no
+    ``is_stable``: a stable float sort is emitted with an s32 iota beside
+    the values, twice the bytes a compare-exchange stage moves."""
+    from h2o3_tpu.models.tree import binning
+    if backend == "tpu":
+        _, sds, rows = request.getfixturevalue("one_chip")
+        fn, operands = _sketch(sds, rows)
+    else:
+        fn = binning._make_sketch_fn(1000, 1024, 5, NBINS - 1)
+        operands = (jax.ShapeDtypeStruct((5, 1024), jnp.float32),
+                    jax.ShapeDtypeStruct((1024,), jnp.float32))
+    _, text = _compile(fn, *operands)
+    sorts = re.findall(r"= (\S+) sort\(([^)]*)\)([^\n]*)", text)
+    assert len(sorts) == 1
+    shape, args, attrs = sorts[0]
+    assert shape.startswith("s32[5,") and "," not in args
+    assert "is_stable" not in attrs
+
+
 def _encode(sds, rows):
     from h2o3_tpu.models.tree import binning
     is_cat = (False,) * 5 + (True,) * 3

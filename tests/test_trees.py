@@ -167,6 +167,147 @@ def test_fit_bins_inf_stays_in_own_feature(cl, rng):
     assert codes[0, :n].max() <= len(bn.edges[0])
 
 
+def _float_sort_sketch(n, padded, ncols, nq):
+    """The reference: the quantile sketch as it read before its sort took
+    int32 keys, a stable sort of the masked float32 values."""
+    import jax
+    import jax.numpy as jnp
+
+    def sketch(X, w):
+        iota = jax.lax.broadcasted_iota(jnp.int32, (ncols, padded), 1)
+        valid = (iota < n) & jnp.isfinite(X) & (w[None, :] > 0)
+        m = jnp.sum(valid, axis=1)
+        Xs = jnp.sort(jnp.where(valid, X, jnp.inf), axis=1, stable=True)
+        lo = jnp.min(jnp.where(valid, X, jnp.inf), axis=1)
+        hi = jnp.max(jnp.where(valid, X, -jnp.inf), axis=1)
+        qs = jnp.arange(1, nq + 1, dtype=jnp.float32) / (nq + 1)
+        pos = qs[None, :] * jnp.maximum(m[:, None] - 1, 0)
+        p0 = jnp.floor(pos).astype(jnp.int32)
+        frac = pos - p0
+        v0 = jnp.take_along_axis(Xs, p0, axis=1)
+        v1 = jnp.take_along_axis(
+            Xs, jnp.minimum(p0 + 1, jnp.maximum(m[:, None] - 1, 0)), axis=1)
+        return v0 * (1 - frac) + v1 * frac, lo, hi, m
+
+    return jax.jit(sketch)
+
+
+def _sketch_case(case, rng, n=1500):
+    """Columns [C, n] float32, weights [n] or None, and how many finite
+    rows past ``n`` the sketch's block carries."""
+    f32 = np.float32
+    w, tail = None, 0
+    if case == "ties_rounded":
+        cols = [rng.integers(-3, 4, n), np.round(rng.normal(size=n), 1),
+                np.repeat(rng.normal(size=n // 100), 100)]
+    elif case == "signed_zero_subnormal":
+        tiny = np.finfo(f32).smallest_subnormal
+        cols = [rng.choice(np.array([-0.0, 0.0], f32), n),
+                rng.choice(np.array([-0.0, 0.0, tiny, -tiny, 7 * tiny,
+                                     -1e-39, 1e-40, 1.0], f32), n),
+                np.where(rng.random(n) < 0.5, -0.0, rng.normal(size=n))]
+    elif case == "sign_only_and_mixed":
+        cols = [-rng.exponential(size=n), rng.exponential(size=n),
+                rng.normal(scale=1e3, size=n)]
+    elif case == "nan_inf_masked":
+        x = rng.normal(size=(3, n))
+        x[0, rng.random(n) < 0.2] = np.nan
+        x[1, rng.random(n) < 0.05] = np.inf
+        x[1, rng.random(n) < 0.05] = -np.inf
+        x[2, ::3] = np.nan
+        x[2, 1::7] = -np.inf
+        cols = list(x)
+    elif case == "weights_masked":
+        cols = list(rng.normal(size=(3, n)))
+        w = rng.choice(np.array([-1.0, 0.0, 1.0, 2.5], f32), n)
+        for c in cols:
+            c[w <= 0] *= 1e4               # outliers that only the mask drops
+    elif case == "padded_tail":
+        cols, tail = list(rng.normal(size=(3, n))), 77
+    else:                                   # "all_nan_and_single_row"
+        x = np.full((3, n), np.nan)
+        x[1, 17] = -2.5
+        x[2] = rng.normal(size=n)
+        cols = list(x)
+    return np.stack(cols).astype(f32), w, tail
+
+
+SKETCH_CASES = ["ties_rounded", "signed_zero_subnormal",
+                "sign_only_and_mixed", "nan_inf_masked", "weights_masked",
+                "padded_tail", "all_nan_and_single_row"]
+
+
+@pytest.mark.parametrize("case", SKETCH_CASES)
+def test_sketch_int_keys_equal_the_float_sort(cl, rng, monkeypatch, case):
+    """The sketch sorts order-preserving int32 keys unstably; its edges are
+    the stable float sort's as numbers (they may differ in the sign of a
+    zero), its min, max and counts the same bits, and ``fit_bins``' edges
+    and codes through ``encode_bins`` the same."""
+    import jax.numpy as jnp
+    import h2o3_tpu
+    from h2o3_tpu.models.tree import binning
+    from h2o3_tpu.runtime import observability as obs
+    X, w, tail = _sketch_case(case, rng)
+    C, n = X.shape
+    nq = 63
+    Xp = np.concatenate([X, rng.choice(np.array([-1e9, 0.0, 5.0], np.float32),
+                                       (C, tail))], axis=1)
+    wp = np.ones(n + tail, np.float32) if w is None \
+        else np.concatenate([w, np.ones(tail, np.float32)])
+    got = binning.run_sketch(jnp.asarray(Xp), jnp.asarray(wp), n, nq, "bins")
+    want = _float_sort_sketch(n, n + tail, C, nq)(Xp, wp)
+    (ge, glo, ghi, gm), (we, wlo, whi, wm) = (
+        [np.asarray(a) for a in r] for r in (got, want))
+    assert np.array_equal(ge, we, equal_nan=True)
+    assert np.array_equal(glo.view(np.int32), wlo.view(np.int32))
+    assert np.array_equal(ghi.view(np.int32), whi.view(np.int32))
+    assert np.array_equal(gm, wm)
+
+    names = [f"x{j}" for j in range(C)]
+    fr = h2o3_tpu.Frame.from_numpy(dict(zip(names, X)))
+    sorted_values = obs.counter("binning_sketch_values_total", caller="bins")
+    before = sorted_values.value
+    bn = binning.fit_bins(fr, names, nbins=nq + 1, weights=w)
+    assert sorted_values.value - before == C * fr.padded_rows
+    monkeypatch.setattr(binning, "_make_sketch_fn", _float_sort_sketch)
+    ref = binning.fit_bins(fr, names, nbins=nq + 1, weights=w)
+    for e, r in zip(bn.edges, ref.edges):
+        assert np.array_equal(e, r)
+    assert np.array_equal(np.asarray(bn.codes), np.asarray(ref.codes))
+
+
+def test_gam_knots_equal_the_float_sort_and_np_quantile(cl, rng,
+                                                        monkeypatch):
+    """GAM's device knots run the same sketch: equal as numbers to the
+    stable float sort's, and to ``np.quantile`` of the non-missing values
+    up to the sketch's float32 positions."""
+    import h2o3_tpu
+    from h2o3_tpu.models import gam
+    from h2o3_tpu.models.tree import binning
+    from h2o3_tpu.runtime import observability as obs
+    n = 2001
+    cols = {"a": rng.normal(size=n), "b": np.round(rng.exponential(size=n), 1),
+            "c": rng.choice(np.array([-0.0, 0.0, -1.5, 2.0, 3.25]), n)}
+    cols["a"][::9] = np.nan
+    fr = h2o3_tpu.Frame.from_numpy(cols)
+    smooths = [{"cols": [c], "num_knots": k}
+               for c, k in (("a", 10), ("b", 7), ("c", 5))]
+    sorted_values = obs.counter("binning_sketch_values_total",
+                                caller="gam_knots")
+    before = sorted_values.value
+    got = gam._device_knots(fr, smooths)
+    # one sketch a knot count, each over its own columns
+    assert sorted_values.value - before == len(smooths) * fr.padded_rows
+    monkeypatch.setattr(binning, "_make_sketch_fn", _float_sort_sketch)
+    want = gam._device_knots(fr, smooths)
+    for s, g, r in zip(smooths, got, want):
+        assert np.array_equal(g, r)
+        x = cols[s["cols"][0]].astype(np.float32).astype(np.float64)
+        x = x[~np.isnan(x)]
+        q = np.unique(np.quantile(x, np.linspace(0, 1, s["num_knots"])))
+        np.testing.assert_allclose(g, q, rtol=0, atol=1e-5 * np.ptp(x))
+
+
 def test_depth_cap_multinomial_and_default_depth_drf(cl, rng):
     """Dense-level depth cap: a depth request above the cap must produce
     a working (capped) model on every scan driver — the multinomial
